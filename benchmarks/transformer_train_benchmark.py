@@ -22,11 +22,12 @@ per-layer rematerialization by default.
 
 Model FLOPs per token = 6*N + 12*L*d_model*S*0.5 (causal attention),
 the standard accounting (PaLM appendix B convention). Peak chip FLOPs for
-the MFU denominator comes from PEAK_TFLOPS (default 197, TPU v5e bf16).
+the MFU denominator is looked up by ``device_kind`` in
+:data:`PEAK_BF16_TFLOPS`; a device that is not in the table is an error,
+not a default (so the benchmark fails on the CPU before it measures).
 
 Usage: python benchmarks/transformer_train_benchmark.py [d_model] [layers] [seq]
-Env: REMAT=0/1 (default 1 on TPU), ATTN=auto|flash|xla, BATCH, STEPS,
-PEAK_TFLOPS.
+Env: REMAT=0/1 (default 1 on TPU), ATTN=auto|flash|xla, BATCH, STEPS.
 """
 
 from __future__ import annotations
@@ -38,31 +39,32 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-# The flagship measurement shape shared by bench.py's MFU stage and
-# tools/mfu_tune.py — one source of truth so a committed tuning config
-# and warmed compilation cache always describe the program bench.py
-# actually measures.
+# The flagship measurement shape shared by bench.py's MFU stage,
+# tools/mfu_tune.py and chip_smoke.py (which cuts only its depth).
 FLAGSHIP = {"d_model": 2048, "n_layers": 12, "seq": 2048, "vocab": 32768}
 
 
-def enable_compilation_cache():
-    """Point JAX at the repo-local persistent compilation cache so the
-    flagship step compiles once per (program, jaxlib, chip) ever — a
-    driver/bench run on a warm cache skips the multi-minute XLA compile
-    that previously ate the whole measurement budget (VERDICT r2 #1)."""
-    import jax
+# Published bf16 peak of one chip, keyed by ``jax.devices()[0].device_kind``
+# as the installation reports it. Source: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16).
+PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0}
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache",
-    )
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+def peak_tflops_for(device_kind: str) -> float:
+    try:
+        return PEAK_BF16_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAK_BF16_TFLOPS)} — add it with its source, do not "
+            "default it"
+        ) from None
 
 
 def run(d_model=512, n_layers=8, seq=1024, batch=8, steps=20, remat=None,
-        attn="auto", peak_tflops=197.0, vocab=8192):
+        attn="auto", vocab=8192):
+    from rayfed_tpu.utils import enable_compilation_cache
+
     enable_compilation_cache()
 
     import jax
@@ -72,7 +74,6 @@ def run(d_model=512, n_layers=8, seq=1024, batch=8, steps=20, remat=None,
     from rayfed_tpu.models import transformer as tfm
     from rayfed_tpu.parallel import sharding as shd
     from rayfed_tpu.parallel.train import make_fed_train_step
-
     from rayfed_tpu.utils import is_tpu_backend
 
     on_tpu = is_tpu_backend()
@@ -89,6 +90,7 @@ def run(d_model=512, n_layers=8, seq=1024, batch=8, steps=20, remat=None,
         n_layers=n_layers, d_ff=int(d_model * 2.75) // 16 * 16,
     )
     devices = jax.devices()
+    peak_tflops = peak_tflops_for(devices[0].device_kind)
     mesh = Mesh(np.array(devices).reshape(len(devices)), ("data",))
     init_fn, step_fn = make_fed_train_step(
         cfg, mesh, party_axis=None, data_axis="data", lr=1e-3, remat=remat,
@@ -123,6 +125,7 @@ def run(d_model=512, n_layers=8, seq=1024, batch=8, steps=20, remat=None,
     mfu = tok_s * flops_per_token / (peak_tflops * 1e12 * len(devices))
     result = {
         "backend": jax.default_backend(),
+        "device_kind": devices[0].device_kind,
         "devices": len(devices),
         "n_params": n_params,
         "batch": batch,
@@ -162,7 +165,6 @@ def main():
         steps=int(os.environ.get("STEPS", 20)),
         remat=remat,
         attn=os.environ.get("ATTN", "auto"),
-        peak_tflops=float(os.environ.get("PEAK_TFLOPS", 197.0)),
         vocab=int(os.environ.get("VOCAB", 8192)),
     )
 

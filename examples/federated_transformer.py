@@ -15,7 +15,9 @@
 """Federated LM training: each party runs a dp/tp(/sp)-sharded train step
 on its own device mesh; weight trees cross per round via the push lane.
 
-Run once per party (CPU simulation shown; on TPU hosts drop the env vars):
+Run once per party (CPU simulation shown; on a TPU host each party process
+must first be restricted to its own chips — examples/README.md, and
+chip_smoke.py is this path at the flagship width on the chip):
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/federated_transformer.py alice 127.0.0.1:9111 127.0.0.1:9112

@@ -192,7 +192,14 @@ def get_global_context() -> Optional[GlobalContext]:
 
 def clear_global_context(wait_for_sending: bool = False) -> None:
     with _context_lock:
-        ctx = _contexts.pop()
+        ctx = _contexts.peek()
         if ctx is not None:
+            # Drain BEFORE the context goes away: a failed data send is
+            # replaced by an error envelope from inside the drain, and
+            # barriers.send only tracks a send it can find a context for.
+            # Popping first left that envelope untracked — dropped with
+            # the proxies if the peer was not reachable yet, and the peer
+            # then waited forever on a value that could never come.
             ctx.get_cleanup_manager().stop(wait_for_sending=wait_for_sending)
+            _contexts.pop()
             ctx.get_executor().shutdown(wait=False)

@@ -53,12 +53,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.7 promotes shard_map to the top-level namespace
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: same callable, experimental home
-    from jax.experimental.shard_map import shard_map
 
 
 def party_axis_mesh(n_parties: int, devices=None, inner_axes=("data",),

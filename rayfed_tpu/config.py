@@ -469,11 +469,18 @@ class PartyMeshConfig:
             (None = all local devices).
         mesh_shape: logical mesh shape over those devices.
         axis_names: logical axis names, e.g. ("data", "model").
+        platform: the platform every mesh device must report ("tpu",
+            "cpu"). A party configured for the chip sets "tpu" and
+            ``fed.init`` raises when jax came up on anything else —
+            with ``JAX_PLATFORMS`` unset jax itself falls back to the
+            CPU when it cannot get the chip. None (default) takes
+            whatever ``jax.devices()`` returns.
     """
 
     device_ids: Optional[List[int]] = None
     mesh_shape: Optional[List[int]] = None
     axis_names: Optional[List[str]] = None
+    platform: Optional[str] = None
 
     @classmethod
     def from_dict(cls, data: Optional[Dict[str, Any]]) -> "PartyMeshConfig":

@@ -57,7 +57,9 @@ RETRY_POLICY_FIELDS = frozenset({
     "max_backoff_ms",
 })
 
-PARTY_MESH_FIELDS = frozenset({"axis_names", "device_ids", "mesh_shape"})
+PARTY_MESH_FIELDS = frozenset({
+    "axis_names", "device_ids", "mesh_shape", "platform",
+})
 
 SERVING_FIELDS = frozenset({
     "eos_id", "kv_block_size", "kv_blocks", "kv_layout", "max_len",

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import logging
 import math
+import os
 from typing import List, Optional
 
 from rayfed_tpu.config import PartyMeshConfig
@@ -75,10 +76,13 @@ def build_mesh(
     device_ids: Optional[List[int]] = None,
     mesh_shape: Optional[List[int]] = None,
     axis_names: Optional[List[str]] = None,
+    platform: Optional[str] = None,
 ):
     """Create a ``jax.sharding.Mesh`` over the selected local devices.
 
     Defaults: all local devices, 1-D mesh on axis ``("data",)``.
+    ``platform`` demands that every mesh device reports that platform
+    and raises otherwise (see :class:`PartyMeshConfig`).
     """
     import jax
     import numpy as np
@@ -86,6 +90,15 @@ def build_mesh(
     devices = jax.devices()
     if device_ids is not None:
         devices = [devices[i] for i in device_ids]
+    if platform is not None:
+        found = sorted({d.platform for d in devices})
+        if found != [platform]:
+            raise RuntimeError(
+                f"party mesh demands platform {platform!r} but jax "
+                f"came up on {found} ({len(devices)} device(s), "
+                f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}); "
+                "refusing to run this party on another backend"
+            )
     n = len(devices)
     if mesh_shape is None:
         mesh_shape = [n]
@@ -108,7 +121,9 @@ def init_party_mesh(cfg: Optional[PartyMeshConfig] = None):
     """Establish this party's mesh once, at ``fed.init`` time."""
     global _party_mesh, _party_mesh_config
     cfg = cfg or PartyMeshConfig()
-    _party_mesh = build_mesh(cfg.device_ids, cfg.mesh_shape, cfg.axis_names)
+    _party_mesh = build_mesh(
+        cfg.device_ids, cfg.mesh_shape, cfg.axis_names, cfg.platform
+    )
     _party_mesh_config = cfg
     logger.info(
         "Party mesh established: shape=%s axes=%s",

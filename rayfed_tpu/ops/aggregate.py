@@ -253,10 +253,7 @@ def _psum_flat_fn(mesh, n: int, acc_dtype: str, deterministic: bool):
     the shard_map every call (jit's own cache handles leaf shapes)."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     dtype = jnp.dtype(acc_dtype) if acc_dtype else None
 

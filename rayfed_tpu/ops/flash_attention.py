@@ -142,6 +142,7 @@ def _flash_fwd_raw(q, k, v, block_q, block_k, interpret, q_offset):
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     out = jnp.transpose(out.reshape(b, h, sq, d), (0, 2, 1, 3))
     lse = jnp.transpose(lse.reshape(b, h, sq), (0, 2, 1))  # (B, S, H)
@@ -290,6 +291,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, block_q, block_k, interpret,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     kcol_spec = pl.BlockSpec((1, block_k, d), lambda bh, i: (bh, i, 0))
@@ -306,6 +308,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     def unfold(x, s):

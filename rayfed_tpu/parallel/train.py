@@ -35,9 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from jax import shard_map  # requires jax >= 0.7 (axis_names/check_vma API)
 
 from rayfed_tpu.models import transformer as tfm
 from rayfed_tpu.parallel import sharding as shd
@@ -164,6 +163,26 @@ def make_fed_train_step(
         from rayfed_tpu.ops.flash_attention import make_flash_attn_fn
 
         attn_fn = make_flash_attn_fn()
+        # A Mosaic custom call has no GSPMD partitioning rule: on more
+        # than one real chip jax refuses to lower it ("Mosaic kernels
+        # cannot be automatically partitioned"; interpret mode lowers to
+        # plain HLO and hides this on the CPU). Attention is independent
+        # per (batch row, head), so map the kernel over the batch axes
+        # and the head axis: each chip runs its own shard, no gather.
+        batch_axes = tuple(
+            a for a in (party_axis, data_axis)
+            if a and mesh.shape.get(a, 1) > 1
+        )
+        head_axis = "model" if mesh.shape.get("model", 1) > 1 else None
+        if batch_axes or head_axis:
+            qkv_spec = P(batch_axes or None, None, head_axis, None)
+            attn_fn = shard_map(
+                attn_fn,
+                mesh=mesh,
+                in_specs=(qkv_spec, qkv_spec, qkv_spec),
+                out_specs=qkv_spec,
+                check_vma=False,
+            )
     else:
         attn_fn = None
 

@@ -267,10 +267,7 @@ def _modsum_fn(mesh, n: int):
     needed, every association order gives the same words."""
     import jax
 
-    try:
-        from jax import shard_map
-    except ImportError:  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(local_tree):

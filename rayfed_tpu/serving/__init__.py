@@ -24,8 +24,8 @@ keep landing new aggregates:
  - :mod:`rayfed_tpu.serving.kv_pool` — the KV store, two layouts:
    the contiguous slab and the block-granular paged pool (block tables,
    on-demand grants, prefix reuse by table sharing);
- - :mod:`rayfed_tpu.serving.publish` — versioned atomic hot model swap,
-   shm zero-copy snapshot adoption;
+ - :mod:`rayfed_tpu.serving.publish` — versioned atomic hot model swap
+   over device-resident snapshots;
  - :mod:`rayfed_tpu.serving.stream` — incremental token streaming over
    the inline lane;
  - :mod:`rayfed_tpu.serving.client` — ``fed.serve()`` /

@@ -139,6 +139,10 @@ class KVPool:
     def nbytes(self) -> int:
         return int(self._k.nbytes) + int(self._v.nbytes)
 
+    def jitted_fns(self):
+        """The pool's jitted programs (compile accounting)."""
+        return [_copy_row]
+
     # -- slot lifecycle --------------------------------------------------
 
     def acquire(self) -> Optional[int]:
@@ -388,8 +392,19 @@ class PagedKVPool:
         )
 
     @property
+    def kv(self):
+        return self._k, self._v
+
+    @property
     def nbytes(self) -> int:
         return int(self._k.nbytes) + int(self._v.nbytes)
+
+    def jitted_fns(self):
+        """The pool's jitted programs (compile accounting)."""
+        return [
+            self._gather_fn, self._gather_row_fn, self._scatter_step_fn,
+            self._scatter_rows_fn, self._scatter_row_fn, _copy_block,
+        ]
 
     # -- slot + block lifecycle ------------------------------------------
 

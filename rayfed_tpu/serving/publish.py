@@ -36,65 +36,32 @@ from typing import Any, Dict, List, Optional, Tuple
 from rayfed_tpu import tree_util
 
 
-def _shm_backed(x: Any) -> bool:
-    """True when a numpy array's buffer bottoms out in a native shm-ring
-    chunk (``_fastwire.ShmBuf``). Those views are receiver-owned and
-    release-on-dealloc (proxy/lanes.py): nothing reuses the chunk while
-    a reference is alive, so holding one IS a stable snapshot."""
-    try:
-        from rayfed_tpu import _fastwire
-    except Exception:  # noqa: BLE001 - native wire not built
-        return False
-    shm_buf = getattr(_fastwire, "ShmBuf", None)
-    if shm_buf is None:
-        return False
-    seen = 0
-    base = getattr(x, "base", None)
-    while base is not None and seen < 8:
-        if isinstance(base, shm_buf):
-            return True
-        if isinstance(base, memoryview):
-            base = base.obj
-        else:
-            base = getattr(base, "base", None)
-        seen += 1
-    return isinstance(base, shm_buf)
+def snapshot_tree(params: Any) -> Any:
+    """Donation/reuse-proof, device-resident capture of a param tree.
 
-
-def snapshot_tree(params: Any) -> Tuple[Any, int]:
-    """Donation/reuse-proof capture of a param tree; returns
-    ``(snapshot, zero_copy_leaves)``.
-
-    jax.Array leaves are device-copied (a later donation of the caller's
-    tree cannot invalidate ours) and plain numpy leaves are host-copied
-    (a recv-pool buffer may be recycled once the caller drops it) — with
-    ONE exception: a numpy leaf whose storage is a native shm-ring chunk
-    (:func:`_shm_backed`) is adopted by reference. The chunk is pinned
-    until the snapshot is retired and nobody else can write it, so a
-    cross-party publish of a just-received tree moves zero param bytes.
-    The tree structure is preserved leaf-for-leaf (same treedef the
-    checkpoint lane serializes), so shardings and dtypes survive."""
+    jax.Array leaves are device-copied, keeping their sharding (a later
+    donation of the caller's tree cannot invalidate ours). NumPy leaves
+    — a tree that crossed the wire on the plain socket lane — are
+    uploaded ONCE, here, with ``may_alias=False`` (the host buffer may
+    be a recv-pool or shm-ring chunk that is recycled once the caller
+    drops it). The engine passes the snapshot into its jitted step on
+    every iteration, so a leaf left on the host would be re-uploaded
+    whole per decoded token. The tree structure is preserved
+    leaf-for-leaf (same treedef the checkpoint lane serializes)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    adopted = 0
-
     def leaf(x):
-        nonlocal adopted
         if isinstance(x, jax.Array):
             # jnp.array(copy=True) always materializes new buffers.
             return jnp.array(x, copy=True)
         if isinstance(x, np.ndarray):
-            if _shm_backed(x):
-                adopted += 1
-                return x
-            return np.array(x, copy=True)
+            return jax.device_put(x, may_alias=False)
         return x
 
     leaves, spec = tree_util.tree_flatten(params)
-    out = tree_util.tree_unflatten([leaf(x) for x in leaves], spec)
-    return out, adopted
+    return tree_util.tree_unflatten([leaf(x) for x in leaves], spec)
 
 
 class ModelBank:
@@ -114,30 +81,20 @@ class ModelBank:
         self._extras: Dict[int, Dict[str, Any]] = {}
         self._refs: Dict[int, int] = {}
         self._swap_log: List[Tuple[int, float]] = []
-        self._zerocopy_adopted = 0
 
-    def publish(self, params: Any, *, copy: bool = True, **extras) -> int:
+    def publish(self, params: Any, **extras) -> int:
         """Install ``params`` as the next version; returns its number.
 
-        The snapshot is taken OUTSIDE the lock (it may device-copy a big
-        tree) and the swap itself is a single assignment under it.
-        ``extras`` (e.g. ``draft_params`` for speculative serving) are
-        snapshotted and retired together with the version.
+        The snapshot is taken OUTSIDE the lock (it device-copies or
+        uploads a big tree) and the swap itself is a single assignment
+        under it. ``extras`` (e.g. ``draft_params`` for speculative
+        serving) are snapshotted and retired together with the version.
         """
-        adopted = 0
-        if copy:
-            snap, adopted = snapshot_tree(params)
-            extra_snap = {}
-            for k, v in extras.items():
-                if v is None:
-                    continue
-                extra_snap[k], n = snapshot_tree(v)
-                adopted += n
-        else:
-            snap = params
-            extra_snap = {k: v for k, v in extras.items() if v is not None}
+        snap = snapshot_tree(params)
+        extra_snap = {
+            k: snapshot_tree(v) for k, v in extras.items() if v is not None
+        }
         with self._lock:
-            self._zerocopy_adopted += adopted
             version = self._current + 1
             self._snapshots[version] = snap
             self._extras[version] = extra_snap
@@ -194,12 +151,6 @@ class ModelBank:
         with self._lock:
             return len(self._swap_log)
 
-    def zerocopy_adopted(self) -> int:
-        """Total param-tree leaves this bank adopted by reference from
-        the native shm ring instead of copying (publish + restore)."""
-        with self._lock:
-            return self._zerocopy_adopted
-
     # -- state handoff (HA, docs/ha.md) -------------------------------
 
     def export_state(self) -> Dict[str, Any]:
@@ -225,15 +176,13 @@ class ModelBank:
         version = int(state.get("version") or 0)
         if version <= 0 or state.get("params") is None:
             return self.current_version()
-        snap, adopted = snapshot_tree(state["params"])
-        extra_snap = {}
-        for k, v in (state.get("extras") or {}).items():
-            if v is None:
-                continue
-            extra_snap[k], n = snapshot_tree(v)
-            adopted += n
+        snap = snapshot_tree(state["params"])
+        extra_snap = {
+            k: snapshot_tree(v)
+            for k, v in (state.get("extras") or {}).items()
+            if v is not None
+        }
         with self._lock:
-            self._zerocopy_adopted += adopted
             if version <= self._current:
                 return self._current
             self._snapshots[version] = snap

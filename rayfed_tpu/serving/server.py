@@ -191,7 +191,6 @@ class InferenceServer:
         self._rid_counter = itertools.count()
         self._stopping = False
         self._fatal: Optional[BaseException] = None
-        self._last_zerocopy = 0
         self._stats = {
             "submitted": 0,
             "completed": 0,
@@ -202,7 +201,6 @@ class InferenceServer:
             "prefill_chunks": 0,
             "streamed_tokens": 0,
             "preempted": 0,
-            "publish_zerocopy": 0,
         }
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
@@ -265,11 +263,6 @@ class InferenceServer:
         self._m_preempted = _reg.counter(
             "fed_serving_preemptions_total",
             "Requests preempted to break a KV block-pool deadlock.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_zerocopy = _reg.counter(
-            "fed_serving_publish_zerocopy_total",
-            "Published leaves adopted as zero-copy shm views.",
             labels=("server",),
         ).labels(server=name)
         self._update_kv_gauges()
@@ -425,19 +418,10 @@ class InferenceServer:
 
     def publish(self, params: Any, *, draft_params: Any = None) -> int:
         """Atomically install a new model version; in-flight requests
-        finish on the version they pinned at admission. Leaves that
-        arrived as shm-ring views are adopted zero-copy (the bank's
-        reference keeps the receiver-owned chunk alive — no adoption
-        copy); the saved copies show up in
-        ``fed_serving_publish_zerocopy_total``."""
+        finish on the version they pinned at admission. The bank's
+        snapshot is device-resident (NumPy leaves are uploaded once,
+        here) — the jitted step receives it on every iteration."""
         version = self.bank.publish(params, draft_params=draft_params)
-        adopted = self.bank.zerocopy_adopted()
-        if adopted > self._last_zerocopy:
-            delta = adopted - self._last_zerocopy
-            self._last_zerocopy = adopted
-            self._m_zerocopy.inc(delta)
-            with self._lock:
-                self._stats["publish_zerocopy"] += delta
         tracing.record_request(
             f"publish-v{version}", "publish", version=version
         )
@@ -566,6 +550,16 @@ class InferenceServer:
                 self.pool.max_slots - self.pool.free_count
             )
             out["kv_blocks_free"] = self.pool.free_count
+        # Compiled variants across the engine's jitted programs: flat
+        # after warm-up, or something (a new bucket, a published tree
+        # with another sharding) is compiling inside the serving window.
+        out["compiled_programs"] = sum(
+            fn._cache_size() for fn in (
+                self._step_fn, *self._prefill_fns.values(),
+                *self._paged_prefill_fns.values(),
+                *self._chunk_fns.values(), *self.pool.jitted_fns(),
+            )
+        )
         out["current_version"] = self.bank.current_version()
         out["swaps"] = self.bank.swap_count()
         out["live_versions"] = self.bank.live_versions()

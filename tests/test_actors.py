@@ -123,6 +123,24 @@ def test_actor_constructor_error_propagates():
     run_parties(run_actor_error, ["alice", "bob"])
 
 
+def run_actor_error_peer_late(party, addresses):
+    import time
+
+    if party == "bob":
+        # alice fails, gets her own error and shuts down before bob's
+        # receiver exists: the envelope that replaces her failed push is
+        # still in its connect retries when her drain starts.
+        time.sleep(1.0)
+    run_actor_error(party, addresses)
+
+
+def test_error_envelope_outlives_the_producers_shutdown():
+    """The envelope is sent from inside the shutdown drain; it used to be
+    untracked there (the global context was popped first), dropped with
+    the proxies, and the late peer waited forever."""
+    run_parties(run_actor_error_peer_late, ["alice", "bob"], timeout=60)
+
+
 def run_kill(party, addresses):
     import time
 

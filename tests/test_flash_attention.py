@@ -151,3 +151,50 @@ def test_train_step_with_flash_attn_and_chunked_loss():
     np.testing.assert_allclose(
         float(chunked), float(dense), rtol=1e-5, atol=1e-5
     )
+
+
+def test_flash_train_step_is_mapped_over_batch_and_heads(monkeypatch):
+    """On more than one real chip a Mosaic call cannot be partitioned by
+    GSPMD: jax refuses to lower it ("Mosaic kernels cannot be
+    automatically partitioned") — which interpret mode hides on the CPU.
+    make_fed_train_step maps the kernel over the batch and head axes
+    instead; lowering the step for TPU on a data x model mesh shows each
+    device's kernel working on its (batch/2) x (heads/2) shard."""
+    import re
+
+    import numpy as onp
+    from jax.sharding import Mesh, NamedSharding
+
+    import rayfed_tpu.utils as utils
+    from rayfed_tpu.parallel import sharding as shd
+    from rayfed_tpu.parallel.train import make_fed_train_step
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    cfg = tfm.tiny_config(d_model=256, n_heads=4, n_layers=1, d_ff=256)
+    mesh = Mesh(onp.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    _, step_fn = make_fed_train_step(
+        cfg, mesh, party_axis=None, attn="auto", donate=False
+    )
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)
+    )
+    from rayfed_tpu.parallel.train import make_optimizer
+
+    opt_state = jax.eval_shape(make_optimizer().init, params)
+    batch = jax.ShapeDtypeStruct(
+        (4, 128), jnp.int32,
+        sharding=NamedSharding(mesh, shd.batch_spec(mesh, party_axis=None)),
+    )
+    text = step_fn.trace(params, opt_state, batch, batch).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    kernels = re.findall(
+        r'@tpu_custom_call\((.*?)\).*?kernel_name = "(\w+)".*?'
+        r"-> \(?tensor<(\d+)x128x64x",
+        text,
+    )
+    assert {name for _, name, _ in kernels} == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"
+    }, kernels
+    # 4 rows x 4 heads over a 2 x 2 mesh: each device folds 2 x 2 = 4.
+    assert {lead for _, _, lead in kernels} == {"4"}, kernels

@@ -29,7 +29,7 @@ import numpy as np
 import pytest
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec, PositionalSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from rayfed_tpu._private import serialization as ser
 from rayfed_tpu._private.constants import CODE_INTERNAL_ERROR, CODE_OK
@@ -196,14 +196,14 @@ needs_reactor = pytest.mark.skipif(
 
 def _big_tree(pmesh):
     # "w": 2 MB sharded 4-way -> four 512 KB shard buffers (stripes split
-    # at these boundaries); "p": positionally-sharded; "b": tiny dense.
+    # at these boundaries); "p": committed to one device (not a
+    # NamedSharding, so it rides the dense leaf path); "b": tiny dense.
     host_w = np.arange(4 * 131072, dtype=np.float32).reshape(4, 131072)
     host_p = np.arange(4 * 4096, dtype=np.float32).reshape(4, 4096)
     host_b = np.arange(16, dtype=np.float32)
-    psharding = PositionalSharding(jax.devices()[:4]).reshape(4, 1)
     tree = {
         "w": _sharded(host_w, pmesh, PartitionSpec("data")),
-        "p": jax.device_put(host_p, psharding),
+        "p": jax.device_put(host_p, jax.devices()[1]),
         "b": _sharded(host_b, pmesh, PartitionSpec()),
     }
     return tree, {"w": host_w, "p": host_p, "b": host_b}

@@ -19,15 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:
-    pytest.skip(
-        "requires jax >= 0.7 (top-level jax.shard_map API)",
-        allow_module_level=True,
-    )
 
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
 from rayfed_tpu.parallel import sharding as shd  # noqa: E402

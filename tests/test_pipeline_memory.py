@@ -30,15 +30,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-try:
-    from jax import shard_map  # noqa: F401 - probe for the pipeline dep
-except ImportError:
-    pytest.skip(
-        "requires jax >= 0.7 (top-level jax.shard_map API, used by "
-        "rayfed_tpu.parallel.pipeline)",
-        allow_module_level=True,
-    )
-
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
 from rayfed_tpu.parallel.pipeline import (  # noqa: E402
     make_1f1b_loss_and_grad,

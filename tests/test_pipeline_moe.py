@@ -19,16 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh
-
-try:
-    from jax import shard_map  # noqa: F401 - probe for the moe/pp dep
-except ImportError:
-    pytest.skip(
-        "requires jax >= 0.7 (top-level jax.shard_map API, used by "
-        "rayfed_tpu.models.moe and rayfed_tpu.parallel.pipeline)",
-        allow_module_level=True,
-    )
 
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
 from rayfed_tpu.models.moe import (  # noqa: E402

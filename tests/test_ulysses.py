@@ -25,14 +25,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map
-except ImportError:
-    pytest.skip(
-        "requires jax >= 0.7 (top-level jax.shard_map API)",
-        allow_module_level=True,
-    )
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from rayfed_tpu.models import transformer as tfm  # noqa: E402

@@ -21,7 +21,7 @@ frames, once over the device-DMA descriptor lane — and FAILS LOUDLY
 CPU-sim throughput. The DMA lane's bound here is the jax transfer
 engine itself, so this gate asks the load-bearing question for the
 sharded data plane: does striping across K sockets still out-run the
-single-tunnel engine path it exists to replace? A change that quietly
+single-stream engine path it exists to replace? A change that quietly
 serializes the stripe lanes (one lane doing all the bytes), breaks the
 stripe planner's balancing, or re-adds a full-payload staging copy
 turns the build red.

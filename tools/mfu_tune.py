@@ -14,17 +14,17 @@
 
 """On-hardware MFU tuning sweep for the flagship train step.
 
-Run this ON the TPU host whenever the accelerator is reachable:
+Run this ON the TPU host:
 
-    python tools/mfu_tune.py            # sweep, print, write best config
-    python tools/mfu_tune.py --dry      # sweep + print only
+    python tools/mfu_tune.py            # sweep and print the winner
 
-Each candidate runs in its own subprocess (a config that OOMs or wedges
-must not kill the sweep) with the persistent compilation cache enabled —
-so the sweep doubles as the cache PRE-WARM for bench.py's MFU stage: the
-winning config's executable is cached when the driver measures it.
-Writes the winner to ``benchmarks/mfu_config.json`` (read by bench.py,
-env still overrides)."""
+Each candidate runs in its own subprocess (a chip belongs to one process;
+a config that OOMs or wedges must not kill the sweep) with the persistent
+compilation cache enabled (``rayfed_tpu.utils.enable_compilation_cache``:
+``JAX_COMPILATION_CACHE_DIR`` when set, else the checkout's git-ignored
+``.jax_cache``) — so the sweep doubles as the cache pre-warm for
+bench.py's MFU stage on the same machine. Pass the winner to that stage
+through ``FEDTPU_MFU_BATCH`` / ``FEDTPU_MFU_REMAT``."""
 
 from __future__ import annotations
 
@@ -54,12 +54,7 @@ def run_candidate(cfg: dict, steps: int, timeout_s: int) -> dict | None:
     code = (
         "import sys, json\n"
         f"sys.path.insert(0, {os.path.join(HERE, 'benchmarks')!r})\n"
-        "from transformer_train_benchmark import run, enable_compilation_cache\n"
-        "enable_compilation_cache()\n"
-        "import jax\n"
-        "from rayfed_tpu.utils import is_tpu_backend\n"
-        "if not is_tpu_backend():\n"
-        "    sys.exit(3)\n"
+        "from transformer_train_benchmark import run\n"
         "from contextlib import redirect_stdout\n"
         "from transformer_train_benchmark import FLAGSHIP\n"
         "remat = CFGREMAT\n"
@@ -97,8 +92,6 @@ def run_candidate(cfg: dict, steps: int, timeout_s: int) -> dict | None:
 
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--dry", action="store_true",
-                        help="sweep and print, do not write the config")
     parser.add_argument("--steps", type=int, default=6)
     parser.add_argument("--timeout", type=int, default=900,
                         help="per-candidate budget (cold compiles included)")
@@ -112,13 +105,11 @@ def main() -> int:
     if best is None:
         print("no candidate completed (accelerator down?)", file=sys.stderr)
         return 1
-    winner = {**best_cfg, "steps": 10, "measured_mfu": round(best["mfu"], 4)}
-    print(f"winner: {winner}")
-    if not args.dry:
-        path = os.path.join(HERE, "benchmarks", "mfu_config.json")
-        with open(path, "w") as f:
-            json.dump(winner, f, indent=1)
-        print(f"wrote {path} — commit it together with the warmed .jax_cache")
+    print(
+        f"winner: {best_cfg} (measured_mfu {best['mfu']:.4f}) — run the "
+        f"bench stage with FEDTPU_MFU_BATCH={best_cfg['batch']} "
+        f"FEDTPU_MFU_REMAT={best_cfg['remat']} python bench.py --train-mfu"
+    )
     return 0
 
 
