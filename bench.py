@@ -112,11 +112,8 @@ def _party_entry(target, party, *rest):
                 # when the main thread re-enters the interpreter loop);
                 # the C-level faulthandler stacks below always land.
                 try:
-                    tracing.export_timeline(
-                        os.path.join(d, f"{party}.timeline"), party
-                    )
-                    # Structured twin: feed to tools/trace_view.py for
-                    # a per-seq-id text flamegraph of the wedge.
+                    # Feed to tools/trace_view.py for a per-seq-id text
+                    # flamegraph of the wedge.
                     tracing.export_seq_timeline(
                         os.path.join(d, f"{party}.seq.json"), party
                     )

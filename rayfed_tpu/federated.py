@@ -37,6 +37,7 @@ from typing import Any, Dict, Optional, Sequence
 
 import rayfed_tpu as fed
 from rayfed_tpu import topology as topo
+from rayfed_tpu import tracing
 from rayfed_tpu.telemetry import metrics as telemetry_metrics
 
 _m_aggregates = telemetry_metrics.get_registry().counter(
@@ -50,7 +51,10 @@ _m_aggregates = telemetry_metrics.get_registry().counter(
 def _agg_kary_sum(*trees):
     from rayfed_tpu.ops.aggregate import tree_sum
 
-    return tree_sum(*trees)
+    # fed:agg:reduce: the host side of a reducer task, i.e. the dispatch
+    # of the jitted fold (and, below, of the scale that makes it a mean).
+    with tracing.phase("fed:agg:reduce"):
+        return tree_sum(*trees)
 
 
 @fed.remote
@@ -58,18 +62,20 @@ def _agg_kary_weighted(*pairs):
     # pairs: (tree, weight) partials; returns (weighted-sum tree, total).
     from rayfed_tpu.ops.aggregate import tree_sum
 
-    trees = [t for t, _ in pairs]
-    total = pairs[0][1]
-    for _, w in pairs[1:]:
-        total = total + w
-    return tree_sum(*trees), total
+    with tracing.phase("fed:agg:reduce"):
+        trees = [t for t, _ in pairs]
+        total = pairs[0][1]
+        for _, w in pairs[1:]:
+            total = total + w
+        return tree_sum(*trees), total
 
 
 @fed.remote
 def _scale(tree, denom):
     import jax
 
-    return jax.tree_util.tree_map(lambda x: x / denom, tree)
+    with tracing.phase("fed:agg:reduce"):
+        return jax.tree_util.tree_map(lambda x: x / denom, tree)
 
 
 @fed.remote
@@ -77,7 +83,8 @@ def _scale_weighted(pair):
     import jax
 
     tree, total = pair
-    return jax.tree_util.tree_map(lambda x: x / total, tree)
+    with tracing.phase("fed:agg:reduce"):
+        return jax.tree_util.tree_map(lambda x: x / total, tree)
 
 
 @fed.remote
@@ -101,9 +108,10 @@ def _agg_psum_flat(parties, weights, *trees):
 
     plan = topo_mod.plan(list(parties), "flat")
     contributions = dict(zip(parties, trees))
-    if mesh_mod.composed_mesh_for(plan.parties) is None:
-        return reduce_by_plan(plan, contributions, weights=weights)
-    return psum_by_plan(plan, contributions, weights=weights)
+    with tracing.phase("fed:agg:reduce"):
+        if mesh_mod.composed_mesh_for(plan.parties) is None:
+            return reduce_by_plan(plan, contributions, weights=weights)
+        return psum_by_plan(plan, contributions, weights=weights)
 
 
 @fed.remote

@@ -276,7 +276,17 @@ def lm_loss_pair(params: Params, inputs, targets, cfg: TransformerConfig,
     never materialize — at 32k vocab they dominate step memory. Leave None
     when the sequence dim is sharded (chunking reshapes S).
     """
-    x = hidden_states(params, inputs, cfg, attn_fn, remat=remat)
+    # Named scopes are metadata on the device operations (a profile groups
+    # by them; the backward shows as transpose(jvp(train/forward))); they
+    # change no computation.
+    with jax.named_scope("train/forward"):
+        x = hidden_states(params, inputs, cfg, attn_fn, remat=remat)
+    with jax.named_scope("train/loss_head"):
+        return _head_loss(x, params, targets, cfg, loss_chunk)
+
+
+def _head_loss(x, params: Params, targets, cfg: TransformerConfig,
+               loss_chunk: Optional[int]) -> jax.Array:
     w = params["lm_head"].astype(cfg.compute_dtype)
     if not loss_chunk or x.shape[1] % loss_chunk:
         logits = (x @ w).astype(jnp.float32)

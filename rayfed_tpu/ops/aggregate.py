@@ -44,6 +44,7 @@ def _fold_sum(leaves: Sequence[Any], acc_dtype):
 
 
 @functools.partial(jax.jit, static_argnames=("acc_dtype",))
+@jax.named_scope("aggregate/sum")
 def _tree_sum(trees, acc_dtype: Optional[str] = "float32"):
     dtype = jnp.dtype(acc_dtype) if acc_dtype else None
     return jax.tree_util.tree_map(
@@ -52,6 +53,7 @@ def _tree_sum(trees, acc_dtype: Optional[str] = "float32"):
 
 
 @functools.partial(jax.jit, static_argnames=("acc_dtype",))
+@jax.named_scope("aggregate/mean")
 def _tree_mean(trees, acc_dtype: Optional[str] = "float32"):
     n = len(trees)
     dtype = jnp.dtype(acc_dtype) if acc_dtype else None
@@ -61,6 +63,7 @@ def _tree_mean(trees, acc_dtype: Optional[str] = "float32"):
 
 
 @functools.partial(jax.jit, static_argnames=("acc_dtype",))
+@jax.named_scope("aggregate/mean")
 def _tree_weighted_mean(trees, weights, acc_dtype: Optional[str] = "float32"):
     dtype = jnp.dtype(acc_dtype) if acc_dtype else None
     total = _fold_sum([jnp.asarray(w) for w in weights], dtype)

@@ -249,8 +249,9 @@ def make_fed_train_step(
 
     def step(params, opt_state, inputs, targets):
         loss, grads = grad_step(params, inputs, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("train/optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     if shard_opt_state:

@@ -31,7 +31,7 @@ import time
 from concurrent.futures import Future
 from typing import Callable, Dict, Optional, Type
 
-from rayfed_tpu import sanitize
+from rayfed_tpu import sanitize, tracing
 from rayfed_tpu._private.constants import PING_SEQ_ID
 from rayfed_tpu._private.global_context import get_global_context
 from rayfed_tpu.exceptions import FedRemoteError
@@ -538,7 +538,8 @@ def _capture_for_send(dest_party: str, data):
         # else.
         if dma_lane and _dma_eligible(value):
             return value
-        return _host_snapshot(value)
+        with tracing.phase("fed:wire:encode"):
+            return _host_snapshot(value)
 
     if not isinstance(data, Future):
         return capture(data)

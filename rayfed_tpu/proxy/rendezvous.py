@@ -582,13 +582,19 @@ class RendezvousStore:
                 self._arrived[key] = (header, payload)
             else:
                 self._mark_consumed(key)
+        # Frames of 1 MiB and more carry the reactor's stamp of when their
+        # payload began to arrive: their "recv" has a duration. Smaller
+        # frames (and the other transports') stay arrival instants.
+        recv_t0 = header.pop(tracing.RECV_T0_KEY, None)
+        timed = isinstance(recv_t0, float)  # a stamp off the wire is not
         if tracing.is_enabled():
             import time
 
             tracing.record(
                 "recv", header.get("src", ""), header["up"], header["down"],
                 serialization.payload_nbytes(payload),
-                time.perf_counter(),
+                recv_t0 if timed else time.perf_counter(),
+                **({"timed": True} if timed else {}),
             )
         if waiter is not None:
             self._deliver(header, payload, waiter, nbytes)

@@ -63,7 +63,7 @@ from typing import Callable, Dict, List, Optional
 
 import msgpack
 
-from rayfed_tpu import sanitize
+from rayfed_tpu import sanitize, tracing
 from rayfed_tpu.proxy.tcp import sockio, wire
 from rayfed_tpu.proxy.tcp.pipeline import _Inflight, _m_crc_resends
 from rayfed_tpu.resilience import inject as fault_inject
@@ -481,6 +481,8 @@ class _FrameReader:
         self._ftype = 0
         self._plen = 0
         self._header: Optional[Dict] = None
+        # Open fed:wire:recv phase of the large frame being read, if any.
+        self._recv_phase: Optional[tracing.phase] = None
         self._reset()
 
     def _reset(self) -> None:
@@ -547,6 +549,13 @@ class _FrameReader:
             self._reset()
             return frame
         self._stage = "payload"
+        if plen >= tracing.TIMED_RECV_MIN_BYTES:
+            # The header is in and the payload's first bytes are next: a
+            # large frame's arrival gets a start, so "recv" has a duration
+            # (tracing on) and the read is on the profiler's clock.
+            self._recv_phase = tracing.phase("fed:wire:recv", nbytes=plen)
+            self._recv_phase.__enter__()
+            self._header[tracing.RECV_T0_KEY] = time.perf_counter()
         sizes = sockio._segment_sizes(self._header, plen)
         self._bufs = []
         if sizes is None:
@@ -569,6 +578,9 @@ class _FrameReader:
     def _assemble(self):
         from rayfed_tpu._private import serialization
 
+        if self._recv_phase is not None:
+            self._recv_phase.__exit__(None, None, None)
+            self._recv_phase = None
         if len(self._bufs) == 1:
             payload = memoryview(self._bufs[0])
         else:

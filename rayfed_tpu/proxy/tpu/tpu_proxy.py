@@ -35,6 +35,7 @@ import logging
 import threading
 from collections import OrderedDict
 
+from rayfed_tpu import tracing
 from rayfed_tpu.proxy import lanes, rendezvous
 from rayfed_tpu.proxy.tcp.tcp_proxy import TcpReceiverProxy, TcpSenderProxy
 
@@ -195,11 +196,15 @@ def _device_placer(allowed_list, allow_pickle: bool = True,
             value = dma.pull(payload, dma_listen_addr,
                              max_bytes=max_decompressed_bytes)
         else:
-            value = base(header, payload)
+            with tracing.phase("fed:wire:deserialize"):
+                value = base(header, payload)
         mesh = _party_mesh()
         if mesh is None:
             return value
-        return _place_tree(value, mesh)
+        # Ends where the host's work ends (device_put has returned), not
+        # where the tree is resident: no block_until_ready is added.
+        with tracing.phase("fed:wire:place"):
+            return _place_tree(value, mesh)
 
     return decode
 

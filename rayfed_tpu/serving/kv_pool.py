@@ -283,6 +283,7 @@ class PagedKVPool:
         T = self.row_len
         R = self.max_slots
 
+        @jax.named_scope("serve/gather")
         def gather(pk, pv, tables):
             # tables: (R, NB) int32. Result rows are bit-identical to the
             # slab layout's cache rows for every granted position; junk
@@ -295,6 +296,7 @@ class PagedKVPool:
 
         self._gather_fn = jax.jit(gather)
 
+        @jax.named_scope("serve/gather")
         def gather_row(pk, pv, table):
             # table: (NB,) int32 -> one (L, T, H, Dh) row.
             L = pk.shape[0]
@@ -305,6 +307,7 @@ class PagedKVPool:
 
         self._gather_row_fn = jax.jit(gather_row)
 
+        @jax.named_scope("serve/scatter")
         def scatter_step(pk, pv, k_slab, v_slab, positions, wblocks, woffs):
             # Extract each row's single written position from the step
             # output and write it through the block table. Junk rows
@@ -325,6 +328,7 @@ class PagedKVPool:
 
         pad = NB * bs - T
 
+        @jax.named_scope("serve/scatter")
         def scatter_rows(pk, pv, k_slab, v_slab, tables):
             # Write whole (R, T)-shaped prefill output back through the
             # scatter tables. Rows that must not land (junk vmap lanes,
@@ -345,6 +349,7 @@ class PagedKVPool:
             scatter_rows, donate_argnums=(0, 1)
         )
 
+        @jax.named_scope("serve/scatter")
         def scatter_row(pk, pv, k_row, v_row, table):
             L = pk.shape[0]
             H, Dh = pk.shape[-2:]

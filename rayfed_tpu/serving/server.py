@@ -307,6 +307,7 @@ class InferenceServer:
         rows = jax.vmap(one_row, in_axes=(0, 0, 1, 1, None),
                         out_axes=(0, 1, 1))
 
+        @jax.named_scope("serve/decode_step")
         def step(params, k, v, tokens, positions):
             return rows(tokens, positions, k, v, params)
 
@@ -326,6 +327,7 @@ class InferenceServer:
 
         cfg = self.cfg
 
+        @jax.named_scope("serve/prefill")
         def prefill_slot(params, k, v, prompt, slot, last_idx):
             k_row = jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=1)
             v_row = jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
@@ -378,6 +380,7 @@ class InferenceServer:
 
         rows = jax.vmap(one_row, in_axes=(0, 0, None), out_axes=(0, 1, 1))
 
+        @jax.named_scope("serve/prefill")
         def prefill_rows(params, prompts, last_idx):
             return rows(prompts, last_idx, params)
 
@@ -400,6 +403,7 @@ class InferenceServer:
 
         cfg = self.cfg
 
+        @jax.named_scope("serve/chunk")
         def chunk_step(params, k_row, v_row, toks, offset):
             logits, cache = decode.forward_with_cache(
                 params,
@@ -582,13 +586,10 @@ class InferenceServer:
         try:
             while True:
                 with self._cond:
-                    while (
-                        not self._stopping
-                        and not self._pending
-                        and not self._active
-                        and not self._prefilling
-                    ):
-                        self._cond.wait(0.05)
+                    if self._nothing_to_do():
+                        with tracing.phase("fed:serve:idle"):
+                            while self._nothing_to_do():
+                                self._cond.wait(0.05)
                     if self._stopping:
                         # Drain policy: admitted requests (active OR
                         # mid-chunked-prefill) complete, queued ones fail
@@ -615,18 +616,33 @@ class InferenceServer:
                 # the oldest (already-decoding) requests first, so a
                 # preemption's memory cannot be stolen by new work
                 # (which would livelock the batch under block pressure).
-                progressed = self._admit()
+                # The host phases of an iteration (fed:serve:*, on the
+                # profiler's clock) tile it: none encloses another, so an
+                # idle gap of the device is booked to the piece of host
+                # work that filled it (docs/observability.md).
+                with tracing.phase("fed:serve:admit"):
+                    progressed = self._admit()
                 progressed = self._step_groups() or progressed
-                progressed = self._prefill_tick() or progressed
+                with tracing.phase("fed:serve:prefill_chunk"):
+                    progressed = self._prefill_tick() or progressed
                 self._update_kv_gauges()
                 if not progressed and not self._maybe_preempt():
                     # Blocked on something external (another tenant's
                     # quota, a consumer): bounded backoff, not a hot spin.
-                    with self._cond:
+                    with tracing.phase("fed:serve:idle"), self._cond:
                         self._cond.wait(0.005)
         except BaseException as e:  # noqa: BLE001 - fail loud, never hang
             logger.exception("serving[%s]: engine died", self.name)
             self._fail_all(e)
+
+    def _nothing_to_do(self) -> bool:
+        # Caller holds self._cond.
+        return (
+            not self._stopping
+            and not self._pending
+            and not self._active
+            and not self._prefilling
+        )
 
     def _update_kv_gauges(self) -> None:
         if self.layout == "paged":
@@ -1165,71 +1181,87 @@ class InferenceServer:
             reqs = groups[version]
             params = self.bank.get(version)
             if self.layout == "paged":
-                # Grant each live row's next block at this token
-                # boundary; a row that cannot get one sits out the
-                # iteration as junk (and flags itself for the preemption
-                # check) — decode never stalls the whole batch.
-                live = []
-                for req in reqs:
-                    status = self.pool.ensure_blocks(req.slot, req.pos)
-                    if status == "ok":
-                        req.stalled = False
-                        live.append(req)
-                    elif status == "quota" and self._quota_hopeless(req):
-                        self._fail_admitted(req, self._quota_exc(req))
-                    else:
-                        req.stalled = True
+                with tracing.phase("fed:serve:build"):
+                    # Grant each live row's next block at this token
+                    # boundary; a row that cannot get one sits out the
+                    # iteration as junk (and flags itself for the
+                    # preemption check) — decode never stalls the whole
+                    # batch.
+                    live = []
+                    for req in reqs:
+                        status = self.pool.ensure_blocks(req.slot, req.pos)
+                        if status == "ok":
+                            req.stalled = False
+                            live.append(req)
+                        elif status == "quota" and self._quota_hopeless(req):
+                            self._fail_admitted(req, self._quota_exc(req))
+                        else:
+                            req.stalled = True
+                    tables = np.zeros(
+                        (b, self.pool.blocks_per_row), np.int32
+                    )
+                    tokens = np.zeros(b, np.int32)
+                    positions = np.full(b, self.pool.junk_pos, np.int32)
+                    wblocks = np.zeros(b, np.int32)
+                    woffs = np.zeros(b, np.int32)
+                    for req in live:
+                        tables[req.slot] = self.pool.table(req.slot)
+                        tokens[req.slot] = req.out[-1]
+                        positions[req.slot] = req.pos
+                        wblocks[req.slot], woffs[req.slot] = (
+                            self.pool.write_target(req.slot, req.pos)
+                        )
                 if not live:
                     continue
-                tables = np.zeros(
-                    (b, self.pool.blocks_per_row), np.int32
-                )
-                tokens = np.zeros(b, np.int32)
-                positions = np.full(b, self.pool.junk_pos, np.int32)
-                wblocks = np.zeros(b, np.int32)
-                woffs = np.zeros(b, np.int32)
-                for req in live:
-                    tables[req.slot] = self.pool.table(req.slot)
-                    tokens[req.slot] = req.out[-1]
-                    positions[req.slot] = req.pos
-                    wblocks[req.slot], woffs[req.slot] = (
-                        self.pool.write_target(req.slot, req.pos)
+                with tracing.phase("fed:serve:dispatch"):
+                    k_g, v_g = self.pool.gather(tables)
+                    logits, k_s, v_s = self._step_fn(
+                        params, k_g, v_g,
+                        jnp.asarray(tokens), jnp.asarray(positions),
                     )
-                k_g, v_g = self.pool.gather(tables)
-                logits, k_s, v_s = self._step_fn(
-                    params, k_g, v_g,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                )
-                self.pool.scatter_step(k_s, v_s, positions, wblocks, woffs)
+                    self.pool.scatter_step(
+                        k_s, v_s, positions, wblocks, woffs
+                    )
                 reqs = live
             else:
-                tokens = np.zeros(b, np.int32)
-                positions = np.full(b, self.pool.junk_pos, np.int32)
-                for req in reqs:
-                    tokens[req.slot] = req.out[-1]
-                    positions[req.slot] = req.pos
-                k, v = self.pool.kv
-                logits, k, v = self._step_fn(
-                    params, k, v, jnp.asarray(tokens), jnp.asarray(positions)
-                )
-                self.pool.replace(k, v)
+                with tracing.phase("fed:serve:build"):
+                    tokens = np.zeros(b, np.int32)
+                    positions = np.full(b, self.pool.junk_pos, np.int32)
+                    for req in reqs:
+                        tokens[req.slot] = req.out[-1]
+                        positions[req.slot] = req.pos
+                with tracing.phase("fed:serve:dispatch"):
+                    k, v = self.pool.kv
+                    logits, k, v = self._step_fn(
+                        params, k, v,
+                        jnp.asarray(tokens), jnp.asarray(positions),
+                    )
+                    self.pool.replace(k, v)
             self._stats["steps"] += 1
             self._m_steps.inc()
-            logits_np = np.asarray(logits, np.float32)
-            for req in reqs:
-                tok = self._sample(logits_np[req.slot], req)
-                req.out.append(tok)
-                req.pos += 1
-                progressed = True
-                self._emit_token(req, tok)
-                if (
-                    len(req.out) >= req.max_new_tokens
-                    or tok == self.scfg.eos_id
-                ):
-                    with self._lock:
-                        self._active.pop(req.slot, None)
-                        self._m_active.set(len(self._active))
-                    self._finish(req)
+            with tracing.phase("fed:serve:fetch"):
+                logits_np = np.asarray(logits, np.float32)
+            # Sample every live row, then emit every row: two phases, not
+            # a pair per row. Each request draws from its own rng, so the
+            # tokens per (version, prompt, seed) are what they were when
+            # the two interleaved row by row.
+            with tracing.phase("fed:serve:sample"):
+                toks = [self._sample(logits_np[req.slot], req)
+                        for req in reqs]
+            with tracing.phase("fed:serve:emit"):
+                for req, tok in zip(reqs, toks):
+                    req.out.append(tok)
+                    req.pos += 1
+                    progressed = True
+                    self._emit_token(req, tok)
+                    if (
+                        len(req.out) >= req.max_new_tokens
+                        or tok == self.scfg.eos_id
+                    ):
+                        with self._lock:
+                            self._active.pop(req.slot, None)
+                            self._m_active.set(len(self._active))
+                        self._finish(req)
         return progressed
 
     def _sample(self, logits: np.ndarray, req: _Request) -> int:

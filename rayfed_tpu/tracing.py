@@ -17,11 +17,20 @@
 The reference has NO tracing (SURVEY.md §5.1 — only per-proxy op counters).
 This module adds per-transfer spans: every send and receive records
 (kind, peer, seq ids, bytes, duration) into a bounded in-process ring,
-queryable via :func:`get_spans` / :func:`summary`, plus optional forwarding
-into ``jax.profiler.TraceAnnotation`` so transfers line up with device
-timelines in a profiler capture.
+queryable via :func:`get_spans` / :func:`summary`.
 
-Zero overhead when disabled (module-level flag checked before any work).
+Two context managers also put the program's host work on the profiler's
+clock, so a device trace says what the host was doing in an idle gap:
+:class:`span` (one transfer, ``fed:wire:<kind>``) and :class:`phase` (a
+recurring piece of a loop, ``fed:<layer>:<phase>``). Each opens a
+``jax.profiler.TraceAnnotation`` whenever a profiler session is running —
+the session is the switch, ``enable()`` is not needed for it — under a
+FIXED name: ids, peers and byte counts travel as the annotation's
+metadata, never in its name, so a reduction by name sums a cause instead
+of shattering it into one-off events (docs/observability.md).
+
+Off (no ``enable()``, no profiler session) a span or phase costs two flag
+checks and no allocation beyond the context manager itself.
 """
 
 from __future__ import annotations
@@ -32,8 +41,13 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+# TraceMe's static "is a profiler session recording" check (~20 ns): the
+# annotation object is only built while one is.
+_profiling = _TraceAnnotation.is_enabled
+
 _enabled = False  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
-_use_jax_annotations = False  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
 _lock = threading.Lock()  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
 _MAX_SPANS = 10000
 _spans: Deque["Span"] = deque(maxlen=_MAX_SPANS)  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
@@ -43,6 +57,9 @@ _spans: Deque["Span"] = deque(maxlen=_MAX_SPANS)  # fedlint: disable=global-muta
 _span_seq = 0  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
 _MAX_REQUEST_EVENTS = 20000
 _request_events: Deque["RequestEvent"] = deque(maxlen=_MAX_REQUEST_EVENTS)  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
+# Recurring phases (``phase``) accumulate per name instead of appending to
+# the span ring: {name: [count, seconds, max_s]}.
+_phases: Dict[str, List[float]] = {}  # fedlint: disable=global-mutable-singleton (trace buffer is process-global by contract; drained via snapshot())
 
 
 @dataclass
@@ -59,12 +76,12 @@ class Span:
     idx: int = -1             # ring-append index (monotonic per process)
 
 
-def enable(jax_annotations: bool = False) -> None:
-    """Turn span recording on. ``jax_annotations=True`` additionally wraps
-    spans in ``jax.profiler.TraceAnnotation`` (requires jax)."""
-    global _enabled, _use_jax_annotations
+def enable() -> None:
+    """Turn recording on: spans into the ring, request events, and the
+    per-name phase accumulators. (Profiler annotations need no switch
+    here: they follow the profiler session.)"""
+    global _enabled
     _enabled = True
-    _use_jax_annotations = jax_annotations
 
 
 def disable() -> None:
@@ -80,6 +97,7 @@ def clear() -> None:
     with _lock:
         _spans.clear()
         _request_events.clear()
+        _phases.clear()
 
 
 def get_spans(kind: Optional[str] = None) -> List[Span]:
@@ -112,10 +130,22 @@ def last_span_index() -> int:
 
 
 # Kinds whose spans bracket the full operation (duration is meaningful);
-# "recv" spans are arrival events with no duration — no throughput for them.
+# "recv" spans are arrival events with no duration, except for frames of
+# TIMED_RECV_MIN_BYTES and more (below) — no throughput for the kind.
 # "fold"/"publish" are the async aggregation buffer's K-publish spans
 # (rayfed_tpu/async_rounds.py; docs/async_rounds.md).
 _TIMED_KINDS = {"send", "decode", "task", "fold", "publish"}
+
+# A frame whose payload is at least this long gets a "recv" span with a
+# duration: the reactor stamps the moment its payload starts to arrive
+# into the frame's header under RECV_T0_KEY (never sent on the wire), and
+# the rendezvous store passes the stamp to ``record`` as ``start_s``.
+TIMED_RECV_MIN_BYTES = 1 << 20
+RECV_T0_KEY = "_recv_t0"
+
+
+def _is_timed(s: "Span") -> bool:
+    return s.kind in _TIMED_KINDS or bool(s.extra.get("timed"))
 
 
 def summary() -> Dict[str, Dict]:
@@ -165,7 +195,7 @@ def export_chrome_trace(path: str, party: str = "") -> int:
                 **s.extra,
             },
         }
-        if s.kind in _TIMED_KINDS:
+        if _is_timed(s):
             base["ph"] = "X"
             base["dur"] = max(s.duration_s, 1e-7) * 1e6
         else:
@@ -177,47 +207,11 @@ def export_chrome_trace(path: str, party: str = "") -> int:
     return len(events)
 
 
-def export_timeline(path: str, party: str = "") -> int:
-    """Write a plain-text per-seq-id send/recv/ack timeline — the hang
-    forensics artifact (ISSUE 7 satellite): when a bench party wedges,
-    the watchdog's signal triggers this next to the faulthandler stack
-    dump, so the last wire event per rendezvous edge is visible without
-    a debugger. Grouped by (upstream_seq_id, downstream_seq_id), events
-    time-ordered within each edge. Returns the number of events written.
-
-    Signal-handler safe: the span ring is snapshotted with a
-    non-blocking lock attempt (a handler interrupting the recording
-    thread mid-append must not deadlock on the tracing lock; deques are
-    safe to iterate without it at worst losing the in-flight span)."""
-    acquired = _lock.acquire(blocking=False)
-    try:
-        spans = list(_spans)
-    finally:
-        if acquired:
-            _lock.release()
-    edges: Dict[tuple, List[Span]] = {}
-    for s in spans:
-        edges.setdefault((s.upstream_seq_id, s.downstream_seq_id), []).append(s)
-    n = 0
-    with open(path, "w", encoding="utf-8") as f:
-        f.write(f"# rayfed_tpu wire timeline party={party or '?'} "
-                f"spans={len(spans)}\n")
-        for (up, down), group in sorted(edges.items()):
-            f.write(f"\n[{up} -> {down}]\n")
-            for s in sorted(group, key=lambda s: s.start_s):
-                f.write(
-                    f"  {s.start_s:16.6f} +{s.duration_s * 1e3:9.3f}ms "
-                    f"{s.kind:<6} peer={s.peer or '?':<10} "
-                    f"nbytes={s.nbytes:<12} ok={s.ok}\n"
-                )
-                n += 1
-    return n
-
-
 def export_seq_timeline(path: str, party: str = "") -> int:
-    """Write the per-seq-id timeline as machine-readable JSON — the
-    structured twin of :func:`export_timeline`'s text artifact, and the
-    input format of ``tools/trace_view.py``'s text flamegraph.
+    """Write the per-seq-id timeline as machine-readable JSON — the hang
+    forensics artifact and the input format of ``tools/trace_view.py``'s
+    text flamegraph: when a party wedges, the last wire event per
+    rendezvous edge is visible without a debugger.
 
     Shape::
 
@@ -231,10 +225,12 @@ def export_seq_timeline(path: str, party: str = "") -> int:
     fold/publish spans lands here keyed by its (upstream, downstream)
     seq-id edge, so a straggling round is traceable from the driver's
     offer through the wire to the fold that consumed it. Returns the
-    number of events written. Same snapshot discipline as
-    :func:`export_timeline` — safe from a watchdog signal handler
-    (non-blocking lock attempt; deque iteration without the lock at
-    worst loses the in-flight span)."""
+    number of events written.
+
+    Signal-handler safe: the span ring is snapshotted with a
+    non-blocking lock attempt (a handler interrupting the recording
+    thread mid-append must not deadlock on the tracing lock; deques are
+    safe to iterate without it at worst losing the in-flight span)."""
     import json
 
     acquired = _lock.acquire(blocking=False)
@@ -257,7 +253,7 @@ def export_seq_timeline(path: str, party: str = "") -> int:
                 "kind": s.kind,
                 "peer": s.peer,
                 "t_s": s.start_s,
-                "dur_s": s.duration_s if s.kind in _TIMED_KINDS else 0.0,
+                "dur_s": s.duration_s if _is_timed(s) else 0.0,
                 "nbytes": s.nbytes,
                 "ok": s.ok,
                 **s.extra,
@@ -361,8 +357,8 @@ def export_request_timeline(path: str, party: str = "") -> int:
     """Write the per-request serving timeline as JSON:
     ``{"party", "requests": {id: [{"event", "t_s", ...extra}]}}`` with
     per-request events time-ordered. Returns the number of events
-    written. Lives alongside :func:`export_timeline` (the per-seq-id wire
-    artifact); same snapshot discipline — safe to call from a watchdog
+    written. Lives alongside :func:`export_seq_timeline` (the per-seq-id
+    wire artifact); same snapshot discipline — safe to call from a watchdog
     signal handler (non-blocking lock attempt, ring iterated without it
     at worst losing the in-flight event)."""
     import json
@@ -386,10 +382,12 @@ def export_request_timeline(path: str, party: str = "") -> int:
 
 
 class span:
-    """Context manager recording one span (no-op when tracing is off)."""
+    """Context manager recording one transfer-shaped span: into the ring
+    when tracing is on, and as the profiler annotation ``fed:wire:<kind>``
+    (peer, seq ids and bytes as metadata) when a profiler session runs."""
 
     __slots__ = ("_kind", "_peer", "_up", "_down", "_nbytes", "_t0",
-                 "_jax_ctx", "_active")
+                 "_ann", "_active")
 
     def __init__(self, kind: str, peer: str = "", upstream_seq_id: str = "",
                  downstream_seq_id: str = "", nbytes: int = 0):
@@ -398,36 +396,31 @@ class span:
         self._up = upstream_seq_id
         self._down = downstream_seq_id
         self._nbytes = nbytes
-        self._jax_ctx = None
+        self._ann = None
         # Latched at __enter__: a toggle of the global flag mid-span must
         # not make __exit__ disagree with __enter__.
         self._active = False
 
     def __enter__(self):
-        if not _enabled:
-            return self
-        self._active = True
-        self._t0 = time.perf_counter()
-        if _use_jax_annotations:
-            try:
-                import jax.profiler
-
-                self._jax_ctx = jax.profiler.TraceAnnotation(
-                    f"fed:{self._kind}:{self._peer}:{self._up}->{self._down}"
-                )
-                self._jax_ctx.__enter__()
-            except Exception:  # pragma: no cover - profiler unavailable
-                self._jax_ctx = None
+        if _profiling():
+            self._ann = _TraceAnnotation(
+                "fed:wire:" + self._kind, peer=self._peer,
+                up=str(self._up), down=str(self._down), nbytes=self._nbytes,
+            )
+            self._ann.__enter__()
+        if _enabled:
+            self._active = True
+            self._t0 = time.perf_counter()
         return self
 
     def set_nbytes(self, n: int) -> None:
         self._nbytes = n
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if not self._active:
             return False
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(exc_type, exc, tb)
         global _span_seq
         record = Span(
             kind=self._kind,
@@ -444,3 +437,59 @@ class span:
             _span_seq += 1
             _spans.append(record)
         return False
+
+
+class phase:
+    """Context manager for a RECURRING piece of host work (one part of
+    the serving loop, the placement of an arrival, the mean's dispatch).
+
+    ``name`` is the fixed string ``fed:<layer>:<phase>``; keyword
+    arguments become the annotation's metadata. While a profiler session
+    runs the phase is a host event on the device trace's clock. While
+    tracing is on its duration is added to a per-name accumulator
+    (:func:`phase_summary`) — NOT to the span ring, which a loop running
+    tens of phases a second would turn over in under a minute.
+
+    Phases of one loop tile it and none encloses the others: a reduction
+    books an idle gap to the host event that overlaps it most."""
+
+    __slots__ = ("_name", "_meta", "_ann", "_t0")
+
+    def __init__(self, name: str, **meta):
+        self._name = name
+        self._meta = meta
+        self._ann = None
+        self._t0 = None
+
+    def __enter__(self):
+        if _profiling():
+            self._ann = _TraceAnnotation(self._name, **self._meta)
+            self._ann.__enter__()
+        if _enabled:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._t0 is not None:
+            dt = time.perf_counter() - self._t0
+            with _lock:
+                acc = _phases.get(self._name)
+                if acc is None:
+                    acc = _phases[self._name] = [0, 0.0, 0.0]
+                acc[0] += 1
+                acc[1] += dt
+                if dt > acc[2]:
+                    acc[2] = dt
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def phase_summary() -> Dict[str, Dict]:
+    """Per phase name: ``{"count", "seconds", "max_s"}`` since the last
+    :func:`clear` (recorded only while tracing is on)."""
+    with _lock:
+        return {
+            name: {"count": int(c), "seconds": s, "max_s": m}
+            for name, (c, s, m) in _phases.items()
+        }

@@ -472,7 +472,7 @@ def test_two_party_fedavg_trace_stitched_end_to_end():
 
 
 def test_spans_since_walks_only_new_spans():
-    tracing.enable(1024)
+    tracing.enable()
     try:
         start = tracing.last_span_index()
         tracing.record("send", "bob", "1", "1", 0, time.perf_counter())
