@@ -81,6 +81,15 @@ def init_cache(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _block_tail(x, o, layer, cfg: tfm.TransformerConfig):
+    """The rest of a block after attention: output projection of ``o``
+    (B, S, H, Dh) into the residual, then the FFN half."""
+    x = x + jnp.einsum(
+        "bshk,hkd->bsd", o, layer["wo"].astype(cfg.compute_dtype)
+    )
+    return x + tfm.ffn_apply(tfm.rms_norm(x, layer["ln2"]), layer, cfg)
+
+
 def forward_with_cache(
     params, tokens, cache: Cache, offset, cfg: tfm.TransformerConfig
 ):
@@ -110,7 +119,6 @@ def forward_with_cache(
         )
     x = params["embed"][tokens].astype(cfg.compute_dtype)
     positions = jnp.broadcast_to(offset + jnp.arange(s)[None, :], (b, s))
-    cdt = cfg.compute_dtype
 
     def body(carry, layer):
         x, ck, cv, i = carry
@@ -124,10 +132,7 @@ def forward_with_cache(
             jax.lax.dynamic_index_in_dim(cv, i, axis=0, keepdims=False),
             q_offset=offset,
         )
-        x = x + jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(cdt))
-        hmlp = tfm.rms_norm(x, layer["ln2"])
-        x = x + tfm.ffn_apply(hmlp, layer, cfg)
-        return (x, ck, cv, i + 1), None
+        return (_block_tail(x, o, layer, cfg), ck, cv, i + 1), None
 
     init = (x, cache["k"], cache["v"], jnp.asarray(0, jnp.int32))
     (x, ck, cv, _), _ = jax.lax.scan(body, init, params["layers"])
@@ -136,6 +141,117 @@ def forward_with_cache(
         jnp.float32
     )
     return logits, {"k": ck, "v": cv}
+
+
+# Keys gathered per trip of the paged attention loop. A trip reads whole
+# blocks for every row, so a row pays for the longest live row rounded up
+# to this many keys; the loop's own cost per trip is a few microseconds.
+# On a v5e (PR 25, PERF.md) 128, 256 and 512 read within 0.7 ms of one
+# another at 6 rows x 16 heads and 8 rows x 32 heads, 256 least or next
+# to least in every pattern of lengths; 1024 cost 4 to 8 ms more at
+# 8 x 32 heads.
+PAGED_CHUNK_KEYS = 256
+
+
+def paged_decode_step(
+    params, pk, pv, tokens, positions, tables, cfg: tfm.TransformerConfig
+):
+    """One decode token for every row of a block-paged K/V pool, reading
+    K/V through the block tables and writing the new token's K/V in place.
+
+    ``pk``/``pv`` (L, P, bs, H, Dh) are the pool's physical blocks (donate
+    them: they come back updated); ``tokens``/``positions`` (R,) int32 are
+    each row's input token and its position (= the number of keys it has
+    cached); ``tables`` (R, NB) int32 maps a row's logical block to a
+    physical one. Returns (logits (R, vocab) f32, pk, pv).
+
+    No contiguous per-row cache exists at any point: the layer scan reads
+    the pool as a loop invariant, each layer attends over chunks of
+    ``PAGED_CHUNK_KEYS`` keys gathered through the table under an online
+    softmax (float32 max, sum and accumulator), and the trip count is a
+    runtime value (the longest row's keys), so one program serves every
+    length. The current token is the softmax's first key: the running max
+    starts finite, and a chunk wholly past a row's length is an exact
+    no-op for it (max unchanged, sum + 0, accumulator * 1 + 0) — a row's
+    logits depend on nothing another row holds. The mathematics is
+    :func:`transformer.causal_attention`'s (operands in the compute dtype,
+    float32 scores, probabilities cast to the value dtype before the PV
+    product), re-associated, nothing rounded lower.
+
+    A junk row (position 0 under an all-zero table) visits no block and
+    writes into block 0, which no real query attends.
+    """
+    n_layers, n_phys, bs, n_heads, dh = pk.shape
+    n_rows, blocks_per_row = tables.shape
+    cdt = cfg.compute_dtype
+    chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
+    chunk_keys = chunk_blocks * bs
+    tables_p = jnp.pad(
+        tables, ((0, 0), (0, -blocks_per_row % chunk_blocks))
+    )
+    trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
+    # (L * P, bs, H, Dh): a layer's block b is row layer * P + b, so one
+    # gather reads a chunk's blocks and never the layer's whole pool.
+    pk_flat = pk.reshape(n_layers * n_phys, bs, n_heads, dh)
+    pv_flat = pv.reshape(n_layers * n_phys, bs, n_heads, dh)
+    scale = dh**-0.5
+
+    def attend(q, k1, v1, base):
+        # q, k1, v1: (R, H, Dh), this layer's query and new key / value.
+        s1 = jnp.einsum(
+            "rhd,rhd->rh", q, k1, preferred_element_type=jnp.float32
+        ) * scale
+
+        def chunk(c, carry):
+            m, den, acc = carry
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                tables_p, c * chunk_blocks, chunk_blocks, axis=1
+            )
+            kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_heads, dh)
+            vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_heads, dh)
+            k_pos = c * chunk_keys + jnp.arange(chunk_keys)
+            cached = k_pos[None, :] < positions[:, None]
+            s = jnp.einsum(
+                "rhd,rkhd->rhk", q, kc, preferred_element_type=jnp.float32
+            ) * scale
+            s = jnp.where(cached[:, None, :], s, -jnp.inf)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = den * alpha + p.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "rhk,rkhd->rhd", p.astype(vc.dtype), vc,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, den, acc
+
+        init = (s1, jnp.ones_like(s1), v1.astype(jnp.float32))
+        _, den, acc = jax.lax.fori_loop(0, trips, chunk, init)
+        return (acc / den[..., None]).astype(v1.dtype)
+
+    x = params["embed"][tokens][:, None].astype(cdt)
+
+    def body(carry, layer):
+        x, i = carry
+        q, k, v = tfm.qkv_proj(x, layer, positions[:, None], cfg)
+        k1 = k[:, 0].astype(pk.dtype)
+        v1 = v[:, 0].astype(pv.dtype)
+        o = attend(q[:, 0], k1, v1, i * n_phys)
+        return (_block_tail(x, o[:, None], layer, cfg), i + 1), (k1, v1)
+
+    (x, _), (k_new, v_new) = jax.lax.scan(
+        body, (x, jnp.asarray(0, jnp.int32)), params["layers"]
+    )
+    x = tfm.rms_norm(x[:, 0], params["ln_f"])
+    logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
+    # The one write: each row's (block, offset) in every layer.
+    w_block = jnp.take_along_axis(
+        tables, (positions // bs)[:, None], axis=1
+    )[:, 0]
+    w_off = positions % bs
+    pk = pk.at[:, w_block, w_off].set(k_new)
+    pv = pv.at[:, w_block, w_off].set(v_new)
+    return logits, pk, pv
 
 
 def prefill(params, prompt, cache: Cache, cfg: tfm.TransformerConfig):

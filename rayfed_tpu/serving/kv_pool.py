@@ -38,14 +38,17 @@ clone instead of a full row copy, and every grant/free is charged to
 the tenant ledger so ``tenancy.kv_block_quota`` means actual resident
 blocks.
 
-Bitwise compatibility: the paged decode step gathers each row's block
-chain into a contiguous (L, R, max_len+1, H, Dh) scratch slab, runs the
-LITERAL SAME jitted step program as the slab layout (identical shapes →
-identical executable → identical bits), then scatters each row's single
-written position back through its block table. On real accelerators the
-gather stands in for a fused paged-attention kernel; here it is the
-correctness-first CPU reference, which is exactly what makes
-paged-vs-slab parity testable bit-for-bit.
+Paged decode: one jitted program per iteration
+(:func:`rayfed_tpu.models.decode.paged_decode_step`) reads each row's K/V
+through its block table, a chunk of blocks at a time under an online
+softmax, and writes the new token's K/V straight into its
+(block, offset); the pool pair is donated and is the only K/V buffer —
+no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
+blocks read follow the longest live row, not ``max_len``. The slab
+layout keeps its own step; the two agree to rounding (the online softmax
+re-associates the sum), which the parity tests hold to equal tokens.
+Prefill still moves whole rows (``gather_slot`` / ``scatter_slot`` /
+``scatter_rows``).
 
 Sacrificial position: the cache is one position longer than ``max_len``.
 A batched decode step always runs every pool row; rows that are free, or
@@ -238,11 +241,9 @@ class PagedKVPool:
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_len = max_len
-        self.junk_pos = max_len
         self.block_size = int(block_size)
-        # Logical blocks per full-length row; the gather slab is
-        # (max_len + 1) long so the same step program as the slab layout
-        # (sacrificial position included) compiles once and is shared.
+        # Logical blocks per full-length row; the prefill paths' rows are
+        # (max_len + 1) long, the slab layout's row shape.
         self.row_len = max_len + 1
         self.blocks_per_row = -(-self.row_len // self.block_size)
         self.num_blocks = (
@@ -282,19 +283,15 @@ class PagedKVPool:
         bs = self.block_size
         T = self.row_len
         R = self.max_slots
+        cfg = self.cfg
 
-        @jax.named_scope("serve/gather")
-        def gather(pk, pv, tables):
-            # tables: (R, NB) int32. Result rows are bit-identical to the
-            # slab layout's cache rows for every granted position; junk
-            # entries resolve to block 0 garbage at masked positions.
-            L = pk.shape[0]
-            H, Dh = pk.shape[-2:]
-            k = pk[:, tables].reshape(L, R, NB * bs, H, Dh)[:, :, :T]
-            v = pv[:, tables].reshape(L, R, NB * bs, H, Dh)[:, :, :T]
-            return k, v
+        @jax.named_scope("serve/decode_step")
+        def decode_step(params, pk, pv, tokens, positions, tables):
+            return decode.paged_decode_step(
+                params, pk, pv, tokens, positions, tables, cfg
+            )
 
-        self._gather_fn = jax.jit(gather)
+        self._decode_step_fn = jax.jit(decode_step, donate_argnums=(1, 2))
 
         @jax.named_scope("serve/gather")
         def gather_row(pk, pv, table):
@@ -306,25 +303,6 @@ class PagedKVPool:
             return k, v
 
         self._gather_row_fn = jax.jit(gather_row)
-
-        @jax.named_scope("serve/scatter")
-        def scatter_step(pk, pv, k_slab, v_slab, positions, wblocks, woffs):
-            # Extract each row's single written position from the step
-            # output and write it through the block table. Junk rows
-            # target (block 0, off 0); duplicate junk writes are garbage
-            # into the sacrificial block, never read unmasked.
-            rows = jnp.arange(R)
-            kn = k_slab[:, rows, positions]
-            vn = v_slab[:, rows, positions]
-            pk = pk.at[:, wblocks, woffs].set(kn)
-            pv = pv.at[:, wblocks, woffs].set(vn)
-            return pk, pv
-
-        # Only the pool arrays are donatable (the step/prefill slabs
-        # differ in shape from the outputs, so they could never alias).
-        self._scatter_step_fn = jax.jit(
-            scatter_step, donate_argnums=(0, 1)
-        )
 
         pad = NB * bs - T
 
@@ -367,22 +345,21 @@ class PagedKVPool:
             scatter_row, donate_argnums=(0, 1)
         )
 
-    def gather(self, tables: np.ndarray):
-        """Assemble (L, R, max_len+1, H, Dh) scratch rows for one step."""
-        return self._gather_fn(self._k, self._v, jnp.asarray(tables))
+    def decode_step(self, params, tokens, positions, tables):
+        """One decode token per row through the block tables, the pool
+        updated in place; junk rows carry position 0 and an all-zero
+        table. Returns the (R, vocab) logits."""
+        logits, self._k, self._v = self._decode_step_fn(
+            params, self._k, self._v, jnp.asarray(tokens),
+            jnp.asarray(positions), jnp.asarray(tables),
+        )
+        return logits
 
     def gather_slot(self, slot: int):
         """One slot's contiguous row (chunked-prefill input)."""
         with self._lock:
             table = self._tables[slot].copy()
         return self._gather_row_fn(self._k, self._v, jnp.asarray(table))
-
-    def scatter_step(self, k_slab, v_slab, positions, wblocks, woffs) -> None:
-        self._k, self._v = self._scatter_step_fn(
-            self._k, self._v, k_slab, v_slab,
-            jnp.asarray(positions), jnp.asarray(wblocks),
-            jnp.asarray(woffs),
-        )
 
     def scatter_rows(self, k_slab, v_slab, tables: np.ndarray) -> None:
         self._k, self._v = self._scatter_rows_fn(
@@ -407,7 +384,7 @@ class PagedKVPool:
     def jitted_fns(self):
         """The pool's jitted programs (compile accounting)."""
         return [
-            self._gather_fn, self._gather_row_fn, self._scatter_step_fn,
+            self._decode_step_fn, self._gather_row_fn,
             self._scatter_rows_fn, self._scatter_row_fn, _copy_block,
         ]
 
@@ -485,14 +462,6 @@ class PagedKVPool:
     def table(self, slot: int) -> np.ndarray:
         with self._lock:
             return self._tables[slot].copy()
-
-    def write_target(self, slot: int, pos: int) -> Tuple[int, int]:
-        """(physical block, offset) for writing position ``pos``."""
-        with self._lock:
-            return (
-                int(self._tables[slot, pos // self.block_size]),
-                pos % self.block_size,
-            )
 
     def granted(self, slot: int) -> int:
         with self._lock:

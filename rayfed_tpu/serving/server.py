@@ -26,10 +26,15 @@ the engine compiles a handful of programs at startup cost and never
 again, regardless of request mix.
 
 Two KV layouts (``serving.kv_layout``): the legacy ``"slab"`` row pool
-and the default ``"paged"`` block pool. Paged admission batches a whole
-round of short-prompt prefills into ONE vmapped dispatch (the slab path
-serializes one prefill per request — the measured cap on
-``serve_batching_speedup``), splits prompts longer than
+and the default ``"paged"`` block pool. A slab iteration is one vmapped
+step over the pool's rows, each at full ``max_len``; a paged iteration
+is ONE program too (:func:`rayfed_tpu.models.decode.paged_decode_step`),
+batched over rows, that reads each row's K/V through its block table —
+as many blocks as the longest live row holds — and writes the new
+token's K/V in place: the pool is the only K/V buffer. Paged admission
+batches a whole round of short-prompt prefills into ONE vmapped dispatch
+(the slab path serializes one prefill per request — the measured cap
+on ``serve_batching_speedup``), splits prompts longer than
 ``serving.prefill_chunk`` into fixed-size chunks merged into the running
 decode iteration under a ``prefill_token_budget`` per step (admission
 never stalls the live batch), and grants KV blocks on demand at token
@@ -201,6 +206,11 @@ class InferenceServer:
             "prefill_chunks": 0,
             "streamed_tokens": 0,
             "preempted": 0,
+            # Paged decode: blocks the live rows' lengths cover (what a
+            # step has to read) beside max_slots x blocks_per_row (every
+            # row at full length: what a gathered slab read).
+            "kv_blocks_attended": 0,
+            "kv_blocks_slab": 0,
         }
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
@@ -263,6 +273,18 @@ class InferenceServer:
         self._m_preempted = _reg.counter(
             "fed_serving_preemptions_total",
             "Requests preempted to break a KV block-pool deadlock.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_kv_attended = _reg.counter(
+            "fed_serving_kv_blocks_attended_total",
+            "KV blocks covered by live rows' lengths, summed over paged "
+            "decode steps.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_kv_slab = _reg.counter(
+            "fed_serving_kv_blocks_slab_total",
+            "KV blocks of every row at full length, summed over paged "
+            "decode steps.",
             labels=("server",),
         ).labels(server=name)
         self._update_kv_gauges()
@@ -1067,28 +1089,29 @@ class InferenceServer:
                     req.future.set_exception(e)
         return ran
 
+    def _paged_step_inputs(self, rows):
+        """(tokens, positions, tables) of one paged decode step from the
+        live rows' ``(slot, token, position)``. Every other row is junk:
+        position 0 under an all-zero table, so it visits no block and
+        writes into the sacrificial block 0."""
+        R = self.pool.max_slots
+        tokens = np.zeros(R, np.int32)
+        positions = np.zeros(R, np.int32)
+        tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
+        for slot, token, pos in rows:
+            tokens[slot] = token
+            positions[slot] = pos
+            tables[slot] = self.pool.table(slot)
+        return tokens, positions, tables
+
     def _single_row_step_paged(
         self, params, slot: int, token: int, pos: int
     ) -> np.ndarray:
-        """Paged twin of :meth:`_single_row_step`: gather -> the SAME
-        step program -> scatter the one written position."""
-        import jax.numpy as jnp
-
-        R = self.pool.max_slots
-        tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
-        tables[slot] = self.pool.table(slot)
-        tokens = np.zeros(R, np.int32)
-        positions = np.full(R, self.pool.junk_pos, np.int32)
-        tokens[slot] = token
-        positions[slot] = pos
-        wblocks = np.zeros(R, np.int32)
-        woffs = np.zeros(R, np.int32)
-        wblocks[slot], woffs[slot] = self.pool.write_target(slot, pos)
-        k_g, v_g = self.pool.gather(tables)
-        logits, k_s, v_s = self._step_fn(
-            params, k_g, v_g, jnp.asarray(tokens), jnp.asarray(positions)
+        """Paged twin of :meth:`_single_row_step`: the decode program
+        with only ``slot`` live."""
+        logits = self.pool.decode_step(
+            params, *self._paged_step_inputs([(slot, token, pos)])
         )
-        self.pool.scatter_step(k_s, v_s, positions, wblocks, woffs)
         return np.asarray(logits, np.float32)[slot]
 
     def _emit_token(self, req: _Request, tok: int) -> None:
@@ -1197,31 +1220,22 @@ class InferenceServer:
                             self._fail_admitted(req, self._quota_exc(req))
                         else:
                             req.stalled = True
-                    tables = np.zeros(
-                        (b, self.pool.blocks_per_row), np.int32
+                    # Rows that are free, on another version or stalled
+                    # are junk in this step.
+                    inputs = self._paged_step_inputs(
+                        (req.slot, req.out[-1], req.pos) for req in live
                     )
-                    tokens = np.zeros(b, np.int32)
-                    positions = np.full(b, self.pool.junk_pos, np.int32)
-                    wblocks = np.zeros(b, np.int32)
-                    woffs = np.zeros(b, np.int32)
-                    for req in live:
-                        tables[req.slot] = self.pool.table(req.slot)
-                        tokens[req.slot] = req.out[-1]
-                        positions[req.slot] = req.pos
-                        wblocks[req.slot], woffs[req.slot] = (
-                            self.pool.write_target(req.slot, req.pos)
-                        )
                 if not live:
                     continue
                 with tracing.phase("fed:serve:dispatch"):
-                    k_g, v_g = self.pool.gather(tables)
-                    logits, k_s, v_s = self._step_fn(
-                        params, k_g, v_g,
-                        jnp.asarray(tokens), jnp.asarray(positions),
-                    )
-                    self.pool.scatter_step(
-                        k_s, v_s, positions, wblocks, woffs
-                    )
+                    logits = self.pool.decode_step(params, *inputs)
+                    bs = self.pool.block_size
+                    attended = sum(req.pos // bs + 1 for req in live)
+                    slab = b * self.pool.blocks_per_row
+                    self._stats["kv_blocks_attended"] += attended
+                    self._stats["kv_blocks_slab"] += slab
+                    self._m_kv_attended.inc(attended)
+                    self._m_kv_slab.inc(slab)
                 reqs = live
             else:
                 with tracing.phase("fed:serve:build"):

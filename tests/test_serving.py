@@ -553,9 +553,10 @@ def test_serve_two_party_e2e():
 
 # ---------------------------------------------------------------------------
 # Serving plane v2: paged KV layout. The bitwise contract — a request's
-# output depends only on (version, prompt, seed), never on the KV layout
-# or on what shares its batch — is what lets the paged pool ship as the
-# default without invalidating any recorded generation.
+# output depends only on (version, prompt, seed), never on what shares
+# its batch — holds within a layout. Across layouts the two step programs
+# agree to rounding (the paged step's online softmax re-associates the
+# sum); the six seeded prompts below still sample the same tokens.
 
 
 def test_paged_matches_slab_bitwise_mixed_lengths():

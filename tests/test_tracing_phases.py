@@ -340,13 +340,18 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     assert "aggregate/sum" in aggregate._tree_sum.lower(
         (tree, tree)).as_text(debug_info=True)
     pool = PagedKVPool(CFG, max_slots=2, max_len=8, block_size=4)
-    tables = jnp.zeros((2, pool.blocks_per_row), jnp.int32)
-    assert "serve/gather" in pool._gather_fn.lower(
-        pool._k, pool._v, tables).as_text(debug_info=True)
+    table = jnp.zeros((pool.blocks_per_row,), jnp.int32)
+    assert "serve/gather" in pool._gather_row_fn.lower(
+        pool._k, pool._v, table).as_text(debug_info=True)
+    rows = jnp.zeros((2,), jnp.int32)
+    assert "serve/decode_step" in pool._decode_step_fn.lower(
+        PARAMS, pool._k, pool._v, rows, rows,
+        jnp.zeros((2, pool.blocks_per_row), jnp.int32),
+    ).as_text(debug_info=True)
     # A decorator, not a wrapper program: the jitted functions keep the
     # names the profile and `compiled_programs` know them by.
-    assert pool._gather_fn.__name__ == "gather"
-    assert pool._scatter_step_fn.__name__ == "scatter_step"
+    assert pool._gather_row_fn.__name__ == "gather_row"
+    assert pool._decode_step_fn.__name__ == "decode_step"
 
 
 # ---------------------------------------------------------------------------
