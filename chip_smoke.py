@@ -517,8 +517,9 @@ def _define_tasks(fed):
         prompts[0, :plen] = prompt
         last_idx = np.zeros(rows, np.int32)
         last_idx[0] = plen - 1
-        last, _, _ = srv._get_paged_prefill_fn(bucket)(
-            params, jnp.asarray(prompts), jnp.asarray(last_idx)
+        last, *_ = srv._get_paged_prefill_fn(bucket)(
+            params, jnp.asarray(prompts), jnp.asarray(last_idx),
+            jnp.arange(rows) == 0,
         )
         ref = jax.jit(lambda p, t: tfm.forward(p, t, srv.cfg))(
             params, jnp.asarray([prompt], jnp.int32)

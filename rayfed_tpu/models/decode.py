@@ -153,6 +153,92 @@ def forward_with_cache(
 PAGED_CHUNK_KEYS = 256
 
 
+def paged_attention(pk, pv, positions, tables):
+    """The read of a block-paged K/V pool through the block tables, for
+    one decode token per row. Returns ``attend(q, k1, v1, base)``: ``q``
+    (R, H, Dh) a layer's queries, ``k1``/``v1`` (R, Hkv, Dh) its new key
+    and value, ``base`` the layer's first row of the flattened pool
+    (layer * P); the result is the attention output (R, H, Dh). K/V head
+    i serves query heads i*G..(i+1)*G-1 (G = H / Hkv; 1 for plain MHA).
+
+    Chunks of ``PAGED_CHUNK_KEYS`` keys are gathered through the table
+    under an online softmax (float32 max, sum and accumulator), and the
+    trip count is a runtime value (the longest row's keys), so one
+    program serves every length. The current token is the softmax's first
+    key: the running max starts finite, and a chunk wholly past a row's
+    length is an exact no-op for it (max unchanged, sum + 0, accumulator
+    * 1 + 0): a row's output depends on nothing another row holds. The
+    mathematics is :func:`transformer.causal_attention`'s (operands in
+    the compute dtype, float32 scores, probabilities cast to the value
+    dtype before the PV product), re-associated, nothing rounded lower.
+    """
+    n_layers, n_phys, bs, n_kv, dh = pk.shape
+    n_rows, blocks_per_row = tables.shape
+    chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
+    chunk_keys = chunk_blocks * bs
+    tables_p = jnp.pad(
+        tables, ((0, 0), (0, -blocks_per_row % chunk_blocks))
+    )
+    trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
+    # (L * P, bs, Hkv, Dh): a layer's block b is row layer * P + b, so one
+    # gather reads a chunk's blocks and never the layer's whole pool.
+    pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
+    pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
+    scale = dh**-0.5
+
+    def attend(q, k1, v1, base):
+        n_heads = q.shape[1]
+        q = q.reshape(n_rows, n_kv, n_heads // n_kv, dh)
+        s1 = jnp.einsum(
+            "rhgd,rhd->rhg", q, k1, preferred_element_type=jnp.float32
+        ) * scale
+
+        def chunk(c, carry):
+            m, den, acc = carry
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                tables_p, c * chunk_blocks, chunk_blocks, axis=1
+            )
+            kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
+            vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
+            k_pos = c * chunk_keys + jnp.arange(chunk_keys)
+            cached = k_pos[None, :] < positions[:, None]
+            s = jnp.einsum(
+                "rhgd,rkhd->rhgk", q, kc, preferred_element_type=jnp.float32
+            ) * scale
+            s = jnp.where(cached[:, None, None, :], s, -jnp.inf)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = den * alpha + p.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "rhgk,rkhd->rhgd", p.astype(vc.dtype), vc,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, den, acc
+
+        v_first = jnp.broadcast_to(
+            v1.astype(jnp.float32)[:, :, None, :], q.shape
+        )
+        init = (s1, jnp.ones_like(s1), v_first)
+        _, den, acc = jax.lax.fori_loop(0, trips, chunk, init)
+        out = (acc / den[..., None]).astype(v1.dtype)
+        return out.reshape(n_rows, n_heads, dh)
+
+    return attend
+
+
+def paged_write(pk, pv, k_new, v_new, positions, tables):
+    """The one write of a paged decode step: each row's new K/V (L, R,
+    Hkv, Dh) into its (block, offset) in every layer."""
+    bs = pk.shape[2]
+    w_block = jnp.take_along_axis(
+        tables, (positions // bs)[:, None], axis=1
+    )[:, 0]
+    w_off = positions % bs
+    return (pk.at[:, w_block, w_off].set(k_new),
+            pv.at[:, w_block, w_off].set(v_new))
+
+
 def paged_decode_step(
     params, pk, pv, tokens, positions, tables, cfg: tfm.TransformerConfig
 ):
@@ -166,69 +252,15 @@ def paged_decode_step(
     physical one. Returns (logits (R, vocab) f32, pk, pv).
 
     No contiguous per-row cache exists at any point: the layer scan reads
-    the pool as a loop invariant, each layer attends over chunks of
-    ``PAGED_CHUNK_KEYS`` keys gathered through the table under an online
-    softmax (float32 max, sum and accumulator), and the trip count is a
-    runtime value (the longest row's keys), so one program serves every
-    length. The current token is the softmax's first key: the running max
-    starts finite, and a chunk wholly past a row's length is an exact
-    no-op for it (max unchanged, sum + 0, accumulator * 1 + 0) — a row's
-    logits depend on nothing another row holds. The mathematics is
-    :func:`transformer.causal_attention`'s (operands in the compute dtype,
-    float32 scores, probabilities cast to the value dtype before the PV
-    product), re-associated, nothing rounded lower.
+    the pool as a loop invariant and each layer attends through the table
+    (:func:`paged_attention`).
 
     A junk row (position 0 under an all-zero table) visits no block and
     writes into block 0, which no real query attends.
     """
-    n_layers, n_phys, bs, n_heads, dh = pk.shape
-    n_rows, blocks_per_row = tables.shape
+    n_phys = pk.shape[1]
     cdt = cfg.compute_dtype
-    chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
-    chunk_keys = chunk_blocks * bs
-    tables_p = jnp.pad(
-        tables, ((0, 0), (0, -blocks_per_row % chunk_blocks))
-    )
-    trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
-    # (L * P, bs, H, Dh): a layer's block b is row layer * P + b, so one
-    # gather reads a chunk's blocks and never the layer's whole pool.
-    pk_flat = pk.reshape(n_layers * n_phys, bs, n_heads, dh)
-    pv_flat = pv.reshape(n_layers * n_phys, bs, n_heads, dh)
-    scale = dh**-0.5
-
-    def attend(q, k1, v1, base):
-        # q, k1, v1: (R, H, Dh), this layer's query and new key / value.
-        s1 = jnp.einsum(
-            "rhd,rhd->rh", q, k1, preferred_element_type=jnp.float32
-        ) * scale
-
-        def chunk(c, carry):
-            m, den, acc = carry
-            blocks = base + jax.lax.dynamic_slice_in_dim(
-                tables_p, c * chunk_blocks, chunk_blocks, axis=1
-            )
-            kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_heads, dh)
-            vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_heads, dh)
-            k_pos = c * chunk_keys + jnp.arange(chunk_keys)
-            cached = k_pos[None, :] < positions[:, None]
-            s = jnp.einsum(
-                "rhd,rkhd->rhk", q, kc, preferred_element_type=jnp.float32
-            ) * scale
-            s = jnp.where(cached[:, None, :], s, -jnp.inf)
-            m_new = jnp.maximum(m, s.max(-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            den = den * alpha + p.sum(-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "rhk,rkhd->rhd", p.astype(vc.dtype), vc,
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, den, acc
-
-        init = (s1, jnp.ones_like(s1), v1.astype(jnp.float32))
-        _, den, acc = jax.lax.fori_loop(0, trips, chunk, init)
-        return (acc / den[..., None]).astype(v1.dtype)
-
+    attend = paged_attention(pk, pv, positions, tables)
     x = params["embed"][tokens][:, None].astype(cdt)
 
     def body(carry, layer):
@@ -244,14 +276,97 @@ def paged_decode_step(
     )
     x = tfm.rms_norm(x[:, 0], params["ln_f"])
     logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
-    # The one write: each row's (block, offset) in every layer.
-    w_block = jnp.take_along_axis(
-        tables, (positions // bs)[:, None], axis=1
-    )[:, 0]
-    w_off = positions % bs
-    pk = pk.at[:, w_block, w_off].set(k_new)
-    pv = pv.at[:, w_block, w_off].set(v_new)
+    pk, pv = paged_write(pk, pv, k_new, v_new, positions, tables)
     return logits, pk, pv
+
+
+class TransformerServing:
+    """What the serving engine asks of a model: the protocol, with the
+    dense transformer as its first implementer.
+
+    The engine (:mod:`rayfed_tpu.serving.server`) and its pool resolve a
+    model's config to such an object through ``serving_model(cfg)`` of
+    the module that defines the config's class. It gives the pool its
+    shapes (``kv_shape``, ``state_spec``) and the engine its programs:
+    ``prefill_rows`` (a round of right-padded short prompts, one row
+    each), ``chunk`` (one chunk of a long prompt against one gathered
+    row) and ``decode_step`` (one token for every row, K/V read through
+    the block tables). ``state_spec`` is what a slot holds beside its
+    K/V; a model that has none (this one) returns ``{}``, takes and
+    returns ``{}`` wherever a program hands state on, and ignores
+    ``live``, ``n_real`` and what else exists for the sake of a state.
+    ``forward_with_cache`` serves the slab layout and the whole-request
+    modes (beam, speculative), which a model with a state refuses.
+    """
+
+    def __init__(self, cfg: tfm.TransformerConfig):
+        self.cfg = cfg
+
+    def kv_shape(self):
+        """(layers, K/V heads, head size) of the cache."""
+        return self.cfg.n_layers, self.cfg.n_heads, self.cfg.head_dim
+
+    def state_spec(self, cache_dtype=None):
+        return {}
+
+    def forward_with_cache(self, params, tokens, cache, offset):
+        return forward_with_cache(params, tokens, cache, offset, self.cfg)
+
+    def prefill_rows(self, params, prompts, last_idx, row_len, cache_dtype,
+                     landed):
+        """Prompts (R, S) from fresh zero rows: bit-identical logits to
+        recycled ones (masked positions cannot contribute). Returns the
+        logits at ``last_idx``, the K/V rows (L, R, row_len, H, Dh) and
+        the rows' state. ``landed`` (R,) bool names the rows that are
+        requests: only those are read or land anywhere, so what comes
+        back for the others is unspecified. Here every lane is computed
+        (ROADMAP S2b replaces this with the landed-rows loop)."""
+        cfg = self.cfg
+
+        def one_row(prompt_row, last_i, params):
+            cache = init_cache(cfg, 1, row_len, cache_dtype)
+            logits, cache = forward_with_cache(
+                params, prompt_row[None], cache, 0, cfg
+            )
+            last = jax.lax.dynamic_index_in_dim(
+                logits[0], last_i, axis=0, keepdims=False
+            )
+            return last, cache["k"][:, 0], cache["v"][:, 0]
+
+        rows = jax.vmap(one_row, in_axes=(0, 0, None), out_axes=(0, 1, 1))
+        return (*rows(prompts, last_idx, params), {})
+
+    def chunk(self, params, k_row, v_row, state, toks, offset, n_real):
+        """One chunk of a long prompt against one gathered row: the first
+        ``n_real`` of ``toks`` are the prompt's, the rest padding. Returns
+        the logits of the last real position (vocab,), the rows and the
+        state."""
+        logits, cache = forward_with_cache(
+            params,
+            toks[None],
+            {"k": k_row[:, None], "v": v_row[:, None]},
+            offset,
+            self.cfg,
+        )
+        last = jax.lax.dynamic_index_in_dim(
+            logits[0], n_real - 1, axis=0, keepdims=False
+        )
+        return last, cache["k"][:, 0], cache["v"][:, 0], state
+
+    def decode_step(self, params, pk, pv, state, tokens, positions, tables,
+                    live):
+        logits, pk, pv = paged_decode_step(
+            params, pk, pv, tokens, positions, tables, self.cfg
+        )
+        return logits, pk, pv, state
+
+
+def serving_model(cfg):
+    """The serving engine's view of ``cfg``'s model: ``serving_model`` of
+    the module that defines the config's class."""
+    import sys
+
+    return sys.modules[type(cfg).__module__].serving_model(cfg)
 
 
 def prefill(params, prompt, cache: Cache, cfg: tfm.TransformerConfig):

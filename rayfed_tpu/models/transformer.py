@@ -62,6 +62,14 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
+def serving_model(cfg: TransformerConfig):
+    """What the serving engine asks of this model (see
+    :class:`rayfed_tpu.models.decode.TransformerServing`)."""
+    from rayfed_tpu.models import decode
+
+    return decode.TransformerServing(cfg)
+
+
 def tiny_config(**overrides) -> TransformerConfig:
     """A config small enough to compile in seconds on one chip / CPU sim."""
     base = dict(vocab=256, d_model=64, n_heads=4, n_layers=2, d_ff=176)

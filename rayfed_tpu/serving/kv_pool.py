@@ -63,6 +63,19 @@ Slot recycling needs no zeroing: a recycled slot's stale K/V lives at
 positions the new request has not reached yet, and every position the new
 request *does* attend to was overwritten by its own prefill/decode first.
 
+Recurrent state (a model whose ``state_spec`` is not empty, e.g.
+:mod:`rayfed_tpu.models.falcon_h1`): the paged pool also owns one
+``(L, max_slots, *shape)`` array per entry of the spec, donated through
+the decode step like the K/V pair. Unlike K/V it is *carried*, so none
+of the "stale is invisible" arguments above hold for it: a slot's state
+is made zero by the prefill that starts a request in it (the bucketed
+prefill computes from a zero state and :meth:`PagedKVPool.scatter_rows`
+lands the result for the rows named in ``landed``; the first chunk of a
+chunked prefill zeroes what :meth:`PagedKVPool.gather_slot` handed it),
+and a row that sits a decode step out is handed back bit for bit
+(``live``). The slab layout and prefix reuse hold no such state and
+refuse such a model.
+
 Prefix reuse ("where cheap"): a slot whose live request was prefilled
 from the same (version, prompt) is a donor — its prompt region is never
 rewritten while it decodes (decode writes at positions >= prompt length),
@@ -253,11 +266,24 @@ class PagedKVPool:
         )
         if self.num_blocks < 1:
             raise ValueError("kv_blocks must be >= 1")
-        cache = decode.init_cache(
-            cfg, 1 + self.num_blocks, self.block_size, dtype
+        self.model = decode.serving_model(cfg)
+        n_layers, n_kv_heads, head_dim = self.model.kv_shape()
+        dtype = dtype or cfg.compute_dtype
+        kv_shape = (
+            n_layers, 1 + self.num_blocks, self.block_size, n_kv_heads,
+            head_dim,
         )
-        self._k = cache["k"]
-        self._v = cache["v"]
+        self._k = jnp.zeros(kv_shape, dtype)
+        self._v = jnp.zeros(kv_shape, dtype)
+        # What a slot holds beside its K/V (a recurrent state): one
+        # (L, max_slots, ...) array per entry of the model's spec.
+        self._state = {
+            name: jnp.zeros((n_layers, max_slots, *shape), sdtype)
+            for name, (shape, sdtype) in self.model.state_spec(dtype).items()
+        }
+        self.state_row_bytes = sum(
+            int(a.nbytes) // max_slots for a in self._state.values()
+        )
         self._lock = threading.Lock()
         self._free_slots: List[int] = list(range(max_slots))
         # pop() hands out low block ids first.
@@ -283,34 +309,56 @@ class PagedKVPool:
         bs = self.block_size
         T = self.row_len
         R = self.max_slots
-        cfg = self.cfg
+        model = self.model
 
+        def landed_in(old, new, where):
+            # new where a row's prefill landed, old bit for bit elsewhere.
+            return {
+                name: jnp.where(
+                    where.reshape((1, R) + (1,) * (old[name].ndim - 2)),
+                    new[name].astype(old[name].dtype), old[name],
+                )
+                for name in old
+            }
+
+        # The state is a pytree ({} for a model without one: no argument,
+        # no output, the program it ran before there was one); what exists
+        # for its sake trails and may be left out.
         @jax.named_scope("serve/decode_step")
-        def decode_step(params, pk, pv, tokens, positions, tables):
-            return decode.paged_decode_step(
-                params, pk, pv, tokens, positions, tables, cfg
+        def decode_step(params, pk, pv, tokens, positions, tables,
+                        state=None, live=None):
+            return model.decode_step(
+                params, pk, pv, state or {}, tokens, positions, tables, live
             )
 
-        self._decode_step_fn = jax.jit(decode_step, donate_argnums=(1, 2))
+        self._decode_step_fn = jax.jit(
+            decode_step, donate_argnums=(1, 2, 6)
+        )
 
         @jax.named_scope("serve/gather")
-        def gather_row(pk, pv, table):
-            # table: (NB,) int32 -> one (L, T, H, Dh) row.
+        def gather_row(pk, pv, table, state=None, slot=None):
+            # table: (NB,) int32 -> one (L, T, H, Dh) row (and the slot's
+            # row of every state array).
             L = pk.shape[0]
             H, Dh = pk.shape[-2:]
             k = pk[:, table].reshape(L, NB * bs, H, Dh)[:, :T]
             v = pv[:, table].reshape(L, NB * bs, H, Dh)[:, :T]
-            return k, v
+            return k, v, {
+                name: jax.lax.dynamic_index_in_dim(a, slot, 1, False)
+                for name, a in (state or {}).items()
+            }
 
         self._gather_row_fn = jax.jit(gather_row)
 
         pad = NB * bs - T
 
         @jax.named_scope("serve/scatter")
-        def scatter_rows(pk, pv, k_slab, v_slab, tables):
+        def scatter_rows(pk, pv, k_slab, v_slab, tables, state=None,
+                         new_state=None, landed=None):
             # Write whole (R, T)-shaped prefill output back through the
             # scatter tables. Rows that must not land (junk vmap lanes,
-            # already-live neighbours) carry an all-zero table.
+            # already-live neighbours) carry an all-zero table and a
+            # false `landed`.
             L = pk.shape[0]
             H, Dh = pk.shape[-2:]
             if pad:
@@ -321,14 +369,15 @@ class PagedKVPool:
             vp = v_slab.reshape(L, R, NB, bs, H, Dh)
             pk = pk.at[:, tables].set(kp)
             pv = pv.at[:, tables].set(vp)
-            return pk, pv
+            return pk, pv, landed_in(state or {}, new_state, landed)
 
         self._scatter_rows_fn = jax.jit(
-            scatter_rows, donate_argnums=(0, 1)
+            scatter_rows, donate_argnums=(0, 1, 5)
         )
 
         @jax.named_scope("serve/scatter")
-        def scatter_row(pk, pv, k_row, v_row, table):
+        def scatter_row(pk, pv, k_row, v_row, table, state=None,
+                        state_row=None, slot=None):
             L = pk.shape[0]
             H, Dh = pk.shape[-2:]
             if pad:
@@ -339,38 +388,61 @@ class PagedKVPool:
             vp = v_row.reshape(L, NB, bs, H, Dh)
             pk = pk.at[:, table].set(kp)
             pv = pv.at[:, table].set(vp)
-            return pk, pv
+            return pk, pv, {
+                name: jax.lax.dynamic_update_index_in_dim(
+                    a, state_row[name].astype(a.dtype), slot, 1
+                )
+                for name, a in (state or {}).items()
+            }
 
         self._scatter_row_fn = jax.jit(
-            scatter_row, donate_argnums=(0, 1)
+            scatter_row, donate_argnums=(0, 1, 5)
         )
 
-    def decode_step(self, params, tokens, positions, tables):
+    def decode_step(self, params, tokens, positions, tables, live=None):
         """One decode token per row through the block tables, the pool
         updated in place; junk rows carry position 0 and an all-zero
-        table. Returns the (R, vocab) logits."""
-        logits, self._k, self._v = self._decode_step_fn(
+        table. ``live`` (R,) bool names the rows whose recurrent state
+        advances; every other row's state comes back bit for bit. A
+        model without such a state takes none. Returns the (R, vocab)
+        logits."""
+        logits, self._k, self._v, self._state = self._decode_step_fn(
             params, self._k, self._v, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(tables),
+            jnp.asarray(positions), jnp.asarray(tables), self._state,
+            self._of_state(live, bool),
         )
         return logits
 
+    def _of_state(self, value, dtype):
+        """An argument that exists for the state's sake: on the device
+        for a model that has one, not sent at all for one that has none."""
+        return jnp.asarray(value, dtype) if self._state else None
+
     def gather_slot(self, slot: int):
-        """One slot's contiguous row (chunked-prefill input)."""
+        """One slot's contiguous row (chunked-prefill input) and its row
+        of the recurrent state (``{}`` when the model has none)."""
         with self._lock:
             table = self._tables[slot].copy()
-        return self._gather_row_fn(self._k, self._v, jnp.asarray(table))
-
-    def scatter_rows(self, k_slab, v_slab, tables: np.ndarray) -> None:
-        self._k, self._v = self._scatter_rows_fn(
-            self._k, self._v, k_slab, v_slab, jnp.asarray(tables)
+        return self._gather_row_fn(
+            self._k, self._v, jnp.asarray(table), self._state,
+            self._of_state(slot, jnp.int32),
         )
 
-    def scatter_slot(self, slot: int, k_row, v_row) -> None:
+    def scatter_rows(self, k_slab, v_slab, tables: np.ndarray,
+                     state_rows=None, landed=None) -> None:
+        """Land a round of prefilled rows: K/V through ``tables``, each
+        row's fresh recurrent state where ``landed`` (R,) bool says."""
+        self._k, self._v, self._state = self._scatter_rows_fn(
+            self._k, self._v, k_slab, v_slab, jnp.asarray(tables),
+            self._state, state_rows or {}, self._of_state(landed, bool),
+        )
+
+    def scatter_slot(self, slot: int, k_row, v_row, state_row=None) -> None:
         with self._lock:
             table = self._tables[slot].copy()
-        self._k, self._v = self._scatter_row_fn(
-            self._k, self._v, k_row, v_row, jnp.asarray(table)
+        self._k, self._v, self._state = self._scatter_row_fn(
+            self._k, self._v, k_row, v_row, jnp.asarray(table),
+            self._state, state_row or {}, self._of_state(slot, jnp.int32),
         )
 
     @property
@@ -378,8 +450,15 @@ class PagedKVPool:
         return self._k, self._v
 
     @property
+    def state(self):
+        """The recurrent-state arrays, name -> (L, max_slots, ...)."""
+        return self._state
+
+    @property
     def nbytes(self) -> int:
-        return int(self._k.nbytes) + int(self._v.nbytes)
+        return int(self._k.nbytes) + int(self._v.nbytes) + sum(
+            int(a.nbytes) for a in self._state.values()
+        )
 
     def jitted_fns(self):
         """The pool's jitted programs (compile accounting)."""

@@ -57,7 +57,12 @@ def snapshot_tree(params: Any) -> Any:
             # jnp.array(copy=True) always materializes new buffers.
             return jnp.array(x, copy=True)
         if isinstance(x, np.ndarray):
-            return jax.device_put(x, may_alias=False)
+            y = jax.device_put(x, may_alias=False)
+            if next(iter(y.devices())).platform == "cpu":
+                # The CPU backend aliases a 64-byte-aligned host buffer
+                # whatever may_alias says: copy on the device side.
+                y = jnp.array(y, copy=True)
+            return y
         return x
 
     leaves, spec = tree_util.tree_flatten(params)

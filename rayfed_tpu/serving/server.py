@@ -76,6 +76,7 @@ import numpy as np
 
 from rayfed_tpu import tracing
 from rayfed_tpu.config import ServingConfig
+from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
 from rayfed_tpu.serving.kv_pool import KVPool, PagedKVPool
 from rayfed_tpu.serving.publish import ModelBank
@@ -132,9 +133,12 @@ class InferenceServer:
     """One party's serving engine. See module docstring for the model.
 
     Args:
-        model_cfg: the served transformer's config (all versions published
+        model_cfg: the served model's config (all versions published
             into this server must share it — shapes key the compiled
-            programs).
+            programs). The engine takes its programs and the shapes of
+            its cache from ``serving_model(model_cfg)`` of the module
+            that defines the config (the model protocol:
+            :class:`rayfed_tpu.models.decode.TransformerServing`).
         config: :class:`~rayfed_tpu.config.ServingConfig` (or dict).
         params: optional initial params (published as version 1).
         draft_cfg: optional draft-model config enabling
@@ -145,7 +149,7 @@ class InferenceServer:
 
     def __init__(
         self,
-        model_cfg: tfm.TransformerConfig,
+        model_cfg: Any,
         config: Optional[ServingConfig] = None,
         *,
         params: Any = None,
@@ -156,12 +160,30 @@ class InferenceServer:
         if isinstance(config, dict):
             config = ServingConfig.from_dict(config)
         self.cfg = model_cfg
+        self.model = decode.serving_model(model_cfg)
         self.scfg = config or ServingConfig()
         self.draft_cfg = draft_cfg
         self.name = name
         self.bank = ModelBank()
         self.layout = self.scfg.kv_layout
         self._cache_dtype = cache_dtype
+        # A state that is carried (not masked) cannot be adopted from a
+        # block chain, held in a slab row or rolled back: refuse here,
+        # by name, rather than answer wrongly later.
+        self._recurrent = bool(self.model.state_spec(cache_dtype))
+        if self._recurrent and self.layout != "paged":
+            raise ValueError(
+                f"serving.kv_layout={self.layout!r} holds K/V only; "
+                f"{type(model_cfg).__name__} carries a recurrent state "
+                "per slot, which only the paged pool owns"
+            )
+        if self._recurrent and self.scfg.prefix_reuse:
+            raise ValueError(
+                "serving.prefix_reuse adopts a donor's K/V blocks, which "
+                f"says nothing of {type(model_cfg).__name__}'s recurrent "
+                "state at that point (no state snapshots yet); set "
+                "prefix_reuse=False"
+            )
         if self.layout == "paged":
             self.pool: Any = PagedKVPool(
                 model_cfg,
@@ -211,6 +233,13 @@ class InferenceServer:
             # row at full length: what a gathered slab read).
             "kv_blocks_attended": 0,
             "kv_blocks_slab": 0,
+            # Recurrent state (a model that has one): bytes of state the
+            # live rows read and wrote, summed over decode steps; requests
+            # started from a zero state; rows that sat a decode step out
+            # with their state kept.
+            "ssm_state_bytes": 0,
+            "state_resets": 0,
+            "state_rows_held": 0,
         }
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
@@ -287,6 +316,17 @@ class InferenceServer:
             "decode steps.",
             labels=("server",),
         ).labels(server=name)
+        self._m_state_bytes = _reg.counter(
+            "fed_serving_ssm_state_bytes_total",
+            "Recurrent-state bytes read and written by live rows, summed "
+            "over decode steps.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_state_resets = _reg.counter(
+            "fed_serving_state_resets_total",
+            "Requests started from a zero recurrent state.",
+            labels=("server",),
+        ).labels(server=name)
         self._update_kv_gauges()
         if params is not None:
             self.bank.publish(params)
@@ -312,17 +352,14 @@ class InferenceServer:
         """
         import jax
 
-        from rayfed_tpu.models import decode
-
-        cfg = self.cfg
+        model = self.model
 
         def one_row(tok, pos, k_row, v_row, params):
-            logits, cache = decode.forward_with_cache(
+            logits, cache = model.forward_with_cache(
                 params,
                 tok[None, None],
                 {"k": k_row[:, None], "v": v_row[:, None]},
                 pos,
-                cfg,
             )
             return logits[0, 0], cache["k"][:, 0], cache["v"][:, 0]
 
@@ -345,16 +382,14 @@ class InferenceServer:
             return fn
         import jax
 
-        from rayfed_tpu.models import decode
-
-        cfg = self.cfg
+        model = self.model
 
         @jax.named_scope("serve/prefill")
         def prefill_slot(params, k, v, prompt, slot, last_idx):
             k_row = jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=1)
             v_row = jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-            logits, cache = decode.forward_with_cache(
-                params, prompt[None], {"k": k_row, "v": v_row}, 0, cfg
+            logits, cache = model.forward_with_cache(
+                params, prompt[None], {"k": k_row, "v": v_row}, 0
             )
             k = jax.lax.dynamic_update_slice_in_dim(
                 k, cache["k"], slot, axis=1
@@ -372,71 +407,56 @@ class InferenceServer:
         return fn
 
     def _get_paged_prefill_fn(self, bucket: int):
-        """Batched prefill for the paged layout: one vmapped dispatch
-        prefills EVERY row admitted this round (junk lanes compute on
-        zero prompts and scatter into the sacrificial block). Fresh
-        zero rows instead of recycled ones — bit-identical logits either
-        way (masked positions cannot contribute), and the whole
+        """Batched prefill for the paged layout: one dispatch of the
+        model's ``prefill_rows`` prefills EVERY row admitted this round
+        (``landed`` names them; the other lanes scatter into the
+        sacrificial block and nothing reads what comes back for them, so
+        a model need not compute them). Fresh zero rows (and a zero
+        recurrent state) instead of recycled ones, and the whole
         admission round costs one dispatch instead of one per request,
-        which is where the serialized-prefill speedup cap moves."""
+        which is where the serialized-prefill speedup cap moves. Returns
+        (logits at ``last_idx`` (R, V), K/V rows, the rows' state)."""
         fn = self._paged_prefill_fns.get(bucket)
         if fn is not None:
             return fn
         import jax
 
-        from rayfed_tpu.models import decode
-
-        cfg = self.cfg
+        model = self.model
         row_len = self.scfg.max_len + 1
         dtype = self._cache_dtype
 
-        def one_row(prompt_row, last_i, params):
-            cache = decode.init_cache(cfg, 1, row_len, dtype)
-            logits, cache = decode.forward_with_cache(
-                params, prompt_row[None], cache, 0, cfg
-            )
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], last_i, axis=0, keepdims=False
-            )
-            return last, cache["k"][:, 0], cache["v"][:, 0]
-
-        rows = jax.vmap(one_row, in_axes=(0, 0, None), out_axes=(0, 1, 1))
-
         @jax.named_scope("serve/prefill")
-        def prefill_rows(params, prompts, last_idx):
-            return rows(prompts, last_idx, params)
+        def prefill_rows(params, prompts, last_idx, landed):
+            return model.prefill_rows(
+                params, prompts, last_idx, row_len, dtype, landed
+            )
 
         fn = jax.jit(prefill_rows)
         self._paged_prefill_fns[bucket] = fn
         return fn
 
     def _get_chunk_fn(self, clen: int):
-        """One prompt chunk against one gathered row at a dynamic
-        offset; compiled per padded chunk length. The write range
-        [offset, offset + clen) always lies inside the prompt (the
-        ragged remainder is chunked FIRST), so the dynamic update can
-        never clamp over live positions."""
+        """One prompt chunk against one gathered row (and its recurrent
+        state) at a dynamic offset; compiled per padded chunk length.
+        The write range [offset, offset + clen) always lies inside the
+        prompt (the ragged remainder is chunked FIRST), so the dynamic
+        update can never clamp over live positions. ``n_real`` of the
+        chunk's positions are the prompt's, the rest padding. Returns
+        (the logits of the last real position, K/V rows, state)."""
         fn = self._chunk_fns.get(clen)
         if fn is not None:
             return fn
         import jax
 
-        from rayfed_tpu.models import decode
-
-        cfg = self.cfg
+        model = self.model
 
         @jax.named_scope("serve/chunk")
-        def chunk_step(params, k_row, v_row, toks, offset):
-            logits, cache = decode.forward_with_cache(
-                params,
-                toks[None],
-                {"k": k_row[:, None], "v": v_row[:, None]},
-                offset,
-                cfg,
+        def chunk_step(params, k_row, v_row, state, toks, offset, n_real):
+            return model.chunk(
+                params, k_row, v_row, state, toks, offset, n_real
             )
-            return logits[0], cache["k"][:, 0], cache["v"][:, 0]
 
-        fn = jax.jit(chunk_step, donate_argnums=(1, 2))
+        fn = jax.jit(chunk_step, donate_argnums=(1, 2, 3))
         self._chunk_fns[clen] = fn
         return fn
 
@@ -482,6 +502,12 @@ class InferenceServer:
             raise ValueError("empty prompt")
         if mode not in ("generate", "beam", "speculative"):
             raise ValueError(f"unknown request mode {mode!r}")
+        if mode != "generate" and self._recurrent:
+            raise ValueError(
+                f"mode={mode!r} reorders or rolls back cache rows; "
+                f"{type(self.cfg).__name__}'s recurrent state cannot be "
+                "rolled back (only mode='generate' is served)"
+            )
         if mode == "speculative" and self.draft_cfg is None:
             raise ValueError(
                 "mode='speculative' needs a server started with draft_cfg"
@@ -985,16 +1011,25 @@ class InferenceServer:
                 prompts = np.zeros((R, bucket), np.int32)
                 last_idx = np.zeros(R, np.int32)
                 tables = np.zeros((R, NB), np.int32)
+                landed = np.zeros(R, bool)
                 for req in reqs:
                     plen = int(req.prompt.size)
                     prompts[req.slot, :plen] = req.prompt
                     last_idx[req.slot] = plen - 1
                     tables[req.slot] = self.pool.table(req.slot)
+                    landed[req.slot] = True
                 fn = self._get_paged_prefill_fn(bucket)
-                last, k_slab, v_slab = fn(
-                    params, jnp.asarray(prompts), jnp.asarray(last_idx)
+                last, k_slab, v_slab, state_rows = fn(
+                    params, jnp.asarray(prompts), jnp.asarray(last_idx),
+                    jnp.asarray(landed),
                 )
-                self.pool.scatter_rows(k_slab, v_slab, tables)
+                # Each landed row's recurrent state is the fresh one its
+                # prefill computed from zero: this is where a recycled
+                # slot's old state ends.
+                self.pool.scatter_rows(
+                    k_slab, v_slab, tables, state_rows, landed
+                )
+                self._count_state_resets(len(reqs))
                 last_np = np.asarray(last, np.float32)
                 for req in reqs:
                     self._post_prefill(req, last_np[req.slot])
@@ -1056,12 +1091,17 @@ class InferenceServer:
                 toks = np.zeros(clen, np.int32)
                 toks[:real] = req.prompt[off:off + real]
                 params = self.bank.get(req.version)
-                k_row, v_row = self.pool.gather_slot(req.slot)
-                logits, k_row, v_row = self._get_chunk_fn(clen)(
-                    params, k_row, v_row, jnp.asarray(toks),
+                k_row, v_row, state_row = self.pool.gather_slot(req.slot)
+                # The first chunk (offset 0) starts the request: the
+                # program zeroes the state it was handed.
+                logits, k_row, v_row, state_row = self._get_chunk_fn(clen)(
+                    params, k_row, v_row, state_row, jnp.asarray(toks),
                     jnp.asarray(off, jnp.int32),
+                    jnp.asarray(real, jnp.int32),
                 )
-                self.pool.scatter_slot(req.slot, k_row, v_row)
+                self.pool.scatter_slot(req.slot, k_row, v_row, state_row)
+                if off == 0:
+                    self._count_state_resets(1)
                 req.chunk_done = off + real
                 budget -= clen
                 ran = True
@@ -1071,8 +1111,7 @@ class InferenceServer:
                 if req.chunk_done >= plen:
                     with self._lock:
                         self._prefilling.remove(req)
-                    last = np.asarray(logits, np.float32)[real - 1]
-                    self._post_prefill(req, last)
+                    self._post_prefill(req, np.asarray(logits, np.float32))
             except BaseException as e:  # noqa: BLE001 - per-request fault
                 with self._lock:
                     if req in self._prefilling:
@@ -1090,19 +1129,29 @@ class InferenceServer:
         return ran
 
     def _paged_step_inputs(self, rows):
-        """(tokens, positions, tables) of one paged decode step from the
-        live rows' ``(slot, token, position)``. Every other row is junk:
-        position 0 under an all-zero table, so it visits no block and
-        writes into the sacrificial block 0."""
+        """(tokens, positions, tables, live) of one paged decode step
+        from the live rows' ``(slot, token, position)``. Every other row
+        is junk: position 0 under an all-zero table, so it visits no
+        block and writes into the sacrificial block 0, and not ``live``,
+        so whatever recurrent state its slot holds comes back bit for
+        bit."""
         R = self.pool.max_slots
         tokens = np.zeros(R, np.int32)
         positions = np.zeros(R, np.int32)
         tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
+        live = np.zeros(R, bool)
         for slot, token, pos in rows:
             tokens[slot] = token
             positions[slot] = pos
             tables[slot] = self.pool.table(slot)
-        return tokens, positions, tables
+            live[slot] = True
+        return tokens, positions, tables, live
+
+    def _count_state_resets(self, n: int) -> None:
+        if self._recurrent:
+            with self._lock:
+                self._stats["state_resets"] += n
+            self._m_state_resets.inc(n)
 
     def _single_row_step_paged(
         self, params, slot: int, token: int, pos: int
@@ -1236,6 +1285,18 @@ class InferenceServer:
                     self._stats["kv_blocks_slab"] += slab
                     self._m_kv_attended.inc(attended)
                     self._m_kv_slab.inc(slab)
+                    if self._recurrent:
+                        # Read and written once each by every live row;
+                        # held: admitted rows whose state this step kept
+                        # (stalled, on another version, or between two
+                        # chunks of their prompt).
+                        moved = 2 * len(live) * self.pool.state_row_bytes
+                        held = len(self._active) - len(live) + sum(
+                            1 for r in self._prefilling if r.chunk_done
+                        )
+                        self._stats["ssm_state_bytes"] += moved
+                        self._stats["state_rows_held"] += held
+                        self._m_state_bytes.inc(moved)
                 reqs = live
             else:
                 with tracing.phase("fed:serve:build"):

@@ -140,7 +140,11 @@ def enable_compilation_cache(default_subdir: str = ".jax_cache") -> str:
     if not cache_dir:
         cache_dir = os.path.join(_CHECKOUT_ROOT, default_subdir)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    # Every program, however fast it compiled: with a threshold, a
+    # program that compiles in about that long is written or not by the
+    # noise of one compilation, and a warm start re-compiles it or does
+    # not (the engine's chunk programs compile in 0.9-1.4 s each).
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
 
