@@ -277,7 +277,9 @@ class MembershipManager:
         """Bootstrap state for a JoinAccept, by priority: the registered
         provider, else the newest ``checkpoint.py`` snapshot under
         ``membership.bootstrap_dir``, else the newest live ModelBank
-        version on this party, else None.
+        version on this party (from an engine's bank: the tree in the
+        model's serving dtype, with no optimizer state; it resumes
+        inference, not training), else None.
 
         The checkpoint kind INLINES the snapshot's model and optimizer
         state (plus the pointer for anything else in the cut): a

@@ -297,6 +297,10 @@ class TransformerServing:
     ``live``, ``n_real`` and what else exists for the sake of a state.
     ``forward_with_cache`` serves the slab layout and the whole-request
     modes (beam, speculative), which a model with a state refuses.
+    ``serving_dtype`` is the dtype in which the programs read the
+    floating leaves of a published tree, or None for "as published": the
+    engine's bank casts a version once, when it is installed, and the
+    programs are handed that tree (``InferenceServer._make_snapshot_fn``).
     """
 
     def __init__(self, cfg: tfm.TransformerConfig):
@@ -308,6 +312,13 @@ class TransformerServing:
 
     def state_spec(self, cache_dtype=None):
         return {}
+
+    def serving_dtype(self):
+        """Every leaf is used only through ``.astype(compute_dtype)``
+        (the embedding after its gather, the norms' scales, the ``moe``
+        subtree), so the cast made once gives every program the bits it
+        computed from the wider tree, and those casts become no-ops."""
+        return self.cfg.compute_dtype
 
     def forward_with_cache(self, params, tokens, cache, offset):
         return forward_with_cache(params, tokens, cache, offset, self.cfg)

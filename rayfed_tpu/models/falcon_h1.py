@@ -621,6 +621,12 @@ class FalconH1Serving:
             "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), F32),
         }
 
+    def serving_dtype(self):
+        """As published: the tree is bfloat16 already, and ``conv_w``,
+        ``A_log``, ``dt_bias``, ``D`` and ``ssm_norm`` are widened to
+        float32 where they are used, whatever they arrive in."""
+        return None
+
     def prefill_rows(self, params, prompts, last_idx, row_len, cache_dtype,
                      landed):
         return prefill_rows(

@@ -31,12 +31,33 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from rayfed_tpu import tree_util
 
 
-def snapshot_tree(params: Any) -> Any:
+def _casts(x: Any, dtype: Any) -> bool:
+    """Whether :func:`snapshot_tree` casts leaf ``x`` for ``dtype``: a
+    floating array that is not in it yet."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    return (
+        dtype is not None
+        and isinstance(x, (jax.Array, np.ndarray))
+        and jnp.issubdtype(x.dtype, jnp.floating)
+        and x.dtype != jnp.dtype(dtype)
+    )
+
+
+def cast_nbytes(params: Any, dtype: Any) -> int:
+    """Bytes ``snapshot_tree(params, dtype)`` reads through a cast."""
+    leaves, _ = tree_util.tree_flatten(params)
+    return sum(int(x.nbytes) for x in leaves if _casts(x, dtype))
+
+
+def snapshot_tree(params: Any, dtype: Any = None) -> Any:
     """Donation/reuse-proof, device-resident capture of a param tree.
 
     jax.Array leaves are device-copied, keeping their sharding (a later
@@ -47,12 +68,25 @@ def snapshot_tree(params: Any) -> Any:
     drops it). The engine passes the snapshot into its jitted step on
     every iteration, so a leaf left on the host would be re-uploaded
     whole per decoded token. The tree structure is preserved
-    leaf-for-leaf (same treedef the checkpoint lane serializes)."""
+    leaf-for-leaf (same treedef the checkpoint lane serializes).
+
+    ``dtype`` (an engine's bank: the dtype the model's programs compute
+    in) makes the snapshot the tree those programs read: a floating leaf
+    in another dtype is cast, and the cast is the new buffer the copy
+    would have been, so both trees of a version are never held. Leaves
+    go one at a time; an uploaded leaf's wide buffer is dropped before
+    the next is uploaded."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     def leaf(x):
+        if _casts(x, dtype):
+            if isinstance(x, np.ndarray):
+                wide = jax.device_put(x, may_alias=False)
+                # `wide` dies with this frame: wait for its last reader.
+                return jax.block_until_ready(wide.astype(dtype))
+            return x.astype(dtype)
         if isinstance(x, jax.Array):
             # jnp.array(copy=True) always materializes new buffers.
             return jnp.array(x, copy=True)
@@ -77,9 +111,19 @@ class ModelBank:
     version with zero in-flight requests that is no longer current is
     retired (its snapshot dropped) so memory stays bounded at
     (current + versions still decoding).
+
+    ``prepare(key, tree)`` takes the snapshot of every tree that enters
+    the bank, by ``publish`` or by ``restore_state`` (``key`` is
+    ``"params"`` or an extra's name); the default is
+    :func:`snapshot_tree`, which keeps the tree as it was given. An
+    engine passes its own, so that its bank holds the tree its programs
+    read (``InferenceServer._make_snapshot_fn``).
     """
 
-    def __init__(self):
+    def __init__(
+        self, prepare: Optional[Callable[[str, Any], Any]] = None
+    ):
+        self._prepare = prepare or (lambda key, tree: snapshot_tree(tree))
         self._lock = threading.Lock()
         self._current: int = 0
         self._snapshots: Dict[int, Any] = {}
@@ -95,10 +139,7 @@ class ModelBank:
         under it. ``extras`` (e.g. ``draft_params`` for speculative
         serving) are snapshotted and retired together with the version.
         """
-        snap = snapshot_tree(params)
-        extra_snap = {
-            k: snapshot_tree(v) for k, v in extras.items() if v is not None
-        }
+        snap, extra_snap = self._snapshots_of(params, extras)
         with self._lock:
             version = self._current + 1
             self._snapshots[version] = snap
@@ -108,6 +149,11 @@ class ModelBank:
             self._swap_log.append((version, time.perf_counter()))
             self._retire_locked()
         return version
+
+    def _snapshots_of(self, params: Any, extras: Dict[str, Any]):
+        return self._prepare("params", params), {
+            k: self._prepare(k, v) for k, v in extras.items() if v is not None
+        }
 
     def current_version(self) -> int:
         """0 until the first publish."""
@@ -181,12 +227,9 @@ class ModelBank:
         version = int(state.get("version") or 0)
         if version <= 0 or state.get("params") is None:
             return self.current_version()
-        snap = snapshot_tree(state["params"])
-        extra_snap = {
-            k: snapshot_tree(v)
-            for k, v in (state.get("extras") or {}).items()
-            if v is not None
-        }
+        snap, extra_snap = self._snapshots_of(
+            state["params"], state.get("extras") or {}
+        )
         with self._lock:
             if version <= self._current:
                 return self._current

@@ -79,7 +79,11 @@ from rayfed_tpu.config import ServingConfig
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
 from rayfed_tpu.serving.kv_pool import KVPool, PagedKVPool
-from rayfed_tpu.serving.publish import ModelBank
+from rayfed_tpu.serving.publish import (
+    ModelBank,
+    cast_nbytes,
+    snapshot_tree,
+)
 from rayfed_tpu.telemetry import metrics as telemetry_metrics
 
 logger = logging.getLogger(__name__)
@@ -164,7 +168,6 @@ class InferenceServer:
         self.scfg = config or ServingConfig()
         self.draft_cfg = draft_cfg
         self.name = name
-        self.bank = ModelBank()
         self.layout = self.scfg.kv_layout
         self._cache_dtype = cache_dtype
         # A state that is carried (not masked) cannot be adopted from a
@@ -240,6 +243,10 @@ class InferenceServer:
             "ssm_state_bytes": 0,
             "state_resets": 0,
             "state_rows_held": 0,
+            # Bytes of published leaves read by the casts to the model's
+            # serving dtype as versions were installed (0: the trees came
+            # in it, or the model takes them as published).
+            "publish_cast_bytes": 0,
         }
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
@@ -327,7 +334,16 @@ class InferenceServer:
             "Requests started from a zero recurrent state.",
             labels=("server",),
         ).labels(server=name)
+        self._m_publish_cast = _reg.counter(
+            "fed_serving_publish_cast_bytes_total",
+            "Bytes of published leaves cast to the model's serving dtype "
+            "as versions were installed.",
+            labels=("server",),
+        ).labels(server=name)
         self._update_kv_gauges()
+        # Whatever way a version comes in (publish, a promoted standby's
+        # state), the bank's snapshot of it is the tree the programs read.
+        self.bank = ModelBank(prepare=self._make_snapshot_fn())
         if params is not None:
             self.bank.publish(params)
         self._engine = threading.Thread(
@@ -462,11 +478,37 @@ class InferenceServer:
 
     # -- client surface --------------------------------------------------
 
+    def _make_snapshot_fn(self):
+        """The snapshot an engine's bank takes of an incoming tree
+        (``ModelBank(prepare=)``): in the serving dtype of the model that
+        reads it, cast once here and not in every program that is handed
+        it. It captures the models and where it counts, not the engine:
+        a bank never keeps its engine (and its pool) alive."""
+        models = {"params": self.model}
+        if self.draft_cfg is not None:
+            models["draft_params"] = decode.serving_model(self.draft_cfg)
+        stats, lock, mirror = self._stats, self._lock, self._m_publish_cast
+
+        def snapshot(key: str, tree: Any) -> Any:
+            dtype = models[key].serving_dtype() if key in models else None
+            nbytes = cast_nbytes(tree, dtype)
+            if not nbytes:
+                return snapshot_tree(tree)
+            with tracing.phase("fed:serve:publish_cast"):
+                snap = snapshot_tree(tree, dtype)
+            with lock:
+                stats["publish_cast_bytes"] += nbytes
+            mirror.inc(nbytes)
+            return snap
+
+        return snapshot
+
     def publish(self, params: Any, *, draft_params: Any = None) -> int:
         """Atomically install a new model version; in-flight requests
         finish on the version they pinned at admission. The bank's
         snapshot is device-resident (NumPy leaves are uploaded once,
-        here) — the jitted step receives it on every iteration."""
+        here) and in the model's serving dtype — the jitted step
+        receives it on every iteration."""
         version = self.bank.publish(params, draft_params=draft_params)
         tracing.record_request(
             f"publish-v{version}", "publish", version=version
