@@ -1,6 +1,6 @@
 # Build the native fastwire extension in place (optional: the transport
 # falls back to pure-Python socket IO when the extension is absent).
-.PHONY: native test lint sanitize chaos latency scale dma shm serve async churn obs privacy ha wan tenant clean
+.PHONY: native test lint sanitize chaos latency scale dma shm async churn obs privacy ha wan tenant clean
 
 native:
 	python setup.py build_ext --inplace
@@ -71,16 +71,6 @@ dma:
 # here. Mirrors the `shm` job in .github/workflows/tests.yml.
 shm: native
 	JAX_PLATFORMS=cpu python tools/shm_check.py
-
-# Serving gate (docs/serving.md): the inference engine under 8
-# concurrent clients with hot swaps mid-window must hold its
-# serve_tokens_s floor and serve_p99_ms ceiling, and continuous
-# batching must stay >= FEDTPU_SERVE_BUDGET_SPEEDUP x the naive
-# one-request-at-a-time baseline — a serialized batcher or a request
-# stalled across a swap fails loudly here. Mirrors the `serve` job in
-# .github/workflows/tests.yml.
-serve:
-	JAX_PLATFORMS=cpu python tools/serve_check.py
 
 # Async gate (docs/async_rounds.md): 3 spawned parties with carol's
 # every send delayed by a seeded fault schedule; buffered-async rounds

@@ -1231,254 +1231,6 @@ def _run_scale_sweep() -> dict:
     return out
 
 
-# --- Federated inference serving plane (docs/serving.md) ------------------
-
-
-def _serve_bench_entry(result_path, clients, requests_per_client, reps):
-    """Spawned child: the serving engine under concurrent client load.
-
-    ``clients`` threads each stream ``requests_per_client`` generate
-    requests into one InferenceServer while a publisher thread lands two
-    hot swaps strictly mid-window (after 1/3 and 2/3 of completions, so
-    requests are always in flight across each swap). The identical
-    workload then runs in ``mode='sequential'`` — the same engine
-    admitting one request at a time, the naive no-batching baseline —
-    for the continuous-batching speedup ratio."""
-    import statistics
-    import threading
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from rayfed_tpu.config import ServingConfig
-    from rayfed_tpu.models import transformer as tfm
-    from rayfed_tpu.serving.server import InferenceServer
-
-    cfg = tfm.tiny_config(compute_dtype=jnp.float32)
-    params = [tfm.init_params(jax.random.PRNGKey(i), cfg) for i in (0, 1)]
-    # Long enough that decode dominates prefill: prefill is serialized in
-    # the engine thread in BOTH modes, so short generations dilute the
-    # batching speedup the gate measures.
-    max_new = 48
-    total = clients * requests_per_client
-
-    def window(mode, swap):
-        srv = InferenceServer(
-            cfg,
-            ServingConfig(
-                max_slots=8, max_len=64, max_new_tokens=max_new,
-                max_pending=max(64, 2 * total), mode=mode,
-            ),
-            params=params[0],
-        )
-        try:
-            # Discarded requests compile the prefill + step programs for
-            # EVERY prompt bucket the clients will hit (plen 4..12 spans
-            # three buckets) so the timed window measures the scheduler,
-            # not XLA.
-            for plen in (8, 4, 12):
-                srv.submit_and_wait(list(range(1, plen + 1)), max_new_tokens=2)
-            warm = srv.stats()["completed"]
-            latencies, tokens = [], [0]
-            lock = threading.Lock()
-
-            def client(ci):
-                rng = np.random.default_rng(1000 + ci)
-                for _ in range(requests_per_client):
-                    plen = int(rng.integers(4, 13))
-                    prompt = [
-                        int(t)
-                        for t in rng.integers(1, cfg.vocab - 1, size=plen)
-                    ]
-                    resp = srv.submit_and_wait(
-                        prompt, max_new_tokens=max_new
-                    )
-                    with lock:
-                        latencies.append(resp["latency_ms"])
-                        tokens[0] += len(resp["tokens"])
-
-            swaps = [0]
-
-            def publisher():
-                for thr in (max(1, total // 3), max(2, 2 * total // 3)):
-                    while True:
-                        done = srv.stats()["completed"] - warm
-                        if done >= total:
-                            return  # window drained before the swap slot
-                        if done >= thr:
-                            break
-                        time.sleep(0.005)
-                    srv.publish(params[(swaps[0] + 1) % 2])
-                    swaps[0] += 1
-
-            threads = [
-                threading.Thread(target=client, args=(i,))
-                for i in range(clients)
-            ]
-            pub = threading.Thread(target=publisher) if swap else None
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            if pub is not None:
-                pub.start()
-            for t in threads:
-                t.join()
-            dt = time.perf_counter() - t0
-            if pub is not None:
-                pub.join()
-            assert len(latencies) == total, (len(latencies), total)
-            return {
-                "tokens_s": tokens[0] / dt,
-                "p50_ms": float(np.percentile(latencies, 50)),
-                "p99_ms": float(np.percentile(latencies, 99)),
-                "swaps": swaps[0],
-            }
-        finally:
-            srv.stop()
-
-    def stream_ttft_window(n_requests=8):
-        """Streaming clients: median ms from submit to FIRST streamed
-        token (the latency win streaming buys over waiting for the full
-        response)."""
-        srv = InferenceServer(
-            cfg,
-            ServingConfig(
-                max_slots=8, max_len=64, max_new_tokens=max_new,
-                max_pending=max(64, 2 * total),
-            ),
-            params=params[0],
-        )
-        try:
-            for plen in (8, 4, 12):  # compile every bucket (see above)
-                srv.submit_and_wait(list(range(1, plen + 1)), max_new_tokens=2)
-            ttfts = []
-            lock = threading.Lock()
-
-            def client(ci):
-                rng = np.random.default_rng(7000 + ci)
-                plen = int(rng.integers(4, 13))
-                prompt = [
-                    int(t)
-                    for t in rng.integers(1, cfg.vocab - 1, size=plen)
-                ]
-                t0 = time.perf_counter()
-                fut, stream = srv.submit_stream(
-                    prompt, max_new_tokens=max_new
-                )
-                for _ in stream:
-                    break  # first token only; the rest streams on
-                first = stream.first_token_s
-                fut.result(timeout=300)
-                with lock:
-                    ttfts.append((first - t0) * 1e3)
-
-            threads = [
-                threading.Thread(target=client, args=(i,))
-                for i in range(n_requests)
-            ]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            return float(np.percentile(ttfts, 50))
-        finally:
-            srv.stop()
-
-    def mixed_window(n_short=16, long_len=1024, short_new=16, long_new=8):
-        """Fragmentation regression: 16 short requests interleaved with
-        one 1024-token prompt. Paged KV admits the shorts while the long
-        prompt chunk-prefills under the token budget; the gateable
-        number is the short requests' p99."""
-        srv = InferenceServer(
-            cfg,
-            ServingConfig(
-                max_slots=8, max_len=long_len + long_new + 8,
-                max_new_tokens=max(short_new, long_new),
-                max_pending=2 * (n_short + 1),
-                prompt_buckets=[16, long_len],
-            ),
-            params=params[0],
-        )
-        try:
-            # Warm the short bucket AND the chunked-prefill program (a
-            # 64-token prompt exceeds prefill_chunk, compiling the chunk
-            # step the 1024-token prompt will reuse).
-            srv.submit_and_wait(list(range(1, 9)), max_new_tokens=2)
-            srv.submit_and_wait(list(range(1, 65)), max_new_tokens=2)
-            rng = np.random.default_rng(4242)
-            long_prompt = [
-                int(t)
-                for t in rng.integers(1, cfg.vocab - 1, size=long_len)
-            ]
-            lat = []
-            lock = threading.Lock()
-
-            def short_client(ci):
-                r = np.random.default_rng(5000 + ci)
-                prompt = [
-                    int(t)
-                    for t in r.integers(
-                        1, cfg.vocab - 1, size=int(r.integers(4, 13))
-                    )
-                ]
-                resp = srv.submit_and_wait(
-                    prompt, max_new_tokens=short_new
-                )
-                with lock:
-                    lat.append(resp["latency_ms"])
-
-            long_fut = srv.submit(
-                np.asarray(long_prompt, np.int32), max_new_tokens=long_new
-            )
-            threads = [
-                threading.Thread(target=short_client, args=(i,))
-                for i in range(n_short)
-            ]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            long_resp = long_fut.result(timeout=300)
-            assert len(long_resp["tokens"]) == long_new
-            st = srv.stats()
-            return {
-                "p99_ms": float(np.percentile(lat, 99)),
-                "chunks": st["prefill_chunks"],
-            }
-        finally:
-            srv.stop()
-
-    windows = [window("continuous", swap=True) for _ in range(reps)]
-    naive = window("sequential", swap=False)
-    ttft_ms = stream_ttft_window()
-    mixed = mixed_window()
-    tok = [w["tokens_s"] for w in windows]
-    p99 = [w["p99_ms"] for w in windows]
-    out = {
-        "serve_tokens_s": round(statistics.median(tok), 1),
-        "serve_tokens_s_spread": [round(min(tok), 1), round(max(tok), 1)],
-        "serve_p99_ms": round(statistics.median(p99), 1),
-        "serve_p99_ms_spread": [round(min(p99), 1), round(max(p99), 1)],
-        "serve_p50_ms": round(
-            statistics.median([w["p50_ms"] for w in windows]), 1
-        ),
-        # min across reps: the gateable "every window swapped" statistic.
-        "serve_swaps": min(w["swaps"] for w in windows),
-        "serve_clients": clients,
-        "serve_requests": total,
-        "serve_naive_tokens_s": round(naive["tokens_s"], 1),
-        "serve_batching_speedup": round(
-            statistics.median(tok) / naive["tokens_s"], 2
-        ),
-        "serve_stream_ttft_ms": round(ttft_ms, 1),
-        "serve_mixed_p99_ms": round(mixed["p99_ms"], 1),
-        "serve_mixed_prefill_chunks": mixed["chunks"],
-    }
-    with open(result_path, "w") as f:
-        json.dump(out, f)
-
-
 def _tenant_bench_entry(result_path, window_s, push_mb, inline_kb):
     """Child-process body of the tenant stage: two jobs share one
     listener (the piggyback path), both keep bulk backlog through the
@@ -1616,36 +1368,6 @@ def _run_tenant_bench() -> dict:
             raise RuntimeError("tenant bench child hung")
         if p.exitcode != 0 or not os.path.exists(result_path):
             raise RuntimeError(f"tenant bench child failed rc={p.exitcode}")
-        with open(result_path) as f:
-            return json.load(f)
-
-
-def _run_serve_bench() -> dict:
-    """``serve_tokens_s`` / ``serve_p99_ms`` (+``_spread``) from >=8
-    concurrent clients with hot swaps mid-window, plus the
-    continuous-vs-sequential ``serve_batching_speedup`` ratio
-    (tools/serve_check.py gates these keys). Spawned CPU-forced child,
-    same isolation rationale as the psum stage."""
-    mp = multiprocessing.get_context("spawn")
-    with _cpu_forced(), tempfile.TemporaryDirectory() as tmp:
-        result_path = os.path.join(tmp, "serve.json")
-        p = mp.Process(
-            target=_serve_bench_entry,
-            args=(
-                result_path,
-                int(os.environ.get("FEDTPU_BENCH_SERVE_CLIENTS", 8)),
-                int(os.environ.get("FEDTPU_BENCH_SERVE_REQS", 4)),
-                int(os.environ.get("FEDTPU_BENCH_SERVE_REPS", 3)),
-            ),
-        )
-        p.start()
-        p.join(timeout=600)
-        if p.is_alive():
-            p.kill()
-            p.join(timeout=30)
-            raise RuntimeError("serve bench child hung")
-        if p.exitcode != 0 or not os.path.exists(result_path):
-            raise RuntimeError(f"serve bench child failed rc={p.exitcode}")
         with open(result_path) as f:
             return json.load(f)
 
@@ -2795,12 +2517,6 @@ def main() -> None:
         result.update(_run_scale_sweep())
     except Exception as e:  # noqa: BLE001 - bench must still print its line
         print(f"scale sweep skipped: {e!r}", file=sys.stderr)
-    # Serving plane: continuous batching under concurrent clients with
-    # hot swaps mid-window (docs/serving.md).
-    try:
-        result.update(_run_serve_bench())
-    except Exception as e:  # noqa: BLE001 - bench must still print its line
-        print(f"serve bench skipped: {e!r}", file=sys.stderr)
     # Tenancy plane: weighted-fair sharing between two jobs on one
     # shared listener + the victim's inline p99 under a noisy neighbor
     # (docs/multitenancy.md; tools/tenant_check.py gates both keys).

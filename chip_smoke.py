@@ -517,7 +517,7 @@ def _define_tasks(fed):
         prompts[0, :plen] = prompt
         last_idx = np.zeros(rows, np.int32)
         last_idx[0] = plen - 1
-        last, *_ = srv._get_paged_prefill_fn(bucket)(
+        last, *_ = srv._get_prefill_rows_fn(bucket)(
             params, jnp.asarray(prompts), jnp.asarray(last_idx),
             jnp.arange(rows) == 0,
         )

@@ -596,21 +596,15 @@ class ServingConfig:
         eos_id: stop token (None = always decode the full length).
         prefix_reuse: clone a live identical-(version, prompt) donor row
             instead of re-running prefill.
-        mode: "continuous" (iteration-level batching, the serving plane) or
-            "sequential" (one request at a time — the naive baseline the
-            bench compares against).
         prompt_buckets: prefill compiles once per bucket length; prompts
             are right-padded up to the next bucket (padding is causally
             invisible). None = powers of two up to ``max_len``.
-        kv_layout: "paged" (block-granular KV pool with on-demand block
-            grant — the serving-v2 data path) or "slab" (one contiguous
-            ``max_len+1`` row per slot, the PR 8 layout). Bitwise-identical
-            outputs on identical traffic; paged admits mixed-length
-            traffic without stranding whole rows.
-        kv_block_size: positions per KV block (paged layout only).
-        kv_blocks: physical blocks in the paged pool (one extra
-            sacrificial block is allocated internally). None = slab-
-            equivalent capacity: ``max_slots * ceil((max_len+1)/block)``.
+        kv_layout: always "paged"; the name is still accepted because the
+            benchmark's mix files pass it, and goes when they stop.
+        kv_block_size: positions per KV block.
+        kv_blocks: physical blocks in the pool (one extra sacrificial
+            block is allocated internally). None = every slot at full
+            length: ``max_slots * ceil((max_len+1)/block)``.
         prefill_chunk: prompts longer than this prefill in chunks merged
             into the running decode iteration (chunked prefill) instead of
             one monolithic forward that stalls the live batch.
@@ -628,7 +622,6 @@ class ServingConfig:
     temperature: float = 0.0
     eos_id: Optional[int] = None
     prefix_reuse: bool = True
-    mode: str = "continuous"
     prompt_buckets: Optional[List[int]] = None
     kv_layout: str = "paged"
     kv_block_size: int = 16
@@ -638,15 +631,10 @@ class ServingConfig:
     stream_window: int = 4
 
     def __post_init__(self):
-        if self.mode not in ("continuous", "sequential"):
+        if self.kv_layout != "paged":
             raise ValueError(
-                f"serving.mode must be 'continuous' or 'sequential', "
-                f"got {self.mode!r}"
-            )
-        if self.kv_layout not in ("paged", "slab"):
-            raise ValueError(
-                f"serving.kv_layout must be 'paged' or 'slab', "
-                f"got {self.kv_layout!r}"
+                f"serving.kv_layout must be 'paged', got "
+                f"{self.kv_layout!r}: the slab layout was removed in PR 29"
             )
         if self.max_new_tokens < 1:
             raise ValueError("serving.max_new_tokens must be >= 1")

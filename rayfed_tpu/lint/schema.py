@@ -63,7 +63,7 @@ PARTY_MESH_FIELDS = frozenset({
 
 SERVING_FIELDS = frozenset({
     "eos_id", "kv_block_size", "kv_blocks", "kv_layout", "max_len",
-    "max_new_tokens", "max_pending", "max_slots", "mode", "prefill_chunk",
+    "max_new_tokens", "max_pending", "max_slots", "prefill_chunk",
     "prefill_token_budget", "prefix_reuse", "prompt_buckets",
     "stream_window", "temperature",
 })

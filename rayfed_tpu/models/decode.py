@@ -295,8 +295,6 @@ class TransformerServing:
     K/V; a model that has none (this one) returns ``{}``, takes and
     returns ``{}`` wherever a program hands state on, and ignores
     ``live``, ``n_real`` and what else exists for the sake of a state.
-    ``forward_with_cache`` serves the slab layout and the whole-request
-    modes (beam, speculative), which a model with a state refuses.
     ``serving_dtype`` is the dtype in which the programs read the
     floating leaves of a published tree, or None for "as published": the
     engine's bank casts a version once, when it is installed, and the
@@ -319,9 +317,6 @@ class TransformerServing:
         subtree), so the cast made once gives every program the bits it
         computed from the wider tree, and those casts become no-ops."""
         return self.cfg.compute_dtype
-
-    def forward_with_cache(self, params, tokens, cache, offset):
-        return forward_with_cache(params, tokens, cache, offset, self.cfg)
 
     def prefill_rows(self, params, prompts, last_idx, row_len, cache_dtype,
                      landed):

@@ -18,12 +18,12 @@ One party hosts the freshest aggregate and serves generate / beam /
 speculative-decode requests under concurrent load while training rounds
 keep landing new aggregates:
 
- - :mod:`rayfed_tpu.serving.server` — admission control (batched paged
+ - :mod:`rayfed_tpu.serving.server` — admission control (batched
    prefill, chunked prefill with a per-step token budget) + continuous
    (iteration-level) batching over the KV pool;
- - :mod:`rayfed_tpu.serving.kv_pool` — the KV store, two layouts:
-   the contiguous slab and the block-granular paged pool (block tables,
-   on-demand grants, prefix reuse by table sharing);
+ - :mod:`rayfed_tpu.serving.kv_pool` — the KV store: the block-granular
+   paged pool (block tables, on-demand grants, prefix reuse by table
+   sharing);
  - :mod:`rayfed_tpu.serving.publish` — versioned atomic hot model swap
    over device-resident snapshots;
  - :mod:`rayfed_tpu.serving.stream` — incremental token streaming over
@@ -38,7 +38,7 @@ from rayfed_tpu.serving.client import (  # noqa: F401
     serve,
     submit_request,
 )
-from rayfed_tpu.serving.kv_pool import KVPool, PagedKVPool  # noqa: F401
+from rayfed_tpu.serving.kv_pool import PagedKVPool  # noqa: F401
 from rayfed_tpu.serving.publish import ModelBank  # noqa: F401
 from rayfed_tpu.serving.server import (  # noqa: F401
     InferenceServer,
@@ -56,7 +56,6 @@ __all__ = [
     "submit_request",
     "ServeHandle",
     "InferenceServer",
-    "KVPool",
     "PagedKVPool",
     "ModelBank",
     "LocalTokenStream",

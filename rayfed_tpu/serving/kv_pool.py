@@ -12,59 +12,51 @@
 # See the License for the specific language governing permissions and
 # limitations under the License.
 
-"""Slot-pooled and block-paged K/V caches for the serving plane.
+"""The block-paged K/V cache of the serving plane.
 
-Two layouts share one engine contract:
+:class:`PagedKVPool` — PagedAttention-shaped block granularity (Kwon et
+al. 2023) over the stacked-cache layout of
+:mod:`rayfed_tpu.models.decode`: the physical cache is ONE
+(L, 1 + num_blocks, block_size, H, Dh) pair allocated at server start,
+and each of ``max_slots`` rows (a *slot*, borrowed by one request for
+its lifetime) holds an int32 *block table* mapping logical block i of
+its sequence to a physical block. Blocks are granted on demand at token
+boundaries and returned to a free list at release — a short generation
+pins ceil(len/block_size) blocks, not a whole ``max_len`` row, so
+mixed-length traffic does not strand memory. No per-request allocation
+and no per-request compile: every program is shaped by the pool, not by
+the set of live requests. Prefix reuse is a block-table copy plus one
+boundary-block clone, and every grant/free is charged to the tenant
+ledger so ``tenancy.kv_block_quota`` means actual resident blocks.
 
-:class:`KVPool` (``serving.kv_layout = "slab"``) — vLLM-style slot
-pooling adapted to the stacked-cache layout of
-:mod:`rayfed_tpu.models.decode`: ONE (L, max_slots, max_len+1, H, Dh)
-cache pair is allocated at server start and every request borrows one
-batch row (a *slot*) for its lifetime — no per-request allocation, no
-per-request compile (the batched decode step is shaped by the pool, not
-by the set of live requests).
-
-:class:`PagedKVPool` (``"paged"``, the default) — PagedAttention-shaped
-block granularity (Kwon et al. 2023) over the same stacked layout: the
-physical cache is (L, 1 + num_blocks, block_size, H, Dh) and each slot
-holds an int32 *block table* mapping logical block i of its sequence to
-a physical block. Blocks are granted on demand at token boundaries and
-returned to a free list at release — a short generation pins
-ceil(len/block_size) blocks, not a whole ``max_len`` row, so
-mixed-length traffic stops stranding memory. Block recycling needs no
-zeroing (same sacrificial-position argument as the slab layout, see
-below), prefix reuse is a block-table copy plus one boundary-block
-clone instead of a full row copy, and every grant/free is charged to
-the tenant ledger so ``tenancy.kv_block_quota`` means actual resident
-blocks.
-
-Paged decode: one jitted program per iteration
+Decode: one jitted program per iteration
 (:func:`rayfed_tpu.models.decode.paged_decode_step`) reads each row's K/V
 through its block table, a chunk of blocks at a time under an online
 softmax, and writes the new token's K/V straight into its
 (block, offset); the pool pair is donated and is the only K/V buffer —
 no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
-blocks read follow the longest live row, not ``max_len``. The slab
-layout keeps its own step; the two agree to rounding (the online softmax
-re-associates the sum), which the parity tests hold to equal tokens.
-Prefill still moves whole rows (``gather_slot`` / ``scatter_slot`` /
-``scatter_rows``).
+blocks read follow the longest live row, not ``max_len``. It agrees with
+the plain cached forward (:func:`rayfed_tpu.models.decode.
+forward_with_cache`) to rounding (the online softmax re-associates the
+sum), which the parity tests hold to equal tokens. Prefill still moves
+whole rows (``gather_slot`` / ``scatter_slot`` / ``scatter_rows``).
 
-Sacrificial position: the cache is one position longer than ``max_len``.
-A batched decode step always runs every pool row; rows that are free, or
-pinned to a different model version than the step's params, write their
-(garbage) K/V at position ``max_len`` — a position no real query ever
-attends to (the causal mask admits k_pos <= q_pos and real positions stop
-at ``max_len - 1``). That keeps the step a fixed-shape program with no
-O(cache) masking and makes cross-version cache corruption structurally
-impossible.
+Sacrificial block: the pool is one block larger than ``num_blocks``. A
+batched decode step always runs every pool row; rows that are free,
+stalled, or pinned to a different model version than the step's params
+carry position 0 under an all-zero table, so their (garbage) K/V lands
+in physical block 0 — a block no table entry of a live row's granted
+prefix ever names, so no real query attends to it. That keeps the step
+a fixed-shape program with no O(cache) masking and makes cross-version
+cache corruption structurally impossible.
 
-Slot recycling needs no zeroing: a recycled slot's stale K/V lives at
-positions the new request has not reached yet, and every position the new
-request *does* attend to was overwritten by its own prefill/decode first.
+Block recycling needs no zeroing: a recycled block's stale K/V lives at
+positions the new request has not reached yet (the causal mask admits
+k_pos <= q_pos), and every position the new request *does* attend to
+was overwritten by its own prefill/decode first.
 
 Recurrent state (a model whose ``state_spec`` is not empty, e.g.
-:mod:`rayfed_tpu.models.falcon_h1`): the paged pool also owns one
+:mod:`rayfed_tpu.models.falcon_h1`): the pool also owns one
 ``(L, max_slots, *shape)`` array per entry of the spec, donated through
 the decode step like the K/V pair. Unlike K/V it is *carried*, so none
 of the "stale is invisible" arguments above hold for it: a slot's state
@@ -73,14 +65,14 @@ prefill computes from a zero state and :meth:`PagedKVPool.scatter_rows`
 lands the result for the rows named in ``landed``; the first chunk of a
 chunked prefill zeroes what :meth:`PagedKVPool.gather_slot` handed it),
 and a row that sits a decode step out is handed back bit for bit
-(``live``). The slab layout and prefix reuse hold no such state and
-refuse such a model.
+(``live``). Prefix reuse holds no such state and the engine refuses it
+for such a model.
 
 Prefix reuse ("where cheap"): a slot whose live request was prefilled
 from the same (version, prompt) is a donor — its prompt region is never
 rewritten while it decodes (decode writes at positions >= prompt length),
-so an identical concurrent prompt skips the full prefill by copying the
-donor row and re-running only the last prompt token.
+so an identical concurrent prompt skips the full prefill by sharing the
+donor's fully-prompt blocks and re-running only the last prompt token.
 """
 
 from __future__ import annotations
@@ -95,114 +87,6 @@ import numpy as np
 
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
-
-
-@partial(jax.jit, donate_argnums=(0, 1))
-def _copy_row(k, v, src, dst):
-    """Copy cache batch-row ``src`` over row ``dst`` (donated: in-place
-    where the backend supports aliasing)."""
-    k_row = jax.lax.dynamic_slice_in_dim(k, src, 1, axis=1)
-    v_row = jax.lax.dynamic_slice_in_dim(v, src, 1, axis=1)
-    k = jax.lax.dynamic_update_slice_in_dim(k, k_row, dst, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(v, v_row, dst, axis=1)
-    return k, v
-
-
-class KVPool:
-    """Fixed pool of ``max_slots`` decode rows over one stacked cache.
-
-    The pool owns the cache arrays; jitted steps consume them donated and
-    the engine hands the fresh arrays back via :meth:`replace`. All slot
-    bookkeeping is lock-guarded so ``release`` may be called from request
-    completion paths while the engine thread allocates.
-    """
-
-    def __init__(
-        self,
-        cfg: tfm.TransformerConfig,
-        max_slots: int,
-        max_len: int,
-        dtype=None,
-    ):
-        if max_slots < 1:
-            raise ValueError("max_slots must be >= 1")
-        if max_len < 2:
-            raise ValueError("max_len must be >= 2")
-        self.cfg = cfg
-        self.max_slots = max_slots
-        self.max_len = max_len
-        # One extra position: the sacrificial write target for junk rows.
-        self.junk_pos = max_len
-        cache = decode.init_cache(cfg, max_slots, max_len + 1, dtype)
-        self._k = cache["k"]
-        self._v = cache["v"]
-        self._lock = threading.Lock()
-        self._free: List[int] = list(range(max_slots))
-        # slot -> (version, prompt bytes) for live donor rows.
-        self._prefix: Dict[int, Tuple[int, bytes]] = {}
-
-    # -- cache array handoff (engine thread only) ------------------------
-
-    @property
-    def kv(self):
-        return self._k, self._v
-
-    def replace(self, k, v) -> None:
-        """Install the arrays a donated jitted step returned."""
-        self._k, self._v = k, v
-
-    @property
-    def nbytes(self) -> int:
-        return int(self._k.nbytes) + int(self._v.nbytes)
-
-    def jitted_fns(self):
-        """The pool's jitted programs (compile accounting)."""
-        return [_copy_row]
-
-    # -- slot lifecycle --------------------------------------------------
-
-    def acquire(self) -> Optional[int]:
-        with self._lock:
-            if not self._free:
-                return None
-            return self._free.pop()
-
-    def release(self, slot: int) -> None:
-        with self._lock:
-            if slot in self._free:
-                raise ValueError(f"slot {slot} double-released")
-            # The freed row's bytes stay intact until re-acquired, but only
-            # LIVE rows are donors (a re-prefill would invalidate silently).
-            self._prefix.pop(slot, None)
-            self._free.append(slot)
-
-    @property
-    def free_count(self) -> int:
-        with self._lock:
-            return len(self._free)
-
-    # -- prefix reuse ----------------------------------------------------
-
-    def note_prefix(self, slot: int, version: int, prompt_key: bytes) -> None:
-        with self._lock:
-            self._prefix[slot] = (version, prompt_key)
-
-    def lookup_prefix(self, version: int, prompt_key: bytes) -> Optional[int]:
-        """A live slot prefilled from exactly (version, prompt), if any."""
-        with self._lock:
-            for slot, key in self._prefix.items():
-                if key == (version, prompt_key):
-                    return slot
-        return None
-
-    def copy_row(self, src: int, dst: int) -> None:
-        """Clone donor row ``src`` into ``dst`` (engine thread only)."""
-        self._k, self._v = _copy_row(
-            self._k,
-            self._v,
-            jnp.asarray(src, jnp.int32),
-            jnp.asarray(dst, jnp.int32),
-        )
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -225,8 +109,8 @@ class PagedKVPool:
     arguments keeps every program fixed-shape). Physical block 0 is the
     junk target: ungranted table entries point at it, junk decode rows
     scatter into it, and no real query ever attends a position that
-    resolves to it — so recycled blocks are never zeroed, exactly the
-    slab layout's sacrificial-position argument at block granularity.
+    resolves to it — so recycled blocks are never zeroed: the
+    sacrificial-block argument of the module docstring.
 
     Tenant accounting: every fresh block grant charges one ``kv_blocks``
     unit against the constructing job's :class:`TenantResourceLedger`
@@ -256,7 +140,7 @@ class PagedKVPool:
         self.max_len = max_len
         self.block_size = int(block_size)
         # Logical blocks per full-length row; the prefill paths' rows are
-        # (max_len + 1) long, the slab layout's row shape.
+        # (max_len + 1) long.
         self.row_len = max_len + 1
         self.blocks_per_row = -(-self.row_len // self.block_size)
         self.num_blocks = (
@@ -545,11 +429,6 @@ class PagedKVPool:
     def granted(self, slot: int) -> int:
         with self._lock:
             return self._granted[slot]
-
-    @property
-    def free_count(self) -> int:
-        with self._lock:
-            return len(self._free_slots)
 
     @property
     def blocks_free(self) -> int:

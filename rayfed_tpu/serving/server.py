@@ -25,21 +25,18 @@ at the next iteration. Both jitted programs are shaped by the pool, so
 the engine compiles a handful of programs at startup cost and never
 again, regardless of request mix.
 
-Two KV layouts (``serving.kv_layout``): the legacy ``"slab"`` row pool
-and the default ``"paged"`` block pool. A slab iteration is one vmapped
-step over the pool's rows, each at full ``max_len``; a paged iteration
-is ONE program too (:func:`rayfed_tpu.models.decode.paged_decode_step`),
+The K/V cache is a pool of blocks
+(:class:`~rayfed_tpu.serving.kv_pool.PagedKVPool`). A decode iteration
+is ONE program (:func:`rayfed_tpu.models.decode.paged_decode_step`),
 batched over rows, that reads each row's K/V through its block table —
 as many blocks as the longest live row holds — and writes the new
-token's K/V in place: the pool is the only K/V buffer. Paged admission
-batches a whole round of short-prompt prefills into ONE vmapped dispatch
-(the slab path serializes one prefill per request — the measured cap
-on ``serve_batching_speedup``), splits prompts longer than
-``serving.prefill_chunk`` into fixed-size chunks merged into the running
-decode iteration under a ``prefill_token_budget`` per step (admission
-never stalls the live batch), and grants KV blocks on demand at token
-boundaries — when the pool truly runs dry the engine preempts the
-youngest request (its blocks return to the free list, the request
+token's K/V in place: the pool is the only K/V buffer. Admission batches
+a whole round of short-prompt prefills into ONE dispatch, splits prompts
+longer than ``serving.prefill_chunk`` into fixed-size chunks merged into
+the running decode iteration under a ``prefill_token_budget`` per step
+(admission never stalls the live batch), and grants KV blocks on demand
+at token boundaries — when the pool truly runs dry the engine preempts
+the youngest request (its blocks return to the free list, the request
 re-queues and deterministically re-runs under its pinned version), so
 mixed-length traffic degrades by latency, never by abort.
 
@@ -78,7 +75,7 @@ from rayfed_tpu import tracing
 from rayfed_tpu.config import ServingConfig
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
-from rayfed_tpu.serving.kv_pool import KVPool, PagedKVPool
+from rayfed_tpu.serving.kv_pool import PagedKVPool
 from rayfed_tpu.serving.publish import (
     ModelBank,
     cast_nbytes,
@@ -168,18 +165,11 @@ class InferenceServer:
         self.scfg = config or ServingConfig()
         self.draft_cfg = draft_cfg
         self.name = name
-        self.layout = self.scfg.kv_layout
         self._cache_dtype = cache_dtype
         # A state that is carried (not masked) cannot be adopted from a
-        # block chain, held in a slab row or rolled back: refuse here,
-        # by name, rather than answer wrongly later.
+        # block chain or rolled back: refuse here, by name, rather than
+        # answer wrongly later.
         self._recurrent = bool(self.model.state_spec(cache_dtype))
-        if self._recurrent and self.layout != "paged":
-            raise ValueError(
-                f"serving.kv_layout={self.layout!r} holds K/V only; "
-                f"{type(model_cfg).__name__} carries a recurrent state "
-                "per slot, which only the paged pool owns"
-            )
         if self._recurrent and self.scfg.prefix_reuse:
             raise ValueError(
                 "serving.prefix_reuse adopts a donor's K/V blocks, which "
@@ -187,20 +177,14 @@ class InferenceServer:
                 "state at that point (no state snapshots yet); set "
                 "prefix_reuse=False"
             )
-        if self.layout == "paged":
-            self.pool: Any = PagedKVPool(
-                model_cfg,
-                self.scfg.max_slots,
-                self.scfg.max_len,
-                cache_dtype,
-                block_size=self.scfg.kv_block_size,
-                num_blocks=self.scfg.kv_blocks,
-            )
-        else:
-            self.pool = KVPool(
-                model_cfg, self.scfg.max_slots, self.scfg.max_len,
-                cache_dtype,
-            )
+        self.pool = PagedKVPool(
+            model_cfg,
+            self.scfg.max_slots,
+            self.scfg.max_len,
+            cache_dtype,
+            block_size=self.scfg.kv_block_size,
+            num_blocks=self.scfg.kv_blocks,
+        )
         self._buckets = sorted(
             self.scfg.prompt_buckets or _default_buckets(self.scfg.max_len)
         )
@@ -208,9 +192,7 @@ class InferenceServer:
             {min(b, self.scfg.prefill_chunk) for b in _default_buckets(
                 self.scfg.prefill_chunk)}
         )
-        self._step_fn = self._make_step_fn()
         self._prefill_fns: Dict[int, Any] = {}
-        self._paged_prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
         self._special_fns: Dict[tuple, Any] = {}
         self._lock = threading.Lock()
@@ -231,9 +213,9 @@ class InferenceServer:
             "prefill_chunks": 0,
             "streamed_tokens": 0,
             "preempted": 0,
-            # Paged decode: blocks the live rows' lengths cover (what a
-            # step has to read) beside max_slots x blocks_per_row (every
-            # row at full length: what a gathered slab read).
+            # Decode: blocks the live rows' lengths cover (what a step has
+            # to read) beside max_slots x blocks_per_row (every row at
+            # full length: a contiguous slab of the rows).
             "kv_blocks_attended": 0,
             "kv_blocks_slab": 0,
             # Recurrent state (a model that has one): bytes of state the
@@ -288,12 +270,12 @@ class InferenceServer:
         ).labels(server=name)
         self._m_kv_in_use = _reg.gauge(
             "fed_serving_kv_blocks_in_use",
-            "KV blocks resident for live requests (slots, slab layout).",
+            "KV blocks resident for live requests.",
             labels=("server",),
         ).labels(server=name)
         self._m_kv_free = _reg.gauge(
             "fed_serving_kv_blocks_free",
-            "KV blocks on the free list (slots, slab layout).",
+            "KV blocks on the free list.",
             labels=("server",),
         ).labels(server=name)
         self._m_chunks = _reg.counter(
@@ -355,84 +337,16 @@ class InferenceServer:
 
     # -- jitted programs -------------------------------------------------
 
-    def _make_step_fn(self):
-        """ONE batched decode iteration over the whole pool.
-
-        vmap over pool rows of a single-token cached forward: each row is
-        a pure function of (params, its token, its cache row, its
-        position) — rows never mix, so a request's output is independent
-        of which other requests share the batch (this is what makes
-        fixed-seed output reproducible under concurrency). Junk rows
-        (free slots / other-version requests) write at the pool's
-        sacrificial position. Cache donated: in-place on TPU.
-        """
-        import jax
-
-        model = self.model
-
-        def one_row(tok, pos, k_row, v_row, params):
-            logits, cache = model.forward_with_cache(
-                params,
-                tok[None, None],
-                {"k": k_row[:, None], "v": v_row[:, None]},
-                pos,
-            )
-            return logits[0, 0], cache["k"][:, 0], cache["v"][:, 0]
-
-        rows = jax.vmap(one_row, in_axes=(0, 0, 1, 1, None),
-                        out_axes=(0, 1, 1))
-
-        @jax.named_scope("serve/decode_step")
-        def step(params, k, v, tokens, positions):
-            return rows(tokens, positions, k, v, params)
-
-        return jax.jit(step, donate_argnums=(1, 2))
-
-    def _get_prefill_fn(self, bucket: int):
-        """Prefill one slot row from a right-padded (bucket,) prompt;
-        compiled once per bucket length. Padding K/V beyond the real
-        length is causally invisible and overwritten by decode before any
-        query could reach it."""
+    def _get_prefill_rows_fn(self, bucket: int):
+        """Batched prefill, compiled once per bucket length: one dispatch
+        of the model's ``prefill_rows`` prefills EVERY row admitted this
+        round from right-padded (bucket,) prompts (``landed`` names them;
+        the other lanes scatter into the sacrificial block and nothing
+        reads what comes back for them, so a model need not compute
+        them). Fresh zero rows (and a zero recurrent state), not recycled
+        ones. Returns (logits at ``last_idx`` (R, V), K/V rows, the
+        rows' state)."""
         fn = self._prefill_fns.get(bucket)
-        if fn is not None:
-            return fn
-        import jax
-
-        model = self.model
-
-        @jax.named_scope("serve/prefill")
-        def prefill_slot(params, k, v, prompt, slot, last_idx):
-            k_row = jax.lax.dynamic_slice_in_dim(k, slot, 1, axis=1)
-            v_row = jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-            logits, cache = model.forward_with_cache(
-                params, prompt[None], {"k": k_row, "v": v_row}, 0
-            )
-            k = jax.lax.dynamic_update_slice_in_dim(
-                k, cache["k"], slot, axis=1
-            )
-            v = jax.lax.dynamic_update_slice_in_dim(
-                v, cache["v"], slot, axis=1
-            )
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], last_idx, axis=0, keepdims=False
-            )
-            return last, k, v
-
-        fn = jax.jit(prefill_slot, donate_argnums=(1, 2))
-        self._prefill_fns[bucket] = fn
-        return fn
-
-    def _get_paged_prefill_fn(self, bucket: int):
-        """Batched prefill for the paged layout: one dispatch of the
-        model's ``prefill_rows`` prefills EVERY row admitted this round
-        (``landed`` names them; the other lanes scatter into the
-        sacrificial block and nothing reads what comes back for them, so
-        a model need not compute them). Fresh zero rows (and a zero
-        recurrent state) instead of recycled ones, and the whole
-        admission round costs one dispatch instead of one per request,
-        which is where the serialized-prefill speedup cap moves. Returns
-        (logits at ``last_idx`` (R, V), K/V rows, the rows' state)."""
-        fn = self._paged_prefill_fns.get(bucket)
         if fn is not None:
             return fn
         import jax
@@ -448,7 +362,7 @@ class InferenceServer:
             )
 
         fn = jax.jit(prefill_rows)
-        self._paged_prefill_fns[bucket] = fn
+        self._prefill_fns[bucket] = fn
         return fn
 
     def _get_chunk_fn(self, clen: int):
@@ -562,7 +476,7 @@ class InferenceServer:
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new}) "
                 f"exceeds serving.max_len ({self.scfg.max_len})"
             )
-        if self.layout == "paged" and mode == "generate":
+        if mode == "generate":
             # Worst-case resident blocks for this request (highest
             # written position is prompt + generation - 2). A request
             # that could never fit the whole pool must fail HERE, not
@@ -634,24 +548,16 @@ class InferenceServer:
             out["pending"] = len(self._pending)
             out["active"] = len(self._active) + len(self._prefilling)
             lats = list(self._latencies_ms)
-        out["kv_layout"] = self.layout
-        if self.layout == "paged":
-            out["kv_blocks_in_use"] = self.pool.blocks_in_use
-            out["kv_blocks_free"] = self.pool.blocks_free
-            out["kv_block_size"] = self.pool.block_size
-        else:
-            out["kv_blocks_in_use"] = (
-                self.pool.max_slots - self.pool.free_count
-            )
-            out["kv_blocks_free"] = self.pool.free_count
+        out["kv_blocks_in_use"] = self.pool.blocks_in_use
+        out["kv_blocks_free"] = self.pool.blocks_free
+        out["kv_block_size"] = self.pool.block_size
         # Compiled variants across the engine's jitted programs: flat
         # after warm-up, or something (a new bucket, a published tree
         # with another sharding) is compiling inside the serving window.
         out["compiled_programs"] = sum(
             fn._cache_size() for fn in (
-                self._step_fn, *self._prefill_fns.values(),
-                *self._paged_prefill_fns.values(),
-                *self._chunk_fns.values(), *self.pool.jitted_fns(),
+                *self._prefill_fns.values(), *self._chunk_fns.values(),
+                *self.pool.jitted_fns(),
             )
         )
         out["current_version"] = self.bank.current_version()
@@ -735,13 +641,8 @@ class InferenceServer:
         )
 
     def _update_kv_gauges(self) -> None:
-        if self.layout == "paged":
-            self._m_kv_in_use.set(self.pool.blocks_in_use)
-            self._m_kv_free.set(self.pool.blocks_free)
-        else:
-            free = self.pool.free_count
-            self._m_kv_in_use.set(self.pool.max_slots - free)
-            self._m_kv_free.set(free)
+        self._m_kv_in_use.set(self.pool.blocks_in_use)
+        self._m_kv_free.set(self.pool.blocks_free)
 
     def _fail_all(self, exc: BaseException) -> None:
         with self._cond:
@@ -779,12 +680,6 @@ class InferenceServer:
                     # free (or about-to-be-freed) block is spoken for.
                     # Admitting more would steal it and livelock.
                     break
-                if self.scfg.mode == "sequential" and (
-                    self._active or self._prefilling or batch
-                ):
-                    # Naive baseline: strictly one request end-to-end at
-                    # a time (specials already serialize on the engine).
-                    break
                 req = self._pending[0]
                 if req.mode == "generate":
                     slot = self.pool.acquire()
@@ -795,12 +690,12 @@ class InferenceServer:
                 self._pending.popleft()
                 self._m_pending.set(len(self._pending))
             try:
-                if self.layout == "paged" and req.mode == "generate":
-                    outcome = self._admit_paged(req, slot, batch)
+                if req.mode == "generate":
+                    outcome = self._admit_generate(req, slot, batch)
                     if outcome == "flush":
                         self._batched_prefill(batch)
                         batch = []
-                        outcome = self._admit_paged(req, slot, batch)
+                        outcome = self._admit_generate(req, slot, batch)
                     if outcome == "blocked":
                         # Slot handed back, request re-queued at the
                         # front: nothing later in the queue can be
@@ -808,7 +703,7 @@ class InferenceServer:
                         break
                     admitted += 1
                 else:
-                    self._admit_one(req, slot)
+                    self._admit_special(req)
                     admitted += 1
             except BaseException as e:  # noqa: BLE001 - per-request fault
                 # A bad request (or a bug in its path) fails ITS future;
@@ -825,58 +720,20 @@ class InferenceServer:
         self._batched_prefill(batch)
         return admitted > 0
 
-    def _admit_one(self, req: _Request, slot: int) -> None:
+    def _admit_special(self, req: _Request) -> None:
+        """Admit a beam/speculative request: pin a version and run it
+        whole (it takes no slot of the pool)."""
         req.version, params = self.bank.acquire()
         now = time.perf_counter()
         req.timing["admit"] = now
         tracing.record_request(req.rid, "admit", t_s=now,
-                               version=req.version, slot=slot)
-        if req.mode != "generate":
-            self._run_special(req, params)
-            return
-        req.slot = slot
-        req.rng = np.random.default_rng(req.seed)
-        plen = int(req.prompt.size)
-        prompt_key = req.prompt.tobytes()
-
-        import jax.numpy as jnp
-
-        donor = None
-        if self.scfg.prefix_reuse:
-            donor = self.pool.lookup_prefix(req.version, prompt_key)
-        if donor is not None and donor != slot:
-            # Clone the donor's row (its prompt region is exactly what
-            # prefill wrote — decode never touches positions < plen),
-            # then one single-row step re-derives the last-position
-            # logits; the full prompt forward is skipped.
-            self.pool.copy_row(donor, slot)
-            last = self._single_row_step(
-                params, slot, int(req.prompt[-1]), plen - 1
-            )
-            req.prefix_reuse = True
-            self._stats["prefix_hits"] += 1
-            self._m_prefix_hits.inc()
-        else:
-            bucket = next(
-                (b for b in self._buckets if b >= plen), self._buckets[-1]
-            )
-            bucket = max(bucket, plen)
-            padded = np.zeros(bucket, np.int32)
-            padded[:plen] = req.prompt
-            fn = self._get_prefill_fn(bucket)
-            k, v = self.pool.kv
-            last, k, v = fn(
-                params, k, v, jnp.asarray(padded),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(plen - 1, jnp.int32),
-            )
-            self.pool.replace(k, v)
-        self._post_prefill(req, np.asarray(last, np.float32))
+                               version=req.version, slot=-1)
+        self._run_special(req, params)
 
     def _post_prefill(self, req: _Request, last_logits: np.ndarray) -> None:
-        """Shared admission tail (both layouts, batched/chunked/donor
-        paths): record the prefix donor, sample the first token, and
-        either finish or join the decode batch."""
+        """Shared admission tail (batched/chunked/donor paths): record
+        the prefix donor, sample the first token, and either finish or
+        join the decode batch."""
         plen = int(req.prompt.size)
         self.pool.note_prefix(req.slot, req.version, req.prompt.tobytes())
         now = time.perf_counter()
@@ -897,7 +754,7 @@ class InferenceServer:
                 self._active[req.slot] = req
                 self._m_active.set(len(self._active))
 
-    # -- paged admission / chunked prefill -------------------------------
+    # -- admission / chunked prefill -------------------------------------
 
     def _acquire_version(self, req: _Request):
         """Pin the current version — or, for a preempted request, reuse
@@ -908,12 +765,13 @@ class InferenceServer:
         req.version, params = self.bank.acquire()
         return params
 
-    def _admit_paged(self, req: _Request, slot: int, batch: List[_Request]) -> str:
-        """Admit one generate request under the paged layout. Returns
-        "ok" (admitted: into ``batch``, ``self._prefilling``, or already
-        running via a prefix donor) or "blocked" (no KV blocks for even
-        its first chunk — slot returned, request re-queued at the
-        front)."""
+    def _admit_generate(
+        self, req: _Request, slot: int, batch: List[_Request]
+    ) -> str:
+        """Admit one generate request. Returns "ok" (admitted: into
+        ``batch``, ``self._prefilling``, or already running via a prefix
+        donor) or "blocked" (no KV blocks for even its first chunk —
+        slot returned, request re-queued at the front)."""
         params = self._acquire_version(req)
         now = time.perf_counter()
         req.timing["admit"] = now
@@ -941,7 +799,7 @@ class InferenceServer:
                 # logits.
                 status = self.pool.adopt_prefix(donor, slot, plen)
                 if status == "ok":
-                    last = self._single_row_step_paged(
+                    last = self._step_one_row(
                         params, slot, int(req.prompt[-1]), plen - 1
                     )
                     req.prefix_reuse = True
@@ -1028,10 +886,8 @@ class InferenceServer:
         return "blocked"
 
     def _batched_prefill(self, batch: List[_Request]) -> None:
-        """ONE vmapped prefill dispatch per (version, bucket) group for
-        every short-prompt request admitted this round — the paged
-        layout's answer to the slab path's serialized per-request
-        prefill."""
+        """ONE prefill dispatch per (version, bucket) group for every
+        short-prompt request admitted this round."""
         if not batch:
             return
         import jax.numpy as jnp
@@ -1060,7 +916,7 @@ class InferenceServer:
                     last_idx[req.slot] = plen - 1
                     tables[req.slot] = self.pool.table(req.slot)
                     landed[req.slot] = True
-                fn = self._get_paged_prefill_fn(bucket)
+                fn = self._get_prefill_rows_fn(bucket)
                 last, k_slab, v_slab, state_rows = fn(
                     params, jnp.asarray(prompts), jnp.asarray(last_idx),
                     jnp.asarray(landed),
@@ -1170,7 +1026,7 @@ class InferenceServer:
                     req.future.set_exception(e)
         return ran
 
-    def _paged_step_inputs(self, rows):
+    def _step_inputs(self, rows):
         """(tokens, positions, tables, live) of one paged decode step
         from the live rows' ``(slot, token, position)``. Every other row
         is junk: position 0 under an all-zero table, so it visits no
@@ -1195,13 +1051,13 @@ class InferenceServer:
                 self._stats["state_resets"] += n
             self._m_state_resets.inc(n)
 
-    def _single_row_step_paged(
+    def _step_one_row(
         self, params, slot: int, token: int, pos: int
     ) -> np.ndarray:
-        """Paged twin of :meth:`_single_row_step`: the decode program
-        with only ``slot`` live."""
+        """The decode program with only ``slot`` live (every other row
+        is junk whatever its state: see :meth:`_step_inputs`)."""
         logits = self.pool.decode_step(
-            params, *self._paged_step_inputs([(slot, token, pos)])
+            params, *self._step_inputs([(slot, token, pos)])
         )
         return np.asarray(logits, np.float32)[slot]
 
@@ -1258,24 +1114,6 @@ class InferenceServer:
         logger.info("serving[%s]: preempted %s to free KV blocks",
                     self.name, req.rid)
 
-    def _single_row_step(self, params, slot: int, token: int, pos: int):
-        """One pool iteration with only ``slot`` live (all other rows are
-        junk regardless of their state — their write goes to the
-        sacrificial position, their real cache is untouched)."""
-        import jax.numpy as jnp
-
-        b = self.pool.max_slots
-        tokens = np.zeros(b, np.int32)
-        positions = np.full(b, self.pool.junk_pos, np.int32)
-        tokens[slot] = token
-        positions[slot] = pos
-        k, v = self.pool.kv
-        logits, k, v = self._step_fn(
-            params, k, v, jnp.asarray(tokens), jnp.asarray(positions)
-        )
-        self.pool.replace(k, v)
-        return np.asarray(logits, np.float32)[slot]
-
     def _step_groups(self) -> bool:
         """One decode iteration: a batched pool step per live version
         group. Params differ across groups but shapes do not, so every
@@ -1287,73 +1125,53 @@ class InferenceServer:
                 groups.setdefault(req.version, []).append(req)
         if not groups:
             return False
-        import jax.numpy as jnp
-
-        b = self.pool.max_slots
         progressed = False
         for version in sorted(groups):
-            reqs = groups[version]
             params = self.bank.get(version)
-            if self.layout == "paged":
-                with tracing.phase("fed:serve:build"):
-                    # Grant each live row's next block at this token
-                    # boundary; a row that cannot get one sits out the
-                    # iteration as junk (and flags itself for the
-                    # preemption check) — decode never stalls the whole
-                    # batch.
-                    live = []
-                    for req in reqs:
-                        status = self.pool.ensure_blocks(req.slot, req.pos)
-                        if status == "ok":
-                            req.stalled = False
-                            live.append(req)
-                        elif status == "quota" and self._quota_hopeless(req):
-                            self._fail_admitted(req, self._quota_exc(req))
-                        else:
-                            req.stalled = True
-                    # Rows that are free, on another version or stalled
-                    # are junk in this step.
-                    inputs = self._paged_step_inputs(
-                        (req.slot, req.out[-1], req.pos) for req in live
+            with tracing.phase("fed:serve:build"):
+                # Grant each live row's next block at this token
+                # boundary; a row that cannot get one sits out the
+                # iteration as junk (and flags itself for the
+                # preemption check) — decode never stalls the whole
+                # batch.
+                live = []
+                for req in groups[version]:
+                    status = self.pool.ensure_blocks(req.slot, req.pos)
+                    if status == "ok":
+                        req.stalled = False
+                        live.append(req)
+                    elif status == "quota" and self._quota_hopeless(req):
+                        self._fail_admitted(req, self._quota_exc(req))
+                    else:
+                        req.stalled = True
+                # Rows that are free, on another version or stalled
+                # are junk in this step.
+                inputs = self._step_inputs(
+                    (req.slot, req.out[-1], req.pos) for req in live
+                )
+            if not live:
+                continue
+            with tracing.phase("fed:serve:dispatch"):
+                logits = self.pool.decode_step(params, *inputs)
+                bs = self.pool.block_size
+                attended = sum(req.pos // bs + 1 for req in live)
+                slab = self.pool.max_slots * self.pool.blocks_per_row
+                self._stats["kv_blocks_attended"] += attended
+                self._stats["kv_blocks_slab"] += slab
+                self._m_kv_attended.inc(attended)
+                self._m_kv_slab.inc(slab)
+                if self._recurrent:
+                    # Read and written once each by every live row;
+                    # held: admitted rows whose state this step kept
+                    # (stalled, on another version, or between two
+                    # chunks of their prompt).
+                    moved = 2 * len(live) * self.pool.state_row_bytes
+                    held = len(self._active) - len(live) + sum(
+                        1 for r in self._prefilling if r.chunk_done
                     )
-                if not live:
-                    continue
-                with tracing.phase("fed:serve:dispatch"):
-                    logits = self.pool.decode_step(params, *inputs)
-                    bs = self.pool.block_size
-                    attended = sum(req.pos // bs + 1 for req in live)
-                    slab = b * self.pool.blocks_per_row
-                    self._stats["kv_blocks_attended"] += attended
-                    self._stats["kv_blocks_slab"] += slab
-                    self._m_kv_attended.inc(attended)
-                    self._m_kv_slab.inc(slab)
-                    if self._recurrent:
-                        # Read and written once each by every live row;
-                        # held: admitted rows whose state this step kept
-                        # (stalled, on another version, or between two
-                        # chunks of their prompt).
-                        moved = 2 * len(live) * self.pool.state_row_bytes
-                        held = len(self._active) - len(live) + sum(
-                            1 for r in self._prefilling if r.chunk_done
-                        )
-                        self._stats["ssm_state_bytes"] += moved
-                        self._stats["state_rows_held"] += held
-                        self._m_state_bytes.inc(moved)
-                reqs = live
-            else:
-                with tracing.phase("fed:serve:build"):
-                    tokens = np.zeros(b, np.int32)
-                    positions = np.full(b, self.pool.junk_pos, np.int32)
-                    for req in reqs:
-                        tokens[req.slot] = req.out[-1]
-                        positions[req.slot] = req.pos
-                with tracing.phase("fed:serve:dispatch"):
-                    k, v = self.pool.kv
-                    logits, k, v = self._step_fn(
-                        params, k, v,
-                        jnp.asarray(tokens), jnp.asarray(positions),
-                    )
-                    self.pool.replace(k, v)
+                    self._stats["ssm_state_bytes"] += moved
+                    self._stats["state_rows_held"] += held
+                    self._m_state_bytes.inc(moved)
             self._stats["steps"] += 1
             self._m_steps.inc()
             with tracing.phase("fed:serve:fetch"):
@@ -1364,9 +1182,9 @@ class InferenceServer:
             # the two interleaved row by row.
             with tracing.phase("fed:serve:sample"):
                 toks = [self._sample(logits_np[req.slot], req)
-                        for req in reqs]
+                        for req in live]
             with tracing.phase("fed:serve:emit"):
-                for req, tok in zip(reqs, toks):
+                for req, tok in zip(live, toks):
                     req.out.append(tok)
                     req.pos += 1
                     progressed = True
@@ -1497,9 +1315,6 @@ _servers: JobScoped = JobScoped("serving.servers", default_factory=dict)
 
 
 def register_server(server: InferenceServer) -> None:
-    from rayfed_tpu.tenancy.context import current_job
-    from rayfed_tpu.tenancy.qos import get_ledger
-
     with _registry_lock:
         registry = _servers.get()
         old = registry.get(server.name)
@@ -1508,15 +1323,6 @@ def register_server(server: InferenceServer) -> None:
                 f"a server named {server.name!r} is already registered; "
                 "stop it first or pick another name"
             )
-        if old is not server and not isinstance(server.pool, PagedKVPool):
-            # Slab KV decode rows come out of a pooled accelerator
-            # budget: charge this tenant for the slots its engine pins
-            # up front. Raises TenantQuotaExceeded before the engine is
-            # registered. (A paged pool instead self-charges per block
-            # grant — the whole point of block granularity.)
-            job = current_job()
-            get_ledger().charge(job, "kv_blocks", server.pool.max_slots)
-            server._kv_ledger_charge = (job, server.pool.max_slots)
         registry[server.name] = server
 
 
@@ -1531,20 +1337,9 @@ def get_server(name: str = "default") -> InferenceServer:
     return server
 
 
-def _release_kv_charge(server: Optional[InferenceServer]) -> None:
-    charge = getattr(server, "_kv_ledger_charge", None)
-    if charge is None:
-        return
-    from rayfed_tpu.tenancy.qos import get_ledger
-
-    server._kv_ledger_charge = None
-    get_ledger().release(charge[0], "kv_blocks", charge[1])
-
-
 def unregister_server(name: str) -> None:
     with _registry_lock:
-        server = _servers.get().pop(name, None)
-    _release_kv_charge(server)
+        _servers.get().pop(name, None)
 
 
 # -- standby replicas (ModelBank replication / promotion) --------------------
@@ -1582,4 +1377,3 @@ def stop_all_servers(timeout: float = 10.0) -> None:
             server.stop(timeout)
         except Exception:  # noqa: BLE001 - teardown best-effort
             logger.exception("serving[%s]: stop failed", server.name)
-        _release_kv_charge(server)

@@ -88,8 +88,8 @@ class TenancyConfig:
         shm_ring_quota_mb: cap on this tenant's in-flight shm ring bytes
             across all peers (None = unlimited). Exceeding it raises
             :class:`TenantQuotaExceeded` on the offending send.
-        kv_block_quota: cap on serving KV-cache slots (decode rows)
-            across this tenant's inference servers (None = unlimited).
+        kv_block_quota: cap on resident serving KV-cache blocks across
+            this tenant's inference servers (None = unlimited).
         executor_quota: cap on concurrently in-flight tasks in this
             tenant's executor pool (None = unlimited).
     """

@@ -26,6 +26,7 @@ The load-bearing guarantees:
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import threading
@@ -43,7 +44,7 @@ from rayfed_tpu import tracing  # noqa: E402
 from rayfed_tpu.config import ServingConfig  # noqa: E402
 from rayfed_tpu.models import decode  # noqa: E402
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
-from rayfed_tpu.serving.kv_pool import KVPool  # noqa: E402
+from rayfed_tpu.serving.kv_pool import PagedKVPool  # noqa: E402
 from rayfed_tpu.serving.publish import ModelBank  # noqa: E402
 from rayfed_tpu.serving.server import (  # noqa: E402
     InferenceServer,
@@ -73,7 +74,7 @@ def _reference(params, prompt, max_new):
 
 
 def test_pool_acquire_release_cycle():
-    pool = KVPool(CFG, max_slots=2, max_len=8)
+    pool = PagedKVPool(CFG, max_slots=2, max_len=8, block_size=4)
     a, b = pool.acquire(), pool.acquire()
     assert {a, b} == {0, 1}
     assert pool.acquire() is None
@@ -84,7 +85,7 @@ def test_pool_acquire_release_cycle():
 
 
 def test_pool_prefix_index_dropped_on_release():
-    pool = KVPool(CFG, max_slots=2, max_len=8)
+    pool = PagedKVPool(CFG, max_slots=2, max_len=8, block_size=4)
     slot = pool.acquire()
     pool.note_prefix(slot, 1, b"abc")
     assert pool.lookup_prefix(1, b"abc") == slot
@@ -93,11 +94,21 @@ def test_pool_prefix_index_dropped_on_release():
     assert pool.lookup_prefix(1, b"abc") is None
 
 
-def test_pool_allocates_sacrificial_position():
-    pool = KVPool(CFG, max_slots=2, max_len=8)
+def test_pool_block_zero_is_sacrificial_and_never_granted():
+    pool = PagedKVPool(CFG, max_slots=2, max_len=8, block_size=4)
     k, _ = pool.kv
-    assert k.shape[2] == 9
-    assert pool.junk_pos == 8
+    # One block beyond num_blocks (rows of max_len + 1 = 9 positions
+    # take 3 blocks of 4 each): block 0, where junk rows write.
+    assert pool.num_blocks == 6 and k.shape[1:3] == (7, 4)
+    slots = [pool.acquire(), pool.acquire()]
+    for slot in slots:
+        assert pool.ensure_blocks(slot, 8) == "ok"
+    granted = np.concatenate([pool.table(slot) for slot in slots])
+    assert sorted(granted) == [1, 2, 3, 4, 5, 6]
+    assert pool.blocks_free == 0
+    pool.release(slots[0])
+    # A released slot's table points at block 0 again: ungranted.
+    assert not pool.table(slots[0]).any() and pool.blocks_free == 3
 
 
 # ---------------------------------------------------------------------------
@@ -455,20 +466,44 @@ def test_request_timeline_noop_when_disabled():
 
 
 # ---------------------------------------------------------------------------
-# Sequential (naive) mode — the bench baseline uses the same engine
+# Retired options: refused by name, and the benchmark's own files build
 
 
-def test_sequential_mode_serves_one_at_a_time():
-    srv = _server(mode="sequential")
-    try:
-        prompts = [list(range(i, i + 6)) for i in range(1, 5)]
-        futs = [srv.submit(p, max_new_tokens=4) for p in prompts]
-        for p, f in zip(prompts, futs):
-            assert f.result(timeout=120)["tokens"] == _reference(
-                PARAMS_A, p, 4
-            )
-    finally:
-        srv.stop()
+@pytest.mark.parametrize("build", [
+    lambda: ServingConfig(kv_layout="slab"),
+    lambda: ServingConfig.from_dict({"kv_layout": "slab"}),
+], ids=["init", "from_dict"])
+def test_slab_layout_is_refused_by_name(build):
+    with pytest.raises(ValueError, match="kv_layout.*removed in PR 29"):
+        build()
+    assert ServingConfig.from_dict({"kv_layout": "paged"}).max_slots == 8
+
+
+def test_mode_is_an_unknown_serving_key():
+    with pytest.raises(ValueError, match="unknown serving config key 'mode'"):
+        ServingConfig.from_dict({"mode": "continuous"})
+
+
+def _mix_serving_blocks():
+    root = os.path.join(os.path.dirname(__file__), "..", "chipbench", "mixes")
+    for path in sorted(glob.glob(os.path.join(root, "*.json"))):
+        with open(path) as f:
+            mix = json.load(f)
+        for where, block in (("top", mix), ("rehearsal", mix.get("rehearsal"))):
+            if block and "serving" in block:
+                yield pytest.param(
+                    block["serving"],
+                    id=f"{os.path.basename(path)[:-5]}-{where}",
+                )
+
+
+@pytest.mark.parametrize("serving", _mix_serving_blocks())
+def test_benchmark_mix_serving_blocks_build(serving):
+    # The mix files are the benchmark's (no PR but a `benchmark` one may
+    # edit them) and from_dict is strict: a key retired here while they
+    # still pass it would fail every serving cell.
+    scfg = ServingConfig.from_dict(serving)
+    assert scfg.kv_layout == "paged"
 
 
 # ---------------------------------------------------------------------------
@@ -552,31 +587,59 @@ def test_serve_two_party_e2e():
 
 
 # ---------------------------------------------------------------------------
-# Serving plane v2: paged KV layout. The bitwise contract — a request's
-# output depends only on (version, prompt, seed), never on what shares
-# its batch — holds within a layout. Across layouts the two step programs
-# agree to rounding (the paged step's online softmax re-associates the
+# The paged engine. The bitwise contract — a request's output depends only
+# on (version, prompt, seed), never on what shares its batch — holds among
+# its own schedules. Against the plain cached forward, one request at a
+# time, the step agrees to rounding (its online softmax re-associates the
 # sum); the six seeded prompts below still sample the same tokens.
 
 
-def test_paged_matches_slab_bitwise_mixed_lengths():
+def _sampled_reference(params, prompt, max_new, temperature, seed, max_len):
+    """A host loop over ``decode.forward_with_cache``, one request alone,
+    sampling by the engine's rule (``InferenceServer._sample``): inverse
+    CDF of the float64 softmax, one uniform a token from
+    ``default_rng(seed)``."""
+    rng = np.random.default_rng(seed)
+
+    def sample(logits):
+        z = np.asarray(logits, np.float32).astype(np.float64) / temperature
+        cdf = np.cumsum(np.exp(z - z.max()))
+        u = rng.random() * cdf[-1]
+        return int(min(np.searchsorted(cdf, u, side="right"), z.shape[0] - 1))
+
+    cache = decode.init_cache(CFG, 1, max_len + 1)
+    logits, cache = decode.forward_with_cache(
+        params, jnp.asarray([prompt], jnp.int32), cache, 0, CFG
+    )
+    out = [sample(logits[0, len(prompt) - 1])]
+    while len(out) < max_new:
+        logits, cache = decode.forward_with_cache(
+            params, jnp.asarray([[out[-1]]], jnp.int32), cache,
+            len(prompt) + len(out) - 1, CFG,
+        )
+        out.append(sample(logits[0, 0]))
+    return out
+
+
+def test_mixed_lengths_match_the_plain_reference():
     rng = np.random.default_rng(7)
     prompts = [
         [int(t) for t in rng.integers(1, 255, size=n)]
         for n in (3, 9, 14, 5, 12, 7)
     ]
-    outs = {}
-    for layout in ("slab", "paged"):
-        srv = _server(kv_layout=layout, temperature=0.8)
-        try:
-            futs = [
-                srv.submit(p, max_new_tokens=8, seed=i)
-                for i, p in enumerate(prompts)
-            ]
-            outs[layout] = [f.result(timeout=120)["tokens"] for f in futs]
-        finally:
-            srv.stop()
-    assert outs["paged"] == outs["slab"]
+    srv = _server(temperature=0.8)
+    try:
+        futs = [
+            srv.submit(p, max_new_tokens=8, seed=i)
+            for i, p in enumerate(prompts)
+        ]
+        outs = [f.result(timeout=120)["tokens"] for f in futs]
+    finally:
+        srv.stop()
+    assert outs == [
+        _sampled_reference(PARAMS_A, p, 8, 0.8, i, max_len=32)
+        for i, p in enumerate(prompts)
+    ]
 
 
 def test_chunked_prefill_matches_reference():

@@ -123,7 +123,7 @@ def _run_forward_with_cache(params):
     cache = decode.init_cache(CFG, 2, 24, CDT)
     toks = jnp.asarray([SHORT, LONG[:11]], jnp.int32)
     return jax.jit(
-        lambda p: MODEL.forward_with_cache(p, toks, cache, 0))(params)
+        lambda p: decode.forward_with_cache(p, toks, cache, 0, CFG))(params)
 
 
 @pytest.mark.parametrize(

@@ -395,44 +395,6 @@ def test_shm_ring_quota_on_ledger():
         tenancy.remove_context("quota_shm")
 
 
-def test_kv_block_quota_enforced_at_server_registration():
-    from rayfed_tpu.serving import server as serving_server
-
-    ctx = tenancy.create_context(
-        "quota_kv", "alice",
-        tenancy=TenancyConfig(kv_block_quota=4),
-    )
-
-    class _StubPool:
-        max_slots = 8
-
-    class _StubServer:
-        name = "stub"
-        pool = _StubPool()
-
-        def stop(self, timeout=10.0):
-            pass
-
-    try:
-        with tenancy.use_context(ctx):
-            with pytest.raises(TenantQuotaExceeded) as exc:
-                serving_server.register_server(_StubServer())
-            assert exc.value.resource == "kv_blocks"
-            # Under quota: registers, and unregister releases the charge.
-            _StubPool.max_slots = 4
-            srv = _StubServer()
-            serving_server.register_server(srv)
-            assert tenancy_qos.get_ledger().in_use(
-                "quota_kv", "kv_blocks"
-            ) == 4
-            serving_server.unregister_server("stub")
-            assert tenancy_qos.get_ledger().in_use(
-                "quota_kv", "kv_blocks"
-            ) == 0
-    finally:
-        tenancy.remove_context("quota_kv")
-
-
 def test_quota_rejections_land_in_telemetry():
     from rayfed_tpu.telemetry import metrics
 

@@ -179,12 +179,11 @@ PROMPTS = [[1, 2, 3, 4], [5, 6, 7, 8, 9, 10, 11], [3, 1, 4, 1, 5, 9, 2, 6, 5],
 PER_ITERATION = ("build", "dispatch", "fetch", "sample", "emit")
 
 
-def _serve_mixed(layout):
+def _serve_mixed():
     """A mixed greedy / sampled batch; a 9-token prompt over a 4-token
-    chunk makes the paged engine run chunked prefill as well."""
+    chunk makes the engine run chunked prefill as well."""
     scfg = ServingConfig(max_slots=4, max_len=32, max_new_tokens=6,
-                         kv_layout=layout, prefill_chunk=4,
-                         prefill_token_budget=8)
+                         prefill_chunk=4, prefill_token_budget=8)
     srv = InferenceServer(CFG, scfg, params=PARAMS)
     try:
         futs = [srv.submit(p, temperature=0.0 if i % 2 else 0.8, seed=40 + i)
@@ -196,14 +195,13 @@ def _serve_mixed(layout):
         srv.stop()
 
 
-@pytest.mark.parametrize("layout", ["slab", "paged"])
-def test_engine_phases_tile_the_iteration_and_keep_the_tokens(layout):
+def test_engine_phases_tile_the_iteration_and_keep_the_tokens():
     tracing.clear()
-    plain, _ = _serve_mixed(layout)
+    plain, _ = _serve_mixed()
     assert tracing.phase_summary() == {}
     tracing.enable()
     try:
-        again, stats = _serve_mixed(layout)
+        again, stats = _serve_mixed()
         phases = tracing.phase_summary()
     finally:
         tracing.disable()
@@ -217,8 +215,7 @@ def test_engine_phases_tile_the_iteration_and_keep_the_tokens(layout):
     for name in ("admit", "prefill_chunk", "idle"):
         assert phases["fed:serve:" + name]["count"] >= 1, name
     assert not [n for n in phases if not n.startswith("fed:serve:")]
-    if layout == "paged":
-        assert stats["prefill_chunks"] >= 2
+    assert stats["prefill_chunks"] >= 2
 
 
 # ---------------------------------------------------------------------------

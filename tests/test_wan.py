@@ -303,12 +303,32 @@ def test_backoff_ceiling_caps_every_pause(monkeypatch):
     assert sleeps == [0.08, 0.08]  # WAN-tuned 5s/10s capped to the link
 
 
-def test_final_attempt_always_fits_the_deadline():
+class _SleepClock:
+    """Stands in for the ``time`` module as ``resilience.retry`` reads
+    it: a monotonic clock that only a sleep advances."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def monotonic(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+def test_final_attempt_always_fits_the_deadline(monkeypatch):
     """The boundary case: WAN-scale backoff (5s) against a sub-second
     deadline. Without the final-fit clamp the loop sleeps the budget
     away and the last attempt starts exactly as the deadline expires;
     with it, all attempts run and the loop finishes within the budget
-    (pauses are shortened to leave one attempt's cost of headroom)."""
+    (pauses are shortened to leave one attempt's cost of headroom).
+    On a clock of its own: on the wall clock a sleep that overshoots by
+    the millisecond of headroom (a loaded host) ends the loop early."""
+    from rayfed_tpu.resilience import retry
+
+    clock = _SleepClock()
+    monkeypatch.setattr(retry, "time", clock)
     calls = []
     policy = RetryPolicy(
         max_attempts=3, initial_backoff_ms=5000, max_backoff_ms=30000,
@@ -316,18 +336,19 @@ def test_final_attempt_always_fits_the_deadline():
     )
 
     def fail(attempt):
-        calls.append(time.monotonic())
+        calls.append(clock.monotonic())
         raise OSError("nope")
 
-    deadline = Deadline(0.4)
-    t0 = time.monotonic()
+    budget = 0.4
+    t0 = clock.monotonic()
+    deadline = Deadline(budget)
     with pytest.raises(ConnectionError, match="failed after 3 attempt"):
         run_with_retry(fail, policy, deadline=deadline)
-    elapsed = time.monotonic() - t0
     assert len(calls) == 3
-    assert elapsed < 1.0  # not 5s+5s of uncapped backoff
+    assert clock.monotonic() - t0 < budget  # not 5s+5s of uncapped backoff
     # Every attempt STARTED before the budget ran out.
-    assert all(t - t0 <= 0.45 for t in calls)
+    assert all(t - t0 < budget for t in calls)
+    assert calls[1] - t0 > 0.39  # and the budget was used, not skipped
 
 
 # ---------------------------------------------------------------------------
