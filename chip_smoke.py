@@ -499,8 +499,8 @@ def _define_tasks(fed):
 
     @fed.remote
     def prefill_logits_check(name, prompt):
-        # The engine's own prefill program on the engine's own params,
-        # against the plain forward pass on the same params.
+        # The engine's prefill on the engine's own params, against the
+        # plain forward pass on the same params.
         import jax
         import jax.numpy as jnp
 
@@ -517,10 +517,13 @@ def _define_tasks(fed):
         prompts[0, :plen] = prompt
         last_idx = np.zeros(rows, np.int32)
         last_idx[0] = plen - 1
-        last, *_ = srv._get_prefill_rows_fn(bucket)(
-            params, jnp.asarray(prompts), jnp.asarray(last_idx),
-            jnp.arange(rows) == 0,
-        )
+        # The model's member the engine's program wraps: the engine's own
+        # ends in the choice of the token and returns no logits.
+        last, *_ = jax.jit(
+            lambda p, toks, idx, landed: srv.model.prefill_rows(
+                p, toks, idx, srv.scfg.max_len + 1, srv._cache_dtype, landed)
+        )(params, jnp.asarray(prompts), jnp.asarray(last_idx),
+          jnp.arange(rows) == 0)
         ref = jax.jit(lambda p, t: tfm.forward(p, t, srv.cfg))(
             params, jnp.asarray([prompt], jnp.int32)
         )[0, -1]

@@ -24,6 +24,9 @@ keep landing new aggregates:
  - :mod:`rayfed_tpu.serving.kv_pool` — the KV store: the block-granular
    paged pool (block tables, on-demand grants, prefix reuse by table
    sharing);
+ - :mod:`rayfed_tpu.serving.sampling` — the one sampler, traced at the
+   end of the engine's programs: the next token is chosen on the device
+   and ids, not logits, come back;
  - :mod:`rayfed_tpu.serving.publish` — versioned atomic hot model swap
    over device-resident snapshots;
  - :mod:`rayfed_tpu.serving.stream` — incremental token streaming over

@@ -32,8 +32,10 @@ ledger so ``tenancy.kv_block_quota`` means actual resident blocks.
 Decode: one jitted program per iteration
 (:func:`rayfed_tpu.models.decode.paged_decode_step`) reads each row's K/V
 through its block table, a chunk of blocks at a time under an online
-softmax, and writes the new token's K/V straight into its
-(block, offset); the pool pair is donated and is the only K/V buffer —
+softmax, writes the new token's K/V straight into its
+(block, offset) and ends in the choice of each row's next token
+(:mod:`rayfed_tpu.serving.sampling`): ids come back, not logits; the
+pool pair is donated and is the only K/V buffer —
 no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
 blocks read follow the longest live row, not ``max_len``. It agrees with
 the plain cached forward (:func:`rayfed_tpu.models.decode.
@@ -87,6 +89,7 @@ import numpy as np
 
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
+from rayfed_tpu.serving import sampling
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -207,16 +210,19 @@ class PagedKVPool:
 
         # The state is a pytree ({} for a model without one: no argument,
         # no output, the program it ran before there was one); what exists
-        # for its sake trails and may be left out.
+        # for its sake trails and may be left out. The program ends in the
+        # choice of each row's next token (serving/sampling.py): (R,) ids
+        # come back, the (R, vocab) logits stay on the device.
         @jax.named_scope("serve/decode_step")
-        def decode_step(params, pk, pv, tokens, positions, tables,
+        def decode_step(params, pk, pv, tokens, positions, tables, draw,
                         state=None, live=None):
-            return model.decode_step(
+            logits, pk, pv, state = model.decode_step(
                 params, pk, pv, state or {}, tokens, positions, tables, live
             )
+            return sampling.choose_packed(logits, draw), pk, pv, state
 
         self._decode_step_fn = jax.jit(
-            decode_step, donate_argnums=(1, 2, 6)
+            decode_step, donate_argnums=(1, 2, 7)
         )
 
         @jax.named_scope("serve/gather")
@@ -283,24 +289,30 @@ class PagedKVPool:
             scatter_row, donate_argnums=(0, 1, 5)
         )
 
-    def decode_step(self, params, tokens, positions, tables, live=None):
+    def decode_step(self, params, tokens, positions, tables, draw,
+                    live=None):
         """One decode token per row through the block tables, the pool
         updated in place; junk rows carry position 0 and an all-zero
-        table. ``live`` (R,) bool names the rows whose recurrent state
+        table. ``draw`` (3, R) int32 is the sampler's per-row scalars
+        (:func:`rayfed_tpu.serving.sampling.pack`; all zero: every row
+        greedy). ``live`` (R,) bool names the rows whose recurrent state
         advances; every other row's state comes back bit for bit. A
-        model without such a state takes none. Returns the (R, vocab)
-        logits."""
-        logits, self._k, self._v, self._state = self._decode_step_fn(
-            params, self._k, self._v, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(tables), self._state,
-            self._of_state(live, bool),
+        model without such a state takes none. Returns each row's next
+        token, (R,) int32, on the device. The small host arrays go to
+        the program as NumPy, here and in the pool's other programs: the
+        jitted call uploads its own arguments, and a ``jnp.asarray``
+        around each cost the engine thread 0.15 ms of dispatch apiece
+        on the chip's host (``PERF.md`` §6, PR 30)."""
+        ids, self._k, self._v, self._state = self._decode_step_fn(
+            params, self._k, self._v, tokens, positions, tables, draw,
+            self._state, self._of_state(live, bool),
         )
-        return logits
+        return ids
 
     def _of_state(self, value, dtype):
-        """An argument that exists for the state's sake: on the device
-        for a model that has one, not sent at all for one that has none."""
-        return jnp.asarray(value, dtype) if self._state else None
+        """An argument that exists for the state's sake: handed on for a
+        model that has one, not sent at all for one that has none."""
+        return np.asarray(value, dtype) if self._state else None
 
     def gather_slot(self, slot: int):
         """One slot's contiguous row (chunked-prefill input) and its row
@@ -308,8 +320,8 @@ class PagedKVPool:
         with self._lock:
             table = self._tables[slot].copy()
         return self._gather_row_fn(
-            self._k, self._v, jnp.asarray(table), self._state,
-            self._of_state(slot, jnp.int32),
+            self._k, self._v, table, self._state,
+            self._of_state(slot, np.int32),
         )
 
     def scatter_rows(self, k_slab, v_slab, tables: np.ndarray,
@@ -317,7 +329,7 @@ class PagedKVPool:
         """Land a round of prefilled rows: K/V through ``tables``, each
         row's fresh recurrent state where ``landed`` (R,) bool says."""
         self._k, self._v, self._state = self._scatter_rows_fn(
-            self._k, self._v, k_slab, v_slab, jnp.asarray(tables),
+            self._k, self._v, k_slab, v_slab, tables,
             self._state, state_rows or {}, self._of_state(landed, bool),
         )
 
@@ -325,8 +337,8 @@ class PagedKVPool:
         with self._lock:
             table = self._tables[slot].copy()
         self._k, self._v, self._state = self._scatter_row_fn(
-            self._k, self._v, k_row, v_row, jnp.asarray(table),
-            self._state, state_row or {}, self._of_state(slot, jnp.int32),
+            self._k, self._v, k_row, v_row, table,
+            self._state, state_row or {}, self._of_state(slot, np.int32),
         )
 
     @property
@@ -486,7 +498,7 @@ class PagedKVPool:
         self._k, self._v = _copy_block(
             self._k,
             self._v,
-            jnp.asarray(src_blk, jnp.int32),
-            jnp.asarray(dst_blk, jnp.int32),
+            np.int32(src_blk),
+            np.int32(dst_blk),
         )
         return "ok"

@@ -35,7 +35,10 @@ a whole round of short-prompt prefills into ONE dispatch, splits prompts
 longer than ``serving.prefill_chunk`` into fixed-size chunks merged into
 the running decode iteration under a ``prefill_token_budget`` per step
 (admission never stalls the live batch), and grants KV blocks on demand
-at token boundaries — when the pool truly runs dry the engine preempts
+at token boundaries. Every program ends in the choice of the next token
+(:mod:`rayfed_tpu.serving.sampling`): the host fetches ``(R,)`` ids, never
+the ``(R, vocab)`` logits, and does only bookkeeping (``eos``,
+``max_new_tokens``, streams). When the pool truly runs dry the engine preempts
 the youngest request (its blocks return to the free list, the request
 re-queues and deterministically re-runs under its pinned version), so
 mixed-length traffic degrades by latency, never by abort.
@@ -75,6 +78,7 @@ from rayfed_tpu import tracing
 from rayfed_tpu.config import ServingConfig
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
+from rayfed_tpu.serving import sampling
 from rayfed_tpu.serving.kv_pool import PagedKVPool
 from rayfed_tpu.serving.publish import (
     ModelBank,
@@ -122,7 +126,6 @@ class _Request:
     pos: int = 0                  # next cache write position (= seq length)
     out: List[int] = field(default_factory=list)
     prefix_reuse: bool = False
-    rng: Optional[np.random.Generator] = None
     timing: Dict[str, float] = field(default_factory=dict)
     extra_resp: Dict[str, Any] = field(default_factory=dict)
     stream: Any = None            # optional token sink (serving.stream)
@@ -229,6 +232,13 @@ class InferenceServer:
             # serving dtype as versions were installed (0: the trees came
             # in it, or the model takes them as published).
             "publish_cast_bytes": 0,
+            # Bytes the engine thread pulled host-ward from its programs'
+            # outputs (the chosen ids: 4 x max_slots a step or a prefill
+            # round, 4 a last chunk), and decode steps in which at least
+            # one live row was sampled (the noise's branch ran), beside
+            # "steps".
+            "fetch_bytes": 0,
+            "draw_steps": 0,
         }
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
@@ -322,6 +332,17 @@ class InferenceServer:
             "as versions were installed.",
             labels=("server",),
         ).labels(server=name)
+        self._m_fetch_bytes = _reg.counter(
+            "fed_serving_fetch_bytes_total",
+            "Bytes the engine thread fetched from its programs' outputs "
+            "(the chosen token ids).",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_draw_steps = _reg.counter(
+            "fed_serving_draw_steps_total",
+            "Decode steps in which at least one live row was sampled.",
+            labels=("server",),
+        ).labels(server=name)
         self._update_kv_gauges()
         # Whatever way a version comes in (publish, a promoted standby's
         # state), the bank's snapshot of it is the tree the programs read.
@@ -344,8 +365,9 @@ class InferenceServer:
         the other lanes scatter into the sacrificial block and nothing
         reads what comes back for them, so a model need not compute
         them). Fresh zero rows (and a zero recurrent state), not recycled
-        ones. Returns (logits at ``last_idx`` (R, V), K/V rows, the
-        rows' state)."""
+        ones. Returns (each row's first token (R,) int32, chosen from the
+        logits at ``last_idx`` under ``draw``, K/V rows, the rows'
+        state)."""
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
@@ -356,10 +378,11 @@ class InferenceServer:
         dtype = self._cache_dtype
 
         @jax.named_scope("serve/prefill")
-        def prefill_rows(params, prompts, last_idx, landed):
-            return model.prefill_rows(
+        def prefill_rows(params, prompts, last_idx, landed, draw):
+            last, *rows = model.prefill_rows(
                 params, prompts, last_idx, row_len, dtype, landed
             )
+            return (sampling.choose_packed(last, draw), *rows)
 
         fn = jax.jit(prefill_rows)
         self._prefill_fns[bucket] = fn
@@ -372,7 +395,9 @@ class InferenceServer:
         prompt (the ragged remainder is chunked FIRST), so the dynamic
         update can never clamp over live positions. ``n_real`` of the
         chunk's positions are the prompt's, the rest padding. Returns
-        (the logits of the last real position, K/V rows, state)."""
+        (the token chosen from the last real position's logits under
+        ``draw`` (3, 1): the request's first token when this is its last
+        chunk, read by nobody otherwise; K/V rows; state)."""
         fn = self._chunk_fns.get(clen)
         if fn is not None:
             return fn
@@ -381,10 +406,12 @@ class InferenceServer:
         model = self.model
 
         @jax.named_scope("serve/chunk")
-        def chunk_step(params, k_row, v_row, state, toks, offset, n_real):
-            return model.chunk(
+        def chunk_step(params, k_row, v_row, state, toks, offset, n_real,
+                       draw):
+            last, *row = model.chunk(
                 params, k_row, v_row, state, toks, offset, n_real
             )
+            return (sampling.choose_packed(last[None], draw)[0], *row)
 
         fn = jax.jit(chunk_step, donate_argnums=(1, 2, 3))
         self._chunk_fns[clen] = fn
@@ -730,17 +757,18 @@ class InferenceServer:
                                version=req.version, slot=-1)
         self._run_special(req, params)
 
-    def _post_prefill(self, req: _Request, last_logits: np.ndarray) -> None:
+    def _post_prefill(self, req: _Request, chosen) -> None:
         """Shared admission tail (batched/chunked/donor paths): record
-        the prefix donor, sample the first token, and either finish or
-        join the decode batch."""
+        the prefix donor, take the first token (``chosen``, the id the
+        prefill's program picked), and either finish or join the decode
+        batch."""
         plen = int(req.prompt.size)
         self.pool.note_prefix(req.slot, req.version, req.prompt.tobytes())
         now = time.perf_counter()
         req.timing["prefill"] = now
         tracing.record_request(req.rid, "prefill", t_s=now,
                                reused=req.prefix_reuse)
-        tok = self._sample(last_logits, req)
+        tok = self._sample(chosen, req)
         req.out.append(tok)
         req.pos = plen
         now = time.perf_counter()
@@ -778,7 +806,6 @@ class InferenceServer:
         tracing.record_request(req.rid, "admit", t_s=now,
                                version=req.version, slot=slot)
         req.slot = slot
-        req.rng = np.random.default_rng(req.seed)
         plen = int(req.prompt.size)
         prompt_key = req.prompt.tobytes()
         if self.scfg.prefix_reuse:
@@ -796,16 +823,13 @@ class InferenceServer:
                 # Prefix reuse is a block-table copy: share the donor's
                 # fully-prompt blocks, clone only the boundary block,
                 # then one single-row step re-derives the last-position
-                # logits.
+                # logits and picks the first token from them.
                 status = self.pool.adopt_prefix(donor, slot, plen)
                 if status == "ok":
-                    last = self._step_one_row(
-                        params, slot, int(req.prompt[-1]), plen - 1
-                    )
                     req.prefix_reuse = True
                     self._stats["prefix_hits"] += 1
                     self._m_prefix_hits.inc()
-                    self._post_prefill(req, last)
+                    self._post_prefill(req, self._step_one_row(params, req))
                     return "ok"
                 # fall through: no blocks for the boundary clone — the
                 # plain grant below will hit the same wall and re-queue.
@@ -890,8 +914,6 @@ class InferenceServer:
         short-prompt request admitted this round."""
         if not batch:
             return
-        import jax.numpy as jnp
-
         groups: Dict[tuple, List[_Request]] = {}
         for req in batch:
             plen = int(req.prompt.size)
@@ -917,9 +939,9 @@ class InferenceServer:
                     tables[req.slot] = self.pool.table(req.slot)
                     landed[req.slot] = True
                 fn = self._get_prefill_rows_fn(bucket)
-                last, k_slab, v_slab, state_rows = fn(
-                    params, jnp.asarray(prompts), jnp.asarray(last_idx),
-                    jnp.asarray(landed),
+                ids, k_slab, v_slab, state_rows = fn(
+                    params, prompts, last_idx, landed,
+                    self._draw_inputs(reqs),
                 )
                 # Each landed row's recurrent state is the fresh one its
                 # prefill computed from zero: this is where a recycled
@@ -928,9 +950,9 @@ class InferenceServer:
                     k_slab, v_slab, tables, state_rows, landed
                 )
                 self._count_state_resets(len(reqs))
-                last_np = np.asarray(last, np.float32)
+                ids = self._fetch(ids)
                 for req in reqs:
-                    self._post_prefill(req, last_np[req.slot])
+                    self._post_prefill(req, ids[req.slot])
             except BaseException as e:  # noqa: BLE001 - per-group fault
                 for req in reqs:
                     if req.slot >= 0:
@@ -956,8 +978,6 @@ class InferenceServer:
                 return False
         if not work:
             return False
-        import jax.numpy as jnp
-
         budget = self.scfg.prefill_token_budget
         chunk = self.scfg.prefill_chunk
         ran = False
@@ -992,10 +1012,10 @@ class InferenceServer:
                 k_row, v_row, state_row = self.pool.gather_slot(req.slot)
                 # The first chunk (offset 0) starts the request: the
                 # program zeroes the state it was handed.
-                logits, k_row, v_row, state_row = self._get_chunk_fn(clen)(
-                    params, k_row, v_row, state_row, jnp.asarray(toks),
-                    jnp.asarray(off, jnp.int32),
-                    jnp.asarray(real, jnp.int32),
+                chosen, k_row, v_row, state_row = self._get_chunk_fn(clen)(
+                    params, k_row, v_row, state_row, toks,
+                    np.int32(off), np.int32(real),
+                    sampling.pack([req.temperature], [req.seed], [0]),
                 )
                 self.pool.scatter_slot(req.slot, k_row, v_row, state_row)
                 if off == 0:
@@ -1009,7 +1029,7 @@ class InferenceServer:
                 if req.chunk_done >= plen:
                     with self._lock:
                         self._prefilling.remove(req)
-                    self._post_prefill(req, np.asarray(logits, np.float32))
+                    self._post_prefill(req, self._fetch(chosen))
             except BaseException as e:  # noqa: BLE001 - per-request fault
                 with self._lock:
                     if req in self._prefilling:
@@ -1027,23 +1047,49 @@ class InferenceServer:
         return ran
 
     def _step_inputs(self, rows):
-        """(tokens, positions, tables, live) of one paged decode step
-        from the live rows' ``(slot, token, position)``. Every other row
-        is junk: position 0 under an all-zero table, so it visits no
-        block and writes into the sacrificial block 0, and not ``live``,
-        so whatever recurrent state its slot holds comes back bit for
+        """(tokens, positions, tables, draw, live) of one paged decode
+        step from the live rows' ``(request, token, position)``. Every
+        other row is junk: position 0 under an all-zero table, so it
+        visits no block and writes into the sacrificial block 0; greedy
+        in ``draw``, so it asks for no noise; and not ``live``, so
+        whatever recurrent state its slot holds comes back bit for
         bit."""
         R = self.pool.max_slots
         tokens = np.zeros(R, np.int32)
         positions = np.zeros(R, np.int32)
         tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
         live = np.zeros(R, bool)
-        for slot, token, pos in rows:
-            tokens[slot] = token
-            positions[slot] = pos
-            tables[slot] = self.pool.table(slot)
-            live[slot] = True
-        return tokens, positions, tables, live
+        reqs = []
+        for req, token, pos in rows:
+            tokens[req.slot] = token
+            positions[req.slot] = pos
+            tables[req.slot] = self.pool.table(req.slot)
+            live[req.slot] = True
+            reqs.append(req)
+        return tokens, positions, tables, self._draw_inputs(reqs), live
+
+    def _draw_inputs(self, reqs) -> np.ndarray:
+        """The sampler's per-row scalars for a program over all slots
+        (:func:`sampling.pack`, one upload): each request's temperature,
+        seed and the position in its output of the token about to be
+        chosen, at its slot; zero (greedy) everywhere else."""
+        R = self.pool.max_slots
+        temperature = np.zeros(R, np.float32)
+        seed = [0] * R
+        index = np.zeros(R, np.int32)
+        for req in reqs:
+            temperature[req.slot] = req.temperature
+            seed[req.slot] = req.seed
+            index[req.slot] = len(req.out)
+        return sampling.pack(temperature, seed, index)
+
+    def _fetch(self, ids) -> np.ndarray:
+        """The ids a program chose, on the host (this waits for the
+        program), counted in ``fetch_bytes``."""
+        ids = np.asarray(ids)
+        self._stats["fetch_bytes"] += ids.nbytes
+        self._m_fetch_bytes.inc(ids.nbytes)
+        return ids
 
     def _count_state_resets(self, n: int) -> None:
         if self._recurrent:
@@ -1051,15 +1097,15 @@ class InferenceServer:
                 self._stats["state_resets"] += n
             self._m_state_resets.inc(n)
 
-    def _step_one_row(
-        self, params, slot: int, token: int, pos: int
-    ) -> np.ndarray:
-        """The decode program with only ``slot`` live (every other row
-        is junk whatever its state: see :meth:`_step_inputs`)."""
-        logits = self.pool.decode_step(
-            params, *self._step_inputs([(slot, token, pos)])
-        )
-        return np.asarray(logits, np.float32)[slot]
+    def _step_one_row(self, params, req: _Request):
+        """The decode program with only ``req``'s row live, at the last
+        position of its prompt (every other row is junk whatever its
+        state: see :meth:`_step_inputs`). Returns the id it chose for
+        that row: the request's first token."""
+        ids = self.pool.decode_step(params, *self._step_inputs(
+            [(req, int(req.prompt[-1]), int(req.prompt.size) - 1)]
+        ))
+        return self._fetch(ids)[req.slot]
 
     def _emit_token(self, req: _Request, tok: int) -> None:
         if req.stream is None:
@@ -1073,8 +1119,9 @@ class InferenceServer:
         """Deadlock breaker: when an iteration made no progress and
         someone is stalled on a block grant, preempt the youngest
         admitted request — release its blocks, re-queue it, and let it
-        deterministically re-run later (same version pin, same rng seed
-        => bit-identical tokens, so streams just skip the replay).
+        deterministically re-run later (same version pin, and the
+        sampler's key is (seed, position in the output), no state to
+        rewind => bit-identical tokens, so streams just skip the replay).
         Returns True when a victim was taken (the loop should retry
         immediately rather than back off)."""
         with self._lock:
@@ -1147,12 +1194,12 @@ class InferenceServer:
                 # Rows that are free, on another version or stalled
                 # are junk in this step.
                 inputs = self._step_inputs(
-                    (req.slot, req.out[-1], req.pos) for req in live
+                    (req, req.out[-1], req.pos) for req in live
                 )
             if not live:
                 continue
             with tracing.phase("fed:serve:dispatch"):
-                logits = self.pool.decode_step(params, *inputs)
+                ids = self.pool.decode_step(params, *inputs)
                 bs = self.pool.block_size
                 attended = sum(req.pos // bs + 1 for req in live)
                 slab = self.pool.max_slots * self.pool.blocks_per_row
@@ -1160,6 +1207,9 @@ class InferenceServer:
                 self._stats["kv_blocks_slab"] += slab
                 self._m_kv_attended.inc(attended)
                 self._m_kv_slab.inc(slab)
+                if any(req.temperature > 0.0 for req in live):
+                    self._stats["draw_steps"] += 1
+                    self._m_draw_steps.inc()
                 if self._recurrent:
                     # Read and written once each by every live row;
                     # held: admitted rows whose state this step kept
@@ -1175,16 +1225,10 @@ class InferenceServer:
             self._stats["steps"] += 1
             self._m_steps.inc()
             with tracing.phase("fed:serve:fetch"):
-                logits_np = np.asarray(logits, np.float32)
-            # Sample every live row, then emit every row: two phases, not
-            # a pair per row. Each request draws from its own rng, so the
-            # tokens per (version, prompt, seed) are what they were when
-            # the two interleaved row by row.
-            with tracing.phase("fed:serve:sample"):
-                toks = [self._sample(logits_np[req.slot], req)
-                        for req in live]
+                ids = self._fetch(ids)
             with tracing.phase("fed:serve:emit"):
-                for req, tok in zip(live, toks):
+                for req in live:
+                    tok = self._sample(ids[req.slot], req)
                     req.out.append(tok)
                     req.pos += 1
                     progressed = True
@@ -1199,20 +1243,22 @@ class InferenceServer:
                         self._finish(req)
         return progressed
 
-    def _sample(self, logits: np.ndarray, req: _Request) -> int:
-        if req.temperature <= 0.0:
-            return int(np.argmax(logits))
-        z = logits.astype(np.float64) / req.temperature
-        z -= z.max()
-        p = np.exp(z)
-        # Inverse-CDF draw: one uniform from the request's own rng, one
-        # searchsorted. Semantically Generator.choice(p=...), but ~20x
-        # cheaper — at 8 samples per batched iteration, choice() was the
-        # single largest per-token cost in the engine.
-        cdf = np.cumsum(p)
-        u = req.rng.random() * cdf[-1]
-        return int(min(np.searchsorted(cdf, u, side="right"),
-                       logits.shape[0] - 1))
+    @staticmethod
+    def _sample(chosen, req: _Request) -> int:
+        """The one place where an id fetched from a program becomes the
+        request's token; nothing is sampled here (the choice was made on
+        the device, :mod:`rayfed_tpu.serving.sampling`). It stays an
+        attribute of this name, called as ``self._sample(chosen, req)``
+        once per row by the decode step and by every first-token path,
+        because the benchmark's control ``--inject broken-token`` wraps
+        exactly this attribute (``chipbench/serving.py:run``) to alter a
+        token where it is produced and see ``correct`` come out false; a
+        ``benchmark`` issue may move that hook. Static, so that whoever
+        keeps the original holds a function and not the engine: the
+        control keeps it in a local across the engine's shutdown, and a
+        bound method there kept the pool and the weights on the device
+        under the reference that runs next."""
+        return int(chosen)
 
     def _finish(self, req: _Request) -> None:
         if req.stream is not None:

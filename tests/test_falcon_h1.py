@@ -38,8 +38,10 @@ from rayfed_tpu.config import ServingConfig
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import falcon_h1 as fh
 from rayfed_tpu.models import transformer as tfm
+from rayfed_tpu.serving import sampling
 from rayfed_tpu.serving.kv_pool import PagedKVPool
 from rayfed_tpu.serving.server import InferenceServer
+from tests.utils import step_logits
 
 ref = importlib.import_module("chipbench.references.falcon_h1")
 
@@ -96,16 +98,25 @@ def _server(cfg=CFG, params=PARAMS, **kw):
                            cache_dtype=cfg.compute_dtype)
 
 
-def _record_logits(srv):
-    """Every logits row the engine samples from, by request id."""
+def _record_logits(monkeypatch, seed):
+    """Every logits row the engine's programs choose a token from for the
+    request submitted with ``seed`` (greedy: the seed only marks its row),
+    by the token's position in the output. The sampler is looked up when
+    a program is traced, so engines built after this call record; a
+    chunk that is not the prompt's last also reaches the sampler at
+    position 0, and the last one, which comes last, is the one kept."""
     seen = {}
-    sample = srv._sample
+    choose = sampling.choose_tokens
 
-    def spy(logits, req):
-        seen.setdefault(req.rid, []).append(np.array(logits))
-        return sample(logits, req)
+    def record(logits, seeds, index):
+        for row in np.flatnonzero(seeds == seed):
+            seen[int(index[row])] = np.array(logits[row])
 
-    srv._sample = spy
+    def spy(logits, temperature, seeds, index):
+        jax.debug.callback(record, logits, seeds, index, ordered=True)
+        return choose(logits, temperature, seeds, index)
+
+    monkeypatch.setattr(sampling, "choose_tokens", spy)
     return seen
 
 
@@ -190,16 +201,18 @@ def test_every_multiplier_matters_and_matches_the_reference(name):
     "plen", [1, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK + 5],
     ids=["one", "chunk-1", "chunk", "chunk+1", "two-chunks-and-a-rest"],
 )
-def test_prefill_then_decode_matches_the_reference_forward(plen):
-    """Every logits row the engine samples from (the prefill's last
-    position, then each decode step through the cache and the carried
-    state) == the reference's full forward over prompt + served tokens."""
+def test_prefill_then_decode_matches_the_reference_forward(plen, monkeypatch):
+    """Every logits row the engine chooses a token from (the prefill's
+    last position, then each decode step through the cache and the
+    carried state) == the reference's full forward over prompt + served
+    tokens."""
+    seen = _record_logits(monkeypatch, seed=4242)
     srv = _server()
     try:
-        seen = _record_logits(srv)
         prompt = _tokens(plen, seed=plen).tolist()
-        out = srv.submit(prompt, max_new_tokens=6).result(timeout=300)
-        got = np.stack(seen[out["request_id"]])
+        out = srv.submit(prompt, max_new_tokens=6, seed=4242).result(
+            timeout=300)
+        got = np.stack([seen[i] for i in range(6)])
         want = _ref_logits(prompt + out["tokens"][:-1])[plen - 1:]
         assert got.shape == want.shape
         assert np.abs(got - want).max() < TOL32
@@ -331,15 +344,15 @@ def test_a_row_alone_is_the_row_among_neighbours_bitwise():
     lengths = [5, 17, 30]
     pool, slots, tokens, positions, tables = _pool_with_rows(lengths)
     before = _state_of(pool)
-    together = np.asarray(pool.decode_step(
-        PARAMS, tokens, positions, tables, np.ones(3, bool)))
+    together = np.asarray(step_logits(
+        pool, PARAMS, tokens, positions, tables, np.ones(3, bool)))
     after = _state_of(pool)
     for r in range(3):
         solo, _, _, _, _ = _pool_with_rows(lengths)
         live = np.arange(3) == r
-        alone = np.asarray(solo.decode_step(
-            PARAMS, tokens * live, positions * live, tables * live[:, None],
-            live))
+        alone = np.asarray(step_logits(
+            solo, PARAMS, tokens * live, positions * live,
+            tables * live[:, None], live))
         assert np.array_equal(alone[r], together[r]), r
         solo_state = _state_of(solo)
         for name in before:
@@ -363,17 +376,17 @@ def test_a_held_rows_next_token_is_what_it_would_have_been():
     lengths = [9, 12]
     pool, _, tokens, positions, tables = _pool_with_rows(lengths)
     base, *_ = _pool_with_rows(lengths)
-    want = np.asarray(base.decode_step(
-        PARAMS, tokens, positions, tables, np.ones(2, bool)))[1]
+    want = np.asarray(step_logits(
+        base, PARAMS, tokens, positions, tables, np.ones(2, bool)))[1]
     live0 = np.array([True, False])
     tok, pos = tokens.copy(), positions.copy()
     for _ in range(2):
-        logits = np.asarray(pool.decode_step(
-            PARAMS, tok * live0, pos * live0, tables * live0[:, None],
+        logits = np.asarray(step_logits(
+            pool, PARAMS, tok * live0, pos * live0, tables * live0[:, None],
             live0))
         tok[0], pos[0] = int(logits[0].argmax()), pos[0] + 1
-    got = np.asarray(pool.decode_step(
-        PARAMS, tok, pos, tables, np.ones(2, bool)))[1]
+    got = np.asarray(step_logits(
+        pool, PARAMS, tok, pos, tables, np.ones(2, bool)))[1]
     assert np.array_equal(got, want)
 
 
@@ -384,10 +397,10 @@ def test_zeroing_the_state_moves_the_logits_beyond_the_tolerance():
     fresh, *_ = _pool_with_rows([20])
     fresh._state = jax.tree_util.tree_map(jnp.zeros_like, fresh._state)
     live = np.ones(1, bool)
-    good = np.asarray(pool.decode_step(PARAMS, tokens, positions, tables,
-                                       live))[0]
-    bad = np.asarray(fresh.decode_step(PARAMS, tokens, positions, tables,
-                                       live))[0]
+    good = np.asarray(step_logits(pool, PARAMS, tokens, positions, tables,
+                                  live))[0]
+    bad = np.asarray(step_logits(fresh, PARAMS, tokens, positions, tables,
+                                 live))[0]
     assert np.abs(good - bad).max() > 10 * TOL16
 
 
@@ -583,7 +596,8 @@ def test_mixer_scopes_are_metadata_on_the_lowered_programs():
     rows = jnp.zeros((2,), jnp.int32)
     step = pool._decode_step_fn.lower(
         PARAMS, pool._k, pool._v, rows, rows,
-        jnp.zeros((2, pool.blocks_per_row), jnp.int32), pool.state,
+        jnp.zeros((2, pool.blocks_per_row), jnp.int32),
+        jnp.zeros((3, 2), jnp.int32), pool.state,
         jnp.ones((2,), bool)).as_text(debug_info=True)
     for scope in ("serve/decode_step", "serve/ssm_step", "serve/conv"):
         assert scope in step, scope

@@ -16,6 +16,10 @@
 that reads K/V through the block tables and writes the new token's K/V
 in place.
 
+The pool's program ends in the choice of each row's token and returns
+ids; where a test needs the logits it runs the model's own ``decode_step``
+on the pool's arrays (``tests.utils.step_logits``).
+
 Held here: its logits against ``decode.forward_with_cache`` on a
 contiguous cache at mixed lengths; a row's logits bit for bit whatever
 shares its batch; junk rows touch block 0 only; a step changes exactly
@@ -41,11 +45,14 @@ from rayfed_tpu.models import decode  # noqa: E402
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
 from rayfed_tpu.serving.kv_pool import PagedKVPool  # noqa: E402
 from rayfed_tpu.serving.server import InferenceServer  # noqa: E402
+from tests.utils import step_logits  # noqa: E402
 
 BS = 4
 MAX_LEN = 24
 # 1, a block boundary - 1, a boundary, boundary + 1, max_len - 1.
 LENGTHS = (1, 2 * BS - 1, 2 * BS, 2 * BS + 1, MAX_LEN - 1)
+# The sampler's scalars with every row greedy (``sampling.pack`` of zeros).
+GREEDY = np.zeros((3, len(LENGTHS)), np.int32)
 
 
 def _setup(dtype, lengths, seed=0):
@@ -103,7 +110,7 @@ def _reference_logits(cfg, params, cache, slots, tokens, positions):
 def test_paged_step_matches_contiguous_cache_at_mixed_lengths(dtype, tol):
     cfg, params, pool, cache, slots, tokens, positions, tables = _setup(
         dtype, LENGTHS)
-    logits = np.asarray(pool.decode_step(params, tokens, positions, tables))
+    logits = np.asarray(step_logits(pool, params, tokens, positions, tables))
     ref = _reference_logits(cfg, params, cache, slots, tokens, positions)
     # The tolerance is a share of the logits' scale: in bfloat16 the two
     # programs round activations at different places (the contiguous path
@@ -121,13 +128,13 @@ def test_row_alone_equals_row_among_neighbours_bitwise(dtype, monkeypatch):
     _, params, pool, _, slots, tokens, positions, tables = _setup(
         dtype, LENGTHS)
     k0, v0 = (np.asarray(a) for a in pool.kv)
-    together = np.asarray(pool.decode_step(params, tokens, positions, tables))
+    together = np.asarray(step_logits(pool, params, tokens, positions, tables))
     for slot in slots:
         pool._k, pool._v = jnp.asarray(k0), jnp.asarray(v0)
         tok, pos, tab = (np.zeros_like(a) for a in (tokens, positions, tables))
         tok[slot], pos[slot], tab[slot] = (
             tokens[slot], positions[slot], tables[slot])
-        alone = np.asarray(pool.decode_step(params, tok, pos, tab))
+        alone = np.asarray(step_logits(pool, params, tok, pos, tab))
         np.testing.assert_array_equal(alone[slot], together[slot])
 
 
@@ -136,7 +143,8 @@ def test_junk_rows_write_block_zero_only():
         jnp.float32, LENGTHS)
     k0, v0 = (np.asarray(a) for a in pool.kv)
     pool.decode_step(
-        params, tokens, np.zeros_like(positions), np.zeros_like(tables))
+        params, tokens, np.zeros_like(positions), np.zeros_like(tables),
+        GREEDY)
     k1, v1 = (np.asarray(a) for a in pool.kv)
     # Every granted block is bit-unchanged; block 0 took the junk write,
     # at offset 0.
@@ -150,7 +158,7 @@ def test_step_changes_only_each_live_rows_block_and_offset():
     _, params, pool, _, slots, tokens, positions, tables = _setup(
         jnp.float32, LENGTHS)
     k0, v0 = (np.asarray(a) for a in pool.kv)
-    pool.decode_step(params, tokens, positions, tables)
+    pool.decode_step(params, tokens, positions, tables, GREEDY)
     k1, v1 = (np.asarray(a) for a in pool.kv)
     written = np.zeros(k0.shape[1:3], bool)
     for slot in slots:
@@ -204,6 +212,7 @@ def test_one_compiled_program_serves_every_length(monkeypatch):
         assert pool.ensure_blocks(slot, pos) == "ok"
         tables[slot] = pool.table(slot)
         tokens[slot], positions[slot] = 1 + pos, pos
-        logits = pool.decode_step(params, tokens, positions, tables)
-        assert np.all(np.isfinite(np.asarray(logits)))
+        ids = np.asarray(pool.decode_step(
+            params, tokens, positions, tables, np.zeros((3, 2), np.int32)))
+        assert ids.shape == (2,) and 0 <= ids[slot] < cfg.vocab
     assert pool._decode_step_fn._cache_size() == 1

@@ -26,11 +26,13 @@ The load-bearing guarantees:
 
 from __future__ import annotations
 
+import gc
 import glob
 import json
 import os
 import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -44,8 +46,10 @@ from rayfed_tpu import tracing  # noqa: E402
 from rayfed_tpu.config import ServingConfig  # noqa: E402
 from rayfed_tpu.models import decode  # noqa: E402
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
+from rayfed_tpu.serving import kv_pool, sampling  # noqa: E402
 from rayfed_tpu.serving.kv_pool import PagedKVPool  # noqa: E402
 from rayfed_tpu.serving.publish import ModelBank  # noqa: E402
+from rayfed_tpu.telemetry import metrics as telemetry_metrics  # noqa: E402
 from rayfed_tpu.serving.server import (  # noqa: E402
     InferenceServer,
     ServerOverloadedError,
@@ -594,31 +598,47 @@ def test_serve_two_party_e2e():
 # sum); the six seeded prompts below still sample the same tokens.
 
 
-def _sampled_reference(params, prompt, max_new, temperature, seed, max_len):
-    """A host loop over ``decode.forward_with_cache``, one request alone,
-    sampling by the engine's rule (``InferenceServer._sample``): inverse
-    CDF of the float64 softmax, one uniform a token from
-    ``default_rng(seed)``."""
-    rng = np.random.default_rng(seed)
+_CHOOSE = jax.jit(sampling.choose_packed)
 
-    def sample(logits):
-        z = np.asarray(logits, np.float32).astype(np.float64) / temperature
-        cdf = np.cumsum(np.exp(z - z.max()))
-        u = rng.random() * cdf[-1]
-        return int(min(np.searchsorted(cdf, u, side="right"), z.shape[0] - 1))
 
+def _choose(logits, temperature, seed, index):
+    """The engine's own sampler on a batch of rows, outside any engine;
+    a scalar stands for every row."""
+    rows = len(logits)
+    scalars = (np.broadcast_to(np.asarray(x, object), (rows,))
+               for x in (temperature, seed, index))
+    return np.asarray(_CHOOSE(
+        jnp.asarray(logits, jnp.float32), sampling.pack(*scalars)))
+
+
+def _follow_alone(params, prompt, n, max_len, choose):
+    """``decode.forward_with_cache``, one request alone: ``n`` tokens,
+    token ``i`` being ``choose(its float32 logits (V,), i)``. Returns
+    (the tokens, the logits each was chosen from)."""
     cache = decode.init_cache(CFG, 1, max_len + 1)
     logits, cache = decode.forward_with_cache(
         params, jnp.asarray([prompt], jnp.int32), cache, 0, CFG
     )
-    out = [sample(logits[0, len(prompt) - 1])]
-    while len(out) < max_new:
-        logits, cache = decode.forward_with_cache(
-            params, jnp.asarray([[out[-1]]], jnp.int32), cache,
-            len(prompt) + len(out) - 1, CFG,
-        )
-        out.append(sample(logits[0, 0]))
-    return out
+    row, toks, rows = logits[0, len(prompt) - 1], [], []
+    for i in range(n):
+        rows.append(np.asarray(row, np.float32))
+        toks.append(int(choose(rows[-1], i)))
+        if i + 1 < n:
+            logits, cache = decode.forward_with_cache(
+                params, jnp.asarray([[toks[-1]]], jnp.int32), cache,
+                len(prompt) + i, CFG,
+            )
+            row = logits[0, 0]
+    return toks, np.stack(rows)
+
+
+def _sampled_reference(params, prompt, max_new, temperature, seed, max_len):
+    """The plain cached forward, one request alone, each token chosen by
+    the device rule: the engine's own sampler (``serving/sampling.py``) on
+    that position's logits, keyed by (seed, position in the output)."""
+    return _follow_alone(
+        params, prompt, max_new, max_len,
+        lambda row, i: _choose(row[None], temperature, seed, i)[0])[0]
 
 
 def test_mixed_lengths_match_the_plain_reference():
@@ -640,6 +660,212 @@ def test_mixed_lengths_match_the_plain_reference():
         _sampled_reference(PARAMS_A, p, 8, 0.8, i, max_len=32)
         for i, p in enumerate(prompts)
     ]
+
+
+def test_greedy_is_argmax_of_the_plain_references_float32_logits():
+    rng = np.random.default_rng(5)
+    prompts = [
+        [int(t) for t in rng.integers(1, 255, size=n)] for n in (4, 11, 7)
+    ]
+    srv = _server()
+    try:
+        futs = [srv.submit(p, max_new_tokens=8) for p in prompts]
+        outs = [f.result(timeout=120)["tokens"] for f in futs]
+    finally:
+        srv.stop()
+    for p, toks in zip(prompts, outs):
+        # Teacher-forced along the served tokens: the logits each came from.
+        _, logits = _follow_alone(PARAMS_A, p, len(toks), 32,
+                                  lambda row, i: toks[i])
+        assert toks == [int(t) for t in np.argmax(logits, -1)]
+
+
+@pytest.mark.parametrize("neighbour_temperature", [0.0, 0.8],
+                         ids=["all_greedy", "beside_a_sampled_row"])
+def test_a_greedy_tie_goes_to_the_first_index(neighbour_temperature):
+    """``np.argmax``'s rule, on either branch of the sampler: whether or
+    not another row of the batch makes the noise run."""
+    logits = np.random.default_rng(2).normal(size=(3, 64)).astype(np.float32)
+    logits[0, [9, 30, 51]] = logits[0].max() + 1.0      # a three-way tie
+    logits[1, 63], logits[1, 0] = 7.0, 7.0
+    got = _choose(logits, [0.0, 0.0, neighbour_temperature], 4, 0)
+    assert list(got[:2]) == [9, 0] == list(np.argmax(logits[:2], -1))
+
+
+SAMPLED = dict(max_new_tokens=8, temperature=0.8, seed=2**33 + 77)
+
+
+def _sampled_alone(prompt, **server):
+    srv = _server(**server)
+    try:
+        return srv.submit(prompt, **SAMPLED).result(timeout=120)["tokens"]
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize(
+    "schedule", ["full_mixed_batch", "chunked_prefill", "preempted"])
+def test_a_sampled_request_serves_the_same_tokens_whatever_the_schedule(
+        schedule):
+    """The key of a draw is (seed, position in the output): not the slot,
+    not the neighbours, not how the prompt went in, and a preemption's
+    re-run has no generator state to rewind."""
+    rng = np.random.default_rng(13)
+    prompt = [int(t) for t in rng.integers(1, 255, size=12)]
+    others = [[int(t) for t in rng.integers(1, 255, size=n)]
+              for n in (8, 8, 8, 8, 8)]
+    alone = _sampled_alone(prompt)
+    assert alone == _sampled_reference(
+        PARAMS_A, prompt, SAMPLED["max_new_tokens"], SAMPLED["temperature"],
+        SAMPLED["seed"], max_len=32)
+    if schedule == "chunked_prefill":
+        srv = _server(prefill_chunk=8, prefill_token_budget=8)
+        others = []
+    elif schedule == "preempted":
+        # Four rows in lockstep need a 4th block each with none free
+        # (test_preemption_under_block_pressure_...): the youngest, the
+        # sampled request submitted last, is preempted and re-run.
+        prompt, others = prompt[:8], others[:3]
+        alone = _sampled_alone(prompt)
+        srv = _server(kv_block_size=4, kv_blocks=12)
+    else:
+        srv = _server()
+    try:
+        # Neighbours greedy and sampled, the same seed among them.
+        futs = [srv.submit(p, max_new_tokens=8, temperature=0.9 * (i % 2),
+                           seed=SAMPLED["seed"])
+                for i, p in enumerate(others)]
+        got = srv.submit(prompt, **SAMPLED).result(timeout=120)["tokens"]
+        for f in futs:
+            f.result(timeout=120)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert got == alone
+    if schedule == "chunked_prefill":
+        assert st["prefill_chunks"] >= 2
+    if schedule == "preempted":
+        assert st["preempted"] >= 1
+
+
+@pytest.mark.parametrize("axis", ["seed", "index"])
+def test_draws_follow_softmax_of_logits_over_temperature(axis):
+    """512 draws at fixed keys against the exact distribution of a
+    vocabulary of 8, along either half of the key; the tolerance is 3.5
+    standard deviations of the likeliest token's frequency."""
+    n, temperature = 512, 0.7
+    logits = np.array([2.0, 1.0, 0.5, 0.0, -0.5, 1.5, -2.0, 0.2], np.float32)
+    z = logits.astype(np.float64) / temperature
+    want = np.exp(z - z.max()) / np.exp(z - z.max()).sum()
+    seed, index = (np.arange(n), 3) if axis == "seed" else (11, np.arange(n))
+    toks = _choose(np.tile(logits, (n, 1)), temperature, seed, index)
+    got = np.bincount(toks, minlength=8) / n
+    assert np.abs(got - want).max() < 3.5 * np.sqrt(0.25 / n)
+    assert len(set(toks)) >= 6
+
+
+def test_fetch_bytes_are_the_ids_and_nothing_else():
+    """A prefill round and a decode step each fetch ``max_slots`` int32,
+    a last chunk one."""
+    srv = _server(max_len=48, prefill_chunk=8, prefill_token_budget=16)
+    try:
+        srv.submit_and_wait(list(range(1, 7)), max_new_tokens=5)
+        srv.submit_and_wait(list(range(1, 22)), max_new_tokens=4)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert st["steps"] == 4 + 3 and st["prefill_chunks"] == 3
+    assert st["fetch_bytes"] == 4 * 4 * (st["steps"] + 1) + 4
+    reg = telemetry_metrics.get_registry()
+    assert reg.get("fed_serving_fetch_bytes_total").labels(
+        server="default").value() >= st["fetch_bytes"]
+
+
+def test_no_program_of_the_engine_returns_a_vocabulary_sized_axis():
+    srv = _server(max_len=40)           # no shape of the pool is 256
+    try:
+        pool, R = srv.pool, srv.pool.max_slots
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        k_row, v_row, _ = jax.eval_shape(
+            pool._gather_row_fn, *pool.kv, i32(pool.blocks_per_row))
+        slab = jax.ShapeDtypeStruct(
+            (k_row.shape[0], R, *k_row.shape[1:]), k_row.dtype)
+        tables = i32(R, pool.blocks_per_row)
+        outs = {
+            "decode_step": jax.eval_shape(
+                pool._decode_step_fn, PARAMS_A, *pool.kv, i32(R), i32(R),
+                tables, i32(3, R)),
+            "prefill_rows": jax.eval_shape(
+                srv._get_prefill_rows_fn(16), PARAMS_A, i32(R, 16), i32(R),
+                jax.ShapeDtypeStruct((R,), bool), i32(3, R)),
+            "chunk_step": jax.eval_shape(
+                srv._get_chunk_fn(8), PARAMS_A, k_row, v_row, {}, i32(8),
+                i32(), i32(), i32(3, 1)),
+            "gather_row": (k_row, v_row),
+            "scatter_rows": jax.eval_shape(
+                pool._scatter_rows_fn, *pool.kv, slab, slab, tables),
+            "scatter_row": jax.eval_shape(
+                pool._scatter_row_fn, *pool.kv, k_row, v_row,
+                i32(pool.blocks_per_row)),
+            "copy_block": jax.eval_shape(
+                kv_pool._copy_block, *pool.kv, i32(), i32()),
+        }
+        assert len(outs) == len(pool.jitted_fns()) + 2
+    finally:
+        srv.stop()
+    for name, out in outs.items():
+        for leaf in jax.tree_util.tree_leaves(out):
+            assert CFG.vocab not in leaf.shape, (name, leaf.shape)
+    ids = [jax.tree_util.tree_leaves(outs[n])[0]
+           for n in ("decode_step", "prefill_rows", "chunk_step")]
+    assert [(x.shape, x.dtype) for x in ids] == [
+        ((4,), jnp.int32), ((4,), jnp.int32), ((), jnp.int32)]
+
+
+@pytest.mark.parametrize("temperatures, drew", [
+    ((0.0, 0.0, 0.0), False), ((0.0, 0.8, 0.0), True),
+], ids=["all_greedy", "one_sampled"])
+def test_draw_steps_count_the_steps_in_which_a_live_row_was_sampled(
+        temperatures, drew):
+    srv = _server()
+    try:
+        futs = [srv.submit(list(range(3 + i, 9 + i)), max_new_tokens=6,
+                           temperature=t, seed=i)
+                for i, t in enumerate(temperatures)]
+        for f in futs:
+            f.result(timeout=120)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert st["steps"] >= 5
+    assert st["draw_steps"] == (st["steps"] if drew else 0)
+
+
+def test_wrapping_the_sample_seam_alters_tokens_and_keeps_no_engine_alive():
+    """What the benchmark's ``--inject broken-token`` does: every token
+    goes through ``_sample``, and the original kept in a local is a
+    function, not a bound method that would hold the stopped engine (its
+    pool, its weights) on the device."""
+    prompt = list(range(5, 15))
+    srv = _server(prefix_reuse=False)
+    sample_fn = srv._sample
+    seen = []
+
+    def broken(chosen, req):
+        seen.append(int(chosen))
+        return (sample_fn(chosen, req) + 1) % CFG.vocab
+
+    srv._sample = broken
+    alive = weakref.ref(srv)
+    try:
+        out = srv.submit_and_wait(prompt, max_new_tokens=5)["tokens"]
+    finally:
+        srv.stop()
+    assert out == [(t + 1) % CFG.vocab for t in seen] and len(seen) == 5
+    assert out[0] == (_reference(PARAMS_A, prompt, 1)[0] + 1) % CFG.vocab
+    del srv, broken
+    gc.collect()
+    assert sample_fn(7, None) == 7 and alive() is None
 
 
 def test_chunked_prefill_matches_reference():

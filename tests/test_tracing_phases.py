@@ -146,13 +146,13 @@ def test_annotation_names_are_fixed_whatever_the_ids(tmp_path):
                 with tracing.phase("fed:wire:place", nbytes=7 + i,
                                    peer=peer):
                     time.sleep(0.001)
-        with tracing.phase("fed:serve:sample"):
+        with tracing.phase("fed:serve:emit"):
             time.sleep(0.001)
     finally:
         jax.profiler.stop_trace()
     names = _host_event_names(str(tmp_path))
     assert set(names) == {"fed:wire:decode", "fed:wire:place",
-                          "fed:serve:sample"}
+                          "fed:serve:emit"}
     assert len(names["fed:wire:decode"]) == len(names["fed:wire:place"]) == 3
     assert sorted(m["peer"] for m in names["fed:wire:decode"]) == [
         "bob", "carol", "dave"]
@@ -176,7 +176,7 @@ def test_span_goes_to_the_ring_as_before(traced):
 
 PROMPTS = [[1, 2, 3, 4], [5, 6, 7, 8, 9, 10, 11], [3, 1, 4, 1, 5, 9, 2, 6, 5],
            [2, 7]]
-PER_ITERATION = ("build", "dispatch", "fetch", "sample", "emit")
+PER_ITERATION = ("build", "dispatch", "fetch", "emit")
 
 
 def _serve_mixed():
@@ -206,10 +206,10 @@ def test_engine_phases_tile_the_iteration_and_keep_the_tokens():
     finally:
         tracing.disable()
         tracing.clear()
-    # Sampling all rows and then emitting them draws the same numbers
-    # from each request's own rng as the row-by-row interleaving did.
     assert again == plain
     assert stats["steps"] > 0
+    # The token is chosen inside the step's program: no host phase samples.
+    assert "fed:serve:sample" not in phases
     for name in PER_ITERATION:
         assert phases["fed:serve:" + name]["count"] == stats["steps"], name
     for name in ("admit", "prefill_chunk", "idle"):
@@ -344,6 +344,7 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     assert "serve/decode_step" in pool._decode_step_fn.lower(
         PARAMS, pool._k, pool._v, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
+        jnp.zeros((3, 2), jnp.int32),
     ).as_text(debug_info=True)
     # A decorator, not a wrapper program: the jitted functions keep the
     # names the profile and `compiled_programs` know them by.

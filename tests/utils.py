@@ -82,3 +82,19 @@ def run_parties(
             raise AssertionError(f"party {party} timed out after {timeout}s")
     bad = {party: p.exitcode for party, p in procs.items() if p.exitcode != 0}
     assert not bad, f"party processes failed with exit codes: {bad}"
+
+
+def step_logits(pool, params, tokens, positions, tables, live=None):
+    """The (R, vocab) logits of one paged decode step, which the pool's own
+    program never returns (it ends in the choice of each row's token): the
+    model-protocol member that program wraps, on the pool's arrays, the
+    pool left as ``PagedKVPool.decode_step`` leaves it."""
+    import jax
+    import jax.numpy as jnp
+
+    live = None if live is None or not pool._state else jnp.asarray(live)
+    logits, pool._k, pool._v, pool._state = jax.jit(
+        pool.model.decode_step, donate_argnums=(1, 2, 3)
+    )(params, pool._k, pool._v, pool._state, jnp.asarray(tokens),
+      jnp.asarray(positions), jnp.asarray(tables), live)
+    return logits
