@@ -153,7 +153,7 @@ def forward_with_cache(
 PAGED_CHUNK_KEYS = 256
 
 
-def paged_attention(pk, pv, positions, tables):
+def paged_attention(pk, pv, positions, tables, window=None):
     """The read of a block-paged K/V pool through the block tables, for
     one decode token per row. Returns ``attend(q, k1, v1, base)``: ``q``
     (R, H, Dh) a layer's queries, ``k1``/``v1`` (R, Hkv, Dh) its new key
@@ -171,6 +171,19 @@ def paged_attention(pk, pv, positions, tables):
     mathematics is :func:`transformer.causal_attention`'s (operands in
     the compute dtype, float32 scores, probabilities cast to the value
     dtype before the PV product), re-associated, nothing rounded lower.
+
+    ``window`` (a static int, or None: every key) makes this the read of
+    a sliding-window layer: a row at position ``p`` attends the keys
+    ``p - window + 1 .. p``, its own among them. Each row's loop then
+    starts at ITS window's first chunk: in trip ``c`` row ``r`` gathers
+    chunk ``first[r] + c`` of its own table, the trip count is the most
+    chunks any row's window spans (at most ``window / chunk + 1``,
+    however long the rows are and however unequal), and keys before a
+    row's window are masked. A step reads the window's blocks, not the
+    row's history. A model whose layers differ builds one ``attend`` per
+    kind from the same pool (:mod:`rayfed_tpu.models.cohere2_moe`); with
+    ``window=None`` the loop and the mask are exactly the lines below,
+    nothing added.
     """
     n_layers, n_phys, bs, n_kv, dh = pk.shape
     n_rows, blocks_per_row = tables.shape
@@ -180,6 +193,14 @@ def paged_attention(pk, pv, positions, tables):
         tables, ((0, 0), (0, -blocks_per_row % chunk_blocks))
     )
     trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
+    if window is not None:
+        # The first key each row still sees and the chunk that holds it;
+        # a row without cached keys (junk: position 0) asks for no trip.
+        lo = jnp.maximum(positions - window + 1, 0)
+        first = lo // chunk_keys
+        spans = (jnp.maximum(positions, 1) - 1) // chunk_keys - first + 1
+        trips = jnp.max(jnp.where(positions > 0, spans, 0))
+        last_block = tables_p.shape[1] - 1
     # (L * P, bs, Hkv, Dh): a layer's block b is row layer * P + b, so one
     # gather reads a chunk's blocks and never the layer's whole pool.
     pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
@@ -195,13 +216,27 @@ def paged_attention(pk, pv, positions, tables):
 
         def chunk(c, carry):
             m, den, acc = carry
-            blocks = base + jax.lax.dynamic_slice_in_dim(
-                tables_p, c * chunk_blocks, chunk_blocks, axis=1
-            )
+            if window is None:
+                blocks = base + jax.lax.dynamic_slice_in_dim(
+                    tables_p, c * chunk_blocks, chunk_blocks, axis=1
+                )
+            else:
+                # Row r's chunk first[r] + c; past a row's last block the
+                # index is held in range and every key masked.
+                at = ((first + c) * chunk_blocks)[:, None] + jnp.arange(
+                    chunk_blocks)
+                blocks = base + jnp.take_along_axis(
+                    tables_p, jnp.minimum(at, last_block), axis=1
+                )
             kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
             vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
-            k_pos = c * chunk_keys + jnp.arange(chunk_keys)
-            cached = k_pos[None, :] < positions[:, None]
+            if window is None:
+                k_pos = c * chunk_keys + jnp.arange(chunk_keys)
+                cached = k_pos[None, :] < positions[:, None]
+            else:
+                k_pos = ((first + c) * chunk_keys)[:, None] + jnp.arange(
+                    chunk_keys)
+                cached = (k_pos < positions[:, None]) & (k_pos >= lo[:, None])
             s = jnp.einsum(
                 "rhgd,rkhd->rhgk", q, kc, preferred_element_type=jnp.float32
             ) * scale
@@ -299,6 +334,16 @@ class TransformerServing:
     floating leaves of a published tree, or None for "as published": the
     engine's bank casts a version once, when it is installed, and the
     programs are handed that tree (``InferenceServer._make_snapshot_fn``).
+
+    Two further members are optional, declared by the model that needs
+    them (:mod:`rayfed_tpu.models.cohere2_moe`) and absent here:
+    ``layer_windows()`` (per layer the keys a token attends, or None for
+    every key: the engine counts the blocks each layer must read) and
+    ``step_counters`` (names of int32 counts only the device knows:
+    ``decode_step`` then returns them as a fifth value and they ride home
+    behind the ids). ``prefill_rows`` may hand back K/V rows shorter than
+    ``row_len`` (as long as its bucket): the pool lands rows of the
+    length they come in.
     """
 
     def __init__(self, cfg: tfm.TransformerConfig):

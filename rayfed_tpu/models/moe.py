@@ -12,25 +12,42 @@
 # See the License for the specific language governing permissions and
 # limitations under the License.
 
-"""Mixture-of-experts FFN with expert parallelism.
+"""Mixture-of-experts FFN: two families of paths.
 
-Experts shard over an ``expert`` mesh axis: under ``shard_map`` each device
-computes its local experts' FFN for all tokens scaled by the router's
-(top-1 masked) gate, and one ``psum`` over the expert axis combines —
-expert weights and FLOPs scale out with the axis. Dense-gating math keeps
-the computation static-shaped (no data-dependent dispatch), which is the
-XLA-friendly formulation; the top-1 mask reproduces switch-style routing
-numerics exactly.
+**The all-experts paths** (:func:`moe_ffn_apply`, :func:`moe_ffn_apply_topk`,
+:func:`make_ep_moe_apply`; what ``transformer.py`` wires in under
+``cfg.moe``): softmax routing over ungated GELU experts, every expert run
+on every token and the result masked by the gates. Static shapes and no
+data-dependent dispatch, at ``experts x tokens`` operations whatever the
+routing chose; under ``shard_map`` each device runs its local experts for
+all tokens and one ``psum`` combines. :func:`make_a2a_moe_apply` is the
+capacity-based all-to-all form of the same experts (assignments over an
+expert's capacity are dropped). These exist for the training-side tests
+and have never been measured on a chip.
+
+**The grouped path** (:func:`route_sigmoid_topk`, :func:`routed_experts`,
+:func:`shared_experts`; what :mod:`rayfed_tpu.models.cohere2_moe` serves
+through ``fed.serve``): sigmoid scores over ALL experts, the ``k`` largest
+normalised over those ``k``, gated SiLU experts, and a layer that is told
+which experts it *holds* (one chip's share of a layer divided over
+chips): it computes the part of the result its own experts give. Tokens
+are grouped by expert, so operations and the expert weights read follow
+the assignments: an expert no token chose is never touched. No capacity
+factor, no dropped token. This is the only path with a cell in the
+benchmark (``PERF.md``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+from rayfed_tpu import utils
 
 Params = Dict[str, Any]
 
@@ -240,3 +257,127 @@ def moe_ffn_apply_topk(params: Params, x, k: int = 2):
     """Dense-compute forward with top-k routing (k experts per token)."""
     gates = topk_gates(params, x, k)
     return _expert_ffn_combine(params["w_up"], params["w_down"], x, gates)
+
+
+# ---------------------------------------------------------------------------
+# The grouped path: sigmoid top-k routing, held experts, shared experts
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_topk(h, router, k: int):
+    """Scores ``sigmoid(h router)`` over ALL experts in float32 (operands
+    as they come, float32 accumulation), the ``k`` largest, their weights
+    normalised over the ``k``. ``h`` (T, d), ``router`` (d, E). Returns
+    (expert ids (T, k) int32, weights (T, k) float32)."""
+    with jax.named_scope("serve/moe_route"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", h, router.astype(h.dtype),
+            preferred_element_type=jnp.float32))
+        top, idx = lax.top_k(scores, k)
+        return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+
+
+# Row tile of the grouped matmul on a TPU. An expert's rows cost whole
+# tiles, so the tile is small beside the 32 rows an expert of 128 sees of
+# a 512-token chunk; the contraction is not tiled (one pass, no
+# accumulator traffic) and the output 512 wide. On a v5e at 4096 x 4096
+# experts (``PERF.md`` section 6, PR 31): 16 experts x 32 rows of 4,096
+# in 0.82 ms where ``jax.lax.ragged_dot``'s own kernel, which tiles rows
+# by 512, took 1.84; 11 experts hit by 16 rows of 128 in 0.54 against
+# 0.71; reading the experts' matrices alone takes 0.66 and 0.45.
+GROUPED_ROW_TILE = 128
+GROUPED_OUT_TILE = 512
+
+
+def grouped_matmul(x, w, group_sizes):
+    """``x[rows of group g] @ w[g]`` for consecutive row groups of the
+    given sizes: ``x`` (m, k), ``w`` (g, k, n), ``group_sizes`` (g,)
+    int32 -> (m, n) float32. Rows past the last group come back
+    unspecified. On a TPU the megablox kernel that ships with jax
+    (``jax.experimental.pallas.ops.tpu.megablox``), tiled as above: it
+    visits only (row tile, group) pairs that hold rows, so a group of
+    size 0 is never read. Elsewhere ``jax.lax.ragged_dot``."""
+    m, n = x.shape[0], w.shape[2]
+    tm = min(GROUPED_ROW_TILE, m)
+    tn = min(GROUPED_OUT_TILE, n)
+    if utils.is_tpu_backend() and m % tm == 0 and n % tn == 0:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        return gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
+                   tiling=(tm, x.shape[1], tn))
+    return lax.ragged_dot(x, w, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def routed_experts(h, layer: Params, held: Sequence[int], k: int,
+                   live=None):
+    """The part of a routed-expert layer that the experts ``held`` give.
+
+    ``h`` (T, d) in the compute dtype; ``layer`` holds ``router`` (d, E)
+    over all E experts and ``we_gate``/``we_up`` (Eh, d, f), ``we_down``
+    (Eh, f, d), the weights of the ``Eh = len(held)`` experts held here,
+    in the order of ``held`` (global expert ids, static). Expert ``e``
+    computes ``(silu(h Wg_e) * (h Wu_e)) Wd_e``. Each token's ``k``
+    weights are normalised over all ``k`` chosen experts, held or not;
+    what an absent expert would add is left out (it lies on another
+    chip). ``live`` (T,) bool names the rows that count (None: all):
+    padding and junk rows are routed nowhere and touch no expert.
+
+    The (token, expert) assignments are sorted by expert, those on held
+    experts first, and the three matmuls are grouped over the sorted rows
+    with the held experts' counts as group sizes
+    (:func:`grouped_matmul`): operations follow the assignments, and an
+    expert that no live token chose is not read (rows past the last
+    group belong to no expert; what comes back for them is masked).
+    Returns ``(y (T, d) float32, experts_hit, assignments)``: the two
+    counts are int32 scalars (held experts with at least one live token;
+    (token, expert) pairs on held experts).
+    """
+    t, d = h.shape
+    n_held = len(held)
+    n_experts = layer["router"].shape[-1]
+    idx, w = route_sigmoid_topk(h, layer["router"], k)
+    with jax.named_scope("serve/moe_experts"):
+        # Global expert id -> its index among the held ones; n_held for
+        # an expert that lies elsewhere.
+        local_of = np.full(n_experts, n_held, np.int32)
+        local_of[np.asarray(held, np.int64)] = np.arange(n_held)
+        lid = jnp.asarray(local_of)[idx]
+        if live is not None:
+            lid = jnp.where(live[:, None], lid, n_held)
+        m = t * k
+        flat = lid.reshape(m)
+        order = jnp.argsort(flat, stable=True)       # held first, by expert
+        counts = jnp.sum(
+            flat[:, None] == jnp.arange(n_held), axis=0, dtype=jnp.int32)
+        xs = h[order // k]
+
+        def grouped(x, name):
+            return grouped_matmul(x, layer[name].astype(h.dtype), counts)
+
+        act = (jax.nn.silu(grouped(xs, "we_gate"))
+               * grouped(xs, "we_up")).astype(h.dtype)
+        ys = grouped(act, "we_down")
+        # Back to (token, choice) order; an assignment that lies
+        # elsewhere adds nothing.
+        mine = (jnp.arange(m) < jnp.sum(counts))[:, None]
+        weighted = jnp.where(mine, ys * w.reshape(m)[order][:, None], 0.0)
+        y = weighted[jnp.argsort(order)].reshape(t, k, d).sum(1)
+        return (y, jnp.sum(counts > 0, dtype=jnp.int32),
+                jnp.sum(counts, dtype=jnp.int32))
+
+
+def shared_experts(h, layer: Params, n_shared: int):
+    """The mean of ``n_shared`` gated SiLU experts that every token
+    passes, held as ONE gated MLP ``ws_gate``/``ws_up`` (d, n_shared * f),
+    ``ws_down`` (n_shared * f, d): the sum of the experts' outputs is
+    that MLP's output, and the mean is that over ``n_shared``. Returns
+    (T, d) float32."""
+    with jax.named_scope("serve/moe_shared"):
+        gate = jnp.einsum("...d,df->...f", h, layer["ws_gate"].astype(h.dtype),
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("...d,df->...f", h, layer["ws_up"].astype(h.dtype),
+                        preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
+        return jnp.einsum(
+            "...f,fd->...d", act, layer["ws_down"].astype(h.dtype),
+            preferred_element_type=jnp.float32) / n_shared

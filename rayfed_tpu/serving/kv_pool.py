@@ -171,6 +171,9 @@ class PagedKVPool:
         self.state_row_bytes = sum(
             int(a.nbytes) // max_slots for a in self._state.values()
         )
+        # Numbers a model's decode step counts on the device (optional in
+        # the protocol): they ride home behind the ids, in their array.
+        self.step_counters = tuple(getattr(self.model, "step_counters", ()))
         self._lock = threading.Lock()
         self._free_slots: List[int] = list(range(max_slots))
         # pop() hands out low block ids first.
@@ -212,14 +215,19 @@ class PagedKVPool:
         # no output, the program it ran before there was one); what exists
         # for its sake trails and may be left out. The program ends in the
         # choice of each row's next token (serving/sampling.py): (R,) ids
-        # come back, the (R, vocab) logits stay on the device.
+        # come back, the (R, vocab) logits stay on the device. A model
+        # that declares ``step_counters`` returns them as a fifth value,
+        # and they follow the ids in the one int32 array.
         @jax.named_scope("serve/decode_step")
         def decode_step(params, pk, pv, tokens, positions, tables, draw,
                         state=None, live=None):
-            logits, pk, pv, state = model.decode_step(
+            logits, pk, pv, state, *counted = model.decode_step(
                 params, pk, pv, state or {}, tokens, positions, tables, live
             )
-            return sampling.choose_packed(logits, draw), pk, pv, state
+            ids = sampling.choose_packed(logits, draw)
+            if counted:
+                ids = jnp.concatenate([ids, counted[0].astype(ids.dtype)])
+            return ids, pk, pv, state
 
         self._decode_step_fn = jax.jit(
             decode_step, donate_argnums=(1, 2, 7)
@@ -248,15 +256,21 @@ class PagedKVPool:
             # Write whole (R, T)-shaped prefill output back through the
             # scatter tables. Rows that must not land (junk vmap lanes,
             # already-live neighbours) carry an all-zero table and a
-            # false `landed`.
+            # false `landed`. A model may hand back rows shorter than T
+            # (as long as its bucket): they land in the first blocks of
+            # the tables and the rest of a row is left as it was.
             L = pk.shape[0]
             H, Dh = pk.shape[-2:]
+            nb = -(-k_slab.shape[2] // bs)
+            pad = nb * bs - k_slab.shape[2]
             if pad:
                 z = jnp.zeros((L, R, pad, H, Dh), k_slab.dtype)
                 k_slab = jnp.concatenate([k_slab, z], axis=2)
                 v_slab = jnp.concatenate([v_slab, z], axis=2)
-            kp = k_slab.reshape(L, R, NB, bs, H, Dh)
-            vp = v_slab.reshape(L, R, NB, bs, H, Dh)
+            kp = k_slab.reshape(L, R, nb, bs, H, Dh)
+            vp = v_slab.reshape(L, R, nb, bs, H, Dh)
+            if nb != NB:
+                tables = tables[:, :nb]
             pk = pk.at[:, tables].set(kp)
             pv = pv.at[:, tables].set(vp)
             return pk, pv, landed_in(state or {}, new_state, landed)
@@ -297,15 +311,19 @@ class PagedKVPool:
         (:func:`rayfed_tpu.serving.sampling.pack`; all zero: every row
         greedy). ``live`` (R,) bool names the rows whose recurrent state
         advances; every other row's state comes back bit for bit. A
-        model without such a state takes none. Returns each row's next
-        token, (R,) int32, on the device. The small host arrays go to
+        model without such a state takes none (one that declares
+        ``step_counters`` is told which rows are live all the same: what
+        it counts is over them). Returns each row's next token, (R,)
+        int32, on the device, followed by the model's ``step_counters``
+        where it declares any. The small host arrays go to
         the program as NumPy, here and in the pool's other programs: the
         jitted call uploads its own arguments, and a ``jnp.asarray``
         around each cost the engine thread 0.15 ms of dispatch apiece
         on the chip's host (``PERF.md`` §6, PR 30)."""
+        wants_live = self._state or self.step_counters
         ids, self._k, self._v, self._state = self._decode_step_fn(
             params, self._k, self._v, tokens, positions, tables, draw,
-            self._state, self._of_state(live, bool),
+            self._state, np.asarray(live, bool) if wants_live else None,
         )
         return ids
 

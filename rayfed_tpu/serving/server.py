@@ -239,7 +239,28 @@ class InferenceServer:
             # "steps".
             "fetch_bytes": 0,
             "draw_steps": 0,
+            # Decode, per layer: the blocks each layer of each live row
+            # must read (a windowed layer only its window's), summed over
+            # layers and steps: n_layers x kv_blocks_attended for a model
+            # without windows. And the real prompt tokens put through
+            # both prefill paths, with the (query, key) pairs they
+            # attended, summed over layers (a windowed layer's queries
+            # see at most its window).
+            "kv_layer_blocks_attended": 0,
+            "prefill_tokens": 0,
+            "prefill_keys_attended": 0,
         }
+        # What the model's decode step counts on the device (it declares
+        # the names; none for most models): fetched behind the ids.
+        self._stats.update(dict.fromkeys(self.pool.step_counters, 0))
+        # The windows of the layers that have one (optional in the
+        # protocol: a model without ``layer_windows`` attends every key
+        # on every layer).
+        self._n_layers = self.model.kv_shape()[0]
+        self._windows = tuple(
+            w for w in getattr(self.model, "layer_windows", tuple)()
+            if w is not None
+        )
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
         # stats() stays the per-instance source of truth.
@@ -343,6 +364,32 @@ class InferenceServer:
             "Decode steps in which at least one live row was sampled.",
             labels=("server",),
         ).labels(server=name)
+        self._m_kv_layer_attended = _reg.counter(
+            "fed_serving_kv_layer_blocks_attended_total",
+            "KV blocks each layer of each live row must read (a windowed "
+            "layer its window's), summed over layers and decode steps.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_prefill_tokens = _reg.counter(
+            "fed_serving_prefill_tokens_total",
+            "Real prompt tokens put through the prefill programs.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_prefill_keys = _reg.counter(
+            "fed_serving_prefill_keys_attended_total",
+            "(query, key) pairs the prefill programs' real tokens "
+            "attended, summed over layers.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_step_counters = {
+            key: _reg.counter(
+                f"fed_serving_{key}_total",
+                f"The served model's decode-step counter {key!r}, counted "
+                "on the device and fetched with the chosen ids.",
+                labels=("server",),
+            ).labels(server=name)
+            for key in self.pool.step_counters
+        }
         self._update_kv_gauges()
         # Whatever way a version comes in (publish, a promoted standby's
         # state), the bank's snapshot of it is the tree the programs read.
@@ -950,6 +997,8 @@ class InferenceServer:
                     k_slab, v_slab, tables, state_rows, landed
                 )
                 self._count_state_resets(len(reqs))
+                for req in reqs:
+                    self._count_prefill(0, int(req.prompt.size))
                 ids = self._fetch(ids)
                 for req in reqs:
                     self._post_prefill(req, ids[req.slot])
@@ -1026,6 +1075,7 @@ class InferenceServer:
                 with self._lock:
                     self._stats["prefill_chunks"] += 1
                 self._m_chunks.inc()
+                self._count_prefill(off, real)
                 if req.chunk_done >= plen:
                     with self._lock:
                         self._prefilling.remove(req)
@@ -1090,6 +1140,38 @@ class InferenceServer:
         self._stats["fetch_bytes"] += ids.nbytes
         self._m_fetch_bytes.inc(ids.nbytes)
         return ids
+
+    def _count_prefill(self, off: int, n: int) -> None:
+        """``n`` real prompt tokens at positions ``off .. off + n - 1``
+        went through a prefill program: count them and the keys they
+        attended over the layers (the query at position q sees q + 1
+        keys, at most ``window`` on a windowed layer)."""
+        def seen(upto: int, window: int) -> int:
+            # Keys seen by the queries at positions 0 .. upto - 1.
+            ramp = min(upto, window)
+            return ramp * (ramp + 1) // 2 + (upto - ramp) * window
+
+        end = off + n                 # no window binds below this
+        keys = (self._n_layers - len(self._windows)) * (
+            seen(end, end) - seen(off, end)
+        ) + sum(seen(end, w) - seen(off, w) for w in self._windows)
+        with self._lock:
+            self._stats["prefill_tokens"] += n
+            self._stats["prefill_keys_attended"] += keys
+        self._m_prefill_tokens.inc(n)
+        self._m_prefill_keys.inc(keys)
+
+    def _layer_blocks(self, live, attended: int) -> int:
+        """Blocks the layers of the ``live`` rows must read in a decode
+        step, summed over rows and layers: every block up to a row's
+        position (``attended``, summed over the rows) on a layer that
+        attends every key, those that hold ``pos - window + 1 .. pos``
+        on a windowed one."""
+        bs = self.pool.block_size
+        return (self._n_layers - len(self._windows)) * attended + sum(
+            req.pos // bs - max(req.pos - window + 1, 0) // bs + 1
+            for window in self._windows for req in live
+        )
 
     def _count_state_resets(self, n: int) -> None:
         if self._recurrent:
@@ -1203,10 +1285,13 @@ class InferenceServer:
                 bs = self.pool.block_size
                 attended = sum(req.pos // bs + 1 for req in live)
                 slab = self.pool.max_slots * self.pool.blocks_per_row
+                by_layer = self._layer_blocks(live, attended)
                 self._stats["kv_blocks_attended"] += attended
                 self._stats["kv_blocks_slab"] += slab
+                self._stats["kv_layer_blocks_attended"] += by_layer
                 self._m_kv_attended.inc(attended)
                 self._m_kv_slab.inc(slab)
+                self._m_kv_layer_attended.inc(by_layer)
                 if any(req.temperature > 0.0 for req in live):
                     self._stats["draw_steps"] += 1
                     self._m_draw_steps.inc()
@@ -1226,6 +1311,11 @@ class InferenceServer:
             self._m_steps.inc()
             with tracing.phase("fed:serve:fetch"):
                 ids = self._fetch(ids)
+                # Behind the R ids: what the model counted in this step.
+                for key, n in zip(self.pool.step_counters,
+                                  ids[self.pool.max_slots:]):
+                    self._stats[key] += int(n)
+                    self._m_step_counters[key].inc(int(n))
             with tracing.phase("fed:serve:emit"):
                 for req in live:
                     tok = self._sample(ids[req.slot], req)

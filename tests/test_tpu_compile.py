@@ -1,0 +1,88 @@
+# Copyright 2026 The rayfed-tpu Authors.
+#
+# Licensed under the Apache License, Version 2.0 (the "License");
+# you may not use this file except in compliance with the License.
+# You may obtain a copy of the License at
+#
+#     http://www.apache.org/licenses/LICENSE-2.0
+#
+# Unless required by applicable law or agreed to in writing, software
+# distributed under the License is distributed on an "AS IS" BASIS,
+# WITHOUT WARRANTIES OR CONDITIONS OF ANY KIND, either express or implied.
+# See the License for the specific language governing permissions and
+# limitations under the License.
+
+"""Programs of the serving path compiled for a TPU v5e that is described,
+not attached (the TPU's compiler is installed here): what interpret mode
+and the CPU branch cannot show. Only the TPU branch of
+``moe.grouped_matmul`` (jax's megablox kernel) lives behind
+``is_tpu_backend()``, so these compiles are the one place tier-1 sees it:
+at the published widths of ``command-a-plus-05-2026`` and the row counts
+the engine's programs hand it. Nothing runs; a compile that passes is
+not a chip run.
+
+The topology is described inside a fixture, after a test of this file has
+started, and every such test is in this one file: the process that
+describes it keeps the TPU's library until it exits.
+"""
+
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip (the next
+    # run warns and compiles again): keep these compiles out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "rows", [16, 512, 8], ids=["decode-16-rows", "chunk-512", "chunk-8"])
+def test_the_routed_experts_compile_for_v5e_with_the_grouped_kernel(
+        rows, one_chip, monkeypatch):
+    """16 held experts of 4096 x 4096 under a 128-wide router, 8 a token:
+    the decode step's 16 rows, a full chunk and the smallest ragged one.
+    The program holds the Mosaic kernel three times and no copy of an
+    expert's matrices."""
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import moe
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    d, f, held, scored, k = 4096, 4096, 16, 128, 8
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = {"router": sds((d, scored)), "we_gate": sds((held, d, f)),
+             "we_up": sds((held, d, f)), "we_down": sds((held, f, d))}
+    compiled = jax.jit(
+        lambda h, layer, live: moe.routed_experts(
+            h, layer, tuple(range(held)), k, live)
+    ).lower(sds((rows, d)), layer, sds((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    # Beside its arguments (1.6 GB of experts) it needs room for the
+    # sorted rows and three (rows * k, 4096) float32 results, not for
+    # copies of weights.
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
