@@ -37,7 +37,10 @@ experts are whole.
 The serving engine (:mod:`rayfed_tpu.serving.server`) takes this module
 through :func:`serving_model`, the protocol of
 :class:`rayfed_tpu.models.decode.TransformerServing`. Its K/V are the
-paged pool's own (no state beside them). What it adds to the protocol is
+paged pool's own (no state beside them): the decode step and the prompt
+chunk read them through the block tables and write theirs in place, each
+building one ``decode.paged_attention`` / ``decode.paged_chunk_attention``
+per kind of layer over the same pool. What it adds to the protocol is
 optional and declared: ``layer_windows()`` (the engine counts the blocks
 each layer must read) and ``step_counters`` (numbers only the device
 knows, appended to the ids a decode step returns).
@@ -78,9 +81,6 @@ from rayfed_tpu.models import moe
 
 Params = Dict[str, Any]
 F32 = jnp.float32
-# Keys a trip of the chunked-prefill attention reads from the gathered
-# row (a chunk's queries against one block of keys, online softmax).
-CHUNK_KEY_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,65 +246,6 @@ def seq_attention(q, k, v, q_pos, kind: str, cfg: Cohere2MoeConfig):
         return o.reshape(s, h, dh)
 
 
-def chunk_attention(q, k_rows, v_rows, layer: int, offset, n_real,
-                    kind: str, cfg: Cohere2MoeConfig):
-    """Attention of a chunk's queries (C, H, Dh) at positions ``offset ..
-    offset + C - 1`` over layer ``layer`` of one gathered row (L, T, Hkv,
-    Dh) that already holds the chunk's own keys (the layer is sliced a
-    block of keys at a time, never whole). Reads the keys ``[lo, offset +
-    n_real)`` only, ``lo`` the first key the chunk's first query sees (0
-    on a full layer), a block of ``CHUNK_KEY_BLOCK`` at a time under an online
-    softmax whose trip count is a runtime value: a chunk costs what its
-    context costs, not what the row's length would. A padded query (past
-    ``n_real``) attends only real keys; nobody reads it."""
-    with _scope(kind):
-        c, h, dh = q.shape
-        t, hkv = k_rows.shape[1], k_rows.shape[2]
-        kb = min(CHUNK_KEY_BLOCK, t)
-        qg = q.reshape(c, hkv, h // hkv, dh)
-        q_pos = offset + jnp.arange(c)
-        end = offset + n_real
-        lo = (jnp.maximum(offset - cfg.window + 1, 0)
-              if kind == "sliding" else 0)
-
-        def block(i, carry):
-            m, den, acc = carry
-            # The row's last block is read from where it still fits; the
-            # keys it shares with the block before are masked.
-            start = jnp.minimum(i * kb, t - kb)
-            kc = jax.lax.dynamic_slice(
-                k_rows, (layer, start, 0, 0), (1, kb, hkv, dh))[0]
-            vc = jax.lax.dynamic_slice(
-                v_rows, (layer, start, 0, 0), (1, kb, hkv, dh))[0]
-            k_pos = start + jnp.arange(kb)
-            ok = ((k_pos[None, :] <= q_pos[:, None])
-                  & (k_pos >= i * kb)[None, :] & (k_pos < end)[None, :])
-            if kind == "sliding":
-                ok &= k_pos[None, :] > q_pos[:, None] - cfg.window
-            s = jnp.einsum("qhgd,khd->hgqk", qg, kc,
-                           preferred_element_type=F32) * dh**-0.5
-            s = jnp.where(ok[None, None], s, -jnp.inf)
-            m_new = jnp.maximum(m, s.max(-1))
-            # A query that has seen no key yet keeps a finite reference
-            # point: exp(-inf - 0) = 0, never inf - inf.
-            safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            alpha = jnp.exp(m - safe)
-            p = jnp.exp(s - safe[..., None])
-            den = den * alpha + p.sum(-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "hgqk,khd->hgqd", p.astype(vc.dtype), vc,
-                preferred_element_type=F32)
-            return m_new, den, acc
-
-        shape = (hkv, h // hkv, c)
-        init = (jnp.full(shape, -jnp.inf, F32), jnp.zeros(shape, F32),
-                jnp.zeros(shape + (dh,), F32))
-        _, den, acc = jax.lax.fori_loop(
-            lo // kb, (end + kb - 1) // kb, block, init)
-        out = acc / jnp.maximum(den, 1e-30)[..., None]
-        return jnp.moveaxis(out, 2, 0).reshape(c, h, dh).astype(v_rows.dtype)
-
-
 def ffn(h, layer, cfg: Cohere2MoeConfig, live=None):
     """Routed (the held experts' part) plus shared experts of a normed
     ``h`` (T, d). Returns ((T, d) float32, experts hit, assignments on
@@ -412,33 +353,43 @@ def prefill_rows(params, prompts, last_idx, cache_dtype,
     return logits, k, v, {}
 
 
-def chunk(params, k_row, v_row, toks, offset, n_real,
+def chunk(params, pk, pv, table, toks, offset, n_real,
           cfg: Cohere2MoeConfig):
-    """One prompt chunk ``toks`` (C,), real up to ``n_real``, of one row
-    at position ``offset``: K/V rows (L, T, Hkv, Dh) written in [offset,
-    offset + C) and read no further than the chunk's context
-    (:func:`chunk_attention`). Returns the logits (V,) at the last real
-    position and the rows."""
+    """One prompt chunk ``toks`` (C,), real up to ``n_real``, at positions
+    ``offset .. offset + C - 1`` of the slot whose block table is
+    ``table``: its context read from the pool through the table and no
+    further than it reaches (:func:`decode.paged_chunk_attention`; on a
+    sliding layer from the window's first keys), its own K/V written
+    there in place, once, after the last layer. ``pk``/``pv`` are
+    donated. Returns the logits (V,) at the last real position and the
+    pool."""
     clen = toks.shape[0]
+    n_phys = pk.shape[1]
     positions = offset + jnp.arange(clen)
     live = jnp.arange(clen) < n_real
+    attend = {
+        "full": decode.paged_chunk_attention(pk, pv, table, offset, n_real),
+        "sliding": decode.paged_chunk_attention(
+            pk, pv, table, offset, n_real, window=cfg.window),
+    }
     x = _embed(params, toks, cfg)
+    ks, vs = [], []
     for i, (layer, kind) in enumerate(
             zip(params["layers"], cfg.layer_types, strict=True)):
         h = layer_norm(x, layer["ln"], cfg.ln_eps)
         q, k, v = qkv(h, layer, positions, kind, cfg)
-        # In place, in the donated rows: layer i, [offset, offset + C).
-        k_row = jax.lax.dynamic_update_slice(
-            k_row, k.astype(k_row.dtype)[None], (i, offset, 0, 0))
-        v_row = jax.lax.dynamic_update_slice(
-            v_row, v.astype(v_row.dtype)[None], (i, offset, 0, 0))
-        att = attn_out(
-            chunk_attention(q, k_row, v_row, i, offset, n_real, kind, cfg),
-            layer, cfg)
+        k, v = k.astype(pk.dtype), v.astype(pv.dtype)
+        with _scope(kind):
+            o = attend[kind](q, k, v, i * n_phys)
+        att = attn_out(o, layer, cfg)
         f, _, _ = ffn(h, layer, cfg, live)
         x = (x.astype(F32) + att + f).astype(cfg.compute_dtype)
+        ks.append(k)
+        vs.append(v)
+    pk, pv = decode.paged_chunk_write(
+        pk, pv, jnp.stack(ks), jnp.stack(vs), table, offset)
     last = jax.lax.dynamic_index_in_dim(x, n_real - 1, 0, keepdims=False)
-    return _head(last, params, cfg), k_row, v_row
+    return _head(last, params, cfg), pk, pv
 
 
 def paged_decode_step(params, pk, pv, tokens, positions, tables, live,
@@ -511,10 +462,10 @@ class Cohere2MoeServing:
         return prefill_rows(
             params, prompts, last_idx, cache_dtype, self.cfg, landed)
 
-    def chunk(self, params, k_row, v_row, state, toks, offset, n_real):
-        last, k_row, v_row = chunk(
-            params, k_row, v_row, toks, offset, n_real, self.cfg)
-        return last, k_row, v_row, state
+    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
+        last, pk, pv = chunk(
+            params, pk, pv, table, toks, offset, n_real, self.cfg)
+        return last, pk, pv, state
 
     def decode_step(self, params, pk, pv, state, tokens, positions, tables,
                     live):

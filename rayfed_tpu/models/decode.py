@@ -90,6 +90,15 @@ def _block_tail(x, o, layer, cfg: tfm.TransformerConfig):
     return x + tfm.ffn_apply(tfm.rms_norm(x, layer["ln2"]), layer, cfg)
 
 
+def _head(x, params, cfg: tfm.TransformerConfig):
+    """Logits (.., vocab) float32 of hidden states ``x`` (.., d): the
+    final norm, then the head."""
+    x = tfm.rms_norm(x, params["ln_f"])
+    return (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(
+        jnp.float32
+    )
+
+
 def forward_with_cache(
     params, tokens, cache: Cache, offset, cfg: tfm.TransformerConfig
 ):
@@ -136,11 +145,7 @@ def forward_with_cache(
 
     init = (x, cache["k"], cache["v"], jnp.asarray(0, jnp.int32))
     (x, ck, cv, _), _ = jax.lax.scan(body, init, params["layers"])
-    x = tfm.rms_norm(x, params["ln_f"])
-    logits = (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(
-        jnp.float32
-    )
-    return logits, {"k": ck, "v": cv}
+    return _head(x, params, cfg), {"k": ck, "v": cv}
 
 
 # Keys gathered per trip of the paged attention loop. A trip reads whole
@@ -274,6 +279,129 @@ def paged_write(pk, pv, k_new, v_new, positions, tables):
             pv.at[:, w_block, w_off].set(v_new))
 
 
+# Keys gathered per trip of a prompt chunk's loop over its cached context
+# (:func:`paged_chunk_attention`). A trip scores all of the chunk's
+# queries against this many keys, so its float32 scores are C x H x this
+# wide, and the context is read rounded up to it. On a v5e (PR 32,
+# PERF.md; host clock around one call) the dense chunk program (256
+# queries x 16 heads, 24 layers) read 8.4 / 9.1 / 10.0 ms at contexts of
+# 188 / 700 / 1,468 keys with 256, 8.6 / 9.0 / 9.6 with 512 and 8.9 /
+# 9.0 / 9.8 with 1,024: within 0.4 ms of one another. At 512 queries x
+# 128 heads over 8 K/V heads (four layers' attention alone, three of
+# them under a 4,096 window) contexts of 2,048 / 6,144 / 11,776 keys
+# read 7.0 / 12.4 / 15.9 ms with 256, 12.5 / 24.6 / 31.2 with 512 and
+# 13.2 / 26.1 / 37.5 with 1,024: there a trip's scores are 67 MB at 256
+# and 134 MB at 512, and a key costs twice as much in the wider trip.
+CHUNK_TRIP_KEYS = 256
+
+
+def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
+    """The read of a block-paged K/V pool through one slot's block table,
+    for one chunk of its prompt. Returns ``attend(q, k, v, base)``: ``q``
+    (C, H, Dh) a layer's queries at positions ``offset .. offset + C - 1``,
+    ``k``/``v`` (C, Hkv, Dh) the chunk's own keys and values (in hand:
+    they are not read back from the pool), ``base`` the layer's first row
+    of the flattened pool (layer * P), as in :func:`paged_attention`; the
+    result is the attention output (C, H, Dh). K/V head i serves query
+    heads i*G..(i+1)*G-1.
+
+    The chunk's own C keys are the online softmax's first block, under
+    the causal mask inside the chunk. The first ``n_real`` positions are
+    the prompt's, the rest padding: a padded query attends the real keys
+    and itself (so no softmax is ever empty and no NaN can reach the
+    pool); nobody reads it. The cached keys ``[lo, offset)`` are then
+    gathered through ``table`` ``CHUNK_TRIP_KEYS`` at a time, and the
+    trip count is a runtime value: a chunk costs what its context costs,
+    not what the row's length would. The mathematics is
+    :func:`transformer.causal_attention`'s (operands in the compute
+    dtype, float32 scores, max, sum and accumulator, probabilities cast
+    to the value dtype before the PV product), re-associated, nothing
+    rounded lower.
+
+    ``window`` (a static int, or None: every key) makes this the read of
+    a sliding-window layer: the query at ``p`` attends ``p - window + 1
+    .. p``, ``lo`` is the first key the chunk's first query sees, rounded
+    down to a trip, and keys before a query's window are masked.
+    """
+    n_layers, n_phys, bs, n_kv, dh = pk.shape
+    (blocks_per_row,) = table.shape
+    trip_blocks = max(1, min(blocks_per_row, CHUNK_TRIP_KEYS // bs))
+    trip_keys = trip_blocks * bs
+    table_p = jnp.pad(table, (0, -blocks_per_row % trip_blocks))
+    first = 0
+    if window is not None:
+        first = jnp.maximum(offset - window + 1, 0) // trip_keys
+    last = (offset + trip_keys - 1) // trip_keys
+    pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
+    pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
+    scale = dh**-0.5
+
+    def attend(q, k, v, base):
+        c, n_heads, _ = q.shape
+        q = q.reshape(c, n_kv, n_heads // n_kv, dh)
+        idx = jnp.arange(c)
+        q_pos = offset + idx
+
+        def scores(keys, seen):
+            s = jnp.einsum(
+                "qhgd,khd->hgqk", q, keys, preferred_element_type=jnp.float32
+            ) * scale
+            return jnp.where(seen[None, None], s, -jnp.inf)
+
+        own = (idx[None, :] <= idx[:, None]) & (
+            (idx < n_real)[None, :] | (idx[None, :] == idx[:, None]))
+        if window is not None:
+            own &= idx[None, :] > idx[:, None] - window
+        s = scores(k, own)
+        m = s.max(-1)
+        p = jnp.exp(s - m[..., None])
+        init = (m, p.sum(-1), jnp.einsum(
+            "hgqk,khd->hgqd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
+        ))
+
+        def trip(t, carry):
+            m, den, acc = carry
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                table_p, t * trip_blocks, trip_blocks)
+            kc = pk_flat[blocks].reshape(trip_keys, n_kv, dh)
+            vc = pv_flat[blocks].reshape(trip_keys, n_kv, dh)
+            k_pos = t * trip_keys + jnp.arange(trip_keys)
+            cached = (k_pos < offset)[None, :]
+            if window is not None:
+                cached &= k_pos[None, :] > q_pos[:, None] - window
+            s = scores(kc, cached)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = den * alpha + p.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "hgqk,khd->hgqd", p.astype(vc.dtype), vc,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, den, acc
+
+        _, den, acc = jax.lax.fori_loop(first, last, trip, init)
+        out = (acc / den[..., None]).astype(v.dtype)
+        return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, dh)
+
+    return attend
+
+
+def paged_chunk_write(pk, pv, k_new, v_new, table, offset):
+    """The one write of a prompt chunk: the stack's new K/V (L, C, Hkv,
+    Dh) into ``(table[p // bs], p % bs)`` for ``p = offset .. offset + C -
+    1`` in every layer. A position whose block is not granted (padding
+    past the prompt's granted blocks: the table holds 0 there) falls into
+    the sacrificial block."""
+    bs = pk.shape[2]
+    pos = offset + jnp.arange(k_new.shape[1])
+    w_block = table[pos // bs]
+    w_off = pos % bs
+    return (pk.at[:, w_block, w_off].set(k_new),
+            pv.at[:, w_block, w_off].set(v_new))
+
+
 def paged_decode_step(
     params, pk, pv, tokens, positions, tables, cfg: tfm.TransformerConfig
 ):
@@ -294,9 +422,8 @@ def paged_decode_step(
     writes into block 0, which no real query attends.
     """
     n_phys = pk.shape[1]
-    cdt = cfg.compute_dtype
     attend = paged_attention(pk, pv, positions, tables)
-    x = params["embed"][tokens][:, None].astype(cdt)
+    x = params["embed"][tokens][:, None].astype(cfg.compute_dtype)
 
     def body(carry, layer):
         x, i = carry
@@ -309,8 +436,7 @@ def paged_decode_step(
     (x, _), (k_new, v_new) = jax.lax.scan(
         body, (x, jnp.asarray(0, jnp.int32)), params["layers"]
     )
-    x = tfm.rms_norm(x[:, 0], params["ln_f"])
-    logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
+    logits = _head(x[:, 0], params, cfg)
     pk, pv = paged_write(pk, pv, k_new, v_new, positions, tables)
     return logits, pk, pv
 
@@ -324,9 +450,10 @@ class TransformerServing:
     the module that defines the config's class. It gives the pool its
     shapes (``kv_shape``, ``state_spec``) and the engine its programs:
     ``prefill_rows`` (a round of right-padded short prompts, one row
-    each), ``chunk`` (one chunk of a long prompt against one gathered
-    row) and ``decode_step`` (one token for every row, K/V read through
-    the block tables). ``state_spec`` is what a slot holds beside its
+    each), ``chunk`` (one chunk of a long prompt: its context read from
+    the pool through the slot's block table, its own K/V written there
+    in place) and ``decode_step`` (one token for every row, K/V read
+    through the block tables). ``state_spec`` is what a slot holds beside its
     K/V; a model that has none (this one) returns ``{}``, takes and
     returns ``{}`` wherever a program hands state on, and ignores
     ``live``, ``n_real`` and what else exists for the sake of a state.
@@ -387,22 +514,37 @@ class TransformerServing:
         rows = jax.vmap(one_row, in_axes=(0, 0, None), out_axes=(0, 1, 1))
         return (*rows(prompts, last_idx, params), {})
 
-    def chunk(self, params, k_row, v_row, state, toks, offset, n_real):
-        """One chunk of a long prompt against one gathered row: the first
-        ``n_real`` of ``toks`` are the prompt's, the rest padding. Returns
-        the logits of the last real position (vocab,), the rows and the
-        state."""
-        logits, cache = forward_with_cache(
-            params,
-            toks[None],
-            {"k": k_row[:, None], "v": v_row[:, None]},
-            offset,
-            self.cfg,
+    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
+        """One chunk ``toks`` (C,) of a long prompt, at positions ``offset
+        .. offset + C - 1`` of the slot whose block table is ``table``:
+        the first ``n_real`` are the prompt's, the rest padding. The
+        chunk's context is read from the donated pool through the table
+        (:func:`paged_chunk_attention`) and its own K/V written there in
+        place, once, after the last layer (:func:`paged_chunk_write`).
+        Returns the logits of the last real position (vocab,), the pool
+        and the state (``slot`` names its row of a state; none here)."""
+        cfg = self.cfg
+        n_phys = pk.shape[1]
+        attend = paged_chunk_attention(pk, pv, table, offset, n_real)
+        positions = (offset + jnp.arange(toks.shape[0]))[None]
+        x = params["embed"][toks][None].astype(cfg.compute_dtype)
+
+        def body(carry, layer):
+            x, i = carry
+            q, k, v = tfm.qkv_proj(x, layer, positions, cfg)
+            k, v = k[0].astype(pk.dtype), v[0].astype(pv.dtype)
+            o = attend(q[0], k, v, i * n_phys)
+            return (_block_tail(x, o[None], layer, cfg), i + 1), (k, v)
+
+        (x, _), (k_new, v_new) = jax.lax.scan(
+            body, (x, jnp.asarray(0, jnp.int32)), params["layers"]
         )
+        # The head on the one position read, not on all C.
         last = jax.lax.dynamic_index_in_dim(
-            logits[0], n_real - 1, axis=0, keepdims=False
+            x[0], n_real - 1, axis=0, keepdims=False
         )
-        return last, cache["k"][:, 0], cache["v"][:, 0], state
+        pk, pv = paged_chunk_write(pk, pv, k_new, v_new, table, offset)
+        return _head(last, params, cfg), pk, pv, state
 
     def decode_step(self, params, pk, pv, state, tokens, positions, tables,
                     live):

@@ -33,7 +33,7 @@ no query attends it; a recurrent state is *carried*, so here
   is that of the last real inputs;
 * a request always starts from zero: the bucketed prefill computes from
   a fresh zero state, the first chunk of a chunked prefill (``offset ==
-  0``) zeroes what it was handed;
+  0``) zeroes what its slot held;
 * a row that sits a decode step out (``live`` false) gets its state back
   bit for bit.
 
@@ -524,42 +524,54 @@ def prefill_rows(params, prompts, last_idx, row_len: int, cache_dtype,
     return logits, k, v, {"conv": tails, "ssm": states}
 
 
-def chunk(params, k_row, v_row, state, toks, offset, n_real,
+def chunk(params, pk, pv, state, table, slot, toks, offset, n_real,
           cfg: FalconH1Config):
-    """One prompt chunk ``toks`` (C,), real up to ``n_real``, of one row
-    at position ``offset``: K/V rows (L, T, Hkv, Dh) written in
-    [offset, offset + C), the row's state handed from chunk to chunk
-    (zeroed when ``offset == 0``: a request starts here). Returns the
-    logits (V,) at the last real position, the rows and the state."""
+    """One prompt chunk ``toks`` (C,), real up to ``n_real``, at positions
+    ``offset .. offset + C - 1`` of the slot whose block table is
+    ``table``: its context read from the pool through the table
+    (:func:`decode.paged_chunk_attention`, each K/V head serving its
+    group of query heads), its own K/V written there in place; the slot's
+    rows of the recurrent state (L, slots, ...) read at ``slot``, handed
+    from chunk to chunk (zeroed when ``offset == 0``: a request starts
+    here) and written back at ``slot``, every other slot's bit for bit.
+    ``pk``/``pv``/``state`` are donated. Returns the logits (V,) at the
+    last real position, the pool and the state."""
     clen = toks.shape[0]
+    n_phys = pk.shape[1]
     positions = (offset + jnp.arange(clen))[None]
     real = (jnp.arange(clen) < n_real)[None]
     carried = offset > 0
+    attend = decode.paged_chunk_attention(pk, pv, table, offset, n_real)
 
-    def body(x, xs):
-        layer, k_l, v_l, tail, st = xs
+    def body(carry, xs):
+        x, i = carry
+        layer, tail, st = xs
         tail = jnp.where(carried, tail, jnp.zeros_like(tail))
         st = jnp.where(carried, st, jnp.zeros_like(st))
         h = tfm.rms_norm(x, layer["ln1"], cfg.rms_eps)
         q, k, v = qkv(h, layer, positions, cfg)
-        k_l = jax.lax.dynamic_update_slice(
-            k_l, k[0].astype(k_l.dtype), (offset, 0, 0))
-        v_l = jax.lax.dynamic_update_slice(
-            v_l, v[0].astype(v_l.dtype), (offset, 0, 0))
-        att = attn_out(
-            gqa_attention(q, k_l[None], v_l[None], positions), layer, cfg)
+        k, v = k[0].astype(pk.dtype), v[0].astype(pv.dtype)
+        att = attn_out(attend(q[0], k, v, i * n_phys)[None], layer, cfg)
         ssm, tail, st = mixer_seq(
             h, layer, tail[None], st[None], real, n_real[None], cfg)
         x = x + (ssm + att)
         x = x + mlp(x, layer, cfg)
-        return x, (k_l, v_l, tail[0], st[0])
+        return (x, i + 1), (k, v, tail[0], st[0])
 
-    x, (k_row, v_row, tails, states) = jax.lax.scan(
-        body, _embed(params, toks[None], cfg),
-        (params["layers"], k_row, v_row, state["conv"], state["ssm"]))
+    def rows(a):
+        return jax.lax.dynamic_index_in_dim(a, slot, 1, keepdims=False)
+
+    def put(a, new):
+        return jax.lax.dynamic_update_index_in_dim(
+            a, new.astype(a.dtype), slot, 1)
+
+    (x, _), (k_new, v_new, tails, states) = jax.lax.scan(
+        body, (_embed(params, toks[None], cfg), jnp.asarray(0, jnp.int32)),
+        (params["layers"], rows(state["conv"]), rows(state["ssm"])))
     last = jax.lax.dynamic_index_in_dim(x[0], n_real - 1, 0, keepdims=False)
-    return (_head(last, params, cfg), k_row, v_row,
-            {"conv": tails, "ssm": states})
+    pk, pv = decode.paged_chunk_write(pk, pv, k_new, v_new, table, offset)
+    return _head(last, params, cfg), pk, pv, {
+        "conv": put(state["conv"], tails), "ssm": put(state["ssm"], states)}
 
 
 def paged_decode_step(params, pk, pv, state, tokens, positions, tables,
@@ -633,9 +645,10 @@ class FalconH1Serving:
             params, prompts, last_idx, row_len, cache_dtype, self.cfg,
             landed)
 
-    def chunk(self, params, k_row, v_row, state, toks, offset, n_real):
+    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
         return chunk(
-            params, k_row, v_row, state, toks, offset, n_real, self.cfg)
+            params, pk, pv, state, table, slot, toks, offset, n_real,
+            self.cfg)
 
     def decode_step(self, params, pk, pv, state, tokens, positions, tables,
                     live):

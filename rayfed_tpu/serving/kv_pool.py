@@ -40,8 +40,11 @@ no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
 blocks read follow the longest live row, not ``max_len``. It agrees with
 the plain cached forward (:func:`rayfed_tpu.models.decode.
 forward_with_cache`) to rounding (the online softmax re-associates the
-sum), which the parity tests hold to equal tokens. Prefill still moves
-whole rows (``gather_slot`` / ``scatter_slot`` / ``scatter_rows``).
+sum), which the parity tests hold to equal tokens. A chunk of a long
+prompt is one program of the same kind (:meth:`PagedKVPool.chunk_step`):
+it reads its context through the slot's block table and writes its own
+K/V in place. Only the bucketed prefill still lands whole rows
+(``scatter_rows``).
 
 Sacrificial block: the pool is one block larger than ``num_blocks``. A
 batched decode step always runs every pool row; rows that are free,
@@ -65,7 +68,7 @@ of the "stale is invisible" arguments above hold for it: a slot's state
 is made zero by the prefill that starts a request in it (the bucketed
 prefill computes from a zero state and :meth:`PagedKVPool.scatter_rows`
 lands the result for the rows named in ``landed``; the first chunk of a
-chunked prefill zeroes what :meth:`PagedKVPool.gather_slot` handed it),
+chunked prefill starts from zero whatever its slot's rows held),
 and a row that sits a decode step out is handed back bit for bit
 (``live``). Prefix reuse holds no such state and the engine refuses it
 for such a model.
@@ -197,7 +200,6 @@ class PagedKVPool:
     def _build_fns(self) -> None:
         NB = self.blocks_per_row
         bs = self.block_size
-        T = self.row_len
         R = self.max_slots
         model = self.model
 
@@ -233,23 +235,6 @@ class PagedKVPool:
             decode_step, donate_argnums=(1, 2, 7)
         )
 
-        @jax.named_scope("serve/gather")
-        def gather_row(pk, pv, table, state=None, slot=None):
-            # table: (NB,) int32 -> one (L, T, H, Dh) row (and the slot's
-            # row of every state array).
-            L = pk.shape[0]
-            H, Dh = pk.shape[-2:]
-            k = pk[:, table].reshape(L, NB * bs, H, Dh)[:, :T]
-            v = pv[:, table].reshape(L, NB * bs, H, Dh)[:, :T]
-            return k, v, {
-                name: jax.lax.dynamic_index_in_dim(a, slot, 1, False)
-                for name, a in (state or {}).items()
-            }
-
-        self._gather_row_fn = jax.jit(gather_row)
-
-        pad = NB * bs - T
-
         @jax.named_scope("serve/scatter")
         def scatter_rows(pk, pv, k_slab, v_slab, tables, state=None,
                          new_state=None, landed=None):
@@ -277,30 +262,6 @@ class PagedKVPool:
 
         self._scatter_rows_fn = jax.jit(
             scatter_rows, donate_argnums=(0, 1, 5)
-        )
-
-        @jax.named_scope("serve/scatter")
-        def scatter_row(pk, pv, k_row, v_row, table, state=None,
-                        state_row=None, slot=None):
-            L = pk.shape[0]
-            H, Dh = pk.shape[-2:]
-            if pad:
-                z = jnp.zeros((L, pad, H, Dh), k_row.dtype)
-                k_row = jnp.concatenate([k_row, z], axis=1)
-                v_row = jnp.concatenate([v_row, z], axis=1)
-            kp = k_row.reshape(L, NB, bs, H, Dh)
-            vp = v_row.reshape(L, NB, bs, H, Dh)
-            pk = pk.at[:, table].set(kp)
-            pv = pv.at[:, table].set(vp)
-            return pk, pv, {
-                name: jax.lax.dynamic_update_index_in_dim(
-                    a, state_row[name].astype(a.dtype), slot, 1
-                )
-                for name, a in (state or {}).items()
-            }
-
-        self._scatter_row_fn = jax.jit(
-            scatter_row, donate_argnums=(0, 1, 5)
         )
 
     def decode_step(self, params, tokens, positions, tables, draw,
@@ -332,15 +293,20 @@ class PagedKVPool:
         model that has one, not sent at all for one that has none."""
         return np.asarray(value, dtype) if self._state else None
 
-    def gather_slot(self, slot: int):
-        """One slot's contiguous row (chunked-prefill input) and its row
-        of the recurrent state (``{}`` when the model has none)."""
+    def chunk_step(self, fn, params, slot: int, toks, offset: int,
+                   n_real: int, draw):
+        """One chunk of ``slot``'s prompt through the engine's chunk
+        program ``fn`` (``InferenceServer._get_chunk_fn``): the pool and
+        the state go in donated with the slot's block table and come back
+        updated in place, as in :meth:`decode_step`. Returns the token the
+        program chose at the chunk's last real position, on the device."""
         with self._lock:
             table = self._tables[slot].copy()
-        return self._gather_row_fn(
-            self._k, self._v, table, self._state,
-            self._of_state(slot, np.int32),
+        chosen, self._k, self._v, self._state = fn(
+            params, self._k, self._v, self._state, table, np.int32(slot),
+            toks, np.int32(offset), np.int32(n_real), draw,
         )
+        return chosen
 
     def scatter_rows(self, k_slab, v_slab, tables: np.ndarray,
                      state_rows=None, landed=None) -> None:
@@ -349,14 +315,6 @@ class PagedKVPool:
         self._k, self._v, self._state = self._scatter_rows_fn(
             self._k, self._v, k_slab, v_slab, tables,
             self._state, state_rows or {}, self._of_state(landed, bool),
-        )
-
-    def scatter_slot(self, slot: int, k_row, v_row, state_row=None) -> None:
-        with self._lock:
-            table = self._tables[slot].copy()
-        self._k, self._v, self._state = self._scatter_row_fn(
-            self._k, self._v, k_row, v_row, table,
-            self._state, state_row or {}, self._of_state(slot, np.int32),
         )
 
     @property
@@ -376,10 +334,7 @@ class PagedKVPool:
 
     def jitted_fns(self):
         """The pool's jitted programs (compile accounting)."""
-        return [
-            self._decode_step_fn, self._gather_row_fn,
-            self._scatter_rows_fn, self._scatter_row_fn, _copy_block,
-        ]
+        return [self._decode_step_fn, self._scatter_rows_fn, _copy_block]
 
     # -- slot + block lifecycle ------------------------------------------
 

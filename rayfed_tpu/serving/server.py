@@ -249,6 +249,13 @@ class InferenceServer:
             "kv_layer_blocks_attended": 0,
             "prefill_tokens": 0,
             "prefill_keys_attended": 0,
+            # Chunked prefill, per chunk and summed over layers: the
+            # blocks that hold the cached keys its attention gathers
+            # through the table (a windowed layer's from its first
+            # query's window on), beside n_layers x blocks_per_row: what
+            # a contiguous row of the slot would carry each way.
+            "chunk_blocks_read": 0,
+            "chunk_blocks_row": 0,
         }
         # What the model's decode step counts on the device (it declares
         # the names; none for most models): fetched behind the ids.
@@ -381,6 +388,18 @@ class InferenceServer:
             "attended, summed over layers.",
             labels=("server",),
         ).labels(server=name)
+        self._m_chunk_read = _reg.counter(
+            "fed_serving_chunk_blocks_read_total",
+            "KV blocks holding the cached keys a prompt chunk's attention "
+            "gathers, summed over layers and chunks.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_chunk_row = _reg.counter(
+            "fed_serving_chunk_blocks_row_total",
+            "KV blocks of a slot's whole row in every layer, summed over "
+            "prompt chunks.",
+            labels=("server",),
+        ).labels(server=name)
         self._m_step_counters = {
             key: _reg.counter(
                 f"fed_serving_{key}_total",
@@ -436,15 +455,18 @@ class InferenceServer:
         return fn
 
     def _get_chunk_fn(self, clen: int):
-        """One prompt chunk against one gathered row (and its recurrent
-        state) at a dynamic offset; compiled per padded chunk length.
-        The write range [offset, offset + clen) always lies inside the
-        prompt (the ragged remainder is chunked FIRST), so the dynamic
-        update can never clamp over live positions. ``n_real`` of the
-        chunk's positions are the prompt's, the rest padding. Returns
+        """One prompt chunk of one slot at a dynamic offset, against the
+        pool itself: the model's ``chunk`` reads the chunk's context
+        through the slot's block table and writes its K/V (and the slot's
+        rows of a recurrent state) in place; the pool and the state are
+        donated (:meth:`PagedKVPool.chunk_step` makes the call). Compiled
+        per padded chunk length. The write range [offset, offset + clen)
+        always lies inside the prompt (the ragged remainder is chunked
+        FIRST), so padding never lands on live positions. ``n_real`` of
+        the chunk's positions are the prompt's, the rest padding. Returns
         (the token chosen from the last real position's logits under
         ``draw`` (3, 1): the request's first token when this is its last
-        chunk, read by nobody otherwise; K/V rows; state)."""
+        chunk, read by nobody otherwise; the pool; the state)."""
         fn = self._chunk_fns.get(clen)
         if fn is not None:
             return fn
@@ -453,12 +475,12 @@ class InferenceServer:
         model = self.model
 
         @jax.named_scope("serve/chunk")
-        def chunk_step(params, k_row, v_row, state, toks, offset, n_real,
-                       draw):
-            last, *row = model.chunk(
-                params, k_row, v_row, state, toks, offset, n_real
+        def chunk_step(params, pk, pv, state, table, slot, toks, offset,
+                       n_real, draw):
+            last, pk, pv, state = model.chunk(
+                params, pk, pv, state, table, slot, toks, offset, n_real
             )
-            return (sampling.choose_packed(last[None], draw)[0], *row)
+            return sampling.choose_packed(last[None], draw)[0], pk, pv, state
 
         fn = jax.jit(chunk_step, donate_argnums=(1, 2, 3))
         self._chunk_fns[clen] = fn
@@ -1057,16 +1079,13 @@ class InferenceServer:
                 req.stalled = False
                 toks = np.zeros(clen, np.int32)
                 toks[:real] = req.prompt[off:off + real]
-                params = self.bank.get(req.version)
-                k_row, v_row, state_row = self.pool.gather_slot(req.slot)
                 # The first chunk (offset 0) starts the request: the
-                # program zeroes the state it was handed.
-                chosen, k_row, v_row, state_row = self._get_chunk_fn(clen)(
-                    params, k_row, v_row, state_row, toks,
-                    np.int32(off), np.int32(real),
+                # program zeroes the slot's recurrent state.
+                chosen = self.pool.chunk_step(
+                    self._get_chunk_fn(clen), self.bank.get(req.version),
+                    req.slot, toks, off, real,
                     sampling.pack([req.temperature], [req.seed], [0]),
                 )
-                self.pool.scatter_slot(req.slot, k_row, v_row, state_row)
                 if off == 0:
                     self._count_state_resets(1)
                 req.chunk_done = off + real
@@ -1076,6 +1095,7 @@ class InferenceServer:
                     self._stats["prefill_chunks"] += 1
                 self._m_chunks.inc()
                 self._count_prefill(off, real)
+                self._count_chunk_blocks(off)
                 if req.chunk_done >= plen:
                     with self._lock:
                         self._prefilling.remove(req)
@@ -1160,6 +1180,24 @@ class InferenceServer:
             self._stats["prefill_keys_attended"] += keys
         self._m_prefill_tokens.inc(n)
         self._m_prefill_keys.inc(keys)
+
+    def _count_chunk_blocks(self, off: int) -> None:
+        """A chunk at offset ``off`` ran: count the blocks that hold the
+        cached keys ``[lo, off)`` its attention gathers, over the layers
+        (``lo`` 0, or the first key the chunk's first query sees on a
+        windowed layer), beside the blocks of a whole row in every
+        layer."""
+        bs = self.pool.block_size
+        upto = -(-off // bs)
+        read = (self._n_layers - len(self._windows)) * upto + sum(
+            upto - max(off - w + 1, 0) // bs for w in self._windows
+        )
+        row = self._n_layers * self.pool.blocks_per_row
+        with self._lock:
+            self._stats["chunk_blocks_read"] += read
+            self._stats["chunk_blocks_row"] += row
+        self._m_chunk_read.inc(read)
+        self._m_chunk_row.inc(row)
 
     def _layer_blocks(self, live, attended: int) -> int:
         """Blocks the layers of the ``live`` rows must read in a decode

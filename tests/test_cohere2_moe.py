@@ -47,6 +47,7 @@ from rayfed_tpu.models import transformer as tfm
 from rayfed_tpu.serving import sampling
 from rayfed_tpu.serving.kv_pool import PagedKVPool
 from rayfed_tpu.serving.server import InferenceServer
+from tests.utils import slot_rows
 
 ref = importlib.import_module("chipbench.references.cohere2_moe")
 
@@ -276,8 +277,8 @@ def test_a_full_layer_ignores_positions_and_a_sliding_one_rotates():
 
 
 def _attention_paths():
-    """Three ways a layer attends: over a whole sequence, a chunk against
-    a gathered row, a decode token through the block tables; each as
+    """Three ways a layer attends: over a whole sequence, a chunk and a
+    decode token through the block tables; each as
     ``f(k_all, kind) -> the last position's output`` for keys (P + 1, Hkv,
     Dh) at positions 0..P."""
     rng = np.random.default_rng(11)
@@ -289,13 +290,24 @@ def _attention_paths():
         return cm.seq_attention(q, k, v, jnp.arange(p + 1), kind, CFG)[-1]
 
     def chunked(k, kind):
-        row = MAX_LEN + 1
-        k_rows = jnp.zeros((2, row, 2, 8)).at[1, :p + 1].set(k)
-        v_rows = jnp.zeros((2, row, 2, 8)).at[1, :p + 1].set(v)
+        # The last CHUNK positions as a chunk: its own keys in hand, the
+        # ones before it in the pool, behind a table that runs backwards.
         off = p + 1 - CHUNK
-        return cm.chunk_attention(
-            q[off:], k_rows, v_rows, 1, jnp.asarray(off),
-            jnp.asarray(CHUNK), kind, CFG)[-1]
+        nb = -(-off // BLOCK)
+        pad = nb * BLOCK - off
+        blocks = lambda a: jnp.pad(  # noqa: E731
+            a[:off], ((0, pad), (0, 0), (0, 0))).reshape(nb, BLOCK, 2, 8)
+        order = np.arange(nb)[::-1]
+        pk = jnp.zeros((2, 1 + nb, BLOCK, 2, 8)).at[1, 1 + order].set(
+            blocks(k))
+        pv = jnp.zeros((2, 1 + nb, BLOCK, 2, 8)).at[1, 1 + order].set(
+            blocks(v))
+        table = jnp.zeros((-(-(MAX_LEN + 1) // BLOCK),), jnp.int32).at[
+            :nb].set(1 + order)
+        attend = decode.paged_chunk_attention(
+            pk, pv, table, jnp.asarray(off), jnp.asarray(CHUNK),
+            window=WINDOW if kind == "sliding" else None)
+        return attend(q[off:], k[off:], v[off:], 1 + nb)[-1]
 
     def paged(k, kind):
         nb = -(-(p + 1) // BLOCK)
@@ -624,7 +636,7 @@ def test_the_pool_lands_rows_of_the_length_they_come_in():
     pool.scatter_rows(jnp.asarray(long_rows), jnp.asarray(long_rows), tables)
     short = rng.normal(size=(4, 2, 6, 2, 8)).astype(np.float32)
     pool.scatter_rows(jnp.asarray(short), jnp.asarray(-short), tables)
-    k_row, v_row, _ = pool.gather_slot(slot)
+    k_row, v_row = slot_rows(pool, slot)
     assert np.array_equal(np.asarray(k_row)[:, :6], short[:, slot])
     assert np.array_equal(np.asarray(v_row)[:, :6], -short[:, slot])
     # Block 1 (positions 4..7) was rewritten whole: 6, 7 by the padding.

@@ -282,24 +282,32 @@ def test_an_admission_round_computes_its_landed_rows_and_no_other():
 
 def test_padded_ragged_chunk_ends_in_the_last_real_tokens_state():
     """The chunk program: a ragged first chunk padded to its bucket hands
-    on the state of its last real token, whatever the padding holds."""
+    on the state of its last real token, whatever the padding holds, and
+    starts from zero whatever its slot's rows held; the other slot's rows
+    of the state come back bit for bit."""
     pool = PagedKVPool(CFG, max_slots=2, max_len=MAX_LEN,
                        dtype=jnp.float32, block_size=8)
-    slot = pool.acquire()
+    other, slot = pool.acquire(), pool.acquire()
     assert pool.ensure_blocks(slot, CHUNK) == "ok"
+    rng = np.random.default_rng(3)
+    held = {name: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for name, a in pool.state.items()}
     chunk = jax.jit(lambda *a: fh.chunk(*a, CFG))
     real = 5
     outs = []
     for junk_seed in (1, 2):
         toks = _tokens(8, seed=junk_seed)
         toks[:real] = _tokens(real, seed=7)
-        k_row, v_row, state = pool.gather_slot(slot)
-        outs.append(chunk(PARAMS, k_row, v_row, state, jnp.asarray(toks),
-                          jnp.asarray(0, jnp.int32),
-                          jnp.asarray(real, jnp.int32)))
+        outs.append(chunk(PARAMS, *pool.kv, held, pool.table(slot),
+                          np.int32(slot), jnp.asarray(toks), np.int32(0),
+                          np.int32(real)))
     for name in ("conv", "ssm"):
-        assert np.array_equal(np.asarray(outs[0][3][name]),
-                              np.asarray(outs[1][3][name])), name
+        new = [np.asarray(o[3][name]) for o in outs]
+        assert np.array_equal(new[0][:, slot], new[1][:, slot]), name
+        assert np.array_equal(new[0][:, other],
+                              np.asarray(held[name])[:, other]), name
+        assert not np.array_equal(new[0][:, slot],
+                                  np.asarray(held[name])[:, slot]), name
     assert np.array_equal(np.asarray(outs[0][0]), np.asarray(outs[1][0]))
     want = _ref_logits(_tokens(real, seed=7))[-1]
     assert np.abs(np.asarray(outs[0][0]) - want).max() < TOL32
@@ -631,16 +639,19 @@ def test_both_implementers_chunk_returns_the_last_real_positions_logits():
     cfg = tfm.tiny_config(compute_dtype=jnp.float32)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     model = decode.serving_model(cfg)
-    real, clen, row_len = 5, 8, 17
-    row = decode.init_cache(cfg, 1, row_len, jnp.float32)
+    real, clen = 5, 8
+    pool = PagedKVPool(cfg, max_slots=1, max_len=16, dtype=jnp.float32,
+                       block_size=8)
+    slot = pool.acquire()
+    assert pool.ensure_blocks(slot, real - 1) == "ok"
     chunk = jax.jit(model.chunk)
     outs = []
     for junk_seed in (1, 2):
         toks = _tokens(clen, seed=junk_seed) % cfg.vocab
         toks[:real] = _tokens(real, seed=7) % cfg.vocab
         outs.append(np.asarray(chunk(
-            params, row["k"][:, 0], row["v"][:, 0], {}, jnp.asarray(toks),
-            jnp.asarray(0, jnp.int32), jnp.asarray(real, jnp.int32))[0]))
+            params, *pool.kv, {}, pool.table(slot), np.int32(slot),
+            jnp.asarray(toks), np.int32(0), np.int32(real))[0]))
     assert outs[0].shape == (cfg.vocab,)
     assert np.array_equal(outs[0], outs[1])
     want = tfm.forward(params, jnp.asarray(toks[None, :real]), cfg)[0, -1]
@@ -648,8 +659,8 @@ def test_both_implementers_chunk_returns_the_last_real_positions_logits():
     # The same shape from the second implementer.
     pool = PagedKVPool(CFG, max_slots=1, max_len=MAX_LEN,
                        dtype=jnp.float32, block_size=8)
-    k_row, v_row, state = pool.gather_slot(pool.acquire())
+    slot = pool.acquire()
     last = decode.serving_model(CFG).chunk(
-        PARAMS, k_row, v_row, state, jnp.asarray(_tokens(clen, seed=1)),
-        jnp.asarray(0, jnp.int32), jnp.asarray(real, jnp.int32))[0]
+        PARAMS, *pool.kv, pool.state, pool.table(slot), np.int32(slot),
+        jnp.asarray(_tokens(clen, seed=1)), np.int32(0), np.int32(real))[0]
     assert last.shape == (TINY["vocab_size"],)

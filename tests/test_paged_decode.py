@@ -45,7 +45,7 @@ from rayfed_tpu.models import decode  # noqa: E402
 from rayfed_tpu.models import transformer as tfm  # noqa: E402
 from rayfed_tpu.serving.kv_pool import PagedKVPool  # noqa: E402
 from rayfed_tpu.serving.server import InferenceServer  # noqa: E402
-from tests.utils import step_logits  # noqa: E402
+from tests.utils import land_row, step_logits  # noqa: E402
 
 BS = 4
 MAX_LEN = 24
@@ -84,7 +84,7 @@ def _setup(dtype, lengths, seed=0):
         }
         slot = pool.acquire()
         assert pool.ensure_blocks(slot, n) == "ok"
-        pool.scatter_slot(slot, cache["k"][:, r], cache["v"][:, r])
+        land_row(pool, slot, cache["k"][:, r], cache["v"][:, r])
         slots.append(slot)
         tokens[slot], positions[slot] = seqs[r, n], n
         tables[slot] = pool.table(slot)

@@ -786,10 +786,9 @@ def test_no_program_of_the_engine_returns_a_vocabulary_sized_axis():
     try:
         pool, R = srv.pool, srv.pool.max_slots
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-        k_row, v_row, _ = jax.eval_shape(
-            pool._gather_row_fn, *pool.kv, i32(pool.blocks_per_row))
-        slab = jax.ShapeDtypeStruct(
-            (k_row.shape[0], R, *k_row.shape[1:]), k_row.dtype)
+        L, _, _, H, Dh = pool.kv[0].shape
+        slab = jax.ShapeDtypeStruct((L, R, pool.row_len, H, Dh),
+                                    pool.kv[0].dtype)
         tables = i32(R, pool.blocks_per_row)
         outs = {
             "decode_step": jax.eval_shape(
@@ -799,14 +798,11 @@ def test_no_program_of_the_engine_returns_a_vocabulary_sized_axis():
                 srv._get_prefill_rows_fn(16), PARAMS_A, i32(R, 16), i32(R),
                 jax.ShapeDtypeStruct((R,), bool), i32(3, R)),
             "chunk_step": jax.eval_shape(
-                srv._get_chunk_fn(8), PARAMS_A, k_row, v_row, {}, i32(8),
-                i32(), i32(), i32(3, 1)),
-            "gather_row": (k_row, v_row),
+                srv._get_chunk_fn(8), PARAMS_A, *pool.kv, {},
+                i32(pool.blocks_per_row), i32(), i32(8), i32(), i32(),
+                i32(3, 1)),
             "scatter_rows": jax.eval_shape(
                 pool._scatter_rows_fn, *pool.kv, slab, slab, tables),
-            "scatter_row": jax.eval_shape(
-                pool._scatter_row_fn, *pool.kv, k_row, v_row,
-                i32(pool.blocks_per_row)),
             "copy_block": jax.eval_shape(
                 kv_pool._copy_block, *pool.kv, i32(), i32()),
         }

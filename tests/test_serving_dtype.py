@@ -95,15 +95,16 @@ def _run_prefill_rows(params):
 
 
 def _run_chunk(params):
-    row = decode.init_cache(CFG, 1, 40, CDT)
+    pool = PagedKVPool(CFG, max_slots=1, max_len=40, dtype=CDT, block_size=8)
     toks = np.asarray(LONG[:16], np.int32)
+    table = np.arange(1, 1 + pool.blocks_per_row, dtype=np.int32)
     out = None
-    k_row, v_row = row["k"][:, 0], row["v"][:, 0]
+    pk, pv = pool.kv
     for offset, n_real in ((0, 16), (16, 9)):
         out = jax.jit(MODEL.chunk)(
-            params, k_row, v_row, {}, jnp.asarray(toks),
-            jnp.asarray(offset, jnp.int32), jnp.asarray(n_real, jnp.int32))
-        k_row, v_row = out[1], out[2]
+            params, pk, pv, {}, table, np.int32(0), jnp.asarray(toks),
+            np.int32(offset), np.int32(n_real))
+        pk, pv = out[1], out[2]
     return out
 
 

@@ -86,3 +86,34 @@ def test_the_routed_experts_compile_for_v5e_with_the_grouped_kernel(
     # sorted rows and three (rows * k, 4096) float32 results, not for
     # copies of weights.
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_the_chunk_program_updates_the_donated_pool_in_place(one_chip):
+    """The dense chunk program at ``coder1b-complete-closed16``'s shapes
+    (24 layers, 16 heads of 128, 6 slots of 2,049 positions in blocks of
+    16, a chunk of 256): both halves of the donated pool come back
+    aliased to their arguments, and beside them the program needs room
+    for a chunk's activations, not for a copy of a pool or of a row."""
+    from rayfed_tpu.models import decode
+    from rayfed_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(vocab=32256, d_model=2048, n_heads=16,
+                                n_layers=24, d_ff=5504, rope_theta=1e5)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    blocks_per_row = -(-2049 // 16)
+    pool = sds((24, 1 + 6 * blocks_per_row, 16, 16, 128))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    compiled = jax.jit(
+        decode.serving_model(cfg).chunk, donate_argnums=(1, 2)
+    ).lower(params, pool, pool, {}, i32(blocks_per_row), i32(), i32(256),
+            i32(), i32()).compile()
+    pool_bytes = 2 * 24 * (1 + 6 * blocks_per_row) * 16 * 16 * 128 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 400e6

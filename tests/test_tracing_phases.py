@@ -337,9 +337,24 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     assert "aggregate/sum" in aggregate._tree_sum.lower(
         (tree, tree)).as_text(debug_info=True)
     pool = PagedKVPool(CFG, max_slots=2, max_len=8, block_size=4)
-    table = jnp.zeros((pool.blocks_per_row,), jnp.int32)
-    assert "serve/gather" in pool._gather_row_fn.lower(
-        pool._k, pool._v, table).as_text(debug_info=True)
+    # The chunk program holds its own read and write of the pool: no
+    # serve/gather or serve/scatter program stands around it.
+    srv = InferenceServer(
+        CFG, ServingConfig(max_slots=2, max_len=8, max_new_tokens=2,
+                           kv_block_size=4), params=PARAMS)
+    try:
+        chunk_fn = srv._get_chunk_fn(4)
+        text = chunk_fn.lower(
+            PARAMS, *srv.pool.kv, {},
+            jnp.zeros((srv.pool.blocks_per_row,), jnp.int32), jnp.int32(0),
+            jnp.zeros((4,), jnp.int32), jnp.int32(0), jnp.int32(4),
+            jnp.zeros((3, 1), jnp.int32),
+        ).as_text(debug_info=True)
+    finally:
+        srv.stop()
+    assert "serve/chunk" in text
+    assert "serve/gather" not in text and "serve/scatter" not in text
+    assert chunk_fn.__name__ == "chunk_step"
     rows = jnp.zeros((2,), jnp.int32)
     assert "serve/decode_step" in pool._decode_step_fn.lower(
         PARAMS, pool._k, pool._v, rows, rows,
@@ -348,7 +363,7 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     ).as_text(debug_info=True)
     # A decorator, not a wrapper program: the jitted functions keep the
     # names the profile and `compiled_programs` know them by.
-    assert pool._gather_row_fn.__name__ == "gather_row"
+    assert pool._scatter_rows_fn.__name__ == "scatter_rows"
     assert pool._decode_step_fn.__name__ == "decode_step"
 
 

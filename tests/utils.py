@@ -98,3 +98,35 @@ def step_logits(pool, params, tokens, positions, tables, live=None):
     )(params, pool._k, pool._v, pool._state, jnp.asarray(tokens),
       jnp.asarray(positions), jnp.asarray(tables), live)
     return logits
+
+
+def slot_rows(pool, slot):
+    """One slot's K and V as contiguous (L, row_len, Hkv, Dh) NumPy rows,
+    read through its block table (no program of the pool builds such a
+    row)."""
+    import numpy as np
+
+    table = pool.table(slot)
+
+    def row(a):
+        a = np.asarray(a)[:, table]
+        return a.reshape(a.shape[0], -1, *a.shape[3:])[:, :pool.row_len]
+
+    return row(pool.kv[0]), row(pool.kv[1])
+
+
+def land_row(pool, slot, k_row, v_row):
+    """Write contiguous (L, T, Hkv, Dh) rows into ``slot``'s blocks (a
+    one-row ``scatter_rows``; the other rows go to the sacrificial
+    block)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    tables = np.zeros((pool.max_slots, pool.blocks_per_row), np.int32)
+    tables[slot] = pool.table(slot)
+
+    def slab(row):
+        shape = (row.shape[0], pool.max_slots, *row.shape[1:])
+        return jnp.zeros(shape, row.dtype).at[:, slot].set(row)
+
+    pool.scatter_rows(slab(k_row), slab(v_row), tables)
