@@ -440,8 +440,9 @@ class Cohere2MoeServing:
     def __init__(self, cfg: Cohere2MoeConfig):
         self.cfg = cfg
 
-    def kv_shape(self) -> Tuple[int, int, int]:
-        return self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+    def kv_spec(self):
+        head = (self.cfg.n_kv_heads, self.cfg.head_dim)
+        return self.cfg.n_layers, (head, head)
 
     def state_spec(self, cache_dtype=None):
         return {}
@@ -459,19 +460,20 @@ class Cohere2MoeServing:
 
     def prefill_rows(self, params, prompts, last_idx, row_len, cache_dtype,
                      landed):
-        return prefill_rows(
+        last, k, v, state = prefill_rows(
             params, prompts, last_idx, cache_dtype, self.cfg, landed)
+        return last, (k, v), state
 
-    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
+    def chunk(self, params, kv, state, table, slot, toks, offset, n_real):
         last, pk, pv = chunk(
-            params, pk, pv, table, toks, offset, n_real, self.cfg)
-        return last, pk, pv, state
+            params, *kv, table, toks, offset, n_real, self.cfg)
+        return last, (pk, pv), state
 
-    def decode_step(self, params, pk, pv, state, tokens, positions, tables,
+    def decode_step(self, params, kv, state, tokens, positions, tables,
                     live):
         logits, pk, pv, counters = paged_decode_step(
-            params, pk, pv, tokens, positions, tables, live, self.cfg)
-        return logits, pk, pv, state, counters
+            params, *kv, tokens, positions, tables, live, self.cfg)
+        return logits, (pk, pv), state, counters
 
 
 def serving_model(cfg: Cohere2MoeConfig) -> Cohere2MoeServing:

@@ -158,13 +158,44 @@ def forward_with_cache(
 PAGED_CHUNK_KEYS = 256
 
 
-def paged_attention(pk, pv, positions, tables, window=None):
+def to_width(x, width: int):
+    """``x`` with its last dimension zero-padded to ``width`` (a pool of
+    single vectors keeps them padded to whole tiles:
+    ``kv_pool.LANES``); as it is where it is that wide already."""
+    short = width - x.shape[-1]
+    if not short:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+
+
+def _pool_dims(pk):
+    """(L, P, block, heads, width) of a pool array: (L, P, block, Hkv,
+    Dh), or (L, P, block, width) where a token keeps one vector (a
+    latent row: one head, in effect)."""
+    if pk.ndim == 4:
+        return (*pk.shape[:3], 1, pk.shape[3])
+    return pk.shape
+
+
+def paged_attention(pk, pv, positions, tables, window=None, *,
+                    scale=None, v_width=None):
     """The read of a block-paged K/V pool through the block tables, for
     one decode token per row. Returns ``attend(q, k1, v1, base)``: ``q``
     (R, H, Dh) a layer's queries, ``k1``/``v1`` (R, Hkv, Dh) its new key
     and value, ``base`` the layer's first row of the flattened pool
     (layer * P); the result is the attention output (R, H, Dh). K/V head
     i serves query heads i*G..(i+1)*G-1 (G = H / Hkv; 1 for plain MHA).
+
+    A pool that keeps ONE array a token (``pv`` None: a latent cache,
+    ``pk`` (L, P, bs, width), :mod:`rayfed_tpu.models.pangu_ultra_moe`)
+    is read once: every query head reads the same row, and a key's value
+    is its own first ``v_width`` columns, taken from the keys a trip
+    gathered (``k1`` (R, 1, width), ``v1`` None too, and the output (R,
+    H, v_width)). The pool may keep its rows zero-padded beyond what
+    ``q`` and ``k1`` carry (to whole tiles): they are padded to match.
+    ``scale`` multiplies the scores (None: ``Dh ** -0.5`` of the keys as
+    cached; a query that was multiplied into the latent space keeps the
+    scale of the head it came from).
 
     Chunks of ``PAGED_CHUNK_KEYS`` keys are gathered through the table
     under an online softmax (float32 max, sum and accumulator), and the
@@ -190,7 +221,7 @@ def paged_attention(pk, pv, positions, tables, window=None):
     ``window=None`` the loop and the mask are exactly the lines below,
     nothing added.
     """
-    n_layers, n_phys, bs, n_kv, dh = pk.shape
+    n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
     n_rows, blocks_per_row = tables.shape
     chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
     chunk_keys = chunk_blocks * bs
@@ -208,12 +239,20 @@ def paged_attention(pk, pv, positions, tables, window=None):
         last_block = tables_p.shape[1] - 1
     # (L * P, bs, Hkv, Dh): a layer's block b is row layer * P + b, so one
     # gather reads a chunk's blocks and never the layer's whole pool.
-    pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
-    pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
-    scale = dh**-0.5
+    pk_flat = pk.reshape(n_layers * n_phys, *pk.shape[2:])
+    if pv is not None:
+        pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
+    if scale is None:
+        scale = dh**-0.5
 
     def attend(q, k1, v1, base):
         n_heads = q.shape[1]
+        if pv is None:
+            # The pool's rows may be padded to whole tiles (zeros): the
+            # query and the new key are padded to match, and score the
+            # padding as nothing.
+            v1 = k1[..., :v_width]
+            q, k1 = to_width(q, dh), to_width(k1, dh)
         q = q.reshape(n_rows, n_kv, n_heads // n_kv, dh)
         s1 = jnp.einsum(
             "rhgd,rhd->rhg", q, k1, preferred_element_type=jnp.float32
@@ -234,7 +273,10 @@ def paged_attention(pk, pv, positions, tables, window=None):
                     tables_p, jnp.minimum(at, last_block), axis=1
                 )
             kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
-            vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
+            if pv is None:
+                vc = kc[..., :v_width]
+            else:
+                vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
             if window is None:
                 k_pos = c * chunk_keys + jnp.arange(chunk_keys)
                 cached = k_pos[None, :] < positions[:, None]
@@ -257,24 +299,42 @@ def paged_attention(pk, pv, positions, tables, window=None):
             return m_new, den, acc
 
         v_first = jnp.broadcast_to(
-            v1.astype(jnp.float32)[:, :, None, :], q.shape
+            v1.astype(jnp.float32)[:, :, None, :], s1.shape + v1.shape[-1:]
         )
         init = (s1, jnp.ones_like(s1), v_first)
         _, den, acc = jax.lax.fori_loop(0, trips, chunk, init)
         out = (acc / den[..., None]).astype(v1.dtype)
-        return out.reshape(n_rows, n_heads, dh)
+        return out.reshape(n_rows, n_heads, v1.shape[-1])
 
     return attend
 
 
+def _write_rows(pool, rows, w_block, w_off):
+    """``rows`` (L, N, width) into ``(w_block[n], w_off[n])`` of every
+    layer of a pool (L, P, bs, width) that keeps one vector a token: as
+    L * N single rows of the pool with its layers flattened into its
+    blocks. (One scatter whose window spans the layers, as the K/V pair
+    is written, makes the layers a tiled dimension of this pool on a TPU:
+    (5, 576) pads to (8, 640), and the program first copies the whole
+    pool into that layout.)"""
+    n_layers, n_phys = pool.shape[:2]
+    flat = pool.reshape(n_layers * n_phys, *pool.shape[2:])
+    blocks = (jnp.arange(n_layers) * n_phys)[:, None] + w_block[None, :]
+    rows = to_width(rows, pool.shape[-1])
+    return flat.at[blocks, w_off[None, :]].set(rows).reshape(pool.shape)
+
+
 def paged_write(pk, pv, k_new, v_new, positions, tables):
     """The one write of a paged decode step: each row's new K/V (L, R,
-    Hkv, Dh) into its (block, offset) in every layer."""
+    Hkv, Dh) into its (block, offset) in every layer. A pool of one
+    array (``pv`` None) gets its one new row a token."""
     bs = pk.shape[2]
     w_block = jnp.take_along_axis(
         tables, (positions // bs)[:, None], axis=1
     )[:, 0]
     w_off = positions % bs
+    if pv is None:
+        return _write_rows(pk, k_new, w_block, w_off), None
     return (pk.at[:, w_block, w_off].set(k_new),
             pv.at[:, w_block, w_off].set(v_new))
 
@@ -322,8 +382,17 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
     a sliding-window layer: the query at ``p`` attends ``p - window + 1
     .. p``, ``lo`` is the first key the chunk's first query sees, rounded
     down to a trip, and keys before a query's window are masked.
+
+    A pool that keeps one latent array a token (``pv`` None, ``pk`` (L,
+    P, bs, width)) is read in the expanded form: ``attend`` takes a fifth
+    argument, ``expand``,
+    the layer's map from a trip's cached rows (trip keys, 1, width as
+    allocated: the model's own width and zero padding) to
+    the keys and values the queries attend ((trip keys, Hk, Dh), (trip
+    keys, Hk, Dv)); heads, head sizes and the scores' scale are then
+    those of ``q``, ``k`` and ``v`` as handed over, not the pool's.
     """
-    n_layers, n_phys, bs, n_kv, dh = pk.shape
+    n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
     (blocks_per_row,) = table.shape
     trip_blocks = max(1, min(blocks_per_row, CHUNK_TRIP_KEYS // bs))
     trip_keys = trip_blocks * bs
@@ -332,13 +401,15 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
     if window is not None:
         first = jnp.maximum(offset - window + 1, 0) // trip_keys
     last = (offset + trip_keys - 1) // trip_keys
-    pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
-    pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
-    scale = dh**-0.5
+    pk_flat = pk.reshape(n_layers * n_phys, *pk.shape[2:])
+    if pv is not None:
+        pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
 
-    def attend(q, k, v, base):
-        c, n_heads, _ = q.shape
-        q = q.reshape(c, n_kv, n_heads // n_kv, dh)
+    def attend(q, k, v, base, expand=None):
+        c, n_heads, d_qk = q.shape
+        n_k = k.shape[1]
+        scale = d_qk**-0.5
+        q = q.reshape(c, n_k, n_heads // n_k, d_qk)
         idx = jnp.arange(c)
         q_pos = offset + idx
 
@@ -365,7 +436,10 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
             blocks = base + jax.lax.dynamic_slice_in_dim(
                 table_p, t * trip_blocks, trip_blocks)
             kc = pk_flat[blocks].reshape(trip_keys, n_kv, dh)
-            vc = pv_flat[blocks].reshape(trip_keys, n_kv, dh)
+            if pv is None:
+                kc, vc = expand(kc)
+            else:
+                vc = pv_flat[blocks].reshape(trip_keys, n_kv, dh)
             k_pos = t * trip_keys + jnp.arange(trip_keys)
             cached = (k_pos < offset)[None, :]
             if window is not None:
@@ -383,7 +457,7 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
 
         _, den, acc = jax.lax.fori_loop(first, last, trip, init)
         out = (acc / den[..., None]).astype(v.dtype)
-        return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, dh)
+        return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, v.shape[-1])
 
     return attend
 
@@ -398,6 +472,8 @@ def paged_chunk_write(pk, pv, k_new, v_new, table, offset):
     pos = offset + jnp.arange(k_new.shape[1])
     w_block = table[pos // bs]
     w_off = pos % bs
+    if pv is None:
+        return _write_rows(pk, k_new, w_block, w_off), None
     return (pk.at[:, w_block, w_off].set(k_new),
             pv.at[:, w_block, w_off].set(v_new))
 
@@ -447,14 +523,30 @@ class TransformerServing:
 
     The engine (:mod:`rayfed_tpu.serving.server`) and its pool resolve a
     model's config to such an object through ``serving_model(cfg)`` of
-    the module that defines the config's class. It gives the pool its
-    shapes (``kv_shape``, ``state_spec``) and the engine its programs:
+    the module that defines the config's class. It tells the pool what
+    to hold and gives the engine its programs.
+
+    ``kv_spec()`` declares what a token keeps in the paged pool: the
+    number of layers and, per layer, the shapes of the arrays a token has
+    a row in. This model (and the hybrid and the expert model) declares
+    a key and a value array, ``((H, Dh), (H, Dh))``; a model with latent
+    attention declares one vector, ``((width,),)``
+    (:mod:`rayfed_tpu.models.pangu_ultra_moe`). The pool allocates one
+    ``(L, 1 + blocks, block, *shape)`` array per entry (a lone vector
+    padded to whole tiles: ``kv_pool.LANES``) and hands the
+    programs the tuple of them, ``kv``, donated; they hand it back in the
+    same order. Nothing else is assumed of it: how a token's row is read
+    is the model's.
+
     ``prefill_rows`` (a round of right-padded short prompts, one row
-    each), ``chunk`` (one chunk of a long prompt: its context read from
-    the pool through the slot's block table, its own K/V written there
-    in place) and ``decode_step`` (one token for every row, K/V read
-    through the block tables). ``state_spec`` is what a slot holds beside its
-    K/V; a model that has none (this one) returns ``{}``, takes and
+    each) returns the logits at each row's last position, the rows of
+    the cache ``(L, R, S, *shape)`` per declared array, and the rows'
+    state; ``chunk`` (one chunk of a long prompt: its context read from
+    the pool through the slot's block table, its own rows written there
+    in place) and ``decode_step`` (one token for every row, the cache
+    read through the block tables) take and return ``kv``.
+    ``state_spec`` is what a slot holds beside its paged rows; a model
+    that has none (this one) returns ``{}``, takes and
     returns ``{}`` wherever a program hands state on, and ignores
     ``live``, ``n_real`` and what else exists for the sake of a state.
     ``serving_dtype`` is the dtype in which the programs read the
@@ -467,18 +559,20 @@ class TransformerServing:
     ``layer_windows()`` (per layer the keys a token attends, or None for
     every key: the engine counts the blocks each layer must read) and
     ``step_counters`` (names of int32 counts only the device knows:
-    ``decode_step`` then returns them as a fifth value and they ride home
-    behind the ids). ``prefill_rows`` may hand back K/V rows shorter than
-    ``row_len`` (as long as its bucket): the pool lands rows of the
+    ``decode_step`` then returns them as a fourth value and they ride
+    home behind the ids). ``prefill_rows`` may hand back rows shorter
+    than ``row_len`` (as long as its bucket): the pool lands rows of the
     length they come in.
     """
 
     def __init__(self, cfg: tfm.TransformerConfig):
         self.cfg = cfg
 
-    def kv_shape(self):
-        """(layers, K/V heads, head size) of the cache."""
-        return self.cfg.n_layers, self.cfg.n_heads, self.cfg.head_dim
+    def kv_spec(self):
+        """(layers, the per-token shapes of the arrays the pool holds):
+        a key and a value row of (heads, head size)."""
+        head = (self.cfg.n_heads, self.cfg.head_dim)
+        return self.cfg.n_layers, (head, head)
 
     def state_spec(self, cache_dtype=None):
         return {}
@@ -494,8 +588,8 @@ class TransformerServing:
                      landed):
         """Prompts (R, S) from fresh zero rows: bit-identical logits to
         recycled ones (masked positions cannot contribute). Returns the
-        logits at ``last_idx``, the K/V rows (L, R, row_len, H, Dh) and
-        the rows' state. ``landed`` (R,) bool names the rows that are
+        logits at ``last_idx``, the K and V rows (L, R, row_len, H, Dh)
+        and the rows' state. ``landed`` (R,) bool names the rows that are
         requests: only those are read or land anywhere, so what comes
         back for the others is unspecified. Here every lane is computed
         (ROADMAP S2b replaces this with the landed-rows loop)."""
@@ -512,18 +606,21 @@ class TransformerServing:
             return last, cache["k"][:, 0], cache["v"][:, 0]
 
         rows = jax.vmap(one_row, in_axes=(0, 0, None), out_axes=(0, 1, 1))
-        return (*rows(prompts, last_idx, params), {})
+        last, k, v = rows(prompts, last_idx, params)
+        return last, (k, v), {}
 
-    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
+    def chunk(self, params, kv, state, table, slot, toks, offset, n_real):
         """One chunk ``toks`` (C,) of a long prompt, at positions ``offset
         .. offset + C - 1`` of the slot whose block table is ``table``:
         the first ``n_real`` are the prompt's, the rest padding. The
         chunk's context is read from the donated pool through the table
         (:func:`paged_chunk_attention`) and its own K/V written there in
         place, once, after the last layer (:func:`paged_chunk_write`).
-        Returns the logits of the last real position (vocab,), the pool
-        and the state (``slot`` names its row of a state; none here)."""
+        Returns the logits of the last real position (vocab,), the pool's
+        arrays and the state (``slot`` names its row of a state; none
+        here)."""
         cfg = self.cfg
+        pk, pv = kv
         n_phys = pk.shape[1]
         attend = paged_chunk_attention(pk, pv, table, offset, n_real)
         positions = (offset + jnp.arange(toks.shape[0]))[None]
@@ -543,15 +640,15 @@ class TransformerServing:
         last = jax.lax.dynamic_index_in_dim(
             x[0], n_real - 1, axis=0, keepdims=False
         )
-        pk, pv = paged_chunk_write(pk, pv, k_new, v_new, table, offset)
-        return _head(last, params, cfg), pk, pv, state
+        kv = paged_chunk_write(pk, pv, k_new, v_new, table, offset)
+        return _head(last, params, cfg), kv, state
 
-    def decode_step(self, params, pk, pv, state, tokens, positions, tables,
+    def decode_step(self, params, kv, state, tokens, positions, tables,
                     live):
-        logits, pk, pv = paged_decode_step(
-            params, pk, pv, tokens, positions, tables, self.cfg
+        logits, *kv = paged_decode_step(
+            params, *kv, tokens, positions, tables, self.cfg
         )
-        return logits, pk, pv, state
+        return logits, tuple(kv), state
 
 
 def serving_model(cfg):

@@ -620,8 +620,9 @@ class FalconH1Serving:
     def __init__(self, cfg: FalconH1Config):
         self.cfg = cfg
 
-    def kv_shape(self) -> Tuple[int, int, int]:
-        return self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim
+    def kv_spec(self):
+        head = (self.cfg.n_kv_heads, self.cfg.head_dim)
+        return self.cfg.n_layers, (head, head)
 
     def state_spec(self, cache_dtype=None):
         """Per layer and slot, beside the paged K/V: name -> (shape,
@@ -641,20 +642,23 @@ class FalconH1Serving:
 
     def prefill_rows(self, params, prompts, last_idx, row_len, cache_dtype,
                      landed):
-        return prefill_rows(
+        last, k, v, state = prefill_rows(
             params, prompts, last_idx, row_len, cache_dtype, self.cfg,
             landed)
+        return last, (k, v), state
 
-    def chunk(self, params, pk, pv, state, table, slot, toks, offset, n_real):
-        return chunk(
-            params, pk, pv, state, table, slot, toks, offset, n_real,
+    def chunk(self, params, kv, state, table, slot, toks, offset, n_real):
+        last, pk, pv, state = chunk(
+            params, *kv, state, table, slot, toks, offset, n_real,
             self.cfg)
+        return last, (pk, pv), state
 
-    def decode_step(self, params, pk, pv, state, tokens, positions, tables,
+    def decode_step(self, params, kv, state, tokens, positions, tables,
                     live):
-        return paged_decode_step(
-            params, pk, pv, state, tokens, positions, tables, live,
+        logits, pk, pv, state = paged_decode_step(
+            params, *kv, state, tokens, positions, tables, live,
             self.cfg)
+        return logits, (pk, pv), state
 
 
 def serving_model(cfg: FalconH1Config) -> FalconH1Serving:

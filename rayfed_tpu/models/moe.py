@@ -26,9 +26,11 @@ expert's capacity are dropped). These exist for the training-side tests
 and have never been measured on a chip.
 
 **The grouped path** (:func:`route_sigmoid_topk`, :func:`routed_experts`,
-:func:`shared_experts`; what :mod:`rayfed_tpu.models.cohere2_moe` serves
-through ``fed.serve``): sigmoid scores over ALL experts, the ``k`` largest
-normalised over those ``k``, gated SiLU experts, and a layer that is told
+:func:`shared_experts`; what :mod:`rayfed_tpu.models.cohere2_moe` and
+:mod:`rayfed_tpu.models.pangu_ultra_moe` serve through ``fed.serve``):
+sigmoid scores over ALL experts, the ``k`` largest normalised over those
+``k`` (and scaled by the model's factor, where it has one), gated SiLU
+experts, and a layer that is told
 which experts it *holds* (one chip's share of a layer divided over
 chips): it computes the part of the result its own experts give. Tokens
 are grouped by expert, so operations and the expert weights read follow
@@ -286,6 +288,20 @@ def route_sigmoid_topk(h, router, k: int):
 # 0.71; reading the experts' matrices alone takes 0.66 and 0.45.
 GROUPED_ROW_TILE = 128
 GROUPED_OUT_TILE = 512
+# ... up to this width: a (contraction x 512) tile of an expert's matrix
+# is double-buffered in the kernel's fast memory, and at 7680 (4096 was
+# the widest until PR 33) two of them no longer fit. A wider contraction
+# is cut into the fewest equal tiles that are multiples of 128.
+GROUPED_CONTRACT_TILE = 4096
+
+
+def _contract_tile(k: int) -> int:
+    if k <= GROUPED_CONTRACT_TILE:
+        return k
+    for parts in range(-(-k // GROUPED_CONTRACT_TILE), k // 128 + 1):
+        if k % (parts * 128) == 0:
+            return k // parts
+    return k
 
 
 def grouped_matmul(x, w, group_sizes):
@@ -303,13 +319,13 @@ def grouped_matmul(x, w, group_sizes):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         return gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
-                   tiling=(tm, x.shape[1], tn))
+                   tiling=(tm, _contract_tile(x.shape[1]), tn))
     return lax.ragged_dot(x, w, group_sizes,
                           preferred_element_type=jnp.float32)
 
 
 def routed_experts(h, layer: Params, held: Sequence[int], k: int,
-                   live=None):
+                   live=None, scale: float = 1.0):
     """The part of a routed-expert layer that the experts ``held`` give.
 
     ``h`` (T, d) in the compute dtype; ``layer`` holds ``router`` (d, E)
@@ -317,9 +333,11 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
     (Eh, f, d), the weights of the ``Eh = len(held)`` experts held here,
     in the order of ``held`` (global expert ids, static). Expert ``e``
     computes ``(silu(h Wg_e) * (h Wu_e)) Wd_e``. Each token's ``k``
-    weights are normalised over all ``k`` chosen experts, held or not;
-    what an absent expert would add is left out (it lies on another
-    chip). ``live`` (T,) bool names the rows that count (None: all):
+    weights are normalised over all ``k`` chosen experts, held or not,
+    and then multiplied by ``scale`` (a model's ``routed_scaling_factor``;
+    1 where it has none); what an absent expert would add is left out (it
+    lies on another chip). ``live`` (T,) bool names the rows that count
+    (None: all):
     padding and junk rows are routed nowhere and touch no expert.
 
     The (token, expert) assignments are sorted by expert, those on held
@@ -360,6 +378,8 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
         # Back to (token, choice) order; an assignment that lies
         # elsewhere adds nothing.
         mine = (jnp.arange(m) < jnp.sum(counts))[:, None]
+        if scale != 1.0:
+            w = w * scale
         weighted = jnp.where(mine, ys * w.reshape(m)[order][:, None], 0.0)
         y = weighted[jnp.argsort(order)].reshape(t, k, d).sum(1)
         return (y, jnp.sum(counts > 0, dtype=jnp.int32),

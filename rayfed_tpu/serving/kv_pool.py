@@ -12,13 +12,19 @@
 # See the License for the specific language governing permissions and
 # limitations under the License.
 
-"""The block-paged K/V cache of the serving plane.
+"""The block-paged cache of the serving plane.
 
 :class:`PagedKVPool` — PagedAttention-shaped block granularity (Kwon et
 al. 2023) over the stacked-cache layout of
-:mod:`rayfed_tpu.models.decode`: the physical cache is ONE
-(L, 1 + num_blocks, block_size, H, Dh) pair allocated at server start,
-and each of ``max_slots`` rows (a *slot*, borrowed by one request for
+:mod:`rayfed_tpu.models.decode`: the physical cache is what the served
+model declares a token keeps (``kv_spec()`` of its serving protocol):
+one (L, 1 + num_blocks, block_size, *shape) array per declared per-token
+shape, allocated at server start. A model with keys and values declares
+two arrays of (H, Dh), and "K/V" below means that pair; a model with
+latent attention declares ONE array of (width,) and nothing else is
+held for it (no second copy, no per-head K/V; ``LANES`` below for how
+it is allocated). Each of ``max_slots`` rows (a *slot*, borrowed by one
+request for
 its lifetime) holds an int32 *block table* mapping logical block i of
 its sequence to a physical block. Blocks are granted on demand at token
 boundaries and returned to a free list at release — a short generation
@@ -35,7 +41,7 @@ through its block table, a chunk of blocks at a time under an online
 softmax, writes the new token's K/V straight into its
 (block, offset) and ends in the choice of each row's next token
 (:mod:`rayfed_tpu.serving.sampling`): ids come back, not logits; the
-pool pair is donated and is the only K/V buffer —
+pool's arrays are donated and are the only cache buffers —
 no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
 blocks read follow the longest live row, not ``max_len``. It agrees with
 the plain cached forward (:func:`rayfed_tpu.models.decode.
@@ -91,24 +97,51 @@ import jax.numpy as jnp
 import numpy as np
 
 from rayfed_tpu.models import decode
-from rayfed_tpu.models import transformer as tfm
 from rayfed_tpu.serving import sampling
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _copy_block(pk, pv, src, dst):
-    """Copy physical block ``src`` over block ``dst`` (prefix-reuse
-    boundary clone)."""
-    kb = jax.lax.dynamic_slice_in_dim(pk, src, 1, axis=1)
-    vb = jax.lax.dynamic_slice_in_dim(pv, src, 1, axis=1)
-    pk = jax.lax.dynamic_update_slice_in_dim(pk, kb, dst, axis=1)
-    pv = jax.lax.dynamic_update_slice_in_dim(pv, vb, dst, axis=1)
-    return pk, pv
+@partial(jax.jit, donate_argnums=(0,))
+def _copy_block(kv, src, dst):
+    """Copy physical block ``src`` over block ``dst`` in every array of
+    the pool (prefix-reuse boundary clone)."""
+    blocks = [jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1) for a in kv]
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(a, b, dst, axis=1)
+        for a, b in zip(kv, blocks)
+    )
+
+
+# A TPU lays an array out in tiles whose minor dimension is this many
+# values. A pool array whose rows are single vectors that are not whole
+# tiles (a 576-wide latent row) would get, by the device's own default,
+# ANOTHER dimension as its minor one, so that nothing is padded: for a
+# pool that is its blocks, and every program that gathers blocks or
+# writes rows would first copy the whole pool into an order it can work
+# in (compiled for a v5e: two copies of 3.1 GB a decode step). Such an
+# array is allocated with its rows padded to whole tiles (640): what the
+# device would hold for a row-major array anyway. The padding is zero
+# and stays zero; ``decode.paged_attention`` / ``paged_chunk_attention``
+# and the writes pad what they are handed to the pool's width. (Pinning
+# the unpadded array to a row-major layout through ``jax.jit``'s
+# ``Format`` arguments did the same on a cold process, but a process that
+# read its programs from the persistent compile cache got the pool back
+# in the default order: PR 33, PERF.md section 6.)
+LANES = 128
+
+
+def _allocated(shape: tuple) -> tuple:
+    """The per-token shape as the pool allocates it."""
+    if len(shape) == 1 and shape[0] % LANES:
+        return (-(-shape[0] // LANES) * LANES,)
+    return tuple(shape)
 
 
 class PagedKVPool:
-    """Block-granular K/V pool: ``max_slots`` logical rows over
-    ``num_blocks`` shared physical blocks (+ the sacrificial block 0).
+    """Block-granular pool of what the model's ``kv_spec()`` declares
+    a token keeps (K and V rows for most models, one latent row for
+    some): ``max_slots`` logical rows over ``num_blocks`` shared physical
+    blocks (+ the sacrificial block 0). ``cfg`` is any config whose
+    module has a ``serving_model``.
 
     Block tables live on the host as plain int32 numpy (they change a
     few entries per iteration; shipping them into jitted programs as
@@ -127,7 +160,7 @@ class PagedKVPool:
 
     def __init__(
         self,
-        cfg: tfm.TransformerConfig,
+        cfg,
         max_slots: int,
         max_len: int,
         dtype=None,
@@ -157,16 +190,25 @@ class PagedKVPool:
         if self.num_blocks < 1:
             raise ValueError("kv_blocks must be >= 1")
         self.model = decode.serving_model(cfg)
-        n_layers, n_kv_heads, head_dim = self.model.kv_shape()
+        n_layers, token_shapes = self.model.kv_spec()
         dtype = dtype or cfg.compute_dtype
-        kv_shape = (
-            n_layers, 1 + self.num_blocks, self.block_size, n_kv_heads,
-            head_dim,
+        # One array per shape the model declares a token keeps.
+        self._kv = tuple(
+            jnp.zeros(
+                (n_layers, 1 + self.num_blocks, self.block_size,
+                 *_allocated(shape)),
+                dtype,
+            )
+            for shape in token_shapes
         )
-        self._k = jnp.zeros(kv_shape, dtype)
-        self._v = jnp.zeros(kv_shape, dtype)
-        # What a slot holds beside its K/V (a recurrent state): one
-        # (L, max_slots, ...) array per entry of the model's spec.
+        # Bytes a token keeps in the pool, all layers and arrays, as the
+        # model declares them: a fact of the model and the cache dtype
+        # (``stats()["kv_token_bytes"]``; ``nbytes`` is what is allocated).
+        self.token_bytes = n_layers * jnp.dtype(dtype).itemsize * sum(
+            int(np.prod(shape)) for shape in token_shapes
+        )
+        # What a slot holds beside its paged rows (a recurrent state):
+        # one (L, max_slots, ...) array per entry of the model's spec.
         self._state = {
             name: jnp.zeros((n_layers, max_slots, *shape), sdtype)
             for name, (shape, sdtype) in self.model.state_spec(dtype).items()
@@ -221,47 +263,49 @@ class PagedKVPool:
         # that declares ``step_counters`` returns them as a fifth value,
         # and they follow the ids in the one int32 array.
         @jax.named_scope("serve/decode_step")
-        def decode_step(params, pk, pv, tokens, positions, tables, draw,
+        def decode_step(params, kv, tokens, positions, tables, draw,
                         state=None, live=None):
-            logits, pk, pv, state, *counted = model.decode_step(
-                params, pk, pv, state or {}, tokens, positions, tables, live
+            logits, kv, state, *counted = model.decode_step(
+                params, kv, state or {}, tokens, positions, tables, live
             )
             ids = sampling.choose_packed(logits, draw)
             if counted:
                 ids = jnp.concatenate([ids, counted[0].astype(ids.dtype)])
-            return ids, pk, pv, state
+            return ids, kv, state
 
         self._decode_step_fn = jax.jit(
-            decode_step, donate_argnums=(1, 2, 7)
+            decode_step, donate_argnums=(1, 6)
         )
 
         @jax.named_scope("serve/scatter")
-        def scatter_rows(pk, pv, k_slab, v_slab, tables, state=None,
-                         new_state=None, landed=None):
+        def scatter_rows(kv, slabs, tables, state=None, new_state=None,
+                         landed=None):
             # Write whole (R, T)-shaped prefill output back through the
-            # scatter tables. Rows that must not land (junk vmap lanes,
-            # already-live neighbours) carry an all-zero table and a
-            # false `landed`. A model may hand back rows shorter than T
-            # (as long as its bucket): they land in the first blocks of
-            # the tables and the rest of a row is left as it was.
-            L = pk.shape[0]
-            H, Dh = pk.shape[-2:]
-            nb = -(-k_slab.shape[2] // bs)
-            pad = nb * bs - k_slab.shape[2]
-            if pad:
-                z = jnp.zeros((L, R, pad, H, Dh), k_slab.dtype)
-                k_slab = jnp.concatenate([k_slab, z], axis=2)
-                v_slab = jnp.concatenate([v_slab, z], axis=2)
-            kp = k_slab.reshape(L, R, nb, bs, H, Dh)
-            vp = v_slab.reshape(L, R, nb, bs, H, Dh)
+            # scatter tables, one slab per array of the pool. Rows that
+            # must not land (junk vmap lanes, already-live neighbours)
+            # carry an all-zero table and a false `landed`. A model may
+            # hand back rows shorter than T (as long as its bucket): they
+            # land in the first blocks of the tables and the rest of a
+            # row is left as it was.
+            nb = -(-slabs[0].shape[2] // bs)
+            pad = nb * bs - slabs[0].shape[2]
             if nb != NB:
                 tables = tables[:, :nb]
-            pk = pk.at[:, tables].set(kp)
-            pv = pv.at[:, tables].set(vp)
-            return pk, pv, landed_in(state or {}, new_state, landed)
+
+            def land(pool, slab):
+                L = pool.shape[0]
+                slab = decode.to_width(slab, pool.shape[-1])
+                if pad:
+                    z = jnp.zeros((L, R, pad, *pool.shape[3:]), slab.dtype)
+                    slab = jnp.concatenate([slab, z], axis=2)
+                return pool.at[:, tables].set(
+                    slab.reshape(L, R, nb, bs, *pool.shape[3:]))
+
+            kv = tuple(land(pool, slab) for pool, slab in zip(kv, slabs))
+            return kv, landed_in(state or {}, new_state, landed)
 
         self._scatter_rows_fn = jax.jit(
-            scatter_rows, donate_argnums=(0, 1, 5)
+            scatter_rows, donate_argnums=(0, 3)
         )
 
     def decode_step(self, params, tokens, positions, tables, draw,
@@ -282,8 +326,8 @@ class PagedKVPool:
         around each cost the engine thread 0.15 ms of dispatch apiece
         on the chip's host (``PERF.md`` §6, PR 30)."""
         wants_live = self._state or self.step_counters
-        ids, self._k, self._v, self._state = self._decode_step_fn(
-            params, self._k, self._v, tokens, positions, tables, draw,
+        ids, self._kv, self._state = self._decode_step_fn(
+            params, self._kv, tokens, positions, tables, draw,
             self._state, np.asarray(live, bool) if wants_live else None,
         )
         return ids
@@ -302,24 +346,30 @@ class PagedKVPool:
         program chose at the chunk's last real position, on the device."""
         with self._lock:
             table = self._tables[slot].copy()
-        chosen, self._k, self._v, self._state = fn(
-            params, self._k, self._v, self._state, table, np.int32(slot),
+        chosen, self._kv, self._state = fn(
+            params, self._kv, self._state, table, np.int32(slot),
             toks, np.int32(offset), np.int32(n_real), draw,
         )
         return chosen
 
-    def scatter_rows(self, k_slab, v_slab, tables: np.ndarray,
-                     state_rows=None, landed=None) -> None:
-        """Land a round of prefilled rows: K/V through ``tables``, each
-        row's fresh recurrent state where ``landed`` (R,) bool says."""
-        self._k, self._v, self._state = self._scatter_rows_fn(
-            self._k, self._v, k_slab, v_slab, tables,
+    def scatter_rows(self, *args) -> None:
+        """``scatter_rows(*slabs, tables, state_rows=None, landed=None)``:
+        land a round of prefilled rows, one slab (L, R, S, *shape) per
+        array of the pool in the order of ``kv_spec()`` (``k_slab,
+        v_slab`` for a model with keys and values), through ``tables``;
+        each row's fresh recurrent state where ``landed`` (R,) bool
+        says."""
+        n = len(self._kv)
+        tables, state_rows, landed = (*args[n:], None, None)[:3]
+        self._kv, self._state = self._scatter_rows_fn(
+            self._kv, tuple(args[:n]), tables,
             self._state, state_rows or {}, self._of_state(landed, bool),
         )
 
     @property
     def kv(self):
-        return self._k, self._v
+        """The pool's arrays, in the order the model declares them."""
+        return self._kv
 
     @property
     def state(self):
@@ -328,7 +378,7 @@ class PagedKVPool:
 
     @property
     def nbytes(self) -> int:
-        return int(self._k.nbytes) + int(self._v.nbytes) + sum(
+        return sum(int(a.nbytes) for a in self._kv) + sum(
             int(a.nbytes) for a in self._state.values()
         )
 
@@ -468,10 +518,7 @@ class PagedKVPool:
         with self._lock:
             src_blk = int(self._tables[donor, full])
             dst_blk = int(self._tables[dst, full])
-        self._k, self._v = _copy_block(
-            self._k,
-            self._v,
-            np.int32(src_blk),
-            np.int32(dst_blk),
+        self._kv = _copy_block(
+            self._kv, np.int32(src_blk), np.int32(dst_blk)
         )
         return "ok"

@@ -25,12 +25,12 @@ at the next iteration. Both jitted programs are shaped by the pool, so
 the engine compiles a handful of programs at startup cost and never
 again, regardless of request mix.
 
-The K/V cache is a pool of blocks
-(:class:`~rayfed_tpu.serving.kv_pool.PagedKVPool`). A decode iteration
-is ONE program (:func:`rayfed_tpu.models.decode.paged_decode_step`),
-batched over rows, that reads each row's K/V through its block table —
-as many blocks as the longest live row holds — and writes the new
-token's K/V in place: the pool is the only K/V buffer. Admission batches
+The cache (K/V, or whatever the model's ``kv_spec()`` declares) is a
+pool of blocks (:class:`~rayfed_tpu.serving.kv_pool.PagedKVPool`). A
+decode iteration is ONE program (the model's ``decode_step``), batched
+over rows, that reads each row's cache through its block table — as
+many blocks as the longest live row holds — and writes the new token's
+rows in place: the pool is the only cache buffer. Admission batches
 a whole round of short-prompt prefills into ONE dispatch, splits prompts
 longer than ``serving.prefill_chunk`` into fixed-size chunks merged into
 the running decode iteration under a ``prefill_token_budget`` per step
@@ -256,6 +256,11 @@ class InferenceServer:
             # a contiguous row of the slot would carry each way.
             "chunk_blocks_read": 0,
             "chunk_blocks_row": 0,
+            # Decode: the keys the live rows' steps scored, each row's
+            # own among them, summed over layers and steps (a windowed
+            # layer's at most its window): times the bytes a token keeps
+            # in one layer, what a step had to read of the cache.
+            "decode_keys_attended": 0,
         }
         # What the model's decode step counts on the device (it declares
         # the names; none for most models): fetched behind the ids.
@@ -263,7 +268,7 @@ class InferenceServer:
         # The windows of the layers that have one (optional in the
         # protocol: a model without ``layer_windows`` attends every key
         # on every layer).
-        self._n_layers = self.model.kv_shape()[0]
+        self._n_layers = self.model.kv_spec()[0]
         self._windows = tuple(
             w for w in getattr(self.model, "layer_windows", tuple)()
             if w is not None
@@ -343,6 +348,18 @@ class InferenceServer:
             "decode steps.",
             labels=("server",),
         ).labels(server=name)
+        self._m_decode_keys = _reg.counter(
+            "fed_serving_decode_keys_attended_total",
+            "Keys scored by live rows in paged decode steps, summed over "
+            "layers.",
+            labels=("server",),
+        ).labels(server=name)
+        _reg.gauge(
+            "fed_serving_kv_token_bytes",
+            "Bytes one token keeps in the paged pool, all layers and "
+            "arrays the model declares.",
+            labels=("server",),
+        ).labels(server=name).set(self.pool.token_bytes)
         self._m_state_bytes = _reg.counter(
             "fed_serving_ssm_state_bytes_total",
             "Recurrent-state bytes read and written by live rows, summed "
@@ -432,8 +449,8 @@ class InferenceServer:
         reads what comes back for them, so a model need not compute
         them). Fresh zero rows (and a zero recurrent state), not recycled
         ones. Returns (each row's first token (R,) int32, chosen from the
-        logits at ``last_idx`` under ``draw``, K/V rows, the rows'
-        state)."""
+        logits at ``last_idx`` under ``draw``, the rows of the cache (one
+        array per array of the pool), the rows' state)."""
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
@@ -445,10 +462,10 @@ class InferenceServer:
 
         @jax.named_scope("serve/prefill")
         def prefill_rows(params, prompts, last_idx, landed, draw):
-            last, *rows = model.prefill_rows(
+            last, rows, state = model.prefill_rows(
                 params, prompts, last_idx, row_len, dtype, landed
             )
-            return (sampling.choose_packed(last, draw), *rows)
+            return sampling.choose_packed(last, draw), rows, state
 
         fn = jax.jit(prefill_rows)
         self._prefill_fns[bucket] = fn
@@ -475,14 +492,14 @@ class InferenceServer:
         model = self.model
 
         @jax.named_scope("serve/chunk")
-        def chunk_step(params, pk, pv, state, table, slot, toks, offset,
+        def chunk_step(params, kv, state, table, slot, toks, offset,
                        n_real, draw):
-            last, pk, pv, state = model.chunk(
-                params, pk, pv, state, table, slot, toks, offset, n_real
+            last, kv, state = model.chunk(
+                params, kv, state, table, slot, toks, offset, n_real
             )
-            return sampling.choose_packed(last[None], draw)[0], pk, pv, state
+            return sampling.choose_packed(last[None], draw)[0], kv, state
 
-        fn = jax.jit(chunk_step, donate_argnums=(1, 2, 3))
+        fn = jax.jit(chunk_step, donate_argnums=(1, 2))
         self._chunk_fns[clen] = fn
         return fn
 
@@ -647,6 +664,7 @@ class InferenceServer:
         out["kv_blocks_in_use"] = self.pool.blocks_in_use
         out["kv_blocks_free"] = self.pool.blocks_free
         out["kv_block_size"] = self.pool.block_size
+        out["kv_token_bytes"] = self.pool.token_bytes
         # Compiled variants across the engine's jitted programs: flat
         # after warm-up, or something (a new bucket, a published tree
         # with another sharding) is compiling inside the serving window.
@@ -1008,16 +1026,14 @@ class InferenceServer:
                     tables[req.slot] = self.pool.table(req.slot)
                     landed[req.slot] = True
                 fn = self._get_prefill_rows_fn(bucket)
-                ids, k_slab, v_slab, state_rows = fn(
+                ids, slabs, state_rows = fn(
                     params, prompts, last_idx, landed,
                     self._draw_inputs(reqs),
                 )
                 # Each landed row's recurrent state is the fresh one its
                 # prefill computed from zero: this is where a recycled
                 # slot's old state ends.
-                self.pool.scatter_rows(
-                    k_slab, v_slab, tables, state_rows, landed
-                )
+                self.pool.scatter_rows(*slabs, tables, state_rows, landed)
                 self._count_state_resets(len(reqs))
                 for req in reqs:
                     self._count_prefill(0, int(req.prompt.size))
@@ -1211,6 +1227,17 @@ class InferenceServer:
             for window in self._windows for req in live
         )
 
+    def _layer_keys(self, live) -> int:
+        """Keys the layers of the ``live`` rows score in a decode step,
+        summed over rows and layers: a row at position ``pos`` sees its
+        ``pos`` cached keys and its own, at most ``window`` of them on a
+        windowed layer."""
+        seen = sum(req.pos + 1 for req in live)
+        return (self._n_layers - len(self._windows)) * seen + sum(
+            min(req.pos + 1, window)
+            for window in self._windows for req in live
+        )
+
     def _count_state_resets(self, n: int) -> None:
         if self._recurrent:
             with self._lock:
@@ -1324,12 +1351,15 @@ class InferenceServer:
                 attended = sum(req.pos // bs + 1 for req in live)
                 slab = self.pool.max_slots * self.pool.blocks_per_row
                 by_layer = self._layer_blocks(live, attended)
+                keys = self._layer_keys(live)
                 self._stats["kv_blocks_attended"] += attended
                 self._stats["kv_blocks_slab"] += slab
                 self._stats["kv_layer_blocks_attended"] += by_layer
+                self._stats["decode_keys_attended"] += keys
                 self._m_kv_attended.inc(attended)
                 self._m_kv_slab.inc(slab)
                 self._m_kv_layer_attended.inc(by_layer)
+                self._m_decode_keys.inc(keys)
                 if any(req.temperature > 0.0 for req in live):
                     self._stats["draw_steps"] += 1
                     self._m_draw_steps.inc()

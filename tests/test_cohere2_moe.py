@@ -44,10 +44,9 @@ from rayfed_tpu.models import cohere2_moe as cm
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import moe
 from rayfed_tpu.models import transformer as tfm
-from rayfed_tpu.serving import sampling
 from rayfed_tpu.serving.kv_pool import PagedKVPool
 from rayfed_tpu.serving.server import InferenceServer
-from tests.utils import slot_rows
+from tests.utils import record_logits, slot_rows
 
 ref = importlib.import_module("chipbench.references.cohere2_moe")
 
@@ -94,29 +93,6 @@ def _server(cfg=CFG, params=PARAMS, **kw):
     base.update(kw)
     return InferenceServer(cfg, ServingConfig(**base), params=params,
                            cache_dtype=cfg.compute_dtype)
-
-
-def _record_logits(monkeypatch):
-    """Every logits row the engine's programs choose a token from, by the
-    request's seed (greedy: the seed only marks its rows) and the token's
-    position in the output. The sampler is looked up when a program is
-    traced, so engines built after this call record; a chunk that is not
-    a prompt's last also reaches the sampler at position 0, and the last
-    one, which comes last, is the one kept."""
-    seen = {}
-    choose = sampling.choose_tokens
-
-    def record(logits, seeds, index):
-        for row in np.flatnonzero(seeds):
-            seen.setdefault(int(seeds[row]), {})[int(index[row])] = np.array(
-                logits[row])
-
-    def spy(logits, temperature, seeds, index):
-        jax.debug.callback(record, logits, seeds, index, ordered=True)
-        return choose(logits, temperature, seeds, index)
-
-    monkeypatch.setattr(sampling, "choose_tokens", spy)
-    return seen
 
 
 def _served_against_reference(seen, seed, prompt, out, n_new):
@@ -493,7 +469,7 @@ def test_prefill_then_decode_matches_the_reference_forward(plen, monkeypatch):
     the chunked prefill's last position, then each decode step through the
     block tables, contexts crossing window, block and chunk boundaries) ==
     the reference's full forward over prompt + served tokens."""
-    seen = _record_logits(monkeypatch)
+    seen = record_logits(monkeypatch)
     srv = _server()
     try:
         prompt = _tokens(plen, seed=plen).tolist()
@@ -514,7 +490,7 @@ def test_rows_of_unequal_length_share_a_batch_and_a_slot_is_reused(
     several windows long (chunked), then a shorter request into a slot
     that held a longer one: each one's logits are the reference's for it
     alone."""
-    seen = _record_logits(monkeypatch)
+    seen = record_logits(monkeypatch)
     srv = _server(max_slots=3)
     try:
         first = {101: _tokens(5, seed=1).tolist(),

@@ -603,7 +603,7 @@ def test_mixer_scopes_are_metadata_on_the_lowered_programs():
                        block_size=8)
     rows = jnp.zeros((2,), jnp.int32)
     step = pool._decode_step_fn.lower(
-        PARAMS, pool._k, pool._v, rows, rows,
+        PARAMS, pool.kv, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
         jnp.zeros((3, 2), jnp.int32), pool.state,
         jnp.ones((2,), bool)).as_text(debug_info=True)
@@ -650,7 +650,7 @@ def test_both_implementers_chunk_returns_the_last_real_positions_logits():
         toks = _tokens(clen, seed=junk_seed) % cfg.vocab
         toks[:real] = _tokens(real, seed=7) % cfg.vocab
         outs.append(np.asarray(chunk(
-            params, *pool.kv, {}, pool.table(slot), np.int32(slot),
+            params, pool.kv, {}, pool.table(slot), np.int32(slot),
             jnp.asarray(toks), np.int32(0), np.int32(real))[0]))
     assert outs[0].shape == (cfg.vocab,)
     assert np.array_equal(outs[0], outs[1])
@@ -661,6 +661,6 @@ def test_both_implementers_chunk_returns_the_last_real_positions_logits():
                        dtype=jnp.float32, block_size=8)
     slot = pool.acquire()
     last = decode.serving_model(CFG).chunk(
-        PARAMS, *pool.kv, pool.state, pool.table(slot), np.int32(slot),
+        PARAMS, pool.kv, pool.state, pool.table(slot), np.int32(slot),
         jnp.asarray(_tokens(clen, seed=1)), np.int32(0), np.int32(real))[0]
     assert last.shape == (TINY["vocab_size"],)

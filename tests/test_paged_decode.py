@@ -130,7 +130,7 @@ def test_row_alone_equals_row_among_neighbours_bitwise(dtype, monkeypatch):
     k0, v0 = (np.asarray(a) for a in pool.kv)
     together = np.asarray(step_logits(pool, params, tokens, positions, tables))
     for slot in slots:
-        pool._k, pool._v = jnp.asarray(k0), jnp.asarray(v0)
+        pool._kv = (jnp.asarray(k0), jnp.asarray(v0))
         tok, pos, tab = (np.zeros_like(a) for a in (tokens, positions, tables))
         tok[slot], pos[slot], tab[slot] = (
             tokens[slot], positions[slot], tables[slot])

@@ -792,19 +792,20 @@ def test_no_program_of_the_engine_returns_a_vocabulary_sized_axis():
         tables = i32(R, pool.blocks_per_row)
         outs = {
             "decode_step": jax.eval_shape(
-                pool._decode_step_fn, PARAMS_A, *pool.kv, i32(R), i32(R),
-                tables, i32(3, R)),
+                pool._decode_step_fn, PARAMS_A, pool.kv, i32(R), i32(R),
+                tables, i32(3, R), {}, None),
             "prefill_rows": jax.eval_shape(
                 srv._get_prefill_rows_fn(16), PARAMS_A, i32(R, 16), i32(R),
                 jax.ShapeDtypeStruct((R,), bool), i32(3, R)),
             "chunk_step": jax.eval_shape(
-                srv._get_chunk_fn(8), PARAMS_A, *pool.kv, {},
+                srv._get_chunk_fn(8), PARAMS_A, pool.kv, {},
                 i32(pool.blocks_per_row), i32(), i32(8), i32(), i32(),
                 i32(3, 1)),
             "scatter_rows": jax.eval_shape(
-                pool._scatter_rows_fn, *pool.kv, slab, slab, tables),
+                pool._scatter_rows_fn, pool.kv, (slab, slab), tables, {},
+                {}, None),
             "copy_block": jax.eval_shape(
-                kv_pool._copy_block, *pool.kv, i32(), i32()),
+                kv_pool._copy_block, pool.kv, i32(), i32()),
         }
         assert len(outs) == len(pool.jitted_fns()) + 2
     finally:

@@ -99,24 +99,24 @@ def _run_chunk(params):
     toks = np.asarray(LONG[:16], np.int32)
     table = np.arange(1, 1 + pool.blocks_per_row, dtype=np.int32)
     out = None
-    pk, pv = pool.kv
+    kv = pool.kv
     for offset, n_real in ((0, 16), (16, 9)):
         out = jax.jit(MODEL.chunk)(
-            params, pk, pv, {}, table, np.int32(0), jnp.asarray(toks),
+            params, kv, {}, table, np.int32(0), jnp.asarray(toks),
             np.int32(offset), np.int32(n_real))
-        pk, pv = out[1], out[2]
+        kv = out[1]
     return out
 
 
 def _run_decode_step(params):
     pool = PagedKVPool(CFG, max_slots=3, max_len=32, dtype=CDT, block_size=8)
     key = jax.random.PRNGKey(5)
-    pk, pv = (jax.random.normal(k, pool.kv[0].shape, CDT)
-              for k in jax.random.split(key))
+    kv = tuple(jax.random.normal(k, pool.kv[0].shape, CDT)
+               for k in jax.random.split(key))
     tables = np.zeros((3, pool.blocks_per_row), np.int32)
     tables[0, :2], tables[1, :1] = (1, 2), (3,)       # row 2 is a junk row
     return jax.jit(MODEL.decode_step)(
-        params, pk, pv, {}, jnp.asarray([17, 99, 0], jnp.int32),
+        params, kv, {}, jnp.asarray([17, 99, 0], jnp.int32),
         jnp.asarray([13, 4, 0], jnp.int32), jnp.asarray(tables), None)
 
 

@@ -18,8 +18,10 @@ and the CPU branch cannot show. Only the TPU branch of
 ``moe.grouped_matmul`` (jax's megablox kernel) lives behind
 ``is_tpu_backend()``, so these compiles are the one place tier-1 sees it:
 at the published widths of ``command-a-plus-05-2026`` and the row counts
-the engine's programs hand it. Nothing runs; a compile that passes is
-not a chip run.
+the engine's programs hand it, and inside the whole serving programs of
+``openpangu-ultra-moe-718b`` at its cell's shapes (the latent pool, both
+forms of the latent read, a 7680-wide contraction in the grouped
+kernel). Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a fixture, after a test of this file has
 started, and every such test is in this one file: the process that
@@ -110,10 +112,98 @@ def test_the_chunk_program_updates_the_donated_pool_in_place(one_chip):
     pool = sds((24, 1 + 6 * blocks_per_row, 16, 16, 128))
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     compiled = jax.jit(
-        decode.serving_model(cfg).chunk, donate_argnums=(1, 2)
-    ).lower(params, pool, pool, {}, i32(blocks_per_row), i32(), i32(256),
+        decode.serving_model(cfg).chunk, donate_argnums=(1,)
+    ).lower(params, (pool, pool), {}, i32(blocks_per_row), i32(), i32(256),
             i32(), i32()).compile()
     pool_bytes = 2 * 24 * (1 + 6 * blocks_per_row) * 16 * 16 * 128 * 2
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
     assert memory.temp_size_in_bytes < 400e6
+
+
+PANGU = {
+    "hidden_size": 7680, "intermediate_size": 18432,
+    "moe_intermediate_size": 2048, "num_attention_heads": 128,
+    "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "n_routed_experts": 256,
+    "n_shared_experts": 1, "num_experts_per_tok": 8,
+    "routed_scaling_factor": 2.5, "rope_theta": 25600000,
+    "rms_norm_eps": 1e-5,
+    # The cell's cut: one dense and four expert layers, an eighth of the
+    # vocabulary; experts 0-15 held below.
+    "num_hidden_layers": 5, "first_k_dense_replace": 1, "vocab_size": 19200,
+}
+PANGU_SLOTS, PANGU_MAX_LEN, PANGU_BLOCK = 48, 11264, 16
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_512"])
+def test_the_latent_programs_compile_for_v5e_and_fit_beside_their_pool(
+        program, one_chip, monkeypatch):
+    """``openpangu-ultra-moe-718b`` as ``pangu718b-reason-closed72`` runs
+    it: 9.84 GB of weights and a pool of 1,152 B a token a layer (3.47
+    GB as allocated, its rows padded to 640 values) go in; the pool comes back aliased to its argument, and what the
+    program needs beside them leaves the chip's 16.9 GB room."""
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import decode, pangu_ultra_moe as pm
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    cfg = pm.PanguUltraMoeConfig.from_published(PANGU, held=tuple(range(16)))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    d, h, f = cfg.d_model, cfg.n_heads, cfg.d_expert
+    attn = {
+        "ln1": (d,), "ln2": (d,), "ln3": (d,), "ln4": (d,),
+        "wq_a": (d, cfg.q_rank), "q_norm": (cfg.q_rank,),
+        "wq_b": (cfg.q_rank, h * (cfg.d_nope + cfg.d_rope)),
+        "wkv_a": (d, cfg.cache_width), "kv_norm": (cfg.kv_rank,),
+        "wk_b": (cfg.kv_rank, h * cfg.d_nope),
+        "wv_b": (cfg.kv_rank, h * cfg.d_v), "wo": (h * cfg.d_v, d),
+    }
+    dense = {"w_gate": (d, cfg.d_dense), "w_up": (d, cfg.d_dense),
+             "w_down": (cfg.d_dense, d)}
+    expert = {"router": (d, 256), "we_gate": (16, d, f), "we_up": (16, d, f),
+              "we_down": (16, f, d), "ws_gate": (d, f), "ws_up": (d, f),
+              "ws_down": (f, d)}
+    params = {
+        "embed": sds((cfg.vocab, d)), "ln_f": sds((d,)),
+        "lm_head": sds((cfg.vocab, d)),
+        "layers": [{k: sds(v) for k, v in {**attn, **(
+            dense if i < cfg.n_dense else expert)}.items()}
+            for i in range(cfg.n_layers)],
+    }
+    weights = sum(2 * int(jnp.prod(jnp.asarray(a.shape)))
+                  for a in jax.tree_util.tree_leaves(params))
+    assert round(weights / 1e9, 2) == 9.84
+    model = decode.serving_model(cfg)
+    layers, (row,) = model.kv_spec()
+    assert row == (576,)
+    blocks_per_row = -(-(PANGU_MAX_LEN + 1) // PANGU_BLOCK)
+    # Rows padded to whole tiles, as ``PagedKVPool`` allocates them: a
+    # 576-wide array left to the device's default layout gets its blocks
+    # as the minor dimension, and both programs copy the pool, twice.
+    from rayfed_tpu.serving import kv_pool
+
+    assert kv_pool._allocated(row) == (640,)
+    pool = sds((layers, 1 + PANGU_SLOTS * blocks_per_row, PANGU_BLOCK, 640))
+    pool_bytes = 2 * int(jnp.prod(jnp.asarray(pool.shape)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step":
+        r = PANGU_SLOTS
+        lowered = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+            params, (pool,), {}, i32(r), i32(r), i32(r, blocks_per_row),
+            sds((r,), jnp.bool_))
+    else:
+        lowered = jax.jit(model.chunk, donate_argnums=(1,)).lower(
+            params, (pool,), {}, i32(blocks_per_row), i32(), i32(512),
+            i32(), i32())
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # The grouped kernel three times an expert layer, never a copy of a
+    # weight or of the pool: room for activations and score tiles only.
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') >= 12
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9

@@ -345,7 +345,7 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     try:
         chunk_fn = srv._get_chunk_fn(4)
         text = chunk_fn.lower(
-            PARAMS, *srv.pool.kv, {},
+            PARAMS, srv.pool.kv, {},
             jnp.zeros((srv.pool.blocks_per_row,), jnp.int32), jnp.int32(0),
             jnp.zeros((4,), jnp.int32), jnp.int32(0), jnp.int32(4),
             jnp.zeros((3, 1), jnp.int32),
@@ -357,9 +357,9 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     assert chunk_fn.__name__ == "chunk_step"
     rows = jnp.zeros((2,), jnp.int32)
     assert "serve/decode_step" in pool._decode_step_fn.lower(
-        PARAMS, pool._k, pool._v, rows, rows,
+        PARAMS, pool.kv, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
-        jnp.zeros((3, 2), jnp.int32),
+        jnp.zeros((3, 2), jnp.int32), {}, None,
     ).as_text(debug_info=True)
     # A decorator, not a wrapper program: the jitted functions keep the
     # names the profile and `compiled_programs` know them by.

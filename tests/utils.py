@@ -93,17 +93,17 @@ def step_logits(pool, params, tokens, positions, tables, live=None):
     import jax.numpy as jnp
 
     live = None if live is None or not pool._state else jnp.asarray(live)
-    logits, pool._k, pool._v, pool._state = jax.jit(
-        pool.model.decode_step, donate_argnums=(1, 2, 3)
-    )(params, pool._k, pool._v, pool._state, jnp.asarray(tokens),
+    logits, pool._kv, pool._state, *_ = jax.jit(
+        pool.model.decode_step, donate_argnums=(1, 2)
+    )(params, pool._kv, pool._state, jnp.asarray(tokens),
       jnp.asarray(positions), jnp.asarray(tables), live)
     return logits
 
 
 def slot_rows(pool, slot):
-    """One slot's K and V as contiguous (L, row_len, Hkv, Dh) NumPy rows,
-    read through its block table (no program of the pool builds such a
-    row)."""
+    """One slot's rows of every array of the pool (K and V for most
+    models) as contiguous (L, row_len, *shape) NumPy rows, read through
+    its block table (no program of the pool builds such a row)."""
     import numpy as np
 
     table = pool.table(slot)
@@ -112,7 +112,7 @@ def slot_rows(pool, slot):
         a = np.asarray(a)[:, table]
         return a.reshape(a.shape[0], -1, *a.shape[3:])[:, :pool.row_len]
 
-    return row(pool.kv[0]), row(pool.kv[1])
+    return tuple(row(a) for a in pool.kv)
 
 
 def land_row(pool, slot, k_row, v_row):
@@ -130,3 +130,31 @@ def land_row(pool, slot, k_row, v_row):
         return jnp.zeros(shape, row.dtype).at[:, slot].set(row)
 
     pool.scatter_rows(slab(k_row), slab(v_row), tables)
+
+
+def record_logits(monkeypatch):
+    """Every logits row the engine's programs choose a token from, by the
+    request's seed (greedy: the seed only marks its rows) and the token's
+    position in the output. The sampler is looked up when a program is
+    traced, so engines built after this call record; a chunk that is not
+    a prompt's last also reaches the sampler at position 0, and the last
+    one, which comes last, is the one kept."""
+    import jax
+    import numpy as np
+
+    from rayfed_tpu.serving import sampling
+
+    seen = {}
+    choose = sampling.choose_tokens
+
+    def record(logits, seeds, index):
+        for row in np.flatnonzero(seeds):
+            seen.setdefault(int(seeds[row]), {})[int(index[row])] = np.array(
+                logits[row])
+
+    def spy(logits, temperature, seeds, index):
+        jax.debug.callback(record, logits, seeds, index, ordered=True)
+        return choose(logits, temperature, seeds, index)
+
+    monkeypatch.setattr(sampling, "choose_tokens", spy)
+    return seen
