@@ -50,11 +50,15 @@ def placement(tree):
 
 class DeviceTrace:
     """The profiler around a part of the window, in the process that owns
-    the chip; reduced by ``trace_reduce`` after the window has closed."""
+    the chip; reduced by ``trace_reduce`` after the window has closed.
+    Between ``start`` and ``stop`` the host span ``chipbench:traced`` is
+    open, inside the profile at both ends: it is the window that
+    ``trace_reduce.reduce`` clips everything to, on the profiler's clock."""
 
     def __init__(self, ctx):
+        self.ctx = ctx
         self.dir = os.path.join(ctx.run_dir, f"trace-{ctx.party}")
-        self.t0 = self.t1 = None
+        self.window = None
 
     def start(self):
         import jax
@@ -65,21 +69,27 @@ class DeviceTrace:
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
         jax.profiler.start_trace(self.dir, profiler_options=opts)
-        self.t0 = time.perf_counter()
+        self.window = annotate("traced")
+        self.window.__enter__()
 
     def stop(self):
         import jax
 
-        self.t1 = time.perf_counter()
+        self.window.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
     def reduce(self, kernels=()):
+        """The reduction, or None where there is nothing to reduce: no
+        profile, or one without a device plane (a CPU run), so that
+        ``device`` gets ``busy_s`` and ``window_s`` only from a chip."""
         from chipbench import trace_reduce
 
         path = trace_reduce.find_xplane(self.dir)
-        if path is None or self.t0 is None:
+        if path is None or self.window is None:
             return None
+        t0 = time.perf_counter()
         lines = trace_reduce.events_of(path)
+        read_s = time.perf_counter() - t0
         if os.environ.get("CHIPBENCH_KEEP_EVENTS"):
             # For the recorded trace under chipbench/tests/data/.
             import gzip
@@ -90,13 +100,20 @@ class DeviceTrace:
             with gzip.open(os.path.join(self.dir, "events.json.gz"),
                            "wt") as f:
                 json.dump(small, f)
-        out = trace_reduce.reduce(lines, window_s=self.t1 - self.t0,
-                                  kernels=kernels)
+        t0 = time.perf_counter()
+        out = trace_reduce.reduce(lines, kernels=kernels)
+        self.ctx.say(
+            "trace reduced", events=sum(len(ln["events"]) for ln in lines),
+            read_s=round(read_s, 3),
+            reduce_s=round(time.perf_counter() - t0, 3),
+            **{k: out.get(k) for k in ("devices", "window_from", "window_s",
+                                       "busy_s", "idle_small_s",
+                                       "program_spans")})
         try:  # the raw trace is large; the reduction is what is kept
             os.remove(path)
         except OSError:
             pass
-        return out
+        return out if out["devices"] else None
 
 
 def annotate(name):

@@ -4,12 +4,16 @@ idle while the engine did its own bookkeeping between two dispatches:
 (block grants, tables, tokens, positions), ``:dispatch`` (the three
 enqueues), ``:emit`` (token push, finish), ``:prefill_chunk`` and ``:idle``
 (waiting for a request). What preparing iteration t+1 while t runs
-(ROADMAP S3) should take to zero.
+(ROADMAP S3) should take to zero. On the chip most of an iteration's gap
+reads under ``idle_share.unnamed`` instead, by the profile's clocks (that
+reader's docstring): read the two as one sum.
 
-One name per gap, the 150 longest gaps, the ten largest names, and None
-for a program without spans: all as the docstring of
-chipbench/layers/idle_share.sample.py says."""
+Every gap of the window booked to one name, all names read; 0.0 where the
+program has spans and no gap is theirs, None only without a trace or for a
+program without spans: all as the docstring of chipbench/trace_reduce.py
+says."""
 
+from chipbench.trace_reduce import idle_share
 
 PHASES = frozenset("fed:serve:" + p for p in (
     "admit", "build", "dispatch", "emit", "prefill_chunk", "idle"))
@@ -20,10 +24,4 @@ def counted(name):
 
 
 def read(facts):
-    trace = facts.get("trace") or {}
-    gaps = trace.get("idle_gaps") or []
-    if not trace.get("window_s") or not any(
-            name.startswith("fed:") for name, _ in gaps):
-        return None
-    idle_s = sum(seconds for name, seconds in gaps if counted(name))
-    return 100.0 * idle_s / trace["window_s"]
+    return idle_share(facts.get("trace"), counted)

@@ -57,8 +57,10 @@ def test_rehearsal_ends_in_the_contracts_line(tmp_path, cell, trace):
     assert set(line) - {"breakdown"} == KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
-    # A CPU run prints no device metric.
+    # A CPU run prints no device metric, and its profile has no device
+    # plane: busy_s and window_s come from a chip or not at all.
     assert set(line["metrics"]) <= {"setup_s"}
+    assert not {"busy_s", "window_s"} & set(line["device"])
     assert "setup_s is made of" in run.stdout
 
 
@@ -197,7 +199,9 @@ def test_trace_reduction_on_made_and_recorded_traces():
     ]
     out = trace_reduce.reduce(lines, window_s=0.02, kernels=("flash_fwd",))
     assert out["busy_s"] == pytest.approx(0.011)       # [0,10] and [12,13]
-    assert out["window_s"] == 0.02 and out["devices"] == 1
+    # PR 35: the window is read from the trace (without the span
+    # chipbench:traced it is the device's own 13 ms), not handed in.
+    assert out["window_s"] == 0.013 and out["devices"] == 1
     assert out["kernels"] == {"flash_fwd": {"seconds": pytest.approx(0.004),
                                             "calls": 2.0}}
     assert out["device_ops"][0] == ["flash_fwd.3", pytest.approx(0.004)]
