@@ -40,7 +40,9 @@ Decode: one jitted program per iteration
 through its block table, a chunk of blocks at a time under an online
 softmax, writes the new token's K/V straight into its
 (block, offset) and ends in the choice of each row's next token
-(:mod:`rayfed_tpu.serving.sampling`): ids come back, not logits; the
+(:mod:`rayfed_tpu.serving.sampling`): ids come back, not logits, and
+stay on the device as the next step's token source (``prev_ids``: the
+engine dispatches a step before it has fetched the one before); the
 pool's arrays are donated and are the only cache buffers —
 no contiguous (L, R, max_len+1, H, Dh) copy of the rows exists, and the
 blocks read follow the longest live row, not ``max_len``. It agrees with
@@ -219,6 +221,9 @@ class PagedKVPool:
         # Numbers a model's decode step counts on the device (optional in
         # the protocol): they ride home behind the ids, in their array.
         self.step_counters = tuple(getattr(self.model, "step_counters", ()))
+        # What the last decode step returned (the ids, the counters behind
+        # them), kept on the device: see :meth:`decode_step`.
+        self._ids = jnp.zeros(max_slots + len(self.step_counters), jnp.int32)
         self._lock = threading.Lock()
         self._free_slots: List[int] = list(range(max_slots))
         # pop() hands out low block ids first.
@@ -261,10 +266,14 @@ class PagedKVPool:
         # choice of each row's next token (serving/sampling.py): (R,) ids
         # come back, the (R, vocab) logits stay on the device. A model
         # that declares ``step_counters`` returns them as a fifth value,
-        # and they follow the ids in the one int32 array.
+        # and they follow the ids in the one int32 array. That array is
+        # not donated: it is the next step's token source (``prev_ids``)
+        # for every row not ``from_host``, so a step can be dispatched
+        # before the host has read the one before it.
         @jax.named_scope("serve/decode_step")
         def decode_step(params, kv, tokens, positions, tables, draw,
-                        state=None, live=None):
+                        prev_ids, from_host, state=None, live=None):
+            tokens = jnp.where(from_host, tokens, prev_ids[:R])
             logits, kv, state, *counted = model.decode_step(
                 params, kv, state or {}, tokens, positions, tables, live
             )
@@ -274,7 +283,7 @@ class PagedKVPool:
             return ids, kv, state
 
         self._decode_step_fn = jax.jit(
-            decode_step, donate_argnums=(1, 6)
+            decode_step, donate_argnums=(1, 8)
         )
 
         @jax.named_scope("serve/scatter")
@@ -309,10 +318,15 @@ class PagedKVPool:
         )
 
     def decode_step(self, params, tokens, positions, tables, draw,
-                    live=None):
+                    live=None, from_host=None, prev_ids=None):
         """One decode token per row through the block tables, the pool
         updated in place; junk rows carry position 0 and an all-zero
-        table. ``draw`` (3, R) int32 is the sampler's per-row scalars
+        table. A row's token is ``tokens`` (R,) from the host where
+        ``from_host`` (R,) bool says so, and elsewhere the id an earlier
+        step chose for it, read on the device from that step's returned
+        array ``prev_ids`` (which the host need not have fetched yet);
+        without ``prev_ids`` every token is the host's. ``draw`` (3, R)
+        int32 is the sampler's per-row scalars
         (:func:`rayfed_tpu.serving.sampling.pack`; all zero: every row
         greedy). ``live`` (R,) bool names the rows whose recurrent state
         advances; every other row's state comes back bit for bit. A
@@ -326,11 +340,16 @@ class PagedKVPool:
         around each cost the engine thread 0.15 ms of dispatch apiece
         on the chip's host (``PERF.md`` §6, PR 30)."""
         wants_live = self._state or self.step_counters
-        ids, self._kv, self._state = self._decode_step_fn(
+        if prev_ids is None:
+            # Any array of the step's own making: one call signature,
+            # whichever rows read it (none here).
+            prev_ids, from_host = self._ids, np.ones(self.max_slots, bool)
+        self._ids, self._kv, self._state = self._decode_step_fn(
             params, self._kv, tokens, positions, tables, draw,
+            prev_ids, np.asarray(from_host, bool),
             self._state, np.asarray(live, bool) if wants_live else None,
         )
-        return ids
+        return self._ids
 
     def _of_state(self, value, dtype):
         """An argument that exists for the state's sake: handed on for a

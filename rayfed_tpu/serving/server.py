@@ -38,10 +38,14 @@ the running decode iteration under a ``prefill_token_budget`` per step
 at token boundaries. Every program ends in the choice of the next token
 (:mod:`rayfed_tpu.serving.sampling`): the host fetches ``(R,)`` ids, never
 the ``(R, vocab)`` logits, and does only bookkeeping (``eos``,
-``max_new_tokens``, streams). When the pool truly runs dry the engine preempts
-the youngest request (its blocks return to the free list, the request
-re-queues and deterministically re-runs under its pinned version), so
-mixed-length traffic degrades by latency, never by abort.
+``max_new_tokens``, streams). Decode runs one step ahead: the ids stay on
+the device as the next step's tokens, step t + 1 is dispatched before
+step t is fetched, and the host reads every id one dispatch late
+(:meth:`InferenceServer._step_groups` has the rules of that lag). When
+the pool truly runs dry the engine preempts the youngest request (its
+blocks return to the free list, the request re-queues and
+deterministically re-runs under its pinned version), so mixed-length
+traffic degrades by latency, never by abort.
 
 Token streaming: ``submit(..., stream=sink)`` attaches a sink the engine
 pushes each sampled token into (never blocking — see
@@ -131,6 +135,18 @@ class _Request:
     stream: Any = None            # optional token sink (serving.stream)
     chunk_done: int = 0           # prompt positions chunked-prefilled so far
     stalled: bool = False         # waiting on a KV block grant
+    ahead: int = 0                # tokens dispatched and not yet fetched (0/1)
+
+
+@dataclass
+class _Ahead:
+    """A decode step that was dispatched and not yet fetched: what it
+    returned (on the device: the ids, the model's counters behind them)
+    and the requests whose rows were live in it. A request that ends, is
+    preempted or fails while its step is in flight is taken out of
+    ``rows``: its id is dropped."""
+    ids: Any
+    rows: List[_Request]
 
 
 class InferenceServer:
@@ -203,6 +219,8 @@ class InferenceServer:
         self._pending: "deque[_Request]" = deque()
         self._active: Dict[int, _Request] = {}     # slot -> request
         self._prefilling: List[_Request] = []      # chunked prefills
+        # version -> its decode step in flight (engine thread only)
+        self._ahead: Dict[int, _Ahead] = {}
         self._rid_counter = itertools.count()
         self._stopping = False
         self._fatal: Optional[BaseException] = None
@@ -213,6 +231,14 @@ class InferenceServer:
             "prefix_hits": 0,
             "tokens_out": 0,
             "steps": 0,
+            # Run-ahead, beside "steps": decode steps dispatched while
+            # their version group's previous step had not been fetched
+            # (steps_ahead / steps: how often the device had its next
+            # step queued), and rows that were live in a step dispatched
+            # after their last token (an ``eos`` the host read one step
+            # late; an end by ``max_new_tokens`` is known ahead).
+            "steps_ahead": 0,
+            "rows_wasted": 0,
             "prefill_chunks": 0,
             "streamed_tokens": 0,
             "preempted": 0,
@@ -296,6 +322,18 @@ class InferenceServer:
         ).labels(server=name)
         self._m_steps = _reg.counter(
             "fed_serving_steps_total", "Batched decode iterations.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_steps_ahead = _reg.counter(
+            "fed_serving_steps_ahead_total",
+            "Decode iterations dispatched before the previous one of "
+            "their version was fetched.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_rows_wasted = _reg.counter(
+            "fed_serving_rows_wasted_total",
+            "Rows live in a decode iteration dispatched after their last "
+            "token.",
             labels=("server",),
         ).labels(server=name)
         self._m_pending = _reg.gauge(
@@ -709,6 +747,7 @@ class InferenceServer:
                         if (
                             not self._active
                             and not self._prefilling
+                            and not self._ahead
                             and not pending
                         ):
                             return
@@ -726,6 +765,14 @@ class InferenceServer:
                 # the oldest (already-decoding) requests first, so a
                 # preemption's memory cannot be stolen by new work
                 # (which would livelock the batch under block pressure).
+                # A decode step is dispatched before the one before it
+                # is fetched (:meth:`_step_groups`), so admission above
+                # and the chunks below run with a step in flight: the
+                # programs they dispatch queue behind it on the device.
+                # A step dispatched is progress like a token emitted, so
+                # "no progress" (the backoff, a preemption) is concluded
+                # only of an iteration that found nothing in flight and
+                # left nothing in flight.
                 # The host phases of an iteration (fed:serve:*, on the
                 # profiler's clock) tile it: none encloses another, so an
                 # idle gap of the device is booked to the piece of host
@@ -752,6 +799,9 @@ class InferenceServer:
             and not self._pending
             and not self._active
             and not self._prefilling
+            # A step whose rows all ended under it is still fetched: what
+            # it counted on the device rides behind its ids.
+            and not self._ahead
         )
 
     def _update_kv_gauges(self) -> None:
@@ -769,6 +819,7 @@ class InferenceServer:
             self._pending.clear()
             self._active.clear()
             self._prefilling.clear()
+            self._ahead.clear()
             self._m_pending.set(0)
             self._m_active.set(0)
         for req in doomed:
@@ -950,6 +1001,7 @@ class InferenceServer:
 
     def _fail_admitted(self, req: _Request, exc: BaseException) -> None:
         """Hard-fail an already-admitted request (engine thread only)."""
+        self._forget(req)
         with self._lock:
             if self._active.get(req.slot) is req:
                 del self._active[req.slot]
@@ -1133,32 +1185,42 @@ class InferenceServer:
         return ran
 
     def _step_inputs(self, rows):
-        """(tokens, positions, tables, draw, live) of one paged decode
-        step from the live rows' ``(request, token, position)``. Every
-        other row is junk: position 0 under an all-zero table, so it
-        visits no block and writes into the sacrificial block 0; greedy
-        in ``draw``, so it asks for no noise; and not ``live``, so
-        whatever recurrent state its slot holds comes back bit for
-        bit."""
+        """(tokens, positions, tables, draw, live, from_host) of one
+        paged decode step from the live rows' ``(request, token,
+        position)``. A row whose last token the host holds (it came from
+        a prefill, or from a step already fetched) sends it in ``tokens``
+        and is marked ``from_host``; a row whose last token is in the
+        step in flight has ``token`` None and sends nothing: the program
+        reads it from that step's ids. Every other row is junk: position
+        0 under an all-zero table, so it visits no block and writes into
+        the sacrificial block 0; greedy in ``draw``, so it asks for no
+        noise; and not ``live``, so whatever recurrent state its slot
+        holds comes back bit for bit."""
         R = self.pool.max_slots
         tokens = np.zeros(R, np.int32)
         positions = np.zeros(R, np.int32)
         tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
         live = np.zeros(R, bool)
+        from_host = np.ones(R, bool)
         reqs = []
         for req, token, pos in rows:
-            tokens[req.slot] = token
+            if token is None:
+                from_host[req.slot] = False
+            else:
+                tokens[req.slot] = token
             positions[req.slot] = pos
             tables[req.slot] = self.pool.table(req.slot)
             live[req.slot] = True
             reqs.append(req)
-        return tokens, positions, tables, self._draw_inputs(reqs), live
+        return (tokens, positions, tables, self._draw_inputs(reqs), live,
+                from_host)
 
     def _draw_inputs(self, reqs) -> np.ndarray:
         """The sampler's per-row scalars for a program over all slots
         (:func:`sampling.pack`, one upload): each request's temperature,
         seed and the position in its output of the token about to be
-        chosen, at its slot; zero (greedy) everywhere else."""
+        chosen (the tokens it has, and the one in flight), at its slot;
+        zero (greedy) everywhere else."""
         R = self.pool.max_slots
         temperature = np.zeros(R, np.float32)
         seed = [0] * R
@@ -1166,7 +1228,7 @@ class InferenceServer:
         for req in reqs:
             temperature[req.slot] = req.temperature
             seed[req.slot] = req.seed
-            index[req.slot] = len(req.out)
+            index[req.slot] = len(req.out) + req.ahead
         return sampling.pack(temperature, seed, index)
 
     def _fetch(self, ids) -> np.ndarray:
@@ -1215,27 +1277,27 @@ class InferenceServer:
         self._m_chunk_read.inc(read)
         self._m_chunk_row.inc(row)
 
-    def _layer_blocks(self, live, attended: int) -> int:
-        """Blocks the layers of the ``live`` rows must read in a decode
-        step, summed over rows and layers: every block up to a row's
-        position (``attended``, summed over the rows) on a layer that
-        attends every key, those that hold ``pos - window + 1 .. pos``
-        on a windowed one."""
+    def _layer_blocks(self, positions, attended: int) -> int:
+        """Blocks the layers of the live rows (at ``positions``) must
+        read in a decode step, summed over rows and layers: every block
+        up to a row's position (``attended``, summed over the rows) on a
+        layer that attends every key, those that hold ``pos - window + 1
+        .. pos`` on a windowed one."""
         bs = self.pool.block_size
         return (self._n_layers - len(self._windows)) * attended + sum(
-            req.pos // bs - max(req.pos - window + 1, 0) // bs + 1
-            for window in self._windows for req in live
+            pos // bs - max(pos - window + 1, 0) // bs + 1
+            for window in self._windows for pos in positions
         )
 
-    def _layer_keys(self, live) -> int:
-        """Keys the layers of the ``live`` rows score in a decode step,
-        summed over rows and layers: a row at position ``pos`` sees its
-        ``pos`` cached keys and its own, at most ``window`` of them on a
-        windowed layer."""
-        seen = sum(req.pos + 1 for req in live)
+    def _layer_keys(self, positions) -> int:
+        """Keys the layers of the live rows (at ``positions``) score in a
+        decode step, summed over rows and layers: a row at position
+        ``pos`` sees its ``pos`` cached keys and its own, at most
+        ``window`` of them on a windowed layer."""
+        seen = sum(pos + 1 for pos in positions)
         return (self._n_layers - len(self._windows)) * seen + sum(
-            min(req.pos + 1, window)
-            for window in self._windows for req in live
+            min(pos + 1, window)
+            for window in self._windows for pos in positions
         )
 
     def _count_state_resets(self, n: int) -> None:
@@ -1248,7 +1310,8 @@ class InferenceServer:
         """The decode program with only ``req``'s row live, at the last
         position of its prompt (every other row is junk whatever its
         state: see :meth:`_step_inputs`). Returns the id it chose for
-        that row: the request's first token."""
+        that row: the request's first token. It is fetched at once and
+        feeds no later step: no link of a version's run-ahead chain."""
         ids = self.pool.decode_step(params, *self._step_inputs(
             [(req, int(req.prompt[-1]), int(req.prompt.size) - 1)]
         ))
@@ -1282,7 +1345,19 @@ class InferenceServer:
         self._preempt(victim)
         return True
 
+    def _forget(self, req: _Request) -> None:
+        """Drop the id that a step in flight holds for ``req`` (the
+        request ended, was preempted or failed under it): the late fetch
+        emits nothing for it. The step's write for the row went to a
+        block the row held when it was dispatched, and whatever is
+        dispatched from now on runs after it on the device, so the slot
+        and its blocks may be released at once."""
+        if req.ahead:
+            req.ahead = 0
+            self._ahead[req.version].rows.remove(req)
+
     def _preempt(self, req: _Request) -> None:
+        self._forget(req)
         with self._lock:
             if self._active.get(req.slot) is req:
                 del self._active[req.slot]
@@ -1311,95 +1386,150 @@ class InferenceServer:
     def _step_groups(self) -> bool:
         """One decode iteration: a batched pool step per live version
         group. Params differ across groups but shapes do not, so every
-        group reuses the same compiled program. Returns True when any
-        request advanced a token."""
+        group reuses the same compiled program.
+
+        A group keeps one step in flight. Step t + 1 is built and
+        dispatched BEFORE step t is fetched: it needs nothing of t's ids
+        on the host, because the rows that were live in t take their
+        token on the device from t's returned array (the pool's
+        ``prev_ids``), and everything else of t + 1 is host state one
+        token ahead: the dispatched position ``pos + ahead`` and its
+        block grant, the sampler's index ``len(out) + ahead``. Then t is
+        fetched (the wait ends with step t, t + 1 queued behind it) and
+        emitted. What follows from the one-step lag:
+
+        - a row whose end the host knows ahead (``len(out) + ahead``
+          reaches ``max_new_tokens``) is not put into t + 1;
+        - a row that ends at t by ``eos_id`` was live in t + 1: that id
+          is dropped (:meth:`_forget`, counted in ``rows_wasted``);
+        - a row whose grant for t + 1 fails sits t + 1 out and is marked
+          ``stalled`` once t is emitted, if it did not end there;
+        - with nothing in flight (a group's first step, the step after a
+          lull or after every row sat one out) the iteration is build,
+          dispatch and nothing to fetch: the ids come an iteration later.
+
+        Returns True when a step was dispatched or a token emitted."""
         with self._lock:
             groups: Dict[int, List[_Request]] = {}
             for req in self._active.values():
                 groups.setdefault(req.version, []).append(req)
-        if not groups:
-            return False
         progressed = False
-        for version in sorted(groups):
-            params = self.bank.get(version)
+        for version in sorted(groups.keys() | self._ahead.keys()):
+            prev = self._ahead.get(version)
             with tracing.phase("fed:serve:build"):
                 # Grant each live row's next block at this token
-                # boundary; a row that cannot get one sits out the
-                # iteration as junk (and flags itself for the
-                # preemption check) — decode never stalls the whole
-                # batch.
-                live = []
-                for req in groups[version]:
-                    status = self.pool.ensure_blocks(req.slot, req.pos)
+                # boundary (one token ahead of what it has emitted, if
+                # its last step is in flight); a row that cannot get one
+                # sits out the iteration as junk, decode never stalls
+                # the whole batch.
+                live, starved = [], []
+                for req in groups.get(version, ()):
+                    if len(req.out) + req.ahead >= req.max_new_tokens:
+                        continue      # its last token is in flight
+                    pos = req.pos + req.ahead
+                    status = self.pool.ensure_blocks(req.slot, pos)
                     if status == "ok":
                         req.stalled = False
-                        live.append(req)
+                        live.append((
+                            req, None if req.ahead else req.out[-1], pos
+                        ))
                     elif status == "quota" and self._quota_hopeless(req):
                         self._fail_admitted(req, self._quota_exc(req))
                     else:
-                        req.stalled = True
-                # Rows that are free, on another version or stalled
-                # are junk in this step.
-                inputs = self._step_inputs(
-                    (req, req.out[-1], req.pos) for req in live
-                )
-            if not live:
-                continue
-            with tracing.phase("fed:serve:dispatch"):
-                ids = self.pool.decode_step(params, *inputs)
-                bs = self.pool.block_size
-                attended = sum(req.pos // bs + 1 for req in live)
-                slab = self.pool.max_slots * self.pool.blocks_per_row
-                by_layer = self._layer_blocks(live, attended)
-                keys = self._layer_keys(live)
-                self._stats["kv_blocks_attended"] += attended
-                self._stats["kv_blocks_slab"] += slab
-                self._stats["kv_layer_blocks_attended"] += by_layer
-                self._stats["decode_keys_attended"] += keys
-                self._m_kv_attended.inc(attended)
-                self._m_kv_slab.inc(slab)
-                self._m_kv_layer_attended.inc(by_layer)
-                self._m_decode_keys.inc(keys)
-                if any(req.temperature > 0.0 for req in live):
-                    self._stats["draw_steps"] += 1
-                    self._m_draw_steps.inc()
-                if self._recurrent:
-                    # Read and written once each by every live row;
-                    # held: admitted rows whose state this step kept
-                    # (stalled, on another version, or between two
-                    # chunks of their prompt).
-                    moved = 2 * len(live) * self.pool.state_row_bytes
-                    held = len(self._active) - len(live) + sum(
-                        1 for r in self._prefilling if r.chunk_done
+                        starved.append(req)
+                # Rows that are free, on another version, stalled or
+                # waiting for their last token are junk in this step.
+                inputs = self._step_inputs(live) if live else None
+            if live:
+                with tracing.phase("fed:serve:dispatch"):
+                    self._ahead[version] = self._dispatch(
+                        self.bank.get(version), live, inputs, prev
                     )
-                    self._stats["ssm_state_bytes"] += moved
-                    self._stats["state_rows_held"] += held
-                    self._m_state_bytes.inc(moved)
-            self._stats["steps"] += 1
-            self._m_steps.inc()
-            with tracing.phase("fed:serve:fetch"):
-                ids = self._fetch(ids)
-                # Behind the R ids: what the model counted in this step.
-                for key, n in zip(self.pool.step_counters,
-                                  ids[self.pool.max_slots:]):
-                    self._stats[key] += int(n)
-                    self._m_step_counters[key].inc(int(n))
-            with tracing.phase("fed:serve:emit"):
-                for req in live:
-                    tok = self._sample(ids[req.slot], req)
-                    req.out.append(tok)
-                    req.pos += 1
-                    progressed = True
-                    self._emit_token(req, tok)
-                    if (
-                        len(req.out) >= req.max_new_tokens
-                        or tok == self.scfg.eos_id
-                    ):
-                        with self._lock:
-                            self._active.pop(req.slot, None)
-                            self._m_active.set(len(self._active))
-                        self._finish(req)
+                progressed = True
+            elif prev is not None:
+                del self._ahead[version]
+            if prev is not None:
+                with tracing.phase("fed:serve:fetch"):
+                    ids = self._fetch(prev.ids)
+                    # Behind the R ids: what the model counted in that
+                    # step.
+                    for key, n in zip(self.pool.step_counters,
+                                      ids[self.pool.max_slots:]):
+                        self._stats[key] += int(n)
+                        self._m_step_counters[key].inc(int(n))
+                with tracing.phase("fed:serve:emit"):
+                    for req in prev.rows:
+                        tok = self._sample(ids[req.slot], req)
+                        req.out.append(tok)
+                        req.pos += 1
+                        req.ahead -= 1
+                        progressed = True
+                        self._emit_token(req, tok)
+                        if (
+                            len(req.out) >= req.max_new_tokens
+                            or tok == self.scfg.eos_id
+                        ):
+                            if req.ahead:
+                                # Live in the step dispatched above.
+                                self._forget(req)
+                                self._stats["rows_wasted"] += 1
+                                self._m_rows_wasted.inc()
+                            with self._lock:
+                                self._active.pop(req.slot, None)
+                                self._m_active.set(len(self._active))
+                            self._finish(req)
+            for req in starved:
+                # The late fetch has shown whether the row ended (or the
+                # grant's failure failed it): only a row still admitted
+                # is starved, and flags itself for the preemption check.
+                req.stalled = req.slot >= 0
         return progressed
+
+    def _dispatch(self, params, live, inputs, prev) -> _Ahead:
+        """Enqueue one decode step over the ``live`` rows' ``(request,
+        token, position)`` and count it: the counters say what the device
+        was handed, whatever the host later does with the ids."""
+        ids = self.pool.decode_step(
+            params, *inputs, None if prev is None else prev.ids
+        )
+        reqs = [req for req, _, _ in live]
+        positions = [pos for _, _, pos in live]
+        for req in reqs:
+            req.ahead += 1
+        bs = self.pool.block_size
+        attended = sum(pos // bs + 1 for pos in positions)
+        slab = self.pool.max_slots * self.pool.blocks_per_row
+        by_layer = self._layer_blocks(positions, attended)
+        keys = self._layer_keys(positions)
+        self._stats["kv_blocks_attended"] += attended
+        self._stats["kv_blocks_slab"] += slab
+        self._stats["kv_layer_blocks_attended"] += by_layer
+        self._stats["decode_keys_attended"] += keys
+        self._m_kv_attended.inc(attended)
+        self._m_kv_slab.inc(slab)
+        self._m_kv_layer_attended.inc(by_layer)
+        self._m_decode_keys.inc(keys)
+        if any(req.temperature > 0.0 for req in reqs):
+            self._stats["draw_steps"] += 1
+            self._m_draw_steps.inc()
+        if self._recurrent:
+            # Read and written once each by every live row; held:
+            # admitted rows whose state this step kept (stalled, on
+            # another version, waiting for their last token, or between
+            # two chunks of their prompt).
+            moved = 2 * len(reqs) * self.pool.state_row_bytes
+            held = len(self._active) - len(reqs) + sum(
+                1 for r in self._prefilling if r.chunk_done
+            )
+            self._stats["ssm_state_bytes"] += moved
+            self._stats["state_rows_held"] += held
+            self._m_state_bytes.inc(moved)
+        self._stats["steps"] += 1
+        self._m_steps.inc()
+        if prev is not None:
+            self._stats["steps_ahead"] += 1
+            self._m_steps_ahead.inc()
+        return _Ahead(ids, reqs)
 
     @staticmethod
     def _sample(chosen, req: _Request) -> int:

@@ -605,8 +605,8 @@ def test_mixer_scopes_are_metadata_on_the_lowered_programs():
     step = pool._decode_step_fn.lower(
         PARAMS, pool.kv, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
-        jnp.zeros((3, 2), jnp.int32), pool.state,
-        jnp.ones((2,), bool)).as_text(debug_info=True)
+        jnp.zeros((3, 2), jnp.int32), rows, jnp.ones((2,), bool),
+        pool.state, jnp.ones((2,), bool)).as_text(debug_info=True)
     for scope in ("serve/decode_step", "serve/ssm_step", "serve/conv"):
         assert scope in step, scope
     prefill = jax.jit(lambda p, t, i: fh.prefill_rows(

@@ -665,7 +665,8 @@ def test_the_scopes_are_metadata_on_the_lowered_programs():
     step = pool._decode_step_fn.lower(
         PARAMS, pool.kv, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
-        jnp.zeros((3, 2), jnp.int32), {}, jnp.ones((2,), bool),
+        jnp.zeros((3, 2), jnp.int32), rows, jnp.ones((2,), bool), {},
+        jnp.ones((2,), bool),
     ).as_text(debug_info=True)
     for scope in ("serve/decode_step", "serve/mla_project",
                   "serve/attn_latent", "serve/moe_route",

@@ -793,7 +793,8 @@ def test_no_program_of_the_engine_returns_a_vocabulary_sized_axis():
         outs = {
             "decode_step": jax.eval_shape(
                 pool._decode_step_fn, PARAMS_A, pool.kv, i32(R), i32(R),
-                tables, i32(3, R), {}, None),
+                tables, i32(3, R), i32(R),
+                jax.ShapeDtypeStruct((R,), bool), {}, None),
             "prefill_rows": jax.eval_shape(
                 srv._get_prefill_rows_fn(16), PARAMS_A, i32(R, 16), i32(R),
                 jax.ShapeDtypeStruct((R,), bool), i32(3, R)),
@@ -993,6 +994,310 @@ def test_slow_stream_consumer_never_blocks_engine():
         assert stream.tokens() == resp["tokens"]
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# Run-ahead: a decode step is dispatched before the one before it is
+# fetched (its rows' tokens stay on the device), and the host reads every
+# id one dispatch late. The result is the same work; what the lag changes
+# is spelled out below, one property a test. Every wait is bounded.
+
+WAIT_S = 120
+
+
+def _wait_for(what, holds, timeout=60):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if holds():
+            return
+        time.sleep(0.002)
+    raise AssertionError(f"never saw: {what}")
+
+
+def _decode_keys(cfg, prompts, outs):
+    """``decode_keys_attended`` of exactly the steps these outputs need:
+    a request's token ``i >= 1`` comes from a step at position ``len(prompt)
+    + i - 1`` that scores ``position + 1`` keys a layer. A row that was
+    live in one step more reads above this."""
+    return cfg.n_layers * sum(
+        len(p) + i for p, toks in zip(prompts, outs)
+        for i in range(1, len(toks)))
+
+
+def _dense_engine():
+    def want(prompt, toks, temperature, seed):
+        return _sampled_reference(PARAMS_A, prompt, len(toks), temperature,
+                                  seed, max_len=32)
+
+    return (lambda **kw: _server(max_slots=3, **kw)), want, CFG
+
+
+def _recurrent_engine():
+    import test_falcon_h1 as fh_t       # its tiny config, weights, reference
+
+    def want(prompt, toks, temperature, seed):
+        # Teacher-forced along what was served: each token must be the
+        # sampler's choice on the plain reference's logits there.
+        logits = fh_t._ref_logits(prompt + toks[:-1])[len(prompt) - 1:]
+        return [int(t) for t in _choose(logits, temperature, seed,
+                                        np.arange(len(toks)))]
+
+    return (lambda **kw: fh_t._server(max_slots=3, max_len=32, **kw)), want, \
+        fh_t.CFG
+
+
+@pytest.mark.parametrize("engine", [_dense_engine, _recurrent_engine],
+                         ids=["dense", "recurrent_state"])
+def test_run_ahead_serves_the_plain_references_tokens(engine):
+    """Greedy and sampled rows of unequal lengths over fewer slots than
+    requests: rows join a batch whose step is in flight and leave it
+    while the next one is."""
+    make, want, cfg = engine()
+    rng = np.random.default_rng(17)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=n)]
+               for n in (5, 9, 3, 12, 7, 4, 10)]
+    lengths = [9, 3, 6, 2, 8, 5, 7]
+    temps = [0.0, 0.8, 0.0, 0.8, 0.7, 0.0, 0.9]
+    srv = make()
+    try:
+        futs = [srv.submit(p, max_new_tokens=n, temperature=t, seed=100 + i)
+                for i, (p, n, t) in enumerate(zip(prompts, lengths, temps))]
+        outs = [f.result(timeout=WAIT_S)["tokens"] for f in futs]
+        st = srv.stats()
+    finally:
+        srv.stop()
+    for i, (p, n, t, toks) in enumerate(zip(prompts, lengths, temps, outs)):
+        assert len(toks) == n and toks == want(p, toks, t, 100 + i), i
+    assert st["steps_ahead"] > 0 and st["rows_wasted"] == 0
+    assert st["decode_keys_attended"] == _decode_keys(cfg, prompts, outs)
+
+
+def test_an_eos_read_one_step_late_drops_the_id_of_the_step_ahead():
+    """A row that ends by ``eos_id`` was already live in the step ahead:
+    that id never reaches its output, the step is counted as dispatched
+    (``rows_wasted``, the keys it read), and the slot's next tenant, who
+    is admitted behind that step, decodes what it decodes alone."""
+    rng = np.random.default_rng(23)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=n)]
+               for n in (10, 6, 8, 5)]
+    refs = [_reference(PARAMS_A, p, 8) for p in prompts]
+    eos = refs[0][2]
+
+    def cut(ref):
+        return ref[:ref.index(eos) + 1] if eos in ref else ref
+
+    srv = _server(max_slots=2, eos_id=eos)
+    try:
+        futs = [srv.submit(p, max_new_tokens=8) for p in prompts]
+        outs = [f.result(timeout=WAIT_S)["tokens"] for f in futs]
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert outs == [cut(ref) for ref in refs]
+    assert outs[0][-1] == eos and len(outs[0]) <= 3
+    # Wasted: ended by eos at a token a decode step chose (not the
+    # prefill's), with room left under max_new_tokens.
+    wasted = [(p, toks) for p, toks in zip(prompts, outs)
+              if toks[-1] == eos and 1 < len(toks) < 8]
+    assert st["rows_wasted"] == len(wasted) >= 1
+    assert st["decode_keys_attended"] == _decode_keys(CFG, prompts, outs) + \
+        CFG.n_layers * sum(len(p) + len(toks) for p, toks in wasted)
+    assert st["completed"] == 4 and st["kv_blocks_in_use"] == 0
+
+
+@pytest.mark.parametrize("lengths", [(5,), (2, 6, 4), (1, 3)],
+                         ids=["alone", "three_rows", "ends_at_its_prefill"])
+def test_a_row_at_max_new_tokens_is_not_in_the_step_ahead(lengths):
+    """The host knows that end ahead: no row is live in a step after its
+    last token, so the steps read exactly the keys the outputs need."""
+    prompts = [list(range(3 + i, 9 + 2 * i)) for i in range(len(lengths))]
+    srv = _server()
+    try:
+        futs = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, lengths)]
+        outs = [f.result(timeout=WAIT_S)["tokens"] for f in futs]
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert [len(toks) for toks in outs] == list(lengths)
+    assert st["rows_wasted"] == 0
+    assert st["decode_keys_attended"] == _decode_keys(CFG, prompts, outs)
+    if len(lengths) == 1:
+        assert st["steps"] == lengths[0] - 1
+
+
+@pytest.mark.parametrize("how", ["block_pressure", "with_its_id_in_flight"])
+def test_a_preemption_with_a_step_in_flight_replays_to_the_same_tokens(how):
+    """The victim's in-flight id is dropped with its blocks; the replay
+    serves identical tokens and a stream skips it."""
+    rng = np.random.default_rng(11)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=8)]
+               for _ in range(4)]
+    refs = [_reference(PARAMS_A, p, 8) for p in prompts]
+    if how == "block_pressure":
+        # test_preemption_under_block_pressure_...'s deadlock: the grants
+        # that fail are those of the step ahead.
+        srv = _server(kv_block_size=4, kv_blocks=12)
+    else:
+        # Scripted on the engine thread, between two iterations: the
+        # youngest row is preempted while the step it is live in has
+        # been dispatched and not fetched.
+        srv = _server(kv_block_size=4)
+        tick, hit = srv._prefill_tick, []
+
+        def tick_then_preempt():
+            ran = tick()
+            rows = [r for r in srv._active.values()
+                    if r.ahead and len(r.out) >= 3]
+            if rows and not hit:
+                hit.append(max(rows, key=lambda r: r.enqueue_s))
+                srv._preempt(hit[0])
+            return ran
+
+        srv._prefill_tick = tick_then_preempt
+    try:
+        pairs = [srv.submit_stream(p, max_new_tokens=8) for p in prompts]
+        outs = [f.result(timeout=WAIT_S)["tokens"] for f, _ in pairs]
+        streamed = [s.tokens() for _, s in pairs]
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert outs == refs == streamed
+    assert st["preempted"] >= 1 and st["steps_ahead"] > 0
+    assert st["rows_wasted"] == 0 and st["kv_blocks_in_use"] == 0
+
+
+@pytest.mark.parametrize("how", ["stop", "fault_at_dispatch",
+                                 "fault_at_fetch"])
+def test_stop_and_a_fault_with_a_step_in_flight_settle_every_future(how):
+    """``stop()`` finishes what was admitted (the step in flight is
+    fetched, not abandoned); an engine fault fails it. No future hangs."""
+    prompts = [list(range(2 + i, 12 + i)) for i in range(6)]
+    srv = _server(max_slots=2, max_new_tokens=16)
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("injected engine fault")
+
+    try:
+        futs = [srv.submit(p, max_new_tokens=16) for p in prompts]
+        _wait_for("a step dispatched ahead",
+                  lambda: srv.stats()["steps_ahead"] >= 2)
+        if how == "fault_at_dispatch":
+            srv.pool.decode_step = boom
+        elif how == "fault_at_fetch":
+            srv._fetch = boom
+        else:
+            srv.stop(timeout=WAIT_S)
+        srv._engine.join(WAIT_S)
+        assert not srv._engine.is_alive()
+        done, failed = [], []
+        for f in futs:
+            try:
+                done.append(f.result(timeout=WAIT_S)["tokens"])
+            except (ServerStoppedError, RuntimeError) as e:
+                failed.append(e)
+    finally:
+        srv.stop()
+    assert len(done) + len(failed) == len(futs) and not srv._ahead
+    if how == "stop":
+        # Admitted requests complete; the queued ones fail fast.
+        assert done and done == [
+            _reference(PARAMS_A, p, 16) for p in prompts[:len(done)]]
+        assert all(isinstance(e, ServerStoppedError) for e in failed)
+    else:
+        assert failed and all("injected" in str(e) for e in failed)
+        with pytest.raises(ServerStoppedError):
+            srv.submit(prompts[0])
+
+
+def test_two_versions_across_a_publish_each_run_their_own_chain():
+    """While two versions are live each has its own step in flight, and a
+    row takes its token from its own version's ids."""
+    srv = _server(max_slots=4, max_len=48)
+    dispatch, in_flight = srv._dispatch, []
+
+    def spy(*args):
+        ahead = dispatch(*args)
+        in_flight.append(sorted(srv._ahead))
+        return ahead
+
+    srv._dispatch = spy
+    try:
+        prompts = [list(range(3 + i, 13 + i)) for i in range(4)]
+        futs = [srv.submit(p, max_new_tokens=30) for p in prompts[:2]]
+        _wait_for("version 1 decoding", lambda: srv.stats()["steps"] >= 2)
+        assert srv.publish(PARAMS_B) == 2
+        futs += [srv.submit(p, max_new_tokens=12) for p in prompts[2:]]
+        resps = [f.result(timeout=WAIT_S) for f in futs]
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert [r["version"] for r in resps] == [1, 1, 2, 2]
+    for p, r in zip(prompts, resps):
+        assert r["tokens"] == _reference(
+            PARAMS_A if r["version"] == 1 else PARAMS_B, p, len(r["tokens"]))
+    assert [1, 2] in in_flight
+    assert st["rows_wasted"] == 0 and st["steps_ahead"] > 0
+
+
+def test_steps_ahead_are_the_steps_less_those_with_nothing_in_flight():
+    """One request at a time, each after the last has finished: a
+    request's first decode step finds nothing in flight, every other was
+    dispatched before the one before it was fetched."""
+    lengths = (5, 4, 7)
+    srv = _server()
+    try:
+        for i, n in enumerate(lengths):
+            srv.submit(list(range(4 + i, 12 + i)),
+                       max_new_tokens=n).result(timeout=WAIT_S)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    assert st["steps"] == sum(n - 1 for n in lengths)
+    assert st["steps_ahead"] == st["steps"] - len(lengths)
+    reg = telemetry_metrics.get_registry()
+    for name in ("steps_ahead", "rows_wasted"):
+        assert reg.get(f"fed_serving_{name}_total").labels(
+            server="default").value() >= st[name]
+
+
+def test_every_emitted_token_passes_the_sample_seam_exactly_once():
+    """The benchmark's control wraps ``_sample``: an id dropped with the
+    step ahead never passes it, every served token does, once, and what
+    the wrapper makes of it is what is served."""
+    rng = np.random.default_rng(29)
+    prompts = [[int(t) for t in rng.integers(1, 255, size=n)]
+               for n in (6, 9, 4, 7, 5)]
+
+    def run(eos_id):
+        srv = _server(max_slots=2, eos_id=eos_id)
+        sample_fn, seen = srv._sample, {}
+
+        def altered(chosen, req):
+            seen.setdefault(req.rid, []).append(int(chosen))
+            return (sample_fn(chosen, req) + 1) % CFG.vocab
+
+        srv._sample = altered
+        try:
+            # The first alone (an altered token feeds the next step only
+            # where nothing was in flight: its schedule is fixed), then
+            # the rest over two slots.
+            resps = [srv.submit(prompts[0], max_new_tokens=8).result(WAIT_S)]
+            futs = [srv.submit(p, max_new_tokens=8) for p in prompts[1:]]
+            resps += [f.result(timeout=WAIT_S) for f in futs]
+            return resps, seen, srv.stats()
+        finally:
+            srv.stop()
+
+    first = run(None)[0][0]["tokens"]
+    eos = first[2]
+    assert eos not in first[:2]
+    resps, seen, st = run(eos)
+    for r in resps:
+        assert r["tokens"] == [(t + 1) % CFG.vocab
+                               for t in seen[r["request_id"]]]
+    assert resps[0]["tokens"] == first[:3] and st["rows_wasted"] >= 1
+    assert sum(map(len, seen.values())) == st["tokens_out"]
 
 
 # ---------------------------------------------------------------------------

@@ -176,7 +176,7 @@ def test_span_goes_to_the_ring_as_before(traced):
 
 PROMPTS = [[1, 2, 3, 4], [5, 6, 7, 8, 9, 10, 11], [3, 1, 4, 1, 5, 9, 2, 6, 5],
            [2, 7]]
-PER_ITERATION = ("build", "dispatch", "fetch", "emit")
+PER_STEP = ("dispatch", "fetch", "emit")
 
 
 def _serve_mixed():
@@ -210,8 +210,13 @@ def test_engine_phases_tile_the_iteration_and_keep_the_tokens():
     assert stats["steps"] > 0
     # The token is chosen inside the step's program: no host phase samples.
     assert "fed:serve:sample" not in phases
-    for name in PER_ITERATION:
+    # Every step is dispatched once and, an iteration later, fetched and
+    # emitted once; an iteration that only drains the step in flight (its
+    # rows' ends known ahead) builds and dispatches nothing.
+    for name in PER_STEP:
         assert phases["fed:serve:" + name]["count"] == stats["steps"], name
+    assert phases["fed:serve:build"]["count"] > stats["steps"]
+    assert 0 < stats["steps_ahead"] < stats["steps"]
     for name in ("admit", "prefill_chunk", "idle"):
         assert phases["fed:serve:" + name]["count"] >= 1, name
     assert not [n for n in phases if not n.startswith("fed:serve:")]
@@ -359,7 +364,7 @@ def test_named_scopes_are_metadata_on_the_lowered_programs():
     assert "serve/decode_step" in pool._decode_step_fn.lower(
         PARAMS, pool.kv, rows, rows,
         jnp.zeros((2, pool.blocks_per_row), jnp.int32),
-        jnp.zeros((3, 2), jnp.int32), {}, None,
+        jnp.zeros((3, 2), jnp.int32), rows, jnp.ones((2,), bool), {}, None,
     ).as_text(debug_info=True)
     # A decorator, not a wrapper program: the jitted functions keep the
     # names the profile and `compiled_programs` know them by.
