@@ -116,6 +116,76 @@ class DeviceTrace:
         return out if out["devices"] else None
 
 
+def breakdown_of(reduced):
+    """The result line's ``breakdown`` from a reduction (None without one):
+    the ten largest of the device's operations (each ``<scope>:<op>``), of
+    its named scopes and of the idle gaps' causes."""
+    if not reduced:
+        return None
+    return {key: reduced[key]
+            for key in ("device_ops", "device_scopes", "idle_gaps")}
+
+
+class ProgramRecord:
+    """What the program measured of itself over the window, for
+    ``facts["program"]``: its phases' durations (``tracing.phase``), its
+    wire spans of 1 MiB and more (``tracing.span`` / ``record``) and, for a
+    kind that hands them in, the growth of every counter of the engine's
+    ``stats()``. Every kind fills it the same way: ``open()`` before the
+    window (the record starts empty, ``tracing.enable()``), ``close()`` as
+    the window closes (``tracing.disable()``), ``facts(...)`` after it.
+    Off (``--trace 0``) each call does nothing, so an untraced run
+    executes nothing of this."""
+
+    SPAN_MIN_BYTES = 1 << 20
+
+    def __init__(self, on):
+        self.on = bool(on)
+        self.phases, self.spans = {}, []
+
+    def open(self):
+        if not self.on:
+            return
+        from rayfed_tpu import tracing
+
+        tracing.clear()
+        tracing.enable()
+
+    def close(self):
+        """Read the record where the kind reads its counters, and stop
+        recording (a drain may follow the window; it is in no number)."""
+        if not self.on:
+            return
+        from rayfed_tpu import tracing
+
+        tracing.disable()
+        self.phases = tracing.phase_summary()
+        self.spans = [
+            {"kind": s.kind, "nbytes": int(s.nbytes),
+             "duration_s": s.duration_s,
+             # A "recv" is an arrival event without a duration, but for
+             # frames of 1 MiB and more, which the program marks.
+             "timed": s.kind != "recv" or bool(s.extra.get("timed"))}
+            for s in tracing.get_spans()
+            if s.nbytes >= self.SPAN_MIN_BYTES]
+
+    def facts(self, before=None, after=None, **more):
+        """``{"phases", "spans"}``, with ``stats`` where the engine's
+        counters before and after the window are handed in (the growth of
+        every integer key, not a chosen few), and whatever else the kind
+        knows of the recorded stretch (``rounds``). None when off."""
+        if not self.on:
+            return None
+        out = dict(more, phases=self.phases, spans=self.spans)
+        if before is not None and after is not None:
+            # Counters are integers; a gauge among them (``pending``,
+            # ``kv_blocks_free``) has a difference that means nothing.
+            out["stats"] = {
+                k: v - before.get(k, 0) for k, v in after.items()
+                if isinstance(v, int) and not isinstance(v, bool)}
+        return out
+
+
 def annotate(name):
     """A host span on the profiler's clock, from the benchmark's files."""
     import jax

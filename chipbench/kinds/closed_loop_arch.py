@@ -35,7 +35,11 @@ What the engine counted over the window goes into ``facts`` as deltas of
 ``stats()`` (``stats``: read as the window closes, not after the drain
 that follows it), beside the model as run and its precision, for the
 readers under ``layers/``; from a traced run also the device's own time
-in each of the engine's programs (``programs``).
+in each of the engine's programs (``programs``) and what the program
+recorded of itself over the window (``program``, by
+``common.ProgramRecord``: the phases' durations, the wire's spans, and
+the growth of EVERY integer counter of ``stats()``, read as the window
+closes like ``stats``, which stays the closed tuple its readers know).
 """
 
 from __future__ import annotations
@@ -197,10 +201,8 @@ def run(ctx):
     ctx.say("warm", classes=warmed,
             compiled_programs=before["compiled_programs"],
             requests=len(requests["requests"]))
-    if ctx.trace:
-        from rayfed_tpu import tracing
-
-        tracing.enable()
+    record = common.ProgramRecord(ctx.trace)
+    record.open()
     compiles_before = ctx.compiles
     setup_s = time.time() - ctx.spec["t0"]
     trace = common.DeviceTrace(ctx) if ctx.trace else None
@@ -219,7 +221,7 @@ def run(ctx):
     # (fewer and fewer rows live) are in no rate.
     at_close = {}
     closer = threading.Timer(
-        ctx.seconds, lambda: at_close.update(srv.stats()))
+        ctx.seconds, lambda: (at_close.update(srv.stats()), record.close()))
     closer.daemon = True
 
     @fed.remote
@@ -237,10 +239,6 @@ def run(ctx):
     after = srv.stats()
     compiles_in_window = ctx.compiles - compiles_before
     peak = common.memory_peak_bytes()
-    if ctx.trace:
-        from rayfed_tpu import tracing
-
-        tracing.disable()
 
     # ---- after the window ------------------------------------------------
     done = [r for r in win["records"] if r.get("tokens") is not None]
@@ -299,11 +297,8 @@ def run(ctx):
     programs = traced_programs(trace.dir) if trace else {}
     reduced = trace.reduce() if trace else None
     device = {"memory_peak_bytes": peak}
-    breakdown = None
     if reduced:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
-        breakdown = {"device_ops": reduced["device_ops"],
-                     "idle_gaps": reduced["idle_gaps"]}
     stats = {k: at_close.get(k, 0) - before.get(k, 0) for k in STATS_DELTAS}
     facts = dict(
         win["facts"], kind=ctx.mix["kind"], window_s=win["window_s"],
@@ -313,6 +308,7 @@ def run(ctx):
         reference=ctx.spec["reference"],
         kv_block_size=ctx.mix["serving"]["kv_block_size"],
         trace=reduced, programs=programs, device_kind=ctx.device["kind"],
+        program=record.facts(before, at_close),
     )
     if stats["steps"]:
         # A fact of the configuration and the window's occupancy, not a
@@ -333,5 +329,5 @@ def run(ctx):
         "attempted": win["attempted"], "failed": win["failed"],
         "end_to_end": end_to_end, "facts": facts, "checks": checks,
         "notes": notes, "setup_parts": ctx.setup_parts, "device": device,
-        "breakdown": breakdown,
+        "breakdown": common.breakdown_of(reduced),
     }

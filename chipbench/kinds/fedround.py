@@ -24,6 +24,14 @@ gradient's norm as the optimizer got it (from AdamW's first moment after
 one step), the norm of the parameters' change after three steps, both by
 the worst leaf. Besides: the last round's aggregate against a NumPy mean,
 where the arrival lives, and compilations inside the window (none).
+
+A traced run (``--trace 1``) also turns the program's own recording on at
+the lead for the window (``common.ProgramRecord``: ``tracing.enable()``
+before it, ``tracing.disable()`` after) and passes what it recorded on as
+``facts["program"]``: the phases' durations over the window's rounds
+(``fed:wire:encode``, ``:recv``, ``:deserialize``, ``:place``,
+``fed:agg:reduce``), the wire's spans of 1 MiB and more, and how many
+rounds they cover. An untraced run executes nothing of it.
 """
 
 from __future__ import annotations
@@ -266,6 +274,19 @@ def _train_checks(probe, ref, LIMITS, label=""):
     ]
 
 
+def _step_gaps_note(reduced):
+    """What the booking rule cannot tell (``trace_reduce.py``): the idle
+    time between two train steps, all of it inside ``chipbench:local_steps``,
+    by the name it was booked to: the pieces that lie inside a wire span of
+    another thread beside those that lie in none, so that the next issue
+    sees whether the wire slows the steps it overlaps."""
+    inside = reduced["idle_under"].get("chipbench:local_steps", {})
+    return "idle between train steps (inside chipbench:local_steps), " \
+        "by the span booked: " + (", ".join(
+            f"{name} {runs} x {1e3 * s / runs:.3f} ms"
+            for name, (runs, s) in sorted(inside.items())) or "none")
+
+
 def run(ctx):
     import jax
 
@@ -327,6 +348,8 @@ def run(ctx):
     n_landed_before = len(_LOCAL.get("landed", []))
     setup_s = time.time() - ctx.spec["t0"]
     trace = common.DeviceTrace(ctx) if (ctx.trace and ctx.is_lead) else None
+    record = common.ProgramRecord(ctx.trace and ctx.is_lead)
+    record.open()
 
     # ---- the window -----------------------------------------------------
     rounds, overran = [], 0
@@ -347,6 +370,7 @@ def run(ctx):
         else:
             overran += 1
     window_s = time.perf_counter() - window_t0
+    record.close()
     compiles_in_window = ctx.compiles - compiles_before
     peak = common.memory_peak_bytes()
 
@@ -405,11 +429,9 @@ def run(ctx):
     reduced = trace.reduce(kernels=("flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv")) if trace else None
     device = {"memory_peak_bytes": peak}
-    breakdown = None
     if reduced:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
-        breakdown = {"device_ops": reduced["device_ops"],
-                     "idle_gaps": reduced["idle_gaps"]}
+        notes.append(_step_gaps_note(reduced))
     facts = {
         "kind": "fedround", "round_s": round_s, "train_s": train_s,
         "push_place_s": [t - r[0] for t, r in zip(land, rounds)],
@@ -418,6 +440,7 @@ def run(ctx):
         "device_kind": ctx.device["kind"], "model": ctx.model,
         "update_bytes": round_check["update_bytes"],
         "trace": reduced, "traced_rounds": 1 if reduced else 0,
+        "program": record.facts(rounds=n),
         "chip_parties": len(ctx.chip_parties),
         # flash's call shape on one chip: the batch rows, and the heads of
         # the party mesh's "model" axis (2 wide where the chips are even).
@@ -431,7 +454,7 @@ def run(ctx):
         "end_to_end": {"round_tokens_per_s": rate, "setup_s": setup_s},
         "facts": facts, "checks": checks, "notes": notes,
         "setup_parts": ctx.setup_parts, "device": device,
-        "breakdown": breakdown,
+        "breakdown": common.breakdown_of(reduced),
     }
     fed.get(finish.party(lead).remote(None))
     return result

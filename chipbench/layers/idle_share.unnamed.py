@@ -5,20 +5,21 @@ does not start with ``fed:`` (runtime TraceMes such as
 that the traced window cuts, whose span the profile does not hold), and
 ``fed:serve:fetch``, the engine's wait for the step's token ids.
 
-On the chip the gap after a decode step is booked to the fetch or to the
-``np.asarray`` TraceMe inside it (whichever the gap lies wholly inside: the
-shorter), because the device's lines stand 0.5-2.3 ms before the host's
-in the profile (chipbench/trace_reduce.py): on the host's clock most of
-that gap is the dispatch of the next step, which ``idle_share.schedule``
-counts. Until the reducer aligns the two, read this with ``.schedule`` as
-one sum, the idle time of an iteration's host work; the split between the
-two moves with the skew. It is also a guard: it rises when a refactor
-drops a span, because the runtime's names then take the gaps.
+Its value changed by definition in PR 38 (``idle_share.schedule``'s
+docstring has the rule): a piece of a gap inside ``fed:serve:fetch`` and
+the runtime's ``np.asarray(jax.Array)`` is now the fetch's and not the
+shorter TraceMe's (it counts here either way); a piece inside a phase of
+``idle_share.schedule`` and a runtime event (``:dispatch`` and
+``PjitFunction(decode_step)``) is now that phase's and left this reader.
+What stays here beside the fetch: a lull that the traced window cuts
+(``window edge``) and time no span of the program covers. It is also a
+guard: it rises when a refactor drops a span, because the runtime's names
+then take the pieces. The profile's clocks (ROADMAP B11) still move short
+gaps between this reader and ``.schedule``: their sum is exact.
 
-Every gap of the window booked to one name, all names read; 0.0 where the
-program has spans and no gap is theirs, None only without a trace or for a
-program without spans: all as the docstring of chipbench/trace_reduce.py
-says."""
+Every gap of the window booked, all names read; 0.0 where the program has
+spans and nothing is theirs, None only without a trace or for a program
+without spans: all as the docstring of chipbench/trace_reduce.py says."""
 
 from chipbench.trace_reduce import idle_share
 

@@ -505,6 +505,21 @@ def main(argv=None):
     }
     if args.trace == 1 and res.get("breakdown"):
         line["breakdown"] = res["breakdown"]
+    # Each number compared beside its limit: the last key of the result's
+    # line and the last lines of standard error, which is what the
+    # driver's record keeps of a run that is not correct.
+    compared = {c["name"]: {"value": c["value"], "limit": c["limit"],
+                            "ok": c["ok"]}
+                for c in res.get("checks", [])}
+    line["compared"] = compared
+    sys.stdout.flush()
+    for name, c in compared.items():
+        print(f"chipbench: compared {name} = {c['value']} (limit "
+              f"{c['limit']}): {'ok' if c['ok'] else 'OVER ITS LIMIT'}",
+              file=sys.stderr)
+    print(f"chipbench: correct = {line['correct']} (workload "
+          f"{args.workload}, seed {args.seed}, trace {args.trace})",
+          file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -172,10 +172,8 @@ def run(ctx, kind):
     before = srv.stats()
     ctx.say("warm", classes=warmed, compiled_programs=before[
         "compiled_programs"], requests=len(plan["requests"]))
-    if ctx.trace:
-        from rayfed_tpu import tracing
-
-        tracing.enable()
+    record = common.ProgramRecord(ctx.trace)
+    record.open()
     compiles_before = ctx.compiles
     setup_s = time.time() - ctx.spec["t0"]
     trace = common.DeviceTrace(ctx) if ctx.trace else None
@@ -200,6 +198,7 @@ def run(ctx, kind):
     if tracer:
         tracer.join()
     after = srv.stats()
+    record.close()
     compiles_in_window = ctx.compiles - compiles_before
     peak = common.memory_peak_bytes()
     queue_wait = []
@@ -210,7 +209,6 @@ def run(ctx, kind):
             at = {e.event: e.t_s for e in events}
             if "enqueue" in at and "admit" in at:
                 queue_wait.append(at["admit"] - at["enqueue"])
-        tracing.disable()
 
     # ---- after the window ------------------------------------------------
     done = [r for r in win["records"] if r.get("tokens") is not None]
@@ -258,11 +256,8 @@ def run(ctx, kind):
             + " program gaps: " + repr([round(r["gap"], 4) for r in rows]))
     reduced = trace.reduce() if trace else None
     device = {"memory_peak_bytes": peak}
-    breakdown = None
     if reduced:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
-        breakdown = {"device_ops": reduced["device_ops"],
-                     "idle_gaps": reduced["idle_gaps"]}
     steps = after["steps"] - before["steps"]
     facts = dict(
         win["facts"], kind=ctx.mix["kind"], window_s=win["window_s"],
@@ -271,6 +266,9 @@ def run(ctx, kind):
         preempted=after["preempted"] - before["preempted"],
         queue_wait_s=queue_wait, trace=reduced,
         device_kind=ctx.device["kind"],
+        # What the program measured of itself, from the warm-up's end to
+        # the drain's (where ``steps`` is read too); None untraced.
+        program=record.facts(before, after),
     )
     ctx.say("window", attempted=win["attempted"], failed=win["failed"],
             steps=steps, compiles_in_window=compiles_in_window,
@@ -281,7 +279,7 @@ def run(ctx, kind):
         "attempted": win["attempted"], "failed": win["failed"],
         "end_to_end": end_to_end, "facts": facts, "checks": checks,
         "notes": notes, "setup_parts": ctx.setup_parts, "device": device,
-        "breakdown": breakdown,
+        "breakdown": common.breakdown_of(reduced),
     }
 
 

@@ -54,7 +54,15 @@ def test_rehearsal_ends_in_the_contracts_line(tmp_path, cell, trace):
     run = run_cell(tmp_path, "--workload", cell, "--seed", "2147483655",
                    "--seconds", "2", "--trace", str(trace))
     line = last_line(run)
-    assert set(line) - {"breakdown"} == KEYS
+    assert set(line) - {"breakdown", "compared"} == KEYS
+    # Each number compared beside its limit: the line's last key, and the
+    # last lines of standard error.
+    assert list(line)[-1] == "compared" and line["compared"]
+    for name, c in line["compared"].items():
+        assert set(c) == {"value", "limit", "ok"} and c["ok"] is True
+        assert f"chipbench: compared {name} = " in run.stderr
+    assert run.stderr.strip().splitlines()[-1].startswith(
+        "chipbench: correct = True")
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
     # A CPU run prints no device metric, and its profile has no device
@@ -197,15 +205,17 @@ def test_trace_reduction_on_made_and_recorded_traces():
             ["chipbench:wait_push", 9 * ms, 4 * ms],
             ["outer", 0, 20 * ms]]},
     ]
-    out = trace_reduce.reduce(lines, window_s=0.02, kernels=("flash_fwd",))
+    out = trace_reduce.reduce(lines, kernels=("flash_fwd",))
     assert out["busy_s"] == pytest.approx(0.011)       # [0,10] and [12,13]
     # PR 35: the window is read from the trace (without the span
     # chipbench:traced it is the device's own 13 ms), not handed in.
     assert out["window_s"] == 0.013 and out["devices"] == 1
     assert out["kernels"] == {"flash_fwd": {"seconds": pytest.approx(0.004),
                                             "calls": 2.0}}
-    assert out["device_ops"][0] == ["flash_fwd.3", pytest.approx(0.004)]
-    assert all(not n.startswith("while") for n, _ in out["device_ops"])
+    # PR 38: an operation is named with its scope; a made line has none.
+    assert out["device_ops"][0] == ["(no scope):flash_fwd.3",
+                                    pytest.approx(0.004)]
+    assert all(":while" not in n for n, _ in out["device_ops"])
     assert out["idle_gaps"] == [["chipbench:wait_push",
                                  pytest.approx(0.002)]]
     path = os.path.join(HERE, "data", "fedround_v5e_events.json.gz")
@@ -218,6 +228,54 @@ def test_trace_reduction_on_made_and_recorded_traces():
     assert set(out["kernels"]) == {"flash_fwd", "flash_bwd_dq",
                                    "flash_bwd_dkv"}
     assert len(out["device_ops"]) <= 10 and len(out["idle_gaps"]) <= 10
+
+
+@pytest.mark.parametrize("cell", [FED_CELL, SERVE_CELL, CELLS[-1]])
+def test_a_traced_rehearsal_passes_the_programs_record_on(tmp_path, cell):
+    """``facts["program"]`` (``common.ProgramRecord``): what the program
+    measured of itself over the window, in every kind; an untraced run
+    holds none of it."""
+    run_cell(tmp_path, "--workload", cell, "--seed", "38", "--seconds", "3",
+             "--trace", "1")
+    run_dir = next((tmp_path / "out" / cell).iterdir())
+    facts = json.load(open(run_dir / "alice.result.json"))["facts"]
+    program = facts["program"]
+    assert {"phases", "spans"} <= set(program)
+    for phase in program["phases"].values():
+        assert set(phase) == {"count", "seconds", "max_s"}
+    if cell == FED_CELL:
+        assert program["rounds"] >= 3
+        assert {"fed:wire:encode", "fed:wire:recv", "fed:wire:place",
+                "fed:agg:reduce"} <= set(program["phases"])
+        recv = [s for s in program["spans"] if s["kind"] == "recv"]
+        assert recv and all(s["timed"] and s["nbytes"] >= 1 << 20
+                            and s["duration_s"] > 0 for s in recv)
+        assert {s["kind"] for s in program["spans"]} >= {"send", "decode"}
+    else:
+        assert {"fed:serve:build", "fed:serve:dispatch",
+                "fed:serve:fetch"} <= set(program["phases"])
+        # Every integer counter of stats(), not a chosen few.
+        assert {"steps", "steps_ahead", "rows_wasted", "kv_blocks_attended",
+                "kv_blocks_slab", "fetch_bytes", "publish_cast_bytes",
+                "chunk_blocks_read"} <= set(program["stats"])
+        assert 0 < program["stats"]["steps_ahead"] <= program["stats"]["steps"]
+        assert program["spans"] == []
+    # The readers of the record find what they read, whatever the platform.
+    from chipbench.run import load_reader
+    names = [m["name"] for m in json.load(open(os.path.join(
+        ROOT, "BENCHMARK.json")))["per_layer"] if cell in m["workloads"]
+        and m["name"].split(".")[0] in (
+            "wire_recv_gbps", "wire_encode_ms", "steps_ahead_share",
+            "kv_blocks_share", "host_iter_ms")]
+    assert len(names) == (2 if cell == FED_CELL else 3)
+    for name in names:
+        assert load_reader(name)(facts) > 0, name
+    run_cell(tmp_path, "--workload", cell, "--seed", "38", "--seconds", "2",
+             "--trace", "0", "--out", str(tmp_path / "untraced"))
+    run_dir = next((tmp_path / "untraced" / cell).iterdir())
+    facts = json.load(open(run_dir / "alice.result.json"))["facts"]
+    assert facts["program"] is None
+    assert [load_reader(name)(facts) for name in names] == [None] * len(names)
 
 
 def test_flops_against_hand_worked_counts():

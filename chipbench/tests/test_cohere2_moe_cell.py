@@ -154,8 +154,55 @@ def test_the_configuration_holds_the_published_keys_and_states_its_cut():
                 "num_experts_per_tok", "num_shared_experts",
                 "sliding_window"):
         assert model[key] == config[key], key
+    # The mean's limit is set for a sample of this many requests, those
+    # whose gaps sum highest left out, and the kind reads all three here.
     assert (0 < config["limits"]["served_logit_gap_mean"]
             < config["limits"]["served_logit_gap"] < 1)
+    # The limits are set for a sample of this many requests, those whose
+    # gaps sum highest left out, and the kind reads both here.
+    assert config["limits"]["sample_requests"] > 6
+    assert 0 < config["limits"]["worst_requests"] < 4
+
+
+ROWS = [{"gap": 1.12, "mean": 0.005, "n": 229, "c_gap": 0.3, "c_mean": 0.02},
+        {"gap": 0.28, "mean": 0.0737, "n": 115, "c_gap": 0.5, "c_mean": 0.1},
+        {"gap": 0.03, "mean": 0.0001, "n": 449, "c_gap": 0.4, "c_mean": 0.03},
+        {"gap": 0.0, "mean": 0.0, "n": 203, "c_gap": 0.9, "c_mean": 0.02}]
+
+
+@pytest.mark.parametrize("worst, less, ok, widest, mean", [
+    (None, "", False, 1.12, (0.005 * 229 + 0.0737 * 115 + 0.0001 * 449) / 996),
+    (1, "_less_worst", False, 1.12, (0.005 * 229 + 0.0001 * 449) / 881),
+    (2, "_less_worst", True, 0.03, 0.0001 * 449 / 652),
+    (4, "_less_worst", False, None, None),
+])
+def test_the_gaps_with_and_without_the_worst_requests(worst, less, ok,
+                                                      widest, mean):
+    """One request of near-tied tokens is most of a sample's sum and one
+    token on a router's tie its widest gap: over every request a sound
+    sample fails both limits, less those two requests it passes; a control
+    that moves every request fails either way, and ITS worst requests are
+    the ones left out of its numbers."""
+    from chipbench.kinds.closed_loop_moe import gap_checks
+
+    limits = {"served_logit_gap": 0.8, "served_logit_gap_mean": 0.0015}
+    if worst is not None:
+        limits["worst_requests"] = worst
+    checks = gap_checks(ROWS, limits)
+    assert [c["name"] for c in checks] == [
+        f"served_logit_gap.widest{less}", f"served_logit_gap.mean{less}"]
+    assert all(c["ok"] for c in checks) is ok
+    assert checks[0]["value"] == widest
+    assert checks[1]["value"] == (mean if mean is None
+                                  else pytest.approx(mean))
+    control = gap_checks(ROWS, limits, "control[fp8].", "c_gap", "c_mean")
+    assert control[0]["name"] == f"control[fp8].served_logit_gap.widest{less}"
+    assert control[1]["ok"] is False
+    if worst == 2:
+        # The control's sums: 4.58, 11.5, 13.47, 4.06: the middle two go.
+        assert control[0]["value"] == 0.9
+        assert control[1]["value"] == pytest.approx(
+            (0.02 * 229 + 0.02 * 203) / 432)
 
 
 def test_parameter_counts_against_the_hand_worked_ones():
