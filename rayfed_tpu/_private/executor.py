@@ -39,21 +39,29 @@ from __future__ import annotations
 
 import contextvars
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from queue import Queue
 from typing import Any, Callable, List, Optional, Sequence, Union
 
-from rayfed_tpu import tree_util
+from rayfed_tpu import tracing, tree_util
 
 
-def _resolve(obj: Any) -> Any:
+def _resolve(obj: Any, stamps: Optional[list] = None) -> Any:
     """Replace every Future leaf in a pytree with its result (blocking;
-    steals the producing task inline when it has not started yet)."""
+    steals the producing task inline when it has not started yet). A
+    traced task hands in ``stamps`` and gets its future arguments'
+    done-stamps appended (``tracing.task_arg_stamps``)."""
     def leaf(x: Any) -> Any:
         if isinstance(x, Future):
             if not x.done():
                 steal(x)
-            return x.result()
+            value = x.result()
+            if stamps is not None:
+                stamp = tracing.done_stamp(x)
+                if stamp is not None:
+                    stamps.append(stamp)
+            return value
         return x
 
     return tree_util.tree_map(leaf, obj)
@@ -96,14 +104,16 @@ class _StealableTask:
     claims first runs, the other does nothing."""
 
     __slots__ = ("fn", "args", "kwargs", "out", "num_returns",
-                 "_lock", "_claimed", "_ctx")
+                 "_lock", "_claimed", "_ctx", "_t_submit")
 
-    def __init__(self, fn, args, kwargs, out, num_returns, ctx=None):
+    def __init__(self, fn, args, kwargs, out, num_returns, ctx=None,
+                 t_submit=None):
         self.fn = fn
         self.args = args
         self.kwargs = kwargs
         self.out = out
         self.num_returns = num_returns
+        self._t_submit = t_submit
         self._lock = threading.Lock()
         self._claimed = False
         # Submitter's contextvar snapshot: pool workers (and thieves on
@@ -125,10 +135,10 @@ class _StealableTask:
     def _execute(self) -> None:
         if self._ctx is not None:
             self._ctx.run(_run_task, self.fn, self.args, self.kwargs,
-                          self.out, self.num_returns)
+                          self.out, self.num_returns, self._t_submit)
         else:
             _run_task(self.fn, self.args, self.kwargs, self.out,
-                      self.num_returns)
+                      self.num_returns, self._t_submit)
         # Drop payload refs promptly: the out-futures keep this shell
         # alive via their steal attribute until they are collected.
         self.fn = self.args = self.kwargs = self.out = self._ctx = None
@@ -166,17 +176,44 @@ def result_stealing(fut: Future, timeout: Optional[float] = None) -> Any:
     return fut.result(timeout)
 
 
+def _run_traced(fn: Callable, args: Sequence[Any], kwargs: Optional[dict],
+                t_submit: Optional[float]) -> Any:
+    """``_run_task``'s body while tracing is on. An accumulator only, no
+    profiler annotation: a task's span encloses user code and may last a
+    round (docs/observability.md). ``fed:task:queued``: submit -> here
+    (0 for the eager inline path, which passes no stamp)."""
+    tracing.observe(
+        "fed:task:queued",
+        0.0 if t_submit is None else time.perf_counter() - t_submit,
+    )
+    stamps: list = []
+    rargs = _resolve(list(args), stamps)
+    rkwargs = _resolve(kwargs or {}, stamps)
+    # The body reads its arguments' stamps through tracing.task_arg_stamps;
+    # a task run inline inside this one (eager, stolen) puts them back.
+    outer = tracing.swap_task_arg_stamps(stamps)
+    try:
+        return fn(*rargs, **rkwargs)
+    finally:
+        tracing.swap_task_arg_stamps(outer)
+
+
 def _run_task(
     fn: Callable,
     args: Sequence[Any],
     kwargs: Optional[dict],
     out: Union[Future, List[Future]],
     num_returns: int,
+    t_submit: Optional[float] = None,
 ) -> None:
+    traced = tracing._enabled
     try:
-        rargs = _resolve(list(args))
-        rkwargs = _resolve(kwargs or {})
-        result = fn(*rargs, **rkwargs)
+        if traced:
+            result = _run_traced(fn, args, kwargs, t_submit)
+        else:
+            rargs = _resolve(list(args))
+            rkwargs = _resolve(kwargs or {})
+            result = fn(*rargs, **rkwargs)
     except BaseException as e:  # noqa: BLE001 - stored, not swallowed
         if num_returns == 1:
             out.set_exception(e)
@@ -185,6 +222,8 @@ def _run_task(
                 f.set_exception(e)
         return
     if num_returns == 1:
+        if traced:
+            tracing.stamp_done(out)
         out.set_result(result)
     else:
         try:
@@ -199,6 +238,8 @@ def _run_task(
                 f.set_exception(e)
             return
         for f, item in zip(out, items):
+            if traced:
+                tracing.stamp_done(f)
             f.set_result(item)
 
 
@@ -285,6 +326,9 @@ class LocalExecutor:
             )
             return job
 
+        # fed:task:queued runs from here to the start of _run_task on a
+        # lane, a pool worker or a thief; stamped only while tracing is on.
+        t_submit = time.perf_counter() if tracing._enabled else None
         if lane is not None:
             from rayfed_tpu.exceptions import FedActorKilledError
 
@@ -295,7 +339,8 @@ class LocalExecutor:
                 if lane.killed:
                     fail_all(FedActorKilledError("actor was killed"))
                     return
-                task_ctx.run(_run_task, fn, args, kwargs, out, num_returns)
+                task_ctx.run(_run_task, fn, args, kwargs, out, num_returns,
+                             t_submit)
 
             if not lane.submit_thunk(thunk):
                 fail_all(FedActorKilledError("actor was killed"))
@@ -320,7 +365,7 @@ class LocalExecutor:
             _charge_slot()
             task = _StealableTask(
                 fn, args, kwargs, out, num_returns,
-                ctx=contextvars.copy_context(),
+                ctx=contextvars.copy_context(), t_submit=t_submit,
             )
             for f in out if isinstance(out, list) else [out]:
                 f._fedtpu_steal = task

@@ -37,12 +37,13 @@ import pickle
 import signal
 import threading
 import sys
+import time
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import rayfed_tpu._private.constants as constants
 import rayfed_tpu.config as fed_config
 import rayfed_tpu.utils as fed_utils
-from rayfed_tpu import sanitize
+from rayfed_tpu import sanitize, tracing
 from rayfed_tpu._private import executor
 from rayfed_tpu._private import kv as internal_kv
 from rayfed_tpu._private.call_holder import FedCallHolder
@@ -1023,6 +1024,11 @@ def get(
                 fed_object._cache_value_future(fut)
             futures.append(fut)
 
+    # fed:get:lag: for a value that was not ready when get was called,
+    # get's return minus the stamp of whoever resolved it: how late the
+    # driver saw a finished value. An accumulator, no span around the
+    # wait: the driver may sit here for a whole round.
+    late = [f for f in futures if not f.done()] if tracing._enabled else ()
     try:
         if timeout is None and on_missing == "raise":
             # Legacy fast path, bit-for-bit: block forever per future
@@ -1039,6 +1045,12 @@ def get(
         if sanitize.enabled():
             for value in values:
                 sanitize.probe_donation_alias(value)
+        if late:
+            now = time.perf_counter()
+            for f in late:
+                stamp = tracing.done_stamp(f)
+                if stamp is not None:
+                    tracing.observe("fed:get:lag", now - stamp[0])
         if single:
             # A dropped single object leaves nothing to index: it
             # resolves to the MISSING sentinel instead (the ergonomic

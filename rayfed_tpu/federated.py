@@ -47,10 +47,31 @@ _m_aggregates = telemetry_metrics.get_registry().counter(
 )
 
 
+def _observe_straggle():
+    """``fed:agg:straggle``: how long this reducer task still waited for
+    the wire once this party had its own part: the done-stamp of the last
+    of its contributions to ARRIVE (stamped by the rendezvous store) less
+    that of the last this party made itself (stamped by the task engine),
+    0 where the arrivals were all here first. A task with no contribution
+    of its own (an inner node that only sums its children's) counts from
+    its first arrival. It is what a faster wire has to bring to 0 for the
+    round to be bound by its steps, measured at the party that waits. An
+    accumulator, not a span: the interval ends before this task's body
+    begins. Nothing for a task with fewer than two stamped future
+    arguments (the scale) or none that arrived, nor while tracing is off."""
+    stamps = tracing.task_arg_stamps()
+    arrived = [t for t, came in stamps if came]
+    if len(stamps) < 2 or not arrived:
+        return
+    ready = max((t for t, came in stamps if not came), default=min(arrived))
+    tracing.observe("fed:agg:straggle", max(0.0, max(arrived) - ready))
+
+
 @fed.remote
 def _agg_kary_sum(*trees):
     from rayfed_tpu.ops.aggregate import tree_sum
 
+    _observe_straggle()
     # fed:agg:reduce: the host side of a reducer task, i.e. the dispatch
     # of the jitted fold (and, below, of the scale that makes it a mean).
     with tracing.phase("fed:agg:reduce"):
@@ -62,6 +83,7 @@ def _agg_kary_weighted(*pairs):
     # pairs: (tree, weight) partials; returns (weighted-sum tree, total).
     from rayfed_tpu.ops.aggregate import tree_sum
 
+    _observe_straggle()
     with tracing.phase("fed:agg:reduce"):
         trees = [t for t, _ in pairs]
         total = pairs[0][1]
@@ -74,6 +96,7 @@ def _agg_kary_weighted(*pairs):
 def _scale(tree, denom):
     import jax
 
+    _observe_straggle()
     with tracing.phase("fed:agg:reduce"):
         return jax.tree_util.tree_map(lambda x: x / denom, tree)
 
@@ -83,6 +106,7 @@ def _scale_weighted(pair):
     import jax
 
     tree, total = pair
+    _observe_straggle()
     with tracing.phase("fed:agg:reduce"):
         return jax.tree_util.tree_map(lambda x: x / total, tree)
 
@@ -108,6 +132,7 @@ def _agg_psum_flat(parties, weights, *trees):
 
     plan = topo_mod.plan(list(parties), "flat")
     contributions = dict(zip(parties, trees))
+    _observe_straggle()
     with tracing.phase("fed:agg:reduce"):
         if mesh_mod.composed_mesh_for(plan.parties) is None:
             return reduce_by_plan(plan, contributions, weights=weights)

@@ -662,6 +662,7 @@ def recv(party: str, src_party: str, upstream_seq_id, curr_seq_id) -> Future:
                     ctx.set_last_received_error(value)
                 out.set_exception(value)
             else:
+                tracing.stamp_done(out, arrived=True)
                 out.set_result(value)
 
         import threading
@@ -727,6 +728,8 @@ def recv(party: str, src_party: str, upstream_seq_id, curr_seq_id) -> Future:
                 ctx.set_last_received_error(value)
             out.set_exception(value)
         else:
+            # The consumer holds ``out``, the store stamped ``f``.
+            tracing.carry_done_stamp(f, out)
             out.set_result(value)
 
     raw.add_done_callback(_chain)

@@ -664,6 +664,10 @@ class RendezvousStore:
         except BaseException as e:  # noqa: BLE001 - surfaced to consumer
             out.set_exception(e)
             return
+        # Both deliveries end here (inline for a small frame, on the pool
+        # for a large one): the arrival's done-stamp, for the task that
+        # takes it as an argument and for fed.get (tracing on only).
+        tracing.stamp_done(out, arrived=True)
         out.set_result(value)
 
     def evict_source(

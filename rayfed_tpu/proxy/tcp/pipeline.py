@@ -39,6 +39,7 @@ from concurrent.futures import Future
 from queue import Empty, Queue
 from typing import Callable, Optional
 
+from rayfed_tpu import tracing
 from rayfed_tpu._private.constants import CODE_DATA_CORRUPT, CODE_OK
 from rayfed_tpu.proxy.tcp import sockio, wire
 from rayfed_tpu.resilience import inject as fault_inject
@@ -302,9 +303,17 @@ class PipelinedLane:
                     job.sent_at = now
             try:
                 with self._send_mutex:
-                    sockio.send_frames(
-                        sock, [self._wire_frame(j) for j in jobs]
-                    )
+                    frames = [self._wire_frame(j) for j in jobs]
+                    # A large job ends its batch (_writer_loop): its
+                    # "write" span runs from the first byte handed to
+                    # the socket to the last, on this thread.
+                    big = jobs[-1]
+                    t0 = tracing.write_t0(big.nbytes)
+                    sockio.send_frames(sock, frames)
+                    if t0 is not None:
+                        tracing.record(
+                            "write", self._dest, big.header.get("up", ""),
+                            big.header.get("down", ""), big.nbytes, t0)
                 return True
             except (OSError, ConnectionError) as e:
                 self._handle_break(e)

@@ -637,6 +637,27 @@ class _AckParser:
 # ---------------------------------------------------------------------------
 
 
+def _chunks_nbytes(chunks) -> int:
+    return sum(c.nbytes if isinstance(c, memoryview) else len(c)
+               for c in chunks)
+
+
+class _WriteMark:
+    """Where a traced frame of ``tracing.TIMED_RECV_MIN_BYTES`` and more
+    lies in a lane's byte stream (``[start, end)`` in bytes flushed since
+    the lane's oldest live mark was queued), and the start of its
+    ``write`` span: ``t0`` is when the writev that carried its first byte
+    was issued (None until then); the span is recorded with its last."""
+
+    __slots__ = ("start", "end", "job", "t0")
+
+    def __init__(self, start: int, end: int, job: "_Inflight"):
+        self.start = start
+        self.end = end
+        self.job = job
+        self.t0: Optional[float] = None
+
+
 class ReactorLane:
     """Pipelined sender lane driven by a shared reactor instead of a
     per-peer writer thread + per-reconnect reader thread.
@@ -679,6 +700,13 @@ class ReactorLane:
         self._pending: deque = deque()  # jobs without a window slot yet
         self._inflight: deque = deque()  # written, awaiting fseq ack
         self._outbox: deque = deque()  # wire chunks not yet written
+        # Traced large frames whose bytes are (partly) still in the ring,
+        # oldest first, the bytes flushed since the oldest was queued and
+        # when the flush in hand was issued: the "write" spans, the
+        # mirror of the peer's timed "recv".
+        self._marks: deque = deque()
+        self._woff = 0
+        self._t_flush = 0.0
         self._acks = _AckParser()
         self._rbuf = bytearray(64 * 1024)
         self._sock = None
@@ -742,8 +770,7 @@ class ReactorLane:
         if sanitize.enabled():
             sanitize.probe_inline_busy_set(id(self))
         chunks = self._wire_chunks(job)
-        total = sum(c.nbytes if isinstance(c, memoryview) else len(c)
-                    for c in chunks)
+        total = _chunks_nbytes(chunks)
         n = _nb_writev(fd, chunks)
         if n < 0:
             with self._lock:
@@ -785,6 +812,7 @@ class ReactorLane:
             self._inflight.clear()
             self._pending.clear()
             self._outbox.clear()
+            self._marks.clear()
             sock, fd = self._sock, self.fd
             self._sock, self.fd = None, -1
         # An inline send may have captured the fd under the lock *before*
@@ -870,7 +898,11 @@ class ReactorLane:
                 job.attempts += 1
                 job.sent_at = time.monotonic()
                 self._inflight.append(job)
-                self._outbox.extend(self._wire_chunks(job))
+                chunks = self._wire_chunks(job)
+                if (tracing._enabled
+                        and job.nbytes >= tracing.TIMED_RECV_MIN_BYTES):
+                    self._mark_write(job, chunks)
+                self._outbox.extend(chunks)
                 moved = True
         if moved or self._outbox:
             self._reactor.mark_dirty(self)
@@ -879,10 +911,40 @@ class ReactorLane:
         if self._outbox and not self._closed:
             self._reactor.mark_dirty(self)
 
+    # The "write" span of a large frame (docs/observability.md): the ring
+    # is one byte stream of many frames written by many nonblocking
+    # writevs, so a frame's extent in it is kept as byte offsets.
+    # Caller holds self._lock; loop thread only.
+
+    def _mark_write(self, job: _Inflight, chunks: List) -> None:
+        if not self._marks:
+            self._woff = 0
+        start = self._woff + _chunks_nbytes(self._outbox)
+        self._marks.append(
+            _WriteMark(start, start + _chunks_nbytes(chunks), job))
+
+    def _advance_marks(self, flushed: int) -> None:
+        """The writev issued at ``_t_flush`` took ``flushed`` bytes."""
+        self._woff += flushed
+        while self._marks:
+            mark = self._marks[0]
+            if self._woff <= mark.start:
+                return
+            if mark.t0 is None:
+                mark.t0 = self._t_flush     # it carried the first byte
+            if self._woff < mark.end:
+                return
+            header = mark.job.header
+            tracing.record("write", self._dest, header.get("up", ""),
+                           header.get("down", ""), mark.job.nbytes, mark.t0)
+            self._marks.popleft()
+
     def pending_chunks(self) -> List:
         with self._lock:
             if self._inline_busy:
                 return []
+            if self._marks:
+                self._t_flush = time.perf_counter()
             return list(self._outbox)
 
     def on_flushed(self, result: int) -> None:
@@ -907,6 +969,8 @@ class ReactorLane:
                 else:
                     self._outbox[0] = memoryview(head)[n:]
                     n = 0
+            if self._marks:
+                self._advance_marks(result)
             remaining = bool(self._outbox)
         self._reactor.set_write_interest(self.fd, remaining)
         if not remaining:
@@ -1034,6 +1098,7 @@ class ReactorLane:
             _m_lane_breaks.inc()
             sock, self._sock, fd, self.fd = self._sock, None, self.fd, -1
             self._outbox.clear()
+            self._marks.clear()     # a broken write leaves no span
             self._acks.reset()
             survivors: deque = deque()
             failed = []

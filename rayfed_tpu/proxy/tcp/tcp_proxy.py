@@ -558,12 +558,21 @@ class _DestWorker(threading.Thread):
                 self._submit_socket(out, header, buffers, payload_len)
                 continue
             try:
-                out.set_result(self._send_half_duplex(header, buffers))
+                out.set_result(
+                    self._send_half_duplex(header, buffers, payload_len)
+                )
             except BaseException as e:  # noqa: BLE001 - routed to drain
                 out.set_exception(e)
 
     def _attach_done_callbacks(self, out, on_done, payload_len,
                                upstream_seq_id, downstream_seq_id) -> None:
+        """Hooks on the frame's ack future. While tracing is on, the
+        ``send`` span: it is stamped HERE, when the resolved and staged
+        value is handed over to this destination's lane, and closed by
+        the peer's ack, so it holds the wait for the lane, the write and
+        the peer's receipt together (the collector stitches edges by it).
+        The writer's own part of a large frame is the ``write`` span, in
+        the ring only (``tracing.write_t0``; the lanes)."""
         if on_done is not None:
             # Alternate-lane accounting hook (device-DMA failed-send
             # leak bound): tell the lane whether the descriptor frame
@@ -742,7 +751,7 @@ class _DestWorker(threading.Thread):
                 payload_len = len(blob)
         return header, buffers, payload_len, None
 
-    def _send_half_duplex(self, header, buffers) -> bool:
+    def _send_half_duplex(self, header, buffers, nbytes: int = 0) -> bool:
         # TLS path, on the unified retry engine. First attempt gets the
         # full connect budget (peer may still be starting — the reference
         # rides gRPC's in-channel retry policy for this), a reconnect
@@ -783,7 +792,14 @@ class _DestWorker(threading.Thread):
                 )
             try:
                 t0 = time.monotonic()
+                # A large frame's "write" span: its bytes handed to the
+                # socket, not the ack's read.
+                w0 = tracing.write_t0(nbytes)
                 sockio.send_frame(sock, wire.FTYPE_DATA, header, wire_bufs)
+                if w0 is not None:
+                    tracing.record(
+                        "write", self._dest, header.get("up", ""),
+                        header.get("down", ""), nbytes, w0)
                 result = sockio.recv_frame(
                     sock, max_payload=wire.MAX_RESP_FRAME
                 )

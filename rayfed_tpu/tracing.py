@@ -19,6 +19,11 @@ This module adds per-transfer spans: every send and receive records
 (kind, peer, seq ids, bytes, duration) into a bounded in-process ring,
 queryable via :func:`get_spans` / :func:`summary`.
 
+Intervals that do not live on one thread (a task's time in the queue, a
+value's wait for its reader) go through :func:`observe` into the same
+per-name accumulators that :class:`phase` feeds; the futures that carry
+their stamps are marked by :func:`stamp_done`.
+
 Two context managers also put the program's host work on the profiler's
 clock, so a device trace says what the host was doing in an idle gap:
 :class:`span` (one transfer, ``fed:wire:<kind>``) and :class:`phase` (a
@@ -39,7 +44,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
@@ -64,7 +69,7 @@ _phases: Dict[str, List[float]] = {}  # fedlint: disable=global-mutable-singleto
 
 @dataclass
 class Span:
-    kind: str                 # "send" | "recv" | "decode" | "task"
+    kind: str                 # "send" | "write" | "recv" | "decode"
     peer: str                 # destination or source party ("" if n/a)
     upstream_seq_id: str
     downstream_seq_id: str
@@ -132,9 +137,14 @@ def last_span_index() -> int:
 # Kinds whose spans bracket the full operation (duration is meaningful);
 # "recv" spans are arrival events with no duration, except for frames of
 # TIMED_RECV_MIN_BYTES and more (below) — no throughput for the kind.
-# "fold"/"publish" are the async aggregation buffer's K-publish spans
+# "send" runs from the staged frame's hand-over to the sender's lane to
+# its ack (the wait for a slot of the lane's window, the write, the
+# peer's read and its ack together); "write" is the writer's own part of
+# a frame of TIMED_RECV_MIN_BYTES and more (:func:`write_t0`): its
+# first byte handed to the socket -> its last. "fold"/"publish" are
+# the async aggregation buffer's K-publish spans
 # (rayfed_tpu/async_rounds.py; docs/async_rounds.md).
-_TIMED_KINDS = {"send", "decode", "task", "fold", "publish"}
+_TIMED_KINDS = {"send", "write", "decode", "fold", "publish"}
 
 # A frame whose payload is at least this long gets a "recv" span with a
 # duration: the reactor stamps the moment its payload starts to arrive
@@ -221,7 +231,7 @@ def export_seq_timeline(path: str, party: str = "") -> int:
              ...]},   # time-ordered within each edge
           ...]}       # edges ordered by first event
 
-    Every send/recv/decode/task span plus the async aggregator's
+    Every send/write/recv/decode span plus the async aggregator's
     fold/publish spans lands here keyed by its (upstream, downstream)
     seq-id edge, so a straggling round is traceable from the driver's
     offer through the wire to the fold that consumed it. Returns the
@@ -439,6 +449,22 @@ class span:
         return False
 
 
+def write_t0(nbytes: int) -> Optional[float]:
+    """For the lane about to hand a frame's first byte to the socket: the
+    start of the frame's ``write`` span, which the lane closes with
+    ``record("write", peer, up, down, nbytes, t0)`` under the frame's seq
+    ids once its last byte is handed over: the mirror of the receiver's
+    timed ``recv``, in the ring only (an annotation on the profiler's
+    clock would lie over a round's first seconds on the reactor's thread
+    and take the idle pieces of threads the device did wait for). None
+    while tracing is off and for a frame under
+    :data:`TIMED_RECV_MIN_BYTES` (the coalesced small-message lane is the
+    latency path, and the ring holds 10,000 entries)."""
+    if _enabled and nbytes >= TIMED_RECV_MIN_BYTES:
+        return time.perf_counter()
+    return None
+
+
 class phase:
     """Context manager for a RECURRING piece of host work (one part of
     the serving loop, the placement of an arrival, the mean's dispatch).
@@ -451,7 +477,8 @@ class phase:
     tens of phases a second would turn over in under a minute.
 
     Phases of one loop tile it and none encloses the others: a reduction
-    books an idle gap to the host event that overlaps it most."""
+    cuts an idle gap at the host events over it and books each piece to
+    the innermost ``fed:*`` event that covers it, whatever its thread."""
 
     __slots__ = ("_name", "_meta", "_ann", "_t0")
 
@@ -471,18 +498,81 @@ class phase:
 
     def __exit__(self, exc_type, exc, tb):
         if self._t0 is not None:
-            dt = time.perf_counter() - self._t0
-            with _lock:
-                acc = _phases.get(self._name)
-                if acc is None:
-                    acc = _phases[self._name] = [0, 0.0, 0.0]
-                acc[0] += 1
-                acc[1] += dt
-                if dt > acc[2]:
-                    acc[2] = dt
+            _accumulate(self._name, time.perf_counter() - self._t0)
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         return False
+
+
+def _accumulate(name: str, seconds: float) -> None:
+    with _lock:
+        acc = _phases.get(name)
+        if acc is None:
+            acc = _phases[name] = [0, 0.0, 0.0]
+        acc[0] += 1
+        acc[1] += seconds
+        if seconds > acc[2]:
+            acc[2] = seconds
+
+
+def observe(name: str, seconds: float) -> None:
+    """Add one measured interval to ``name``'s accumulator, the one a
+    :class:`phase` of that name feeds (:func:`phase_summary`), for an
+    interval that does not live on one thread and so cannot be a context
+    manager: a task's time in the queue, a value's wait for its reader.
+    No profiler annotation; nothing while tracing is off."""
+    if _enabled:
+        _accumulate(name, seconds)
+
+
+# -- who resolved a future, and when -----------------------------------------
+#
+# While tracing is on, whoever resolves a future that a task or ``fed.get``
+# may wait for stamps it just before it sets the result, in an attribute,
+# the way the engine carries ``_fedtpu_steal``: ``(perf_counter(), arrived)``,
+# ``arrived`` true where the value came off the wire (the rendezvous store)
+# and false where a task of this party made it (the task engine). Off, no
+# future is touched.
+
+_DONE_T = "_fedtpu_done_t"
+_task = threading.local()
+
+
+def stamp_done(fut, arrived: bool = False) -> None:
+    """Mark ``fut`` as resolved now (call before ``set_result``)."""
+    if _enabled:
+        setattr(fut, _DONE_T, (time.perf_counter(), arrived))
+
+
+def carry_done_stamp(src, dst) -> None:
+    """``dst`` resolves with ``src``'s value: it takes ``src``'s stamp."""
+    stamp = getattr(src, _DONE_T, None)
+    if stamp is not None:
+        setattr(dst, _DONE_T, stamp)
+
+
+def done_stamp(fut) -> Optional[Tuple[float, bool]]:
+    """``(when, arrived)`` of ``fut``'s resolution, or None where nobody
+    stamped it."""
+    return getattr(fut, _DONE_T, None)
+
+
+def swap_task_arg_stamps(
+    stamps: Optional[List[Tuple[float, bool]]],
+) -> Optional[List[Tuple[float, bool]]]:
+    """The task engine's side of :func:`task_arg_stamps`: set the running
+    task's stamps on this thread and return what stood there (a task run
+    inline inside another's body or wait puts it back when it ends)."""
+    prev = getattr(_task, "arg_stamps", None)
+    _task.arg_stamps = stamps
+    return prev
+
+
+def task_arg_stamps() -> List[Tuple[float, bool]]:
+    """From inside a task's body: the done-stamps of its future arguments
+    that carry one, in argument order. Empty outside a task, while tracing
+    is off, and for a task whose arguments were plain values."""
+    return getattr(_task, "arg_stamps", None) or []
 
 
 def phase_summary() -> Dict[str, Dict]:

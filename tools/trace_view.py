@@ -43,7 +43,7 @@ _GLYPHS = {
     "send": "s",
     "recv": "r",
     "decode": "d",
-    "task": "t",
+    "write": "w",
     "fold": "F",
     "publish": "P",
     "hb": "h",
