@@ -168,6 +168,12 @@ def steal(fut: Future) -> None:
         _steal_depth.v = depth
 
 
+def stealing() -> bool:
+    """Whether the calling thread is running a stolen task inline: what
+    it does now stands in front of the thief's own wait."""
+    return getattr(_steal_depth, "v", 0) > 0
+
+
 def result_stealing(fut: Future, timeout: Optional[float] = None) -> Any:
     """``fut.result(timeout)`` preceded by an inline steal attempt — the
     entry point for API-level consumers (``fed.get``)."""

@@ -26,12 +26,15 @@ routes every data send's completion future into the cleanup drain queue
 
 from __future__ import annotations
 
+import contextvars
 import logging
+import threading
 import time
 from concurrent.futures import Future
 from typing import Callable, Dict, Optional, Type
 
 from rayfed_tpu import sanitize, tracing
+from rayfed_tpu._private import executor
 from rayfed_tpu._private.constants import PING_SEQ_ID
 from rayfed_tpu._private.global_context import get_global_context
 from rayfed_tpu.exceptions import FedRemoteError
@@ -416,20 +419,54 @@ def send(
     return fut
 
 
+# How far ahead of the leaf being gathered, beyond the one behind it,
+# _gather_to_host starts device -> host transfers.
+_D2H_AHEAD_BYTES = 64 << 20
+
+
+def _gather_to_host(arrays):
+    """Yield ``np.asarray`` of each of ``arrays`` (single-device
+    jax.Arrays) in order, with their D2H transfers started ahead: always
+    that of the array being gathered and of the one behind it, further
+    ones while they fit ``_D2H_AHEAD_BYTES``. A tree of many small leaves
+    so pays one overlapped wave rather than serialized per-leaf copies,
+    and a tree of GB-scale leaves has two transfers in flight, not a
+    dozen: started all at once beside a train loop they cost its steps
+    0.3-0.45 s a round for a staging of 0.5 s, two at a time 0.1-0.2 s
+    (TPU v5e host, a 1.945 GB tree in twelve leaves: PERF.md section 6,
+    PR 40)."""
+    import numpy as np
+
+    started = 0     # transfers of arrays[:started] are started
+    ahead = 0       # bytes of those beyond arrays[k + 1]
+    for k, x in enumerate(arrays):
+        while started < len(arrays) and (
+            started <= k + 1
+            or ahead + arrays[started].nbytes <= _D2H_AHEAD_BYTES
+        ):
+            try:
+                arrays[started].copy_to_host_async()
+            except Exception:  # noqa: BLE001 - optional overlap only
+                pass
+            if started > k + 1:
+                ahead += arrays[started].nbytes
+            started += 1
+        yield np.asarray(x)
+        if k + 2 < started:
+            ahead -= arrays[k + 2].nbytes   # the next gather's one behind
+
+
 def _host_snapshot(value):
     """Capture the jax.Array leaves of ``value`` against later buffer
     donation: single-device leaves are staged to host numpy (the wire
-    needs those bytes anyway), multi-device leaves get an on-device copy
-    (fresh buffers, sharding preserved — the sharded wire format reads
-    per-shard device views). D2H transfers are started asynchronously
-    for every leaf first, then gathered, so a many-leaf tree pays one
-    overlapped transfer wave rather than serialized per-leaf copies."""
+    needs those bytes anyway; ``_gather_to_host``), multi-device leaves
+    get an on-device copy (fresh buffers, sharding preserved — the
+    sharded wire format reads per-shard device views)."""
     import sys
 
     j = sys.modules.get("jax")
     if j is None:
         return value
-    import numpy as np
 
     from rayfed_tpu import tree_util
 
@@ -437,19 +474,16 @@ def _host_snapshot(value):
         leaves, spec = tree_util.tree_flatten(value)
     except Exception:  # noqa: BLE001 - unflattenable values use pickle lane
         return value
-    for x in leaves:
-        if isinstance(x, j.Array) and x.is_fully_addressable and len(
-            x.sharding.device_set
-        ) == 1:
-            try:
-                x.copy_to_host_async()
-            except Exception:  # noqa: BLE001 - optional overlap only
-                break
+    on_host = _gather_to_host([
+        x for x in leaves
+        if isinstance(x, j.Array) and x.is_fully_addressable
+        and len(x.sharding.device_set) == 1
+    ])
     out = []
     for x in leaves:
         if isinstance(x, j.Array) and x.is_fully_addressable:
             if len(x.sharding.device_set) == 1:
-                out.append(np.asarray(x))
+                out.append(next(on_host))
             else:
                 try:
                     # jnp.copy preserves the sharding; the copy's buffers
@@ -475,6 +509,31 @@ def _host_snapshot(value):
             except Exception:  # noqa: BLE001 - optional overlap only
                 break
     return tree_util.tree_unflatten(out, spec)
+
+
+def _stages_bulk(value) -> bool:
+    """Whether capturing ``value`` copies as much of jax.Array leaves (to
+    the host, or on the device) as the wire calls a large frame
+    (``tracing.TIMED_RECV_MIN_BYTES``): too much to run in front of a
+    thief's own wait (``_capture_for_send``)."""
+    import sys
+
+    j = sys.modules.get("jax")
+    if j is None:
+        return False
+    from rayfed_tpu import tree_util
+
+    try:
+        leaves, _ = tree_util.tree_flatten(value)
+    except Exception:  # noqa: BLE001 - unflattenable values use pickle lane
+        return False
+    staged = 0
+    for x in leaves:
+        if isinstance(x, j.Array):
+            staged += x.nbytes
+            if staged >= tracing.TIMED_RECV_MIN_BYTES:
+                return True
+    return False
 
 
 def _dma_eligible(value) -> bool:
@@ -517,6 +576,20 @@ def _capture_for_send(dest_party: str, data):
     resolution callback, which runs on the producer's lane thread BEFORE
     that lane starts its next task.
 
+    WHICH THREAD PAYS THE STAGING (``fed:wire:encode``; a 1.945 GB tree
+    takes 0.5-0.6 s on a TPU v5e host): for a ready value the caller of
+    ``send``, before ``send`` returns; for a value future whoever resolves
+    it, inside its callbacks: the producer's actor lane or the pool worker
+    that ran the task. A consumer that STOLE the task (``executor.steal``:
+    ``fed.get`` on the driver, a task resolving its arguments) runs those
+    callbacks in front of its own use of the value; it stages a small
+    value itself, as ever, and hands the staging of a large frame's worth
+    (``_stages_bulk``) to a thread of its own, so that the driver does not
+    stage a GB-scale tree for its peers before it sees the value itself.
+    That is the order a pool worker's win of the race for the task gave
+    already (the consumer wakes beside the capture); a lane's own results
+    are never stolen, so the guarantee above stands.
+
     Under ``device_dma``, values ELIGIBLE for the DMA lane (every leaf a
     single-device jax.Array) are left untouched so they can be parked on
     the transfer server device-resident — pushed-then-donated buffers on
@@ -550,10 +623,21 @@ def _capture_for_send(dest_party: str, data):
         if err is not None:
             out.set_exception(err)
             return
-        try:
-            out.set_result(capture(f.result()))
-        except BaseException as e:  # noqa: BLE001 - surfaced to drain
-            out.set_exception(e)
+        value = f.result()
+
+        def finish():
+            try:
+                out.set_result(capture(value))
+            except BaseException as e:  # noqa: BLE001 - surfaced to drain
+                out.set_exception(e)
+
+        if executor.stealing() and _stages_bulk(value):
+            threading.Thread(
+                target=contextvars.copy_context().run, args=(finish,),
+                name="fedtpu-capture", daemon=True,
+            ).start()
+        else:
+            finish()
 
     data.add_done_callback(_resolve)
     return staged

@@ -556,6 +556,8 @@ class _FrameReader:
             self._recv_phase = tracing.phase("fed:wire:recv", nbytes=plen)
             self._recv_phase.__enter__()
             self._header[tracing.RECV_T0_KEY] = time.perf_counter()
+        if plen > sockio.SMALL_FRAME_MAX:
+            sockio._RECV_POOL.expect(plen)
         sizes = sockio._segment_sizes(self._header, plen)
         self._bufs = []
         if sizes is None:

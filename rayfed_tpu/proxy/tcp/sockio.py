@@ -29,6 +29,7 @@ import socket
 import ssl
 import sys
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import msgpack
@@ -204,17 +205,38 @@ class BufferPool:
     zero-copy views of the receive buffer, so a buffer is safe to reuse
     exactly when every consumer view has died — detected by its refcount
     dropping back to the pool's own reference.
+
+    The budget follows the traffic. ``max_bytes`` is what the pool keeps
+    whatever is read. A reader announces each frame (:meth:`expect`), and
+    a frame whose buffers the budget cannot hold twice raises it to TWO
+    such frames, as far as ``ceiling`` goes (None: as far as the frames
+    ask, and a reader announces only what it has held against the
+    receiver's size cap): the value a frame delivered usually lives until
+    the next one has replaced it, and a budget under that recycles
+    nothing. A 1.945 GB tree read into fresh buffers pays half a million
+    page faults: the same loopback stream carried 0.84 GB/s so and 2.87
+    recycled (TPU v5e host, PERF.md section 6, PR 40). The raise lapses
+    ``GROWN_TTL_S`` after the last frame that needed all of it: the
+    budget is ``max_bytes`` again and the free blocks over it go back to
+    the allocator, also in a process that reads nothing more.
     """
 
+    GROWN_TTL_S = 60.0
+
     def __init__(
-        self, max_bytes: int, min_size: int = 1 << 20, max_entries: int = 64
+        self, max_bytes: int, min_size: int = 1 << 20, max_entries: int = 64,
+        ceiling: Optional[int] = None,
     ):
         # Free detection relies on exact refcounts; a free-threaded
         # interpreter biases/defers them, so pooling must stand down
         # there (plain allocation, no dead-weight cache).
         if not getattr(sys, "_is_gil_enabled", lambda: True)():
             max_bytes = 0  # pragma: no cover - nogil builds only
-        self._max_bytes = max_bytes
+        self._base = max_bytes
+        self._ceiling = ceiling
+        self._max_bytes = max_bytes  # the budget now: _base, or raised
+        self._grown_until = 0.0  # monotonic; when the raise lapses
+        self._lapse_timer: Optional[threading.Timer] = None
         self._min_size = min_size
         # Bounds the O(entries) refcount scan every take() pays under the
         # lock (and with it, worst-case lock hold time).
@@ -228,15 +250,66 @@ class BufferPool:
     # slice / memoryview chains back to the block) adds more.
     _FREE_RC = 2
 
+    def expect(self, frame_bytes: int) -> None:
+        """A frame of ``frame_bytes`` is about to be read into buffers of
+        this pool (the reader knows it from the frame's prefix, which it
+        has already held against the receiver's size cap)."""
+        want = 2 * frame_bytes
+        if self._ceiling is not None:
+            want = min(want, self._ceiling)
+        # A pool that is off (0) stays off.
+        if want <= self._base or not self._base:
+            return
+        with self._lock:
+            if want < self._max_bytes:
+                return  # a smaller frame neither raises nor holds a raise
+            self._max_bytes = want
+            self._grown_until = time.monotonic() + self.GROWN_TTL_S
+            if self._lapse_timer is None:
+                self._arm_lapse(self.GROWN_TTL_S)
+
+    def _arm_lapse(self, delay: float) -> None:
+        # Caller holds self._lock. One timer while the budget is raised.
+        self._lapse_timer = threading.Timer(delay, self._lapse)
+        self._lapse_timer.daemon = True
+        self._lapse_timer.start()
+
+    def _lapse(self) -> None:
+        with self._lock:
+            self._lapse_timer = None
+            left = self._grown_until - time.monotonic()
+            if left > 0:
+                self._arm_lapse(left)  # a frame has held the raise since
+                return
+            self._max_bytes = self._base
+            evicted = self._evict_over()
+        del evicted  # freed outside the lock
+
+    def _evict_over(self) -> List:
+        """Untrack blocks, oldest first, while the pool is over its
+        budget or its entry count; returns them so that the caller frees
+        them after the lock (a busy block is merely untracked and is
+        freed by GC once its consumers drop their views). Caller holds
+        self._lock."""
+        evicted = []
+        while len(self._entries) > 1 and (
+            self._total > self._max_bytes
+            or len(self._entries) > self._max_entries
+        ):
+            self._total -= self._entries[0].nbytes
+            evicted.append(self._entries.pop(0))
+        return evicted
+
     def take(self, n: int):
         """A writable 1-d uint8 array of exactly ``n`` bytes."""
         import numpy as np
 
-        if n < self._min_size or n > self._max_bytes:
+        if n < self._min_size:
             return np.empty(n, dtype=np.uint8)
         with self._lock:
+            pooled = n <= self._max_bytes
             best = -1
-            for i in range(len(self._entries)):
+            for i in range(len(self._entries)) if pooled else ():
                 nbytes = self._entries[i].nbytes
                 # <=4n bound: don't burn a huge block on a small frame.
                 if (
@@ -252,23 +325,18 @@ class BufferPool:
         # Allocate outside the lock: mmap + page faults of a GB-scale
         # block must not stall other receiver threads' pool hits.
         block = np.empty(n, dtype=np.uint8)
-        evicted = []
+        if not pooled:
+            return block
         with self._lock:
             self._entries.append(block)
             self._total += block.nbytes
-            while len(self._entries) > 1 and (
-                self._total > self._max_bytes
-                or len(self._entries) > self._max_entries
-            ):
-                # Evict oldest-first; a busy block is merely untracked and
-                # is freed by GC once its consumers drop their views.
-                self._total -= self._entries[0].nbytes
-                evicted.append(self._entries.pop(0))
+            evicted = self._evict_over()
         del evicted  # munmap of evicted blocks happens after lock release
         return block[:]
 
     def trim(self) -> None:
-        """Drop every currently-free block (busy blocks stay tracked).
+        """Drop every currently-free block (busy blocks stay tracked) and
+        end a raise of the budget.
 
         Transports call this at shutdown so a burst of large frames does
         not pin pool memory for the rest of the process's life."""
@@ -281,6 +349,10 @@ class BufferPool:
                 (keep if sys.getrefcount(block) > 3 else dropped).append(block)
             self._entries = keep
             self._total = sum(b.nbytes for b in keep)
+            self._max_bytes = self._base
+            if self._lapse_timer is not None:
+                self._lapse_timer.cancel()
+                self._lapse_timer = None
         del dropped  # frees outside the lock
 
 
@@ -291,33 +363,52 @@ def trim_recv_pool() -> None:
         _fastwire.pool_trim()
 
 
-def _pool_max_bytes() -> int:
+_DEFAULT_POOL_BYTES = 2 << 30
+
+
+def _pool_env_bytes() -> Optional[int]:
+    """``FEDTPU_RECV_POOL_MB`` in bytes; None where it is not set (or
+    not a number)."""
     mb = os.environ.get("FEDTPU_RECV_POOL_MB")
+    if mb is None:
+        return None
     try:
-        return max(0, int(mb)) << 20 if mb is not None else 2 << 30
+        return max(0, int(mb)) << 20
     except ValueError:
         import logging
 
         logging.getLogger(__name__).warning(
             "ignoring malformed FEDTPU_RECV_POOL_MB=%r (want integer MB)", mb
         )
-        return 2 << 30
+        return None
 
 
-# FEDTPU_RECV_POOL_MB bounds the TOTAL receive-pool memory of the process.
-# When the native extension is loaded, its C-side pool (which reads the
-# same env var) serves every plaintext connection; the Python pool keeps a
-# quarter-cap residual budget for the TLS connections that still ride the
-# Python receive path (they pay per-byte crypto, but a fresh 100MB
-# allocation per frame still costs page faults + munmap). Worst case the
-# process retains 1.25x the configured cap — documented trade against
-# TLS receivers getting zero recycling. Without the native engine the
-# Python pool owns the whole budget.
-_RECV_POOL = BufferPool(
-    _pool_max_bytes() // 4
-    if (_fastwire is not None and hasattr(_fastwire, "recv_prefix_header"))
-    else _pool_max_bytes()
-)
+def _make_recv_pool() -> BufferPool:
+    """The process's receive pool. It serves the reactor's reader (the
+    default plaintext path), the TLS connections and the Python
+    ``recv_frame``; the native extension's C-side pool serves the blocking
+    plaintext receive (``use_reactor: false``) and reads the same variable
+    for itself.
+
+    ``FEDTPU_RECV_POOL_MB`` is the CEILING of this pool: its budget starts
+    at a quarter of it beside a loaded native engine (at all of it
+    without), which is what it keeps whatever is read, and follows the
+    traffic up to it (``BufferPool.expect``), so the process retains at
+    most twice the variable, and that only if both receive paths carry
+    bulk. Unset, the start is the same share of 2 GiB and the ceiling is
+    the traffic's own: two frames of ``messages_max_size_in_bytes`` at the
+    most, which a receiver that reads one such frame while the last one's
+    value is alive holds anyway. A start under two frames of a GB-scale
+    tree recycles a leaf or two of it and page-faults on the rest, every
+    frame."""
+    ceiling = _pool_env_bytes()
+    budget = _DEFAULT_POOL_BYTES if ceiling is None else ceiling
+    if _fastwire is not None and hasattr(_fastwire, "recv_prefix_header"):
+        budget //= 4
+    return BufferPool(budget, ceiling=ceiling)
+
+
+_RECV_POOL = _make_recv_pool()
 
 
 def recv_frame(
@@ -365,6 +456,7 @@ def recv_frame(
     # overwrites every byte); the returned view stays writable.
     from rayfed_tpu._private import serialization
 
+    _RECV_POOL.expect(plen)
     sizes = _segment_sizes(header, plen)
     if sizes is not None:
         segments = []

@@ -27,6 +27,9 @@ from tests.utils import FAST_COMM_CONFIG, run_parties
 
 STEPS = 4
 N = 4096
+# A tree of several leaves over 1 MiB (a timed, segmented frame): the lane
+# may not move on before the last leaf is on the host.
+N_LARGE = 1 << 19
 
 
 @fed.remote
@@ -35,12 +38,17 @@ class DonatingTrainer:
     — the exact pattern that invalidates in-flight send buffers without
     capture-at-resolution."""
 
-    def __init__(self):
+    def __init__(self, n=N):
         import jax
         import jax.numpy as jnp
 
-        self.step_fn = jax.jit(lambda p: p + 1.0, donate_argnums=0)
-        self.params = jnp.zeros((N,), jnp.float32)
+        self.step_fn = jax.jit(
+            lambda p: jax.tree_util.tree_map(lambda x: x + 1.0, p),
+            donate_argnums=0)
+        self.params = (jnp.zeros((n,), jnp.float32) if n == N else
+                       {"a": jnp.zeros((n,), jnp.float32),
+                        "b": [jnp.zeros((n,), jnp.float32),
+                              jnp.zeros((n // 2,), jnp.float32)]})
         _ = jax.block_until_ready(self.params)
 
     def train(self):
@@ -49,20 +57,23 @@ class DonatingTrainer:
 
 
 @fed.remote
-def check(step, arr):
-    got = np.asarray(arr)
-    expect = np.full((N,), float(step), np.float32)
-    np.testing.assert_array_equal(got, expect)
+def check(step, tree):
+    import jax
+
+    for arr in jax.tree_util.tree_leaves(tree):
+        got = np.asarray(arr)
+        np.testing.assert_array_equal(
+            got, np.full(got.shape, float(step), np.float32))
     return float(got[0])
 
 
-def run_donation_race(party, addresses):
+def run_donation_race(party, addresses, n=N):
     fed.init(
         addresses=addresses, party=party,
         config={"cross_silo_comm": dict(FAST_COMM_CONFIG),
                 "transport": "tcp"},
     )
-    trainer = DonatingTrainer.party("alice").remote()
+    trainer = DonatingTrainer.party("alice").remote(n)
     outs = []
     for step in range(1, STEPS + 1):
         params = trainer.train.remote()
@@ -77,3 +88,7 @@ def run_donation_race(party, addresses):
 
 def test_pushed_result_survives_producer_donation():
     run_parties(run_donation_race, ["alice", "bob"])
+
+
+def test_a_large_pushed_tree_survives_a_lane_that_donates_at_once():
+    run_parties(run_donation_race, ["alice", "bob"], extra_args=(N_LARGE,))
