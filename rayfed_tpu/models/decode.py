@@ -35,6 +35,7 @@ TPU-first design:
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -339,6 +340,119 @@ def paged_write(pk, pv, k_new, v_new, positions, tables):
             pv.at[:, w_block, w_off].set(v_new))
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What a model that generates by blocks declares to the serving
+    engine (``block_spec()`` of its serving protocol, optional): a decode
+    step then carries ``length`` ids a row, ``mask_id`` where a position
+    is still masked, and a block is denoised in at most ``steps``
+    forwards under ``rule`` (:func:`rayfed_tpu.serving.sampling.unmask`)
+    before one more forward commits it."""
+
+    length: int
+    mask_id: int
+    steps: int
+    rule: str = "low_confidence_dynamic"
+    threshold: float = 0.9
+
+    def quota(self, step: int) -> int:
+        """Positions a denoising step must unmask at least: ``length //
+        steps``, the remainder given to the first steps."""
+        return self.length // self.steps + (
+            1 if step < self.length % self.steps else 0)
+
+
+def paged_block_attention(pk, pv, positions, tables):
+    """:func:`paged_attention` for ``B`` queries a row: the read of a
+    block-paged K/V pool through the block tables for a decode step that
+    carries a block a row. ``positions`` (R,) is each row's first
+    position, which is also the number of keys it has cached. Returns
+    ``attend(q, kb, vb, base)``: ``q`` (R, B, H, Dh) a layer's queries at
+    positions ``positions[r] .. + B - 1``, ``kb``/``vb`` (R, B, Hkv, Dh)
+    the block's own keys and values (in hand, beside the pool: they are
+    written there only when the block commits, :func:`paged_block_write`),
+    ``base`` as in :func:`paged_attention`; the result is the attention
+    output (R, B, H, Dh).
+
+    The row's own keys (``kb`` may hold any number of them) are the
+    online softmax's first block, every one visible to every query of the
+    row; the cached keys, all of which every query of the row sees,
+    follow ``PAGED_CHUNK_KEYS`` at a time as in :func:`paged_attention`,
+    the trip count a runtime value. A junk row (position 0 under an
+    all-zero table) reads block 0 masked."""
+    n_layers, n_phys, bs, n_kv, dh = pk.shape
+    n_rows, blocks_per_row = tables.shape
+    chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
+    chunk_keys = chunk_blocks * bs
+    tables_p = jnp.pad(
+        tables, ((0, 0), (0, -blocks_per_row % chunk_blocks))
+    )
+    trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
+    pk_flat = pk.reshape(n_layers * n_phys, bs, n_kv, dh)
+    pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
+    scale = dh**-0.5
+
+    def attend(q, kb, vb, base):
+        n_q, n_heads = q.shape[1], q.shape[2]
+        q = q.reshape(n_rows, n_q, n_kv, n_heads // n_kv, dh)
+        s = jnp.einsum(
+            "rbhgd,rchd->rhgbc", q, kb, preferred_element_type=jnp.float32
+        ) * scale
+        m = s.max(-1)
+        p = jnp.exp(s - m[..., None])
+        init = (m, p.sum(-1), jnp.einsum(
+            "rhgbc,rchd->rhgbd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32,
+        ))
+
+        def chunk(c, carry):
+            m, den, acc = carry
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                tables_p, c * chunk_blocks, chunk_blocks, axis=1
+            )
+            kc = pk_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
+            vc = pv_flat[blocks].reshape(n_rows, chunk_keys, n_kv, dh)
+            k_pos = c * chunk_keys + jnp.arange(chunk_keys)
+            cached = k_pos[None, :] < positions[:, None]
+            s = jnp.einsum(
+                "rbhgd,rkhd->rhgbk", q, kc,
+                preferred_element_type=jnp.float32
+            ) * scale
+            s = jnp.where(cached[:, None, None, None, :], s, -jnp.inf)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = den * alpha + p.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "rhgbk,rkhd->rhgbd", p.astype(vc.dtype), vc,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, den, acc
+
+        _, den, acc = jax.lax.fori_loop(0, trips, chunk, init)
+        out = (acc / den[..., None]).astype(vb.dtype)
+        return jnp.moveaxis(out, 3, 1).reshape(n_rows, n_q, n_heads, dh)
+
+    return attend
+
+
+def paged_block_write(pk, pv, k_new, v_new, positions, tables, commit):
+    """The one write of a decode step that carries a block a row: the
+    block's K/V (L, R, B, Hkv, Dh) into positions ``positions[r] .. + B -
+    1`` of every layer, for the rows whose block commits (``commit`` (R,)
+    bool); every other row's go to the sacrificial block 0. ``B`` divides
+    the pool's block size and a block starts at a multiple of ``B``, so a
+    row's ``B`` positions lie in one block of the pool."""
+    bs = pk.shape[2]
+    w_block = jnp.take_along_axis(
+        tables, (positions // bs)[:, None], axis=1
+    )
+    w_block = jnp.where(commit[:, None], w_block, 0)
+    w_off = (positions % bs)[:, None] + jnp.arange(k_new.shape[2])
+    return (pk.at[:, w_block, w_off].set(k_new),
+            pv.at[:, w_block, w_off].set(v_new))
+
+
 # Keys gathered per trip of a prompt chunk's loop over its cached context
 # (:func:`paged_chunk_attention`). A trip scores all of the chunk's
 # queries against this many keys, so its float32 scores are C x H x this
@@ -355,7 +469,8 @@ def paged_write(pk, pv, k_new, v_new, positions, tables):
 CHUNK_TRIP_KEYS = 256
 
 
-def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
+def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
+                          block=None):
     """The read of a block-paged K/V pool through one slot's block table,
     for one chunk of its prompt. Returns ``attend(q, k, v, base)``: ``q``
     (C, H, Dh) a layer's queries at positions ``offset .. offset + C - 1``,
@@ -419,7 +534,8 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None):
             ) * scale
             return jnp.where(seen[None, None], s, -jnp.inf)
 
-        own = (idx[None, :] <= idx[:, None]) & (
+        upto = idx if block is None else idx | (block - 1)
+        own = (idx[None, :] <= upto[:, None]) & (
             (idx < n_real)[None, :] | (idx[None, :] == idx[:, None]))
         if window is not None:
             own &= idx[None, :] > idx[:, None] - window

@@ -26,9 +26,11 @@ expert's capacity are dropped). These exist for the training-side tests
 and have never been measured on a chip.
 
 **The grouped path** (:func:`route_sigmoid_topk`, :func:`routed_experts`,
-:func:`shared_experts`; what :mod:`rayfed_tpu.models.cohere2_moe` and
-:mod:`rayfed_tpu.models.pangu_ultra_moe` serve through ``fed.serve``):
-sigmoid scores over ALL experts, the ``k`` largest normalised over those
+:func:`shared_experts`; what :mod:`rayfed_tpu.models.cohere2_moe`,
+:mod:`rayfed_tpu.models.pangu_ultra_moe` and
+:mod:`rayfed_tpu.models.sdar_moe` serve through ``fed.serve``):
+sigmoid scores over ALL experts (softmax scores where the model says so:
+:func:`route_softmax_topk`), the ``k`` largest normalised over those
 ``k`` (and scaled by the model's factor, where it has one), gated SiLU
 experts, and a layer that is told
 which experts it *holds* (one chip's share of a layer divided over
@@ -278,6 +280,23 @@ def route_sigmoid_topk(h, router, k: int):
         return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
+def route_softmax_topk(h, router, k: int):
+    """:func:`route_sigmoid_topk`'s softmax sibling: scores ``softmax(h
+    router)`` over ALL experts in float32, the ``k`` largest, their
+    weights normalised over the ``k`` (``norm_topk_prob``). The same
+    experts as the sigmoid's (both rise with the product), other
+    weights."""
+    with jax.named_scope("serve/moe_route"):
+        scores = jax.nn.softmax(jnp.einsum(
+            "td,de->te", h, router.astype(h.dtype),
+            preferred_element_type=jnp.float32), axis=-1)
+        top, idx = lax.top_k(scores, k)
+        return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+
+
+ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk}
+
+
 # Row tile of the grouped matmul on a TPU. An expert's rows cost whole
 # tiles, so the tile is small beside the 32 rows an expert of 128 sees of
 # a 512-token chunk; the contraction is not tiled (one pass, no
@@ -304,6 +323,15 @@ def _contract_tile(k: int) -> int:
     return k
 
 
+def _out_tile(n: int) -> int:
+    """The output tile of a product ``n`` wide: ``GROUPED_OUT_TILE``, or
+    the widest multiple of 128 under it that divides ``n`` (384 of 768)."""
+    if n <= GROUPED_OUT_TILE or n % GROUPED_OUT_TILE == 0:
+        return min(GROUPED_OUT_TILE, n)
+    return next((tn for tn in range(GROUPED_OUT_TILE - 128, 0, -128)
+                 if n % tn == 0), GROUPED_OUT_TILE)
+
+
 def grouped_matmul(x, w, group_sizes):
     """``x[rows of group g] @ w[g]`` for consecutive row groups of the
     given sizes: ``x`` (m, k), ``w`` (g, k, n), ``group_sizes`` (g,)
@@ -314,7 +342,7 @@ def grouped_matmul(x, w, group_sizes):
     size 0 is never read. Elsewhere ``jax.lax.ragged_dot``."""
     m, n = x.shape[0], w.shape[2]
     tm = min(GROUPED_ROW_TILE, m)
-    tn = min(GROUPED_OUT_TILE, n)
+    tn = _out_tile(n)
     if utils.is_tpu_backend() and m % tm == 0 and n % tn == 0:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
@@ -325,7 +353,7 @@ def grouped_matmul(x, w, group_sizes):
 
 
 def routed_experts(h, layer: Params, held: Sequence[int], k: int,
-                   live=None, scale: float = 1.0):
+                   live=None, scale: float = 1.0, scoring: str = "sigmoid"):
     """The part of a routed-expert layer that the experts ``held`` give.
 
     ``h`` (T, d) in the compute dtype; ``layer`` holds ``router`` (d, E)
@@ -336,7 +364,9 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
     weights are normalised over all ``k`` chosen experts, held or not,
     and then multiplied by ``scale`` (a model's ``routed_scaling_factor``;
     1 where it has none); what an absent expert would add is left out (it
-    lies on another chip). ``live`` (T,) bool names the rows that count
+    lies on another chip). ``scoring`` names the router's scores
+    (``ROUTERS``: "sigmoid", or "softmax" over all experts).
+    ``live`` (T,) bool names the rows that count
     (None: all):
     padding and junk rows are routed nowhere and touch no expert.
 
@@ -353,7 +383,7 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
     t, d = h.shape
     n_held = len(held)
     n_experts = layer["router"].shape[-1]
-    idx, w = route_sigmoid_topk(h, layer["router"], k)
+    idx, w = ROUTERS[scoring](h, layer["router"], k)
     with jax.named_scope("serve/moe_experts"):
         # Global expert id -> its index among the held ones; n_held for
         # an expert that lies elsewhere.

@@ -138,6 +138,13 @@ def _allocated(shape: tuple) -> tuple:
     return tuple(shape)
 
 
+# What a decode step that carries a block a row counts on the device,
+# behind the model's own ``step_counters``: rows forwarded, rows whose
+# forward committed their block, positions unmasked.
+BLOCK_STEP_COUNTERS = ("diffusion_row_forwards", "diffusion_commit_forwards",
+                       "diffusion_tokens_unmasked")
+
+
 class PagedKVPool:
     """Block-granular pool of what the model's ``kv_spec()`` declares
     a token keeps (K and V rows for most models, one latent row for
@@ -221,9 +228,24 @@ class PagedKVPool:
         # Numbers a model's decode step counts on the device (optional in
         # the protocol): they ride home behind the ids, in their array.
         self.step_counters = tuple(getattr(self.model, "step_counters", ()))
+        # A model that generates by blocks (optional in the protocol: a
+        # ``decode.BlockSpec``): a row of a decode step carries a block,
+        # not one id, and the step counts what it did with the blocks.
+        self.block = getattr(self.model, "block_spec", lambda: None)()
+        if self.block is not None:
+            if self.block_size % self.block.length:
+                raise ValueError(
+                    f"kv_block_size={self.block_size} is not a multiple of "
+                    f"the model's block length {self.block.length}: a "
+                    "block's positions must lie in one block of the pool"
+                )
+            self.step_counters += BLOCK_STEP_COUNTERS
+        # Ids a decode step returns: one a row, or a block a row.
+        self.ids_len = max_slots * (self.block.length if self.block else 1)
         # What the last decode step returned (the ids, the counters behind
         # them), kept on the device: see :meth:`decode_step`.
-        self._ids = jnp.zeros(max_slots + len(self.step_counters), jnp.int32)
+        self._ids = jnp.zeros(
+            self.ids_len + len(self.step_counters), jnp.int32)
         self._lock = threading.Lock()
         self._free_slots: List[int] = list(range(max_slots))
         # pop() hands out low block ids first.
@@ -282,6 +304,8 @@ class PagedKVPool:
                 ids = jnp.concatenate([ids, counted[0].astype(ids.dtype)])
             return ids, kv, state
 
+        if self.block is not None:
+            decode_step = self._block_step()
         self._decode_step_fn = jax.jit(
             decode_step, donate_argnums=(1, 8)
         )
@@ -317,6 +341,50 @@ class PagedKVPool:
             scatter_rows, donate_argnums=(0, 3)
         )
 
+    def _block_step(self):
+        """The decode step of a model that generates by blocks (the
+        pool's ``block``), under the same name, arguments and donation as
+        the one-token step. A row carries ``B`` ids (``tokens`` (R, B)
+        from the host where ``from_host`` says so, else the block the
+        step before returned for it), the mask id where a position is
+        still masked, and ``positions`` is each block's first position.
+        ONE forward of every row's block does per live row what the
+        model's routine does in one iteration: a block that came in clean
+        COMMITS (its K/V are written through the block table, and the
+        next block, all mask ids, is what the step returns for the row);
+        any other is DENOISED (a candidate and its confidence for every
+        masked position, some unmasked:
+        :func:`rayfed_tpu.serving.sampling.unmask`; its K/V are not kept).
+        Which of the two a row does is decided here from the carried
+        block, so the host need not have read the step before. Returns
+        the (R * B) ids of the blocks as the step leaves them, the
+        model's counters and ``BLOCK_STEP_COUNTERS`` behind them."""
+        R, model, spec = self.max_slots, self.model, self.block
+
+        @jax.named_scope("serve/decode_step")
+        def decode_step(params, kv, tokens, positions, tables, draw,
+                        prev_ids, from_host, state=None, live=None):
+            carried = prev_ids[:self.ids_len].reshape(R, spec.length)
+            tokens = jnp.where(from_host[:, None], tokens, carried)
+            commit = live & jnp.all(tokens != spec.mask_id, axis=-1)
+            logits, kv, state, *counted = model.decode_step(
+                params, kv, state or {}, tokens, positions, tables, live,
+                commit
+            )
+            with jax.named_scope("serve/unmask"):
+                block, unmasked = sampling.unmask(
+                    logits, tokens, draw, spec, live & ~commit)
+                block = jnp.where(commit[:, None], spec.mask_id, block)
+            counts = jnp.stack([
+                jnp.sum(live, dtype=jnp.int32),
+                jnp.sum(commit, dtype=jnp.int32), unmasked])
+            ids = jnp.concatenate(
+                [block.reshape(-1)]
+                + [c.astype(jnp.int32) for c in counted] + [counts])
+            return ids, kv, state
+
+        return decode_step
+
     def decode_step(self, params, tokens, positions, tables, draw,
                     live=None, from_host=None, prev_ids=None):
         """One decode token per row through the block tables, the pool
@@ -334,7 +402,9 @@ class PagedKVPool:
         ``step_counters`` is told which rows are live all the same: what
         it counts is over them). Returns each row's next token, (R,)
         int32, on the device, followed by the model's ``step_counters``
-        where it declares any. The small host arrays go to
+        where it declares any. (A model that generates by blocks: a
+        block a row, ``tokens`` (R, B) and (R * B) ids back:
+        :meth:`_block_step`.) The small host arrays go to
         the program as NumPy, here and in the pool's other programs: the
         jitted call uploads its own arguments, and a ``jnp.asarray``
         around each cost the engine thread 0.15 ms of dispatch apiece

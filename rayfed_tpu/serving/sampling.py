@@ -42,6 +42,16 @@ of the cipher either way.
 
 The three per-row scalars ride to the device as ONE ``(3, R)`` int32
 array (:func:`pack`) beside the step's other small inputs.
+
+A model that generates by blocks (``decode.BlockSpec``) has the sampler
+return each chosen id WITH its probability, and the unmasking rule is
+traced behind it (:func:`unmask`): which of a row's masked positions take
+their chosen id in this step is decided here, on the device, so the host
+still samples nothing. A sampled row's key is then ``(seed, index * steps
++ step)``: ``index`` the position's place in the request's output,
+``step`` the denoising step of its block (a fourth row of :func:`pack`),
+so that a position redrawn in a later step of its block draws anew and a
+request's tokens stay a function of (version, prompt, seed) alone.
 """
 
 from __future__ import annotations
@@ -75,16 +85,30 @@ def choose_tokens(logits, temperature, seed, index):
     return jax.lax.cond(jnp.any(sampled), draw, lambda: greedy)
 
 
-def pack(temperature, seed, index) -> np.ndarray:
+def choose_with_confidence(logits, temperature, seed, index):
+    """:func:`choose_tokens`, and each chosen id's probability under
+    ``softmax(logits)`` (R,) float32 beside it."""
+    logits = logits.astype(jnp.float32)
+    ids = choose_tokens(logits, temperature, seed, index)
+    picked = jnp.take_along_axis(logits, ids[:, None], axis=-1)[:, 0]
+    return ids, jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+
+
+def pack(temperature, seed, index, step=None) -> np.ndarray:
     """The sampler's per-row scalars as one ``(3, R)`` int32 host array
     (one upload): the float32 bits of the temperatures, the seeds (any
-    Python ints) modulo 2**32, the output positions."""
-    return np.stack([
+    Python ints) modulo 2**32, the output positions; ``(4, R)`` with the
+    denoising steps of the rows' blocks where the model generates by
+    blocks."""
+    rows = [
         np.asarray(temperature, np.float32).view(np.int32),
         np.array([int(s) & 0xFFFFFFFF for s in seed], np.uint32).view(
             np.int32),
         np.asarray(index, np.int32),
-    ])
+    ]
+    if step is not None:
+        rows.append(np.asarray(step, np.int32))
+    return np.stack(rows)
 
 
 def choose_packed(logits, draw):
@@ -95,3 +119,55 @@ def choose_packed(logits, draw):
         jax.lax.bitcast_convert_type(draw[1], jnp.uint32),
         draw[2],
     )
+
+
+def unmask(logits, block, draw, spec, active):
+    """One denoising step's choice for every row that carries a block:
+    ``logits`` (R, B, V) at the block's positions (unshifted: position
+    ``j``'s logits are for the token AT ``j``), ``block`` (R, B) int32 the
+    carried ids (``spec.mask_id`` where still masked), ``draw`` a
+    :func:`pack`-ed ``(4, R)`` array (index: the place of the block's
+    FIRST position in the request's output), ``spec`` a
+    ``decode.BlockSpec``, ``active`` (R,) bool the rows that denoise in
+    this step (live, and not committing). Returns (the block after the
+    step (R, B) int32, positions newly unmasked, an int32 scalar).
+
+    Every masked position gets a candidate ``x0`` (argmax, or a draw from
+    ``softmax(logits / t)`` on a sampled row) and its confidence
+    ``softmax(logits)[x0]`` in float32; the mask id itself is never a
+    candidate (its logit is left out). ``spec.quota(step)`` positions at
+    least are unmasked: those of largest confidence, the first at a tie
+    (``low_confidence_static``); under ``low_confidence_dynamic`` all
+    those whose confidence passes ``spec.threshold`` instead, where at
+    least that many do. Only masked positions are ever written."""
+    n_rows, n_pos, vocab = logits.shape
+    temperature = jax.lax.bitcast_convert_type(draw[0], jnp.float32)
+    seed = jax.lax.bitcast_convert_type(draw[1], jnp.uint32)
+    step = draw[3]
+    index = (draw[2][:, None] + jnp.arange(n_pos)) * spec.steps + step[:, None]
+    logits = jnp.where(jnp.arange(vocab) == spec.mask_id, -jnp.inf,
+                       logits.astype(jnp.float32))
+    x0, conf = choose_with_confidence(
+        logits.reshape(n_rows * n_pos, vocab),
+        jnp.repeat(temperature, n_pos), jnp.repeat(seed, n_pos),
+        index.reshape(-1))
+    x0, conf = x0.reshape(n_rows, n_pos), conf.reshape(n_rows, n_pos)
+    masked = block == spec.mask_id
+    conf = jnp.where(masked, conf, -jnp.inf)
+    rest = spec.length % spec.steps
+    quota = spec.length // spec.steps + (step < rest).astype(jnp.int32)
+    # Place of each position in the order of falling confidence, the
+    # earlier position first at a tie.
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (jnp.arange(n_pos)[None, :] < jnp.arange(n_pos)[:, None]))
+    chosen = masked & (ahead.sum(-1) < quota[:, None])
+    if spec.rule == "low_confidence_dynamic":
+        high = masked & (conf > spec.threshold)
+        chosen = jnp.where(
+            (high.sum(-1) >= quota)[:, None], high, chosen)
+    elif spec.rule != "low_confidence_static":
+        raise ValueError(f"unknown remasking rule {spec.rule!r}")
+    chosen &= active[:, None]
+    return (jnp.where(chosen, x0, block).astype(jnp.int32),
+            jnp.sum(chosen, dtype=jnp.int32))

@@ -47,6 +47,21 @@ blocks return to the free list, the request re-queues and
 deterministically re-runs under its pinned version), so mixed-length
 traffic degrades by latency, never by abort.
 
+A model that generates by blocks (its protocol declares ``block_spec()``:
+:mod:`rayfed_tpu.models.sdar_moe`) runs through the same loop. A live row
+then carries a block of ``B`` ids on the device, the mask id where a
+position is still masked; a decode step forwards every row's block and
+either unmasks some of its positions or, where it came in clean, commits
+it (keeps its K/V) and opens the next (:meth:`PagedKVPool._block_step`
+decides which, on the device), so a step yields 0 to ``B`` tokens a row
+and the host learns how many one fetch late. A prompt's whole blocks are
+prefilled; its left-over tokens start the first block unmasked. Tokens
+leave in the order of their positions, none later than the fetch that
+shows its block clean; a request ends when the block that holds its last
+token is clean, and what that block holds beyond ``max_new_tokens`` is
+dropped. Prefix reuse, beam and speculative requests are refused for such
+a model.
+
 Token streaming: ``submit(..., stream=sink)`` attaches a sink the engine
 pushes each sampled token into (never blocking — see
 :mod:`rayfed_tpu.serving.stream` for the backpressure contract); the
@@ -135,7 +150,15 @@ class _Request:
     stream: Any = None            # optional token sink (serving.stream)
     chunk_done: int = 0           # prompt positions chunked-prefilled so far
     stalled: bool = False         # waiting on a KV block grant
-    ahead: int = 0                # tokens dispatched and not yet fetched (0/1)
+    ahead: int = 0                # steps dispatched and not yet fetched (0/1)
+    # A model that generates by blocks: ``pos`` is the first position of
+    # the request's current block, and the host keeps the block as the
+    # last fetch showed it (the mask id where still masked).
+    block: Optional[List[int]] = None
+    block_step: int = 0           # denoising forwards fetched for the block
+    block_out: int = 0            # its positions emitted (or the prompt's)
+    block_steps: List[int] = field(default_factory=list)  # step per position
+    steps_out: List[int] = field(default_factory=list)    # ... per token out
 
 
 @dataclass
@@ -211,6 +234,10 @@ class InferenceServer:
             {min(b, self.scfg.prefill_chunk) for b in _default_buckets(
                 self.scfg.prefill_chunk)}
         )
+        # A model that generates by blocks (``decode.BlockSpec``), or None.
+        self._block = self.pool.block
+        if self._block is not None:
+            self._check_block_shapes()
         self._prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
         self._special_fns: Dict[tuple, Any] = {}
@@ -260,9 +287,10 @@ class InferenceServer:
             "publish_cast_bytes": 0,
             # Bytes the engine thread pulled host-ward from its programs'
             # outputs (the chosen ids: 4 x max_slots a step or a prefill
-            # round, 4 a last chunk), and decode steps in which at least
-            # one live row was sampled (the noise's branch ran), beside
-            # "steps".
+            # round, 4 a last chunk; a block a row and no id of a prefill
+            # where the model generates by blocks), and decode steps in
+            # which at least one live row was sampled (the noise's branch
+            # ran), beside "steps".
             "fetch_bytes": 0,
             "draw_steps": 0,
             # Decode, per layer: the blocks each layer of each live row
@@ -291,6 +319,10 @@ class InferenceServer:
         # What the model's decode step counts on the device (it declares
         # the names; none for most models): fetched behind the ids.
         self._stats.update(dict.fromkeys(self.pool.step_counters, 0))
+        if self._block is not None:
+            # Generation by blocks: positions of requests' last blocks
+            # beyond ``max_new_tokens``, computed and dropped.
+            self._stats["diffusion_positions_dropped"] = 0
         # The windows of the layers that have one (optional in the
         # protocol: a model without ``layer_windows`` attends every key
         # on every layer).
@@ -464,6 +496,12 @@ class InferenceServer:
             ).labels(server=name)
             for key in self.pool.step_counters
         }
+        self._m_dropped = _reg.counter(
+            "fed_serving_diffusion_positions_dropped_total",
+            "Positions of requests' last blocks beyond max_new_tokens, "
+            "computed and dropped (generation by blocks).",
+            labels=("server",),
+        ).labels(server=name)
         self._update_kv_gauges()
         # Whatever way a version comes in (publish, a promoted standby's
         # state), the bank's snapshot of it is the tree the programs read.
@@ -476,6 +514,38 @@ class InferenceServer:
             daemon=True,
         )
         self._engine.start()
+
+    def _check_block_shapes(self) -> None:
+        """What a model that generates by blocks needs of the serving
+        configuration, refused by name otherwise: its K/V at a position
+        depend on the block the position lies in, so a donor's boundary
+        block says nothing of another request's (no prefix reuse until a
+        test shows it sound at block boundaries), and every prefill
+        shape must hold whole blocks (the block-causal masks are
+        ``k_pos <= q_pos | (B - 1)``)."""
+        b = self._block.length
+        who = type(self.cfg).__name__
+        if self.scfg.prefix_reuse:
+            raise ValueError(
+                f"serving.prefix_reuse is not served for {who} (generation "
+                "by blocks: a prompt's last block is denoised with what "
+                "follows it); set prefix_reuse=False"
+            )
+        if self._recurrent or getattr(self.model, "layer_windows", None):
+            raise ValueError(
+                f"{who}: generation by blocks is served without a "
+                "recurrent state and without windowed layers"
+            )
+        shapes = {"max_len": [self.scfg.max_len],
+                  "prefill_chunk": [self.scfg.prefill_chunk],
+                  "prompt_buckets": self._buckets,
+                  "chunk buckets": self._chunk_buckets}
+        for name, sizes in shapes.items():
+            if any(n % b for n in sizes):
+                raise ValueError(
+                    f"serving {name} {sizes} must be multiples of {who}'s "
+                    f"block length {b}"
+                )
 
     # -- jitted programs -------------------------------------------------
 
@@ -615,6 +685,18 @@ class InferenceServer:
                 f"{type(self.cfg).__name__}'s recurrent state cannot be "
                 "rolled back (only mode='generate' is served)"
             )
+        if self._block is not None:
+            if mode != "generate":
+                raise ValueError(
+                    f"mode={mode!r} is not served for "
+                    f"{type(self.cfg).__name__} (generation by blocks: "
+                    "only mode='generate')"
+                )
+            if np.any(prompt == self._block.mask_id):
+                raise ValueError(
+                    f"the prompt holds the mask id {self._block.mask_id}: "
+                    "a masked position is what the model fills in"
+                )
         if mode == "speculative" and self.draft_cfg is None:
             raise ValueError(
                 "mode='speculative' needs a server started with draft_cfg"
@@ -898,14 +980,29 @@ class InferenceServer:
     def _post_prefill(self, req: _Request, chosen) -> None:
         """Shared admission tail (batched/chunked/donor paths): record
         the prefix donor, take the first token (``chosen``, the id the
-        prefill's program picked), and either finish or join the decode
-        batch."""
+        prefill's program picked; None for a model that generates by
+        blocks), and either finish or join the decode batch."""
         plen = int(req.prompt.size)
         self.pool.note_prefix(req.slot, req.version, req.prompt.tobytes())
         now = time.perf_counter()
         req.timing["prefill"] = now
         tracing.record_request(req.rid, "prefill", t_s=now,
                                reused=req.prefix_reuse)
+        if self._block is not None:
+            # No token comes of a prompt (a position predicts itself):
+            # the request's first block opens at the end of the prompt's
+            # whole blocks, the left-over tokens already unmasked.
+            spec = self._block
+            left = plen % spec.length
+            req.pos = plen - left
+            req.block = [int(t) for t in req.prompt[req.pos:]] + [
+                spec.mask_id] * (spec.length - left)
+            req.block_step, req.block_out = 0, left
+            req.block_steps = [-1] * left + [0] * (spec.length - left)
+            with self._lock:
+                self._active[req.slot] = req
+                self._m_active.set(len(self._active))
+            return
         tok = self._sample(chosen, req)
         req.out.append(tok)
         req.pos = plen
@@ -931,6 +1028,15 @@ class InferenceServer:
         req.version, params = self.bank.acquire()
         return params
 
+    def _prefill_len(self, req: _Request) -> int:
+        """The prompt positions the prefill programs are given: all of
+        them, or the prompt's whole blocks for a model that generates by
+        blocks (the left-over tokens start its first block)."""
+        plen = int(req.prompt.size)
+        if self._block is not None:
+            plen -= plen % self._block.length
+        return plen
+
     def _admit_generate(
         self, req: _Request, slot: int, batch: List[_Request]
     ) -> str:
@@ -944,7 +1050,7 @@ class InferenceServer:
         tracing.record_request(req.rid, "admit", t_s=now,
                                version=req.version, slot=slot)
         req.slot = slot
-        plen = int(req.prompt.size)
+        plen = self._prefill_len(req)
         prompt_key = req.prompt.tobytes()
         if self.scfg.prefix_reuse:
             donor = self.pool.lookup_prefix(req.version, prompt_key)
@@ -972,6 +1078,10 @@ class InferenceServer:
                 # fall through: no blocks for the boundary clone — the
                 # plain grant below will hit the same wall and re-queue.
         chunk = self.scfg.prefill_chunk
+        if plen == 0:
+            # A prompt shorter than a block: nothing to prefill.
+            self._post_prefill(req, None)
+            return "ok"
         if plen <= chunk:
             status = self.pool.ensure_blocks(slot, plen - 1)
             if status != "ok":
@@ -1055,7 +1165,7 @@ class InferenceServer:
             return
         groups: Dict[tuple, List[_Request]] = {}
         for req in batch:
-            plen = int(req.prompt.size)
+            plen = self._prefill_len(req)
             bucket = next(
                 (b for b in self._buckets if b >= plen), self._buckets[-1]
             )
@@ -1072,8 +1182,8 @@ class InferenceServer:
                 tables = np.zeros((R, NB), np.int32)
                 landed = np.zeros(R, bool)
                 for req in reqs:
-                    plen = int(req.prompt.size)
-                    prompts[req.slot, :plen] = req.prompt
+                    plen = self._prefill_len(req)
+                    prompts[req.slot, :plen] = req.prompt[:plen]
                     last_idx[req.slot] = plen - 1
                     tables[req.slot] = self.pool.table(req.slot)
                     landed[req.slot] = True
@@ -1088,10 +1198,12 @@ class InferenceServer:
                 self.pool.scatter_rows(*slabs, tables, state_rows, landed)
                 self._count_state_resets(len(reqs))
                 for req in reqs:
-                    self._count_prefill(0, int(req.prompt.size))
-                ids = self._fetch(ids)
+                    self._count_prefill(0, self._prefill_len(req))
+                # (No id to read where a model generates by blocks.)
+                ids = None if self._block else self._fetch(ids)
                 for req in reqs:
-                    self._post_prefill(req, ids[req.slot])
+                    self._post_prefill(
+                        req, None if ids is None else ids[req.slot])
             except BaseException as e:  # noqa: BLE001 - per-group fault
                 for req in reqs:
                     if req.slot >= 0:
@@ -1124,7 +1236,7 @@ class InferenceServer:
             if budget < chunk:
                 break
             try:
-                plen = int(req.prompt.size)
+                plen = self._prefill_len(req)
                 off = req.chunk_done
                 if off == 0 and plen % chunk:
                     # Ragged remainder first, padded to a chunk bucket;
@@ -1167,7 +1279,8 @@ class InferenceServer:
                 if req.chunk_done >= plen:
                     with self._lock:
                         self._prefilling.remove(req)
-                    self._post_prefill(req, self._fetch(chosen))
+                    self._post_prefill(
+                        req, None if self._block else self._fetch(chosen))
             except BaseException as e:  # noqa: BLE001 - per-request fault
                 with self._lock:
                     if req in self._prefilling:
@@ -1195,9 +1308,13 @@ class InferenceServer:
         0 under an all-zero table, so it visits no block and writes into
         the sacrificial block 0; greedy in ``draw``, so it asks for no
         noise; and not ``live``, so whatever recurrent state its slot
-        holds comes back bit for bit."""
+        holds comes back bit for bit. For a model that generates by
+        blocks ``token`` is the row's block (``B`` ids, ``tokens`` (R,
+        B)) and ``position`` the block's first."""
         R = self.pool.max_slots
-        tokens = np.zeros(R, np.int32)
+        tokens = np.zeros(self.pool.ids_len, np.int32).reshape(R, -1)
+        if self._block is None:
+            tokens = tokens[:, 0]
         positions = np.zeros(R, np.int32)
         tables = np.zeros((R, self.pool.blocks_per_row), np.int32)
         live = np.zeros(R, bool)
@@ -1212,24 +1329,55 @@ class InferenceServer:
             tables[req.slot] = self.pool.table(req.slot)
             live[req.slot] = True
             reqs.append(req)
-        return (tokens, positions, tables, self._draw_inputs(reqs), live,
+        return (tokens, positions, tables,
+                self._draw_inputs(reqs, self._block is not None), live,
                 from_host)
 
-    def _draw_inputs(self, reqs) -> np.ndarray:
+    def _draw_inputs(self, reqs, blocks: bool = False) -> np.ndarray:
         """The sampler's per-row scalars for a program over all slots
         (:func:`sampling.pack`, one upload): each request's temperature,
         seed and the position in its output of the token about to be
         chosen (the tokens it has, and the one in flight), at its slot;
-        zero (greedy) everywhere else."""
+        zero (greedy) everywhere else. For a decode step that carries
+        ``blocks``: the place in the output of the block's first position
+        (below zero where the prompt's left-over tokens lead the block),
+        and the denoising step of the block as a fourth row."""
         R = self.pool.max_slots
         temperature = np.zeros(R, np.float32)
         seed = [0] * R
         index = np.zeros(R, np.int32)
+        step = np.zeros(R, np.int32) if blocks else None
         for req in reqs:
             temperature[req.slot] = req.temperature
             seed[req.slot] = req.seed
-            index[req.slot] = len(req.out) + req.ahead
-        return sampling.pack(temperature, seed, index)
+            if blocks:
+                pos, step[req.slot] = self._next_block(req)
+                index[req.slot] = pos - int(req.prompt.size)
+            else:
+                index[req.slot] = len(req.out) + req.ahead
+        return sampling.pack(temperature, seed, index, step)
+
+    def _next_block(self, req: _Request):
+        """(first position, denoising step) of the block that the next
+        step dispatched for ``req`` forwards. The host knows both though
+        it has not read the step in flight: that step commits exactly
+        when the block the last fetch showed is clean, and then the next
+        block opens; else the block stays, a step further on."""
+        if req.ahead and self._block.mask_id not in req.block:
+            return req.pos + self._block.length, 0
+        return req.pos, req.block_step + req.ahead
+
+    def _ends_in_flight(self, req: _Request) -> bool:
+        """True when the host knows that the step in flight ends ``req``:
+        its last token is in it, or (generation by blocks) its last block
+        is, with no more positions masked than that step must unmask."""
+        if self._block is None:
+            return len(req.out) + req.ahead >= req.max_new_tokens
+        spec = self._block
+        end = int(req.prompt.size) + req.max_new_tokens
+        masked = req.block.count(spec.mask_id)
+        return (req.ahead > 0 and req.pos + spec.length >= end
+                and 0 < masked <= spec.quota(req.block_step))
 
     def _fetch(self, ids) -> np.ndarray:
         """The ids a program chose, on the host (this waits for the
@@ -1250,9 +1398,17 @@ class InferenceServer:
             return ramp * (ramp + 1) // 2 + (upto - ramp) * window
 
         end = off + n                 # no window binds below this
-        keys = (self._n_layers - len(self._windows)) * (
-            seen(end, end) - seen(off, end)
-        ) + sum(seen(end, w) - seen(off, w) for w in self._windows)
+        if self._block is not None:
+            # Block-causal: a query sees every key up to its block's end
+            # (``off`` and ``n`` are whole blocks).
+            b = self._block.length
+            lo, hi = off // b, end // b
+            keys = self._n_layers * b * b * (
+                hi * (hi + 1) // 2 - lo * (lo + 1) // 2)
+        else:
+            keys = (self._n_layers - len(self._windows)) * (
+                seen(end, end) - seen(off, end)
+            ) + sum(seen(end, w) - seen(off, w) for w in self._windows)
         with self._lock:
             self._stats["prefill_tokens"] += n
             self._stats["prefill_keys_attended"] += keys
@@ -1294,7 +1450,9 @@ class InferenceServer:
         decode step, summed over rows and layers: a row at position
         ``pos`` sees its ``pos`` cached keys and its own, at most
         ``window`` of them on a windowed layer."""
-        seen = sum(pos + 1 for pos in positions)
+        b = self._block.length if self._block else 1
+        # (A block's b queries each see the context and the block.)
+        seen = sum(b * (pos + b) for pos in positions)
         return (self._n_layers - len(self._windows)) * seen + sum(
             min(pos + 1, window)
             for window in self._windows for pos in positions
@@ -1367,6 +1525,8 @@ class InferenceServer:
         self.pool.release(req.slot)
         req.slot = -1
         req.out = []
+        req.steps_out = []
+        req.block = None
         req.pos = 0
         req.chunk_done = 0
         req.stalled = False
@@ -1408,6 +1568,19 @@ class InferenceServer:
           lull or after every row sat one out) the iteration is build,
           dispatch and nothing to fetch: the ids come an iteration later.
 
+        Where the model generates by blocks the same holds with a block
+        for a token: the rows that were live in t take their BLOCK from
+        t's returned array, and whether t + 1 denoises it or commits it is
+        decided on the device from what t left of it. The host still
+        knows t + 1's position and denoising step (:meth:`_next_block`:
+        t commits exactly when the block the last fetch showed is clean),
+        grants the pool's block for it, and learns one fetch late what t
+        unmasked (:meth:`_take_block`). A row whose last block t is bound
+        to finish is not put into t + 1 (:meth:`_ends_in_flight`); one
+        whose last block t finished early (more confidences over the
+        threshold than the step had to unmask) was live in t + 1 and is
+        counted in ``rows_wasted``.
+
         Returns True when a step was dispatched or a token emitted."""
         with self._lock:
             groups: Dict[int, List[_Request]] = {}
@@ -1424,14 +1597,21 @@ class InferenceServer:
                 # the whole batch.
                 live, starved = [], []
                 for req in groups.get(version, ()):
-                    if len(req.out) + req.ahead >= req.max_new_tokens:
+                    if self._ends_in_flight(req):
                         continue      # its last token is in flight
-                    pos = req.pos + req.ahead
-                    status = self.pool.ensure_blocks(req.slot, pos)
+                    if self._block is None:
+                        pos = last = req.pos + req.ahead
+                        held = req.out[-1]
+                    else:
+                        # The block the step forwards, granted whole.
+                        pos = self._next_block(req)[0]
+                        last = pos + self._block.length - 1
+                        held = req.block
+                    status = self.pool.ensure_blocks(req.slot, last)
                     if status == "ok":
                         req.stalled = False
                         live.append((
-                            req, None if req.ahead else req.out[-1], pos
+                            req, None if req.ahead else held, pos
                         ))
                     elif status == "quota" and self._quota_hopeless(req):
                         self._fail_admitted(req, self._quota_exc(req))
@@ -1454,21 +1634,16 @@ class InferenceServer:
                     # Behind the R ids: what the model counted in that
                     # step.
                     for key, n in zip(self.pool.step_counters,
-                                      ids[self.pool.max_slots:]):
+                                      ids[self.pool.ids_len:]):
                         self._stats[key] += int(n)
                         self._m_step_counters[key].inc(int(n))
                 with tracing.phase("fed:serve:emit"):
+                    take = (self._take_token if self._block is None
+                            else self._take_block)
                     for req in prev.rows:
-                        tok = self._sample(ids[req.slot], req)
-                        req.out.append(tok)
-                        req.pos += 1
                         req.ahead -= 1
                         progressed = True
-                        self._emit_token(req, tok)
-                        if (
-                            len(req.out) >= req.max_new_tokens
-                            or tok == self.scfg.eos_id
-                        ):
+                        if take(req, ids):
                             if req.ahead:
                                 # Live in the step dispatched above.
                                 self._forget(req)
@@ -1485,6 +1660,67 @@ class InferenceServer:
                 req.stalled = req.slot >= 0
         return progressed
 
+    def _take_token(self, req: _Request, ids) -> bool:
+        """The token a fetched step chose for ``req``: emitted. True when
+        it ends the request."""
+        tok = self._sample(ids[req.slot], req)
+        req.out.append(tok)
+        req.pos += 1
+        self._emit_token(req, tok)
+        return (len(req.out) >= req.max_new_tokens
+                or tok == self.scfg.eos_id)
+
+    def _take_block(self, req: _Request, ids) -> bool:
+        """What a fetched step did with ``req``'s block (generation by
+        blocks). If the block the host held was clean, the step committed
+        it and opened the next. Else it denoised: the positions it
+        unmasked are noted with the step of their block, and every token
+        that now follows the last one emitted without a masked position
+        between leaves, in the order of the positions (so none leaves
+        later than the fetch that shows its block clean). True when the
+        request ends: an ``eos_id`` left, or the block that holds its
+        last token is clean (what it holds beyond ``max_new_tokens`` is
+        dropped and counted)."""
+        spec = self._block
+        n, mask = spec.length, spec.mask_id
+        if mask not in req.block:
+            req.pos += n
+            req.block = [mask] * n
+            req.block_steps = [0] * n
+            req.block_step = req.block_out = 0
+            return False
+        new = ids[req.slot * n:(req.slot + 1) * n]
+        for j in range(n):
+            if req.block[j] == mask and new[j] != mask:
+                req.block[j] = int(new[j])
+                req.block_steps[j] = req.block_step
+        req.block_step += 1
+        plen = int(req.prompt.size)
+        end = plen + req.max_new_tokens
+        dropped = 0
+        while req.block_out < n and req.block[req.block_out] != mask:
+            j = req.block_out
+            req.block_out += 1
+            if req.pos + j < plen:
+                continue
+            if req.pos + j >= end:
+                dropped += 1
+                continue
+            tok = self._sample(req.block[j], req)
+            req.out.append(tok)
+            req.steps_out.append(req.block_steps[j])
+            if len(req.out) == 1:
+                now = time.perf_counter()
+                req.timing["first_token"] = now
+                tracing.record_request(req.rid, "first_token", t_s=now)
+            self._emit_token(req, tok)
+            if tok == self.scfg.eos_id:
+                return True
+        if dropped:
+            self._stats["diffusion_positions_dropped"] += dropped
+            self._m_dropped.inc(dropped)
+        return req.block_out == n and req.pos + n >= end
+
     def _dispatch(self, params, live, inputs, prev) -> _Ahead:
         """Enqueue one decode step over the ``live`` rows' ``(request,
         token, position)`` and count it: the counters say what the device
@@ -1497,7 +1733,8 @@ class InferenceServer:
         for req in reqs:
             req.ahead += 1
         bs = self.pool.block_size
-        attended = sum(pos // bs + 1 for pos in positions)
+        width = self._block.length if self._block else 1
+        attended = sum((pos + width - 1) // bs + 1 for pos in positions)
         slab = self.pool.max_slots * self.pool.blocks_per_row
         by_layer = self._layer_blocks(positions, attended)
         keys = self._layer_keys(positions)
@@ -1577,6 +1814,9 @@ class InferenceServer:
             "timing": {k: float(v) for k, v in req.timing.items()},
             "latency_ms": float(latency_ms),
         }
+        if self._block is not None:
+            # Per token, the denoising step of its block that unmasked it.
+            resp["unmask_steps"] = list(req.steps_out)
         resp.update(req.extra_resp)
         req.future.set_result(resp)
 

@@ -207,3 +207,91 @@ def test_the_latent_programs_compile_for_v5e_and_fit_beside_their_pool(
         'custom_call_target="tpu_custom_call"') >= 12
     assert memory.temp_size_in_bytes < 1.5e9
     assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
+
+
+SDAR = {
+    "vocab_size": 151936, "hidden_size": 2048, "num_hidden_layers": 6,
+    "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+    "moe_intermediate_size": 768, "num_experts": 128,
+    "num_experts_per_tok": 8, "rope_theta": 1000000, "rms_norm_eps": 1e-6,
+    "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "rope_scaling": None, "use_sliding_window": False,
+    "tie_word_embeddings": False, "model_type": "sdar_moe",
+}
+SDAR_SLOTS, SDAR_MAX_LEN, SDAR_BLOCK = 96, 1024, 16
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_256",
+                                     "prefill_rows_256"])
+def test_the_block_programs_compile_for_v5e_and_fit_beside_their_pool(
+        program, one_chip, monkeypatch):
+    """``sdar-30b-a3b-chat`` as ``sdar30b-gen-closed128`` runs it: 8.72 GB
+    of weights (all 128 experts of 6 layers, the whole vocabulary twice)
+    and a pool of 12,288 B a token go in; the decode step is the POOL's
+    program (a block a row, the unmasking rule traced behind the head: 96
+    x 4 rows of 151,936 float32 logits), and what each program needs
+    beside weights and pool leaves the chip's 16.9 GB room."""
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import decode, sdar_moe
+    from rayfed_tpu.serving import kv_pool
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    cfg = sdar_moe.SdarMoeConfig.from_published(SDAR)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    d, f, e = cfg.d_model, cfg.d_expert, cfg.n_experts
+    q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    layer = {"ln1": (d,), "ln2": (d,), "q_norm": (cfg.head_dim,),
+             "k_norm": (cfg.head_dim,), "wq": (d, q), "wk": (d, kv),
+             "wv": (d, kv), "wo": (q, d), "router": (d, e),
+             "we_gate": (e, d, f), "we_up": (e, d, f), "we_down": (e, f, d)}
+    params = {
+        "embed": sds((cfg.vocab, d)), "ln_f": sds((d,)),
+        "lm_head": sds((cfg.vocab, d)),
+        "layers": [{k: sds(v) for k, v in layer.items()}
+                   for _ in range(cfg.n_layers)],
+    }
+    weights = sum(2 * int(jnp.prod(jnp.asarray(a.shape)))
+                  for a in jax.tree_util.tree_leaves(params))
+    assert round(weights / 1e9, 2) == 8.72
+    model = decode.serving_model(cfg)
+    blocks_per_row = -(-(SDAR_MAX_LEN + 1) // SDAR_BLOCK)
+    pool = sds((cfg.n_layers, 1 + SDAR_SLOTS * blocks_per_row, SDAR_BLOCK,
+                cfg.n_kv_heads, cfg.head_dim))
+    pool_bytes = 2 * 2 * int(jnp.prod(jnp.asarray(pool.shape)))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    r = SDAR_SLOTS
+    if program == "decode_step":
+        # The pool's own wrapper, built without allocating a pool.
+        shell = object.__new__(kv_pool.PagedKVPool)
+        shell.max_slots, shell.model = r, model
+        shell.block = model.block_spec()
+        shell.ids_len = r * shell.block.length
+        lowered = jax.jit(shell._block_step(), donate_argnums=(1,)).lower(
+            params, (pool, pool), i32(r, 4), i32(r), i32(r, blocks_per_row),
+            i32(4, r), i32(r * 4 + 5), sds((r,), jnp.bool_), {},
+            sds((r,), jnp.bool_))
+    elif program == "chunk_256":
+        lowered = jax.jit(model.chunk, donate_argnums=(1,)).lower(
+            params, (pool, pool), {}, i32(blocks_per_row), i32(), i32(256),
+            i32(), i32())
+    else:
+        lowered = jax.jit(model.prefill_rows, static_argnums=(3, 4)).lower(
+            params, i32(r, 256), i32(r), SDAR_MAX_LEN + 1, None,
+            sds((r,), jnp.bool_))
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    if program != "prefill_rows_256":
+        assert memory.alias_size_in_bytes >= pool_bytes
+    # The grouped kernel three times a layer, never a copy of a weight or
+    # of the pool. A prefill hands back no logits (a prompt's position
+    # predicts itself), so nothing reads the last layer's experts and the
+    # compiler drops them.
+    expert_layers = cfg.n_layers - (program != "decode_step")
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') >= 3 * expert_layers
+    print(program, memory.temp_size_in_bytes, memory.output_size_in_bytes)
+    assert memory.temp_size_in_bytes < 2.5e9
+    assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
