@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from rayfed_tpu import utils
 from rayfed_tpu.models import transformer as tfm
 
 Cache = dict
@@ -221,8 +222,31 @@ def paged_attention(pk, pv, positions, tables, window=None, *,
     kind from the same pool (:mod:`rayfed_tpu.models.cohere2_moe`); with
     ``window=None`` the loop and the mask are exactly the lines below,
     nothing added.
+
+    On a TPU backend the same ``attend`` is one Pallas kernel
+    (:mod:`rayfed_tpu.ops.paged_attention`, ``paged_read`` in a device
+    trace): each row copies its own blocks through its table, once, up to
+    its own length, and the loop below is the definition it is tested
+    against. What the two walk is :func:`paged_blocks_walked`.
     """
     n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
+    if scale is None:
+        scale = dh**-0.5
+    if paged_read_is_kernel(pk, pv, tables.size):
+        # The import is the engine's, begun on a thread of its own when
+        # the server was made: by now it is done, or this waits for it.
+        from rayfed_tpu.ops import paged_attention as kernel
+
+        pk_flat, pv_flat = (
+            None if a is None else a.reshape(-1, *a.shape[2:])
+            for a in (pk, pv))
+
+        def attend(q, k1, v1, base):
+            return kernel.paged_read(
+                q, k1, v1, pk_flat, pv_flat, positions, tables, base,
+                window=window, scale=scale, v_width=v_width)
+
+        return attend
     n_rows, blocks_per_row = tables.shape
     chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
     chunk_keys = chunk_blocks * bs
@@ -243,8 +267,6 @@ def paged_attention(pk, pv, positions, tables, window=None, *,
     pk_flat = pk.reshape(n_layers * n_phys, *pk.shape[2:])
     if pv is not None:
         pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
-    if scale is None:
-        scale = dh**-0.5
 
     def attend(q, k1, v1, base):
         n_heads = q.shape[1]
@@ -308,6 +330,52 @@ def paged_attention(pk, pv, positions, tables, window=None, *,
         return out.reshape(n_rows, n_heads, v1.shape[-1])
 
     return attend
+
+
+# Entries of the block tables the kernel takes: they are a scalar-prefetch
+# operand, whole in a core's 1 MiB of scalar memory beside the positions
+# (a v5e compiles 131,072 and refuses 262,144: ``tests/test_tpu_compile``).
+PAGED_KERNEL_TABLE_ENTRIES = 1 << 17
+
+
+def paged_read_is_kernel(pk, pv, table_entries: int) -> bool:
+    """Whether :func:`paged_attention` reads this pool through the Pallas
+    kernel: on a TPU backend, a pool the kernel can read under tables it
+    can hold. It takes K/V heads out of a block two at a time, from
+    32-bit words: an odd number of heads, or a dtype of another size than
+    these, keeps the loop, and so do tables of more than
+    ``PAGED_KERNEL_TABLE_ENTRIES`` (rows x blocks a row)."""
+    return (utils.is_tpu_backend()
+            and (pv is None or _pool_dims(pk)[3] % 2 == 0)
+            and pk.dtype in (jnp.bfloat16, jnp.float32)
+            and table_entries <= PAGED_KERNEL_TABLE_ENTRIES)
+
+
+def paged_blocks_walked(positions, block_size: int, n_rows: int,
+                        blocks_per_row: int, window=None, *,
+                        kernel: bool) -> int:
+    """Blocks of one array the read of one layer copies in a decode step
+    whose live rows are at ``positions`` (a host count, of Python ints).
+    The kernel copies what each live row's length covers, from its
+    window's first block where the layer has one. The loop gathers for
+    every one of the program's ``n_rows`` rows, live or junk, a chunk of
+    blocks a trip, as many trips as the longest row (or the widest
+    window's span) needs."""
+    bs = block_size
+    if kernel:
+        lo = [0 if window is None else max(p - window + 1, 0)
+              for p in positions]
+        return sum(-(-p // bs) - first // bs
+                   for p, first in zip(positions, lo))
+    chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
+    chunk_keys = chunk_blocks * bs
+    if window is None:
+        trips = -(-max(positions, default=0) // chunk_keys)
+    else:
+        trips = max((
+            (p - 1) // chunk_keys - max(p - window + 1, 0) // chunk_keys + 1
+            for p in positions if p > 0), default=0)
+    return n_rows * trips * chunk_blocks
 
 
 def _write_rows(pool, rows, w_block, w_off):

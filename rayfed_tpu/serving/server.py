@@ -82,6 +82,8 @@ dispatch. No device state is ever touched from two threads.
 
 from __future__ import annotations
 
+import functools
+import importlib
 import itertools
 import logging
 import threading
@@ -93,7 +95,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from rayfed_tpu import tracing
+from rayfed_tpu import tracing, utils
 from rayfed_tpu.config import ServingConfig
 from rayfed_tpu.models import decode
 from rayfed_tpu.models import transformer as tfm
@@ -204,6 +206,19 @@ class InferenceServer:
             config = ServingConfig.from_dict(config)
         self.cfg = model_cfg
         self.model = decode.serving_model(model_cfg)
+        # On a TPU a decode step reads the pool through a Pallas kernel
+        # (``decode.paged_attention``), and Pallas takes half a second to
+        # import: begun here, on a thread of its own, it runs wherever
+        # this thread and the engine's wait with the interpreter's lock
+        # released (a cold start's compiles; the bank's first casts), and
+        # the trace of the decode program, whose own ``import`` it is,
+        # waits for what is left of it.
+        if utils.is_tpu_backend() and not hasattr(self.model, "block_spec"):
+            threading.Thread(
+                target=importlib.import_module,
+                args=("rayfed_tpu.ops.paged_attention",),
+                name="fed-serve-kernel-import", daemon=True,
+            ).start()
         self.scfg = config or ServingConfig()
         self.draft_cfg = draft_cfg
         self.name = name
@@ -238,6 +253,14 @@ class InferenceServer:
         self._block = self.pool.block
         if self._block is not None:
             self._check_block_shapes()
+        # Which read a decode step makes of the pool: what it walks is
+        # counted by that (``kv_blocks_walked``).
+        pk, *pv = self.pool.kv
+        self._kernel_paged = (
+            self._block is None
+            and decode.paged_read_is_kernel(
+                pk, pv[0] if pv else None,
+                self.pool.max_slots * self.pool.blocks_per_row))
         self._prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
         self._special_fns: Dict[tuple, Any] = {}
@@ -274,6 +297,11 @@ class InferenceServer:
             # full length: a contiguous slab of the rows).
             "kv_blocks_attended": 0,
             "kv_blocks_slab": 0,
+            # Decode: blocks the step's read copies for them, the mean
+            # over the layers (``decode.paged_blocks_walked``): the
+            # kernel's what each row's length covers, the loop's every
+            # row of the program walked as far as the longest.
+            "kv_blocks_walked": 0,
             # Recurrent state (a model that has one): bytes of state the
             # live rows read and wrote, summed over decode steps; requests
             # started from a zero state; rows that sat a decode step out
@@ -410,6 +438,12 @@ class InferenceServer:
             "fed_serving_kv_blocks_attended_total",
             "KV blocks covered by live rows' lengths, summed over paged "
             "decode steps.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_kv_walked = _reg.counter(
+            "fed_serving_kv_blocks_walked_total",
+            "KV blocks the decode read copies for the live rows (the mean "
+            "over the layers), summed over paged decode steps.",
             labels=("server",),
         ).labels(server=name)
         self._m_kv_slab = _reg.counter(
@@ -1445,6 +1479,18 @@ class InferenceServer:
             for window in self._windows for pos in positions
         )
 
+    def _blocks_walked(self, positions) -> int:
+        """Blocks the read of a decode step copies for the live rows (at
+        ``positions``), the mean over the layers: a windowed layer's from
+        its window on."""
+        walked = functools.partial(
+            decode.paged_blocks_walked, positions, self.pool.block_size,
+            self.pool.max_slots, self.pool.blocks_per_row,
+            kernel=self._kernel_paged)
+        full = self._n_layers - len(self._windows)
+        return (full * walked() + sum(
+            walked(window) for window in self._windows)) // self._n_layers
+
     def _layer_keys(self, positions) -> int:
         """Keys the layers of the live rows (at ``positions``) score in a
         decode step, summed over rows and layers: a row at position
@@ -1738,8 +1784,11 @@ class InferenceServer:
         slab = self.pool.max_slots * self.pool.blocks_per_row
         by_layer = self._layer_blocks(positions, attended)
         keys = self._layer_keys(positions)
+        walked = self._blocks_walked(positions)
         self._stats["kv_blocks_attended"] += attended
         self._stats["kv_blocks_slab"] += slab
+        self._stats["kv_blocks_walked"] += walked
+        self._m_kv_walked.inc(walked)
         self._stats["kv_layer_blocks_attended"] += by_layer
         self._stats["decode_keys_attended"] += keys
         self._m_kv_attended.inc(attended)

@@ -30,6 +30,7 @@ describes it keeps the TPU's library until it exits.
 
 from __future__ import annotations
 
+import numpy as np
 import pytest
 
 import jax
@@ -121,6 +122,146 @@ def test_the_chunk_program_updates_the_donated_pool_in_place(one_chip):
     assert memory.temp_size_in_bytes < 400e6
 
 
+def _cell_decode_step(workload, one_chip):
+    """``jit_decode_step`` of a cell's model as the cell runs it (the
+    configuration's file cut as the benchmark cuts it, the mix's slots and
+    lengths), lowered for the described chip from shapes: (lowered,
+    bytes of weights, bytes of pool)."""
+    import importlib
+    import inspect
+
+    from chipbench import run
+    from rayfed_tpu.models import decode
+
+    plan = run.resolve(workload, False)
+    adapter = importlib.import_module("chipbench.seeded_" + plan["reference"])
+    cfg = adapter.program_cfg(plan["model"], plan["precision"])
+    model = decode.serving_model(cfg)
+    serving = plan["mix"]["serving"]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def tree(w):
+        # (Falcon-H1's lays its tree out by the model's keys.)
+        wants = inspect.signature(adapter.to_program_tree).parameters
+        return adapter.to_program_tree(w, *[plan["model"]][:len(wants) - 1])
+
+    def canonical():
+        return adapter.make_canonical(jax.random.PRNGKey(0), plan["model"])
+
+    try:
+        shapes = jax.eval_shape(lambda: tree(canonical()))
+    except jax.errors.TracerArrayConversionError:
+        # A program tree laid out on the host: from leaves that take no
+        # room (one zero, broadcast), not from the weights.
+        shapes = tree(jax.tree_util.tree_map(
+            lambda a: np.broadcast_to(np.zeros((), a.dtype), a.shape),
+            jax.eval_shape(canonical)))
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    layers, rows = model.kv_spec()
+    slots, block = serving["max_slots"], serving["kv_block_size"]
+    blocks_per_row = -(-(serving["max_len"] + 1) // block)
+    kv = tuple(sds((layers, 1 + slots * blocks_per_row, block, *row))
+               for row in rows)
+    state = {name: sds((layers, slots, *shape), dtype) for name, (
+        shape, dtype) in model.state_spec(jnp.bfloat16).items()}
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    lowered = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
+        params, kv, state, i32(slots), i32(slots),
+        i32(slots, blocks_per_row), sds((slots,), jnp.bool_))
+    nbytes = lambda t: sum(  # noqa: E731
+        a.dtype.itemsize * int(jnp.prod(jnp.asarray(a.shape)))
+        for a in jax.tree_util.tree_leaves(t))
+    return lowered, nbytes(params), nbytes(kv)
+
+
+@pytest.mark.parametrize("workload, reads", [
+    ("falconh1-chat-closed48", 1),      # grouped heads; layers a scan
+    ("commandaplus-docs-closed24", 2),  # a full and a windowed form
+])
+def test_the_paged_read_is_one_kernel_a_form_in_the_decode_step(
+        workload, reads, one_chip, monkeypatch):
+    """The decode step of the hybrid and of the windowed model at their
+    cells' shapes, compiled for v5e with the paged read as the Pallas
+    kernel: lowered once a form however many layers call it (an inner
+    ``jit``: the lowering is part of every start-up), present in the
+    program under its name, the pool aliased and never copied."""
+    from rayfed_tpu import utils
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    lowered, weights, pool_bytes = _cell_decode_step(workload, one_chip)
+    # One function a form in the lowered module, called from every layer.
+    assert lowered.as_text().count("func.func private @paged_read") == reads
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "paged_read" in text
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
+
+
+def test_the_dense_decode_step_compiles_for_v5e_with_the_paged_read(
+        one_chip, monkeypatch):
+    """``coder1b-complete-closed16``'s decode step (24 layers in a scan,
+    16 heads of 128, 6 rows of 2,049 positions in blocks of 16): the
+    kernel is in the program, both halves of the donated pool come back
+    aliased, and beside them the step needs room for six rows'
+    activations and the kernel's buffers, not for a gathered chunk."""
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import decode
+    from rayfed_tpu.models import transformer as tfm
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    cfg = tfm.TransformerConfig(vocab=32256, d_model=2048, n_heads=16,
+                                n_layers=24, d_ff=5504, rope_theta=1e5)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    blocks_per_row = -(-2049 // 16)
+    pool = sds((24, 1 + 6 * blocks_per_row, 16, 16, 128))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    compiled = jax.jit(
+        decode.serving_model(cfg).decode_step, donate_argnums=(1,)
+    ).lower(params, (pool, pool), {}, i32(6), i32(6), i32(6, blocks_per_row),
+            None).compile()
+    pool_bytes = 2 * 24 * (1 + 6 * blocks_per_row) * 16 * 16 * 128 * 2
+    memory = compiled.memory_analysis()
+    assert "paged_read" in compiled.as_text()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("rows, compiles", [(128, True), (256, False)])
+def test_the_paged_read_holds_its_tables_in_scalar_memory(
+        rows, compiles, one_chip):
+    """The block tables are a scalar-prefetch operand: 1,024 blocks a row
+    compile at 128 rows (``decode.PAGED_KERNEL_TABLE_ENTRIES``, up to
+    which ``decode.paged_attention`` asks for the kernel) and are refused
+    at 256 (1 MiB of scalar memory a core), where it keeps the loop."""
+    from rayfed_tpu.models import decode
+    from rayfed_tpu.ops import paged_attention
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = 1024
+    assert (rows * blocks <= decode.PAGED_KERNEL_TABLE_ENTRIES) == compiles
+    row, pool = sds((rows, 8, 128)), sds((2 * 4097, 16, 8, 128))
+    lowered = jax.jit(lambda *a: paged_attention.paged_read(
+        *a, window=None, scale=0.088)).lower(
+        row, row, row, pool, pool, sds((rows,), jnp.int32),
+        sds((rows, blocks), jnp.int32), sds((), jnp.int32))
+    if compiles:
+        assert "%paged_read" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="smem|RESOURCE_EXHAUSTED"):
+            lowered.compile()
+
+
 PANGU = {
     "hidden_size": 7680, "intermediate_size": 18432,
     "moe_intermediate_size": 2048, "num_attention_heads": 128,
@@ -201,6 +342,10 @@ def test_the_latent_programs_compile_for_v5e_and_fit_beside_their_pool(
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
+    # The decode step reads the latent pool through the paged kernel (one
+    # array, every head the same rows); the chunk's read is a loop still.
+    assert ("%paged_read" in compiled.as_text()) == (
+        program == "decode_step")
     # The grouped kernel three times an expert layer, never a copy of a
     # weight or of the pool: room for activations and score tiles only.
     assert compiled.as_text().count(
