@@ -442,7 +442,7 @@ class Cohere2MoeServing:
 
     def kv_spec(self):
         head = (self.cfg.n_kv_heads, self.cfg.head_dim)
-        return self.cfg.n_layers, (head, head)
+        return (self.cfg.n_layers, head), (self.cfg.n_layers, head)
 
     def state_spec(self, cache_dtype=None):
         return {}

@@ -338,6 +338,16 @@ def paged_attention(pk, pv, positions, tables, window=None, *,
 PAGED_KERNEL_TABLE_ENTRIES = 1 << 17
 
 
+def paged_read_operands(kv_spec, kv):
+    """``(pk, pv)`` of a pool's arrays ``kv`` as :func:`paged_attention`
+    takes them, by what the model's ``kv_spec()`` declares a token keeps:
+    a key and a value row of (heads, head size) are read together; an
+    array of single vectors (a latent row) is read alone, ``pv`` None,
+    and whatever else the pool holds is another read's."""
+    per_head = len(kv_spec[0][1]) == 2
+    return kv[0], (kv[1] if per_head else None)
+
+
 def paged_read_is_kernel(pk, pv, table_entries: int) -> bool:
     """Whether :func:`paged_attention` reads this pool through the Pallas
     kernel: on a TPU backend, a pool the kernel can read under tables it
@@ -566,6 +576,16 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
     .. p``, ``lo`` is the first key the chunk's first query sees, rounded
     down to a trip, and keys before a query's window are masked.
 
+    ``attend``'s ``seen`` (C, W) bool, where given, is a SELECTION: query
+    ``i`` attends key position ``s`` only where ``seen[i, s]`` (and the
+    masks above allow it): the keys a learned indexer kept
+    (:func:`select_mask` of :func:`paged_chunk_index_scores`), each query
+    its own set. The trips are those that exist; a trip's part of the
+    selection masks its scores. A query's own key need not be among its
+    keys, so the softmax is then kept finite where a block holds none of
+    them (the maximum starts at minus infinity and the sum at 0), and
+    every query must be given at least one key.
+
     A pool that keeps one latent array a token (``pv`` None, ``pk`` (L,
     P, bs, width)) is read in the expanded form: ``attend`` takes a fifth
     argument, ``expand``,
@@ -588,13 +608,23 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
     if pv is not None:
         pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
 
-    def attend(q, k, v, base, expand=None):
+    def attend(q, k, v, base, expand=None, seen=None):
         c, n_heads, d_qk = q.shape
         n_k = k.shape[1]
         scale = d_qk**-0.5
         q = q.reshape(c, n_k, n_heads // n_k, d_qk)
         idx = jnp.arange(c)
         q_pos = offset + idx
+        if seen is not None:
+            # As wide as the trips reach and the chunk's own keys lie.
+            reach = max(table_p.shape[0] * bs, blocks_per_row * bs + c)
+            seen = jnp.pad(
+                seen, ((0, 0), (0, max(0, reach - seen.shape[1]))))
+
+        def finite(m):
+            # The maximum a softmax is shifted by: 0 where no key was seen
+            # yet (a selection only; else it is always finite).
+            return m if seen is None else jnp.where(jnp.isfinite(m), m, 0.0)
 
         def scores(keys, seen):
             s = jnp.einsum(
@@ -607,9 +637,14 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
             (idx < n_real)[None, :] | (idx[None, :] == idx[:, None]))
         if window is not None:
             own &= idx[None, :] > idx[:, None] - window
+        if seen is not None:
+            # (A padded query keeps itself whatever its junk scores chose:
+            # no softmax is empty.)
+            own &= jax.lax.dynamic_slice(seen, (0, offset), (c, c)) | (
+                (idx >= n_real)[:, None] & (idx[None, :] == idx[:, None]))
         s = scores(k, own)
         m = s.max(-1)
-        p = jnp.exp(s - m[..., None])
+        p = jnp.exp(s - finite(m)[..., None])
         init = (m, p.sum(-1), jnp.einsum(
             "hgqk,khd->hgqd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32,
@@ -628,10 +663,13 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
             cached = (k_pos < offset)[None, :]
             if window is not None:
                 cached &= k_pos[None, :] > q_pos[:, None] - window
+            if seen is not None:
+                cached &= jax.lax.dynamic_slice(
+                    seen, (0, t * trip_keys), (c, trip_keys))
             s = scores(kc, cached)
             m_new = jnp.maximum(m, s.max(-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - finite(m_new))
+            p = jnp.exp(s - finite(m_new)[..., None])
             den = den * alpha + p.sum(-1)
             acc = acc * alpha[..., None] + jnp.einsum(
                 "hgqk,khd->hgqd", p.astype(vc.dtype), vc,
@@ -644,6 +682,200 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
         return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, v.shape[-1])
 
     return attend
+
+
+# ---------------------------------------------------------------------------
+# A learned selection of keys (an indexer's cache beside the rows it picks)
+# ---------------------------------------------------------------------------
+
+
+def index_scores(qi, w, keys):
+    """An indexer's scores ``I[q, s] = sum_j w[q, j] relu(qi[q, j] .
+    keys[s])``, float32: ``qi`` (Q, J, D) the queries' index heads, ``w``
+    (Q, J) float32 their weights, ``keys`` (K, D) one index key a token,
+    or (Q, K, D) where each query has keys of its own (a decode step's
+    rows). Products take the operands as they come and accumulate in
+    float32."""
+    eq = "qjd,qkd->qjk" if keys.ndim == 3 else "qjd,kd->qjk"
+    s = jnp.einsum(eq, qi, keys, preferred_element_type=jnp.float32)
+    return jnp.einsum("qjk,qj->qk", jax.nn.relu(s), w)
+
+
+def select_mask(scores, valid, k: int):
+    """The selection itself: per row of ``scores`` (Q, W) float32 the
+    ``min(k, valid keys)`` positions of largest score among those
+    ``valid`` (Q, W) bool names, the lower position at a tie, as a (Q, W)
+    bool. EXACT: the set is the definition of the model's attention, not
+    an approximation of it.
+
+    No sort: the ``k``-th largest score of a row is found bit by bit (a
+    float's bits, the sign flipped, order as the float does; 32 counts of
+    ``scores >= candidate`` over the row), then every score above it is
+    kept and of those equal to it the lowest positions that fill the
+    ``k``. A sort of (512, 33k) scores costs a chunk more than the rest
+    of its layer (``PERF.md`` section 6, PR 44)."""
+    u = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
+    u = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+    u = jnp.where(valid, u, jnp.uint32(0))
+    need = jnp.minimum(k, jnp.sum(valid, -1, dtype=jnp.int32))
+
+    def bit(i, th):
+        cand = th | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        count = jnp.sum(u >= cand[:, None], -1, dtype=jnp.int32)
+        return jnp.where(count >= need, cand, th)
+
+    # The largest threshold that at least ``need`` keys reach: the
+    # need-th largest score (all ones where a row needs none).
+    th = jax.lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[:1], jnp.uint32))
+    above = u > th[:, None]
+    equal = (u == th[:, None]) & valid
+    room = need - jnp.sum(above, -1, dtype=jnp.int32)
+    n_equal = jnp.sum(equal, -1, dtype=jnp.int32)
+    # Nearly always the threshold is one key's score: only a tie that
+    # does not fit is ranked by position.
+    equal = jax.lax.cond(
+        jnp.all(n_equal == room), lambda: equal,
+        lambda: equal & (jnp.cumsum(equal, -1, dtype=jnp.int32)
+                         <= room[:, None]))
+    return above | equal
+
+
+def _index_pool(pi, blocks_per_row: int, trip_keys: int):
+    """(flat pool, block size, key width, blocks a trip, keys a trip) of
+    an array of index keys (L_a, P, bs, D) read ``trip_keys`` at a time."""
+    n_layers, n_phys, bs, _, dh = _pool_dims(pi)
+    trip_blocks = max(1, min(blocks_per_row, trip_keys // bs))
+    return (pi.reshape(n_layers * n_phys, bs, dh), bs, dh, trip_blocks,
+            trip_blocks * bs)
+
+
+def paged_index_scores(pi, positions, tables):
+    """An indexer's read of ITS array of the pool (``pi`` (L_a, P, bs,
+    D): one index key a token on each indexed layer) through the block
+    tables, for one decode token a row. Returns ``score(qi, w, k1,
+    base)``: ``qi`` (R, J, D) a layer's index queries, ``w`` (R, J) their
+    weights, ``k1`` (R, D) the new token's own index key (in hand, not
+    yet in the pool), ``base`` the layer's first row of the flattened
+    array (its ordinal among the indexed layers times P); the result is
+    ``(scores (R, W) float32, valid (R, W) bool)``, column ``s`` the key
+    at position ``s``, valid up to the row's own position.
+    ``PAGED_CHUNK_KEYS`` keys are gathered a trip, and the trips are a
+    runtime count (the longest row's): a junk row (position 0) scores
+    its own key and nothing else."""
+    n_rows, blocks_per_row = tables.shape
+    pi_flat, bs, dh, chunk_blocks, chunk_keys = _index_pool(
+        pi, blocks_per_row, PAGED_CHUNK_KEYS)
+    tables_p = jnp.pad(
+        tables, ((0, 0), (0, -blocks_per_row % chunk_blocks)))
+    width = tables_p.shape[1] * bs
+    trips = (jnp.max(positions) + chunk_keys - 1) // chunk_keys
+    col = jnp.arange(width)
+
+    def score(qi, w, k1, base):
+        # (The pool's rows may be padded to whole tiles, with zeros.)
+        qi, k1 = to_width(qi, dh), to_width(k1, dh)
+
+        def chunk(c, buf):
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                tables_p, c * chunk_blocks, chunk_blocks, axis=1)
+            kc = pi_flat[blocks].reshape(n_rows, chunk_keys, dh)
+            return jax.lax.dynamic_update_slice(
+                buf, index_scores(qi, w, kc), (0, c * chunk_keys))
+
+        buf = jax.lax.fori_loop(
+            0, trips, chunk, jnp.zeros((n_rows, width), jnp.float32))
+        own = index_scores(qi, w, k1[:, None].astype(pi.dtype))
+        buf = jnp.where(col[None] == positions[:, None], own, buf)
+        return buf, col[None] <= positions[:, None]
+
+    return score
+
+
+def paged_selected_attention(pc, positions, tables, k: int, *, scale,
+                             v_width):
+    """The read of a pool of latent rows (``pc`` (L_a, P, bs, width))
+    over a SELECTION, for one decode token a row: of each row's keys the
+    ``min(k, position + 1)`` of largest index score. Returns ``attend(q,
+    c1, scores, valid, base)``: ``q`` (R, H, <= width) the queries in the
+    space of the cached row, ``c1`` (R, 1, <= width) the new token's own
+    row (in hand), ``scores``/``valid`` (R, W) of
+    :func:`paged_index_scores`, ``base`` the layer's first row of the
+    flattened array; the result is (R, H, v_width): the softmax over the
+    selected rows only, a key's value its own first ``v_width`` columns.
+
+    The top-``k`` is ``jax.lax.top_k`` (exact; equal scores in order of
+    position) over each row's valid scores; the chosen rows are read one
+    gather through the tables ((R, k) single rows: what a step reads of
+    this array does not grow with the context past ``k``), the row's own
+    among them taken from ``c1``. A junk row (position 0) attends its
+    own key."""
+    n_layers, n_phys, bs, _, width = _pool_dims(pc)
+    pc_flat = pc.reshape(n_layers * n_phys, bs, width)
+    n_sel = min(k, tables.shape[1] * bs)
+
+    def attend(q, c1, scores, valid, base):
+        top, sel = jax.lax.top_k(
+            jnp.where(valid, scores, -jnp.inf)[:, :tables.shape[1] * bs],
+            n_sel)
+        kept = top > -jnp.inf
+        blocks = base + jnp.take_along_axis(tables, sel // bs, axis=1)
+        rows = pc_flat[blocks, sel % bs]                 # (R, n_sel, width)
+        own = (sel == positions[:, None])[..., None]
+        rows = jnp.where(own, to_width(c1, width).astype(pc.dtype), rows)
+        s = jnp.einsum("rhd,rkd->rhk", to_width(q, width), rows,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(kept[:, None], s, -jnp.inf), axis=-1)
+        out = jnp.einsum("rhk,rkd->rhd", p.astype(rows.dtype),
+                         rows[..., :v_width],
+                         preferred_element_type=jnp.float32)
+        return out.astype(c1.dtype)
+
+    return attend
+
+
+# Index keys scored a trip of a prompt chunk's indexer: a trip's float32
+# products are C x J x this wide (512 x 64 x 1,024: 134 MB) before the
+# heads are summed.
+INDEX_TRIP_KEYS = 1024
+
+
+def paged_chunk_index_scores(pi, table, offset):
+    """:func:`paged_index_scores` for one chunk of one slot's prompt:
+    ``score(qi, w, k_own, base)`` with ``qi`` (C, J, D), ``w`` (C, J) the
+    chunk's index queries at positions ``offset .. offset + C - 1`` and
+    ``k_own`` (C, D) its own index keys (in hand); the cached keys ``[0,
+    offset)`` are gathered through ``table`` ``INDEX_TRIP_KEYS`` at a
+    time, a runtime count of trips. Returns ``(scores (C, W), valid (C,
+    W))``: column ``s`` the key at position ``s``, valid up to each
+    query's own position: :func:`select_mask`'s arguments."""
+    (blocks_per_row,) = table.shape
+    pi_flat, bs, dh, trip_blocks, trip_keys = _index_pool(
+        pi, blocks_per_row, INDEX_TRIP_KEYS)
+    table_p = jnp.pad(table, (0, -blocks_per_row % trip_blocks))
+
+    def score(qi, w, k_own, base):
+        c = qi.shape[0]
+        width = table_p.shape[0] * bs + c
+        qi, k_own = to_width(qi, dh), to_width(k_own, dh)
+
+        def trip(t, buf):
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                table_p, t * trip_blocks, trip_blocks)
+            kc = pi_flat[blocks].reshape(trip_keys, dh)
+            return jax.lax.dynamic_update_slice(
+                buf, index_scores(qi, w, kc), (0, t * trip_keys))
+
+        buf = jax.lax.fori_loop(
+            0, (offset + trip_keys - 1) // trip_keys, trip,
+            jnp.zeros((c, width), jnp.float32))
+        # The chunk's own keys last: a trip's tail past ``offset`` held
+        # whatever the blocks did.
+        buf = jax.lax.dynamic_update_slice(
+            buf, index_scores(qi, w, k_own.astype(pi.dtype)), (0, offset))
+        valid = jnp.arange(width)[None] <= (offset + jnp.arange(c))[:, None]
+        return buf, valid
+
+    return score
 
 
 def paged_chunk_write(pk, pv, k_new, v_new, table, offset):
@@ -710,22 +942,29 @@ class TransformerServing:
     the module that defines the config's class. It tells the pool what
     to hold and gives the engine its programs.
 
-    ``kv_spec()`` declares what a token keeps in the paged pool: the
-    number of layers and, per layer, the shapes of the arrays a token has
-    a row in. This model (and the hybrid and the expert model) declares
-    a key and a value array, ``((H, Dh), (H, Dh))``; a model with latent
-    attention declares one vector, ``((width,),)``
-    (:mod:`rayfed_tpu.models.pangu_ultra_moe`). The pool allocates one
-    ``(L, 1 + blocks, block, *shape)`` array per entry (a lone vector
-    padded to whole tiles: ``kv_pool.LANES``) and hands the
+    ``kv_spec()`` declares what a token keeps in the paged pool: one
+    ``(layers, shape)`` pair per array, ``shape`` a token's row in that
+    array and ``layers`` how many of the model's layers keep such a row
+    (the layers OF THAT ARRAY, not the model's depth: a program indexes
+    an array by a layer's ordinal among the layers that keep it). This
+    model (and the hybrid and the expert models) declares a key and a
+    value array over all its layers, ``((L, (H, Dh)), (L, (H, Dh)))``; a
+    model with latent attention declares one vector, ``((L, (width,)),)``
+    (:mod:`rayfed_tpu.models.pangu_ultra_moe`); one whose layer kinds
+    keep different things declares an array per thing, each as deep as
+    the layers of its kind (:mod:`rayfed_tpu.models.dots3_note`: a
+    latent row and an index key on its indexed layers, a wider latent
+    row on its windowed ones). The pool allocates one
+    ``(layers, 1 + blocks, block, *shape)`` array per entry (a lone
+    vector padded to whole tiles: ``kv_pool.LANES``) and hands the
     programs the tuple of them, ``kv``, donated; they hand it back in the
     same order. Nothing else is assumed of it: how a token's row is read
     is the model's.
 
     ``prefill_rows`` (a round of right-padded short prompts, one row
     each) returns the logits at each row's last position, the rows of
-    the cache ``(L, R, S, *shape)`` per declared array, and the rows'
-    state; ``chunk`` (one chunk of a long prompt: its context read from
+    the cache ``(layers of the array, R, S, *shape)`` per declared
+    array, and the rows' state; ``chunk`` (one chunk of a long prompt: its context read from
     the pool through the slot's block table, its own rows written there
     in place) and ``decode_step`` (one token for every row, the cache
     read through the block tables) take and return ``kv``.
@@ -753,10 +992,11 @@ class TransformerServing:
         self.cfg = cfg
 
     def kv_spec(self):
-        """(layers, the per-token shapes of the arrays the pool holds):
-        a key and a value row of (heads, head size)."""
+        """A (layers of the array, per-token shape) pair per array the
+        pool holds: a key and a value row of (heads, head size), both
+        over every layer."""
         head = (self.cfg.n_heads, self.cfg.head_dim)
-        return self.cfg.n_layers, (head, head)
+        return (self.cfg.n_layers, head), (self.cfg.n_layers, head)
 
     def state_spec(self, cache_dtype=None):
         return {}

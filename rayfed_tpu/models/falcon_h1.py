@@ -622,7 +622,7 @@ class FalconH1Serving:
 
     def kv_spec(self):
         head = (self.cfg.n_kv_heads, self.cfg.head_dim)
-        return self.cfg.n_layers, (head, head)
+        return (self.cfg.n_layers, head), (self.cfg.n_layers, head)
 
     def state_spec(self, cache_dtype=None):
         """Per layer and slot, beside the paged K/V: name -> (shape,

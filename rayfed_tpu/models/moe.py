@@ -267,16 +267,25 @@ def moe_ffn_apply_topk(params: Params, x, k: int = 2):
 # The grouped path: sigmoid top-k routing, held experts, shared experts
 # ---------------------------------------------------------------------------
 
-def route_sigmoid_topk(h, router, k: int):
+def route_sigmoid_topk(h, router, k: int, bias=None):
     """Scores ``sigmoid(h router)`` over ALL experts in float32 (operands
     as they come, float32 accumulation), the ``k`` largest, their weights
     normalised over the ``k``. ``h`` (T, d), ``router`` (d, E). Returns
-    (expert ids (T, k) int32, weights (T, k) float32)."""
+    (expert ids (T, k) int32, weights (T, k) float32).
+
+    ``bias`` (E,), where the model has one (``noaux_tc``'s learned
+    ``e_score_correction_bias``), is added to the scores to CHOOSE the
+    ``k`` and not to weigh them: the weights are the chosen experts' own
+    scores, normalised. Without it the lines traced are those above."""
     with jax.named_scope("serve/moe_route"):
         scores = jax.nn.sigmoid(jnp.einsum(
             "td,de->te", h, router.astype(h.dtype),
             preferred_element_type=jnp.float32))
-        top, idx = lax.top_k(scores, k)
+        if bias is None:
+            top, idx = lax.top_k(scores, k)
+        else:
+            _, idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
         return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
@@ -366,6 +375,9 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
     1 where it has none); what an absent expert would add is left out (it
     lies on another chip). ``scoring`` names the router's scores
     (``ROUTERS``: "sigmoid", or "softmax" over all experts).
+    A layer that holds ``router_bias`` (E,) chooses by ``scores +
+    bias`` and weighs by the scores (:func:`route_sigmoid_topk`; sigmoid
+    scoring only); a layer without the leaf routes as it always did.
     ``live`` (T,) bool names the rows that count
     (None: all):
     padding and junk rows are routed nowhere and touch no expert.
@@ -383,7 +395,15 @@ def routed_experts(h, layer: Params, held: Sequence[int], k: int,
     t, d = h.shape
     n_held = len(held)
     n_experts = layer["router"].shape[-1]
-    idx, w = ROUTERS[scoring](h, layer["router"], k)
+    if "router_bias" in layer:
+        if scoring != "sigmoid":
+            raise ValueError(
+                f"a selection bias is computed for sigmoid scores, not "
+                f"{scoring!r}")
+        idx, w = route_sigmoid_topk(h, layer["router"], k,
+                                    layer["router_bias"])
+    else:
+        idx, w = ROUTERS[scoring](h, layer["router"], k)
     with jax.named_scope("serve/moe_experts"):
         # Global expert id -> its index among the held ones; n_held for
         # an expert that lies elsewhere.

@@ -225,15 +225,26 @@ def _mm(x, w, cfg):
                       preferred_element_type=F32)
 
 
-def project(h, layer, positions, cfg: PanguUltraMoeConfig):
-    """The low-rank projections of a normed ``h`` (..., S, d) at
-    ``positions`` (..., S): ``qn`` (..., S, H, dn), ``qr`` (..., S, H,
-    dr) rotated, and the latent row ``c`` (..., S, 1, rkv + dr): the
-    normed latent beside the rotated positional key, as it is cached."""
+def project_low_rank(h, layer, positions, cfg, q_scale=1.0, kv_scale=1.0):
+    """The two low ranks of a normed ``h`` (..., S, d) at ``positions``
+    (..., S), and the queries: ``cq`` (..., S, rq) the query's latent
+    after its norm, ``qn`` (..., S, H, dn), ``qr`` (..., S, H, dr)
+    rotated, and the latent row ``c`` (..., S, 1, rkv + dr): the normed
+    latent beside the rotated positional key, as it is cached. ``cfg``
+    is any object with the latent attention's sizes (``n_heads``,
+    ``kv_rank``, ``d_nope``, ``d_rope``, ``d_v``, ``cache_width``,
+    ``rope_theta``, ``rms_eps``, ``compute_dtype``): a model with two
+    kinds of latent layers hands one per kind
+    (:mod:`rayfed_tpu.models.dots3_note`). ``q_scale`` / ``kv_scale``
+    are constants a model multiplies the two latents by after their
+    norms (1: none, and nothing is traced for them)."""
     with jax.named_scope("serve/mla_project"):
         cdt = cfg.compute_dtype
         cq = rms_norm(_mm(h, layer["wq_a"], cfg), layer["q_norm"],
-                      cfg.rms_eps).astype(cdt)
+                      cfg.rms_eps)
+        if q_scale != 1.0:
+            cq = cq * q_scale
+        cq = cq.astype(cdt)
         q = _mm(cq, layer["wq_b"], cfg)
         q = q.reshape(*q.shape[:-1], cfg.n_heads, cfg.d_nope + cfg.d_rope)
         qn = q[..., :cfg.d_nope].astype(cdt)
@@ -241,10 +252,18 @@ def project(h, layer, positions, cfg: PanguUltraMoeConfig):
         ckr = _mm(h, layer["wkv_a"], cfg)
         ckv = rms_norm(ckr[..., :cfg.kv_rank], layer["kv_norm"],
                        cfg.rms_eps)
+        if kv_scale != 1.0:
+            ckv = ckv * kv_scale
         kr = rope_halves(ckr[..., None, cfg.kv_rank:], positions,
                          cfg.rope_theta)
         c = jnp.concatenate([ckv[..., None, :], kr], -1).astype(cdt)
-        return qn, qr.astype(cdt), c
+        return cq, qn, qr.astype(cdt), c
+
+
+def project(h, layer, positions, cfg: PanguUltraMoeConfig):
+    """:func:`project_low_rank` for this model: ``qn``, ``qr`` and the
+    latent row ``c``, nothing rescaled."""
+    return project_low_rank(h, layer, positions, cfg)[1:]
 
 
 def expand(c, layer, cfg: PanguUltraMoeConfig):
@@ -489,8 +508,9 @@ class PanguUltraMoeServing:
         self.cfg = cfg
 
     def kv_spec(self):
-        """One array: a token's latent row, (kv_rank + d_rope,)."""
-        return self.cfg.n_layers, ((self.cfg.cache_width,),)
+        """One array over every layer: a token's latent row, (kv_rank +
+        d_rope,)."""
+        return ((self.cfg.n_layers, (self.cfg.cache_width,)),)
 
     def state_spec(self, cache_dtype=None):
         return {}

@@ -18,12 +18,18 @@
 al. 2023) over the stacked-cache layout of
 :mod:`rayfed_tpu.models.decode`: the physical cache is what the served
 model declares a token keeps (``kv_spec()`` of its serving protocol):
-one (L, 1 + num_blocks, block_size, *shape) array per declared per-token
-shape, allocated at server start. A model with keys and values declares
-two arrays of (H, Dh), and "K/V" below means that pair; a model with
-latent attention declares ONE array of (width,) and nothing else is
-held for it (no second copy, no per-head K/V; ``LANES`` below for how
-it is allocated). Each of ``max_slots`` rows (a *slot*, borrowed by one
+one (L_a, 1 + num_blocks, block_size, *shape) array per declared array,
+allocated at server start, where ``L_a`` is the number of the model's
+layers that keep a row in THAT array (each array declares its own: a
+model whose layer kinds keep different things allocates every array
+for the layers that use it, and a program indexes an array by a
+layer's ordinal among those). A model with keys and values declares
+two arrays of (H, Dh) over all its layers, and "K/V" below means that
+pair; a model with latent attention declares ONE array of (width,)
+and nothing else is held for it (no second copy, no per-head K/V;
+``LANES`` below for how it is allocated); a model with two kinds of
+latent layers and an indexer declares three
+(:mod:`rayfed_tpu.models.dots3_note`). Each of ``max_slots`` rows (a *slot*, borrowed by one
 request for
 its lifetime) holds an int32 *block table* mapping logical block i of
 its sequence to a physical block. Blocks are granted on demand at token
@@ -70,7 +76,7 @@ was overwritten by its own prefill/decode first.
 
 Recurrent state (a model whose ``state_spec`` is not empty, e.g.
 :mod:`rayfed_tpu.models.falcon_h1`): the pool also owns one
-``(L, max_slots, *shape)`` array per entry of the spec, donated through
+``(model layers, max_slots, *shape)`` array per entry of the spec, donated through
 the decode step like the K/V pair. Unlike K/V it is *carried*, so none
 of the "stale is invisible" arguments above hold for it: a slot's state
 is made zero by the prefill that starts a request in it (the bucketed
@@ -148,7 +154,8 @@ BLOCK_STEP_COUNTERS = ("diffusion_row_forwards", "diffusion_commit_forwards",
 class PagedKVPool:
     """Block-granular pool of what the model's ``kv_spec()`` declares
     a token keeps (K and V rows for most models, one latent row for
-    some): ``max_slots`` logical rows over ``num_blocks`` shared physical
+    some, each array over the layers that keep a row in it):
+    ``max_slots`` logical rows over ``num_blocks`` shared physical
     blocks (+ the sacrificial block 0). ``cfg`` is any config whose
     module has a ``serving_model``.
 
@@ -199,27 +206,31 @@ class PagedKVPool:
         if self.num_blocks < 1:
             raise ValueError("kv_blocks must be >= 1")
         self.model = decode.serving_model(cfg)
-        n_layers, token_shapes = self.model.kv_spec()
+        arrays = self.model.kv_spec()
         dtype = dtype or cfg.compute_dtype
-        # One array per shape the model declares a token keeps.
+        # One array per array the model declares a token keeps, each as
+        # deep as the layers that keep a row in it.
         self._kv = tuple(
             jnp.zeros(
-                (n_layers, 1 + self.num_blocks, self.block_size,
+                (layers_of_array, 1 + self.num_blocks, self.block_size,
                  *_allocated(shape)),
                 dtype,
             )
-            for shape in token_shapes
+            for layers_of_array, shape in arrays
         )
-        # Bytes a token keeps in the pool, all layers and arrays, as the
-        # model declares them: a fact of the model and the cache dtype
-        # (``stats()["kv_token_bytes"]``; ``nbytes`` is what is allocated).
-        self.token_bytes = n_layers * jnp.dtype(dtype).itemsize * sum(
-            int(np.prod(shape)) for shape in token_shapes
+        # Bytes a token keeps in the pool, all arrays over the layers of
+        # each, as the model declares them: a fact of the model and the
+        # cache dtype (``stats()["kv_token_bytes"]``; ``nbytes`` is what
+        # is allocated).
+        self.token_bytes = jnp.dtype(dtype).itemsize * sum(
+            layers_of_array * int(np.prod(shape))
+            for layers_of_array, shape in arrays
         )
         # What a slot holds beside its paged rows (a recurrent state):
-        # one (L, max_slots, ...) array per entry of the model's spec.
+        # one (the model's layers, max_slots, ...) array per entry of the
+        # model's spec.
         self._state = {
-            name: jnp.zeros((n_layers, max_slots, *shape), sdtype)
+            name: jnp.zeros((cfg.n_layers, max_slots, *shape), sdtype)
             for name, (shape, sdtype) in self.model.state_spec(dtype).items()
         }
         self.state_row_bytes = sum(
@@ -326,7 +337,7 @@ class PagedKVPool:
                 tables = tables[:, :nb]
 
             def land(pool, slab):
-                L = pool.shape[0]
+                L = pool.shape[0]             # the layers of THIS array
                 slab = decode.to_width(slab, pool.shape[-1])
                 if pad:
                     z = jnp.zeros((L, R, pad, *pool.shape[3:]), slab.dtype)
@@ -443,8 +454,9 @@ class PagedKVPool:
 
     def scatter_rows(self, *args) -> None:
         """``scatter_rows(*slabs, tables, state_rows=None, landed=None)``:
-        land a round of prefilled rows, one slab (L, R, S, *shape) per
-        array of the pool in the order of ``kv_spec()`` (``k_slab,
+        land a round of prefilled rows, one slab (L_a, R, S, *shape) per
+        array of the pool (``L_a`` that array's layers) in the order of
+        ``kv_spec()`` (``k_slab,
         v_slab`` for a model with keys and values), through ``tables``;
         each row's fresh recurrent state where ``landed`` (R,) bool
         says."""

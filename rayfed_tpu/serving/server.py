@@ -255,11 +255,11 @@ class InferenceServer:
             self._check_block_shapes()
         # Which read a decode step makes of the pool: what it walks is
         # counted by that (``kv_blocks_walked``).
-        pk, *pv = self.pool.kv
         self._kernel_paged = (
             self._block is None
             and decode.paged_read_is_kernel(
-                pk, pv[0] if pv else None,
+                *decode.paged_read_operands(
+                    self.pool.model.kv_spec(), self.pool.kv),
                 self.pool.max_slots * self.pool.blocks_per_row))
         self._prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
@@ -344,6 +344,23 @@ class InferenceServer:
             # in one layer, what a step had to read of the cache.
             "decode_keys_attended": 0,
         }
+        # The indexed layers' selections (a model that declares
+        # ``layer_index_topk``), decode steps and prefill alike, counted
+        # on the host from positions: the (query, key) pairs the indexer
+        # scored (every causal key) and those its top-k kept (``min(k,
+        # pos + 1)`` a query); ``*_decode``: the decode steps' part of
+        # each. And the blocks the live rows hold wholly
+        # behind a windowed layer's window, summed over those layers and
+        # the decode steps: held (one block table a slot), never read
+        # again.
+        self._index_topk = tuple(
+            k for k in getattr(self.model, "layer_index_topk", tuple)()
+            if k is not None
+        )
+        if self._index_topk:
+            self._stats.update(
+                index_keys_scored=0, index_keys_selected=0,
+                index_keys_scored_decode=0, index_keys_selected_decode=0)
         # What the model's decode step counts on the device (it declares
         # the names; none for most models): fetched behind the ids.
         self._stats.update(dict.fromkeys(self.pool.step_counters, 0))
@@ -354,11 +371,22 @@ class InferenceServer:
         # The windows of the layers that have one (optional in the
         # protocol: a model without ``layer_windows`` attends every key
         # on every layer).
-        self._n_layers = self.model.kv_spec()[0]
+        # The MODEL's layers (each attends once a token), whatever arrays
+        # of the pool each of them keeps a row in.
+        self._n_layers = self.cfg.n_layers
         self._windows = tuple(
             w for w in getattr(self.model, "layer_windows", tuple)()
             if w is not None
         )
+        if self._windows:
+            self._stats["kv_dead_blocks"] = 0
+        if self._index_topk and self.scfg.prefix_reuse:
+            raise ValueError(
+                "serving.prefix_reuse is not served for "
+                f"{type(model_cfg).__name__} (a learned selection of keys: "
+                "no test shows an adopted block chain sound under it yet); "
+                "set prefix_reuse=False"
+            )
         self._latencies_ms: "deque[float]" = deque(maxlen=4096)
         # Telemetry mirrors of the stats dict (docs/observability.md);
         # stats() stays the per-instance source of truth.
@@ -509,6 +537,23 @@ class InferenceServer:
             "attended, summed over layers.",
             labels=("server",),
         ).labels(server=name)
+        self._m_host_counters = {
+            key: _reg.counter(
+                f"fed_serving_{key}_total", text, labels=("server",),
+            ).labels(server=name)
+            for key, text in (
+                ("index_keys_scored",
+                 "(query, key) pairs the indexed layers' indexers scored, "
+                 "decode steps and prefill."),
+                ("index_keys_selected",
+                 "(query, key) pairs the indexed layers' top-k kept, "
+                 "decode steps and prefill."),
+                ("kv_dead_blocks",
+                 "KV blocks live rows hold wholly behind a windowed "
+                 "layer's window, summed over those layers and decode "
+                 "steps."),
+            ) if key in self._stats
+        }
         self._m_chunk_read = _reg.counter(
             "fed_serving_chunk_blocks_read_total",
             "KV blocks holding the cached keys a prompt chunk's attention "
@@ -718,6 +763,12 @@ class InferenceServer:
                 f"mode={mode!r} reorders or rolls back cache rows; "
                 f"{type(self.cfg).__name__}'s recurrent state cannot be "
                 "rolled back (only mode='generate' is served)"
+            )
+        if mode != "generate" and self._index_topk:
+            raise ValueError(
+                f"mode={mode!r} is not served for "
+                f"{type(self.cfg).__name__} (a learned selection of keys: "
+                "only mode='generate')"
             )
         if self._block is not None:
             if mode != "generate":
@@ -1440,14 +1491,35 @@ class InferenceServer:
             keys = self._n_layers * b * b * (
                 hi * (hi + 1) // 2 - lo * (lo + 1) // 2)
         else:
-            keys = (self._n_layers - len(self._windows)) * (
-                seen(end, end) - seen(off, end)
-            ) + sum(seen(end, w) - seen(off, w) for w in self._windows)
+            # (An indexed layer's query attends the ``min(k, q + 1)`` keys
+            # its indexer kept: a window's count.)
+            causal = seen(end, end) - seen(off, end)
+            full = self._n_layers - len(self._windows) - len(self._index_topk)
+            keys = full * causal + sum(
+                seen(end, w) - seen(off, w)
+                for w in self._windows + self._index_topk)
+            self._count_index(
+                len(self._index_topk) * causal,
+                sum(seen(end, k) - seen(off, k) for k in self._index_topk))
         with self._lock:
             self._stats["prefill_tokens"] += n
             self._stats["prefill_keys_attended"] += keys
         self._m_prefill_tokens.inc(n)
         self._m_prefill_keys.inc(keys)
+
+    def _count_index(self, scored: int, selected: int,
+                     decode_step: bool = False) -> None:
+        """The indexed layers' (query, key) pairs scored and kept."""
+        if not self._index_topk:
+            return
+        with self._lock:
+            self._stats["index_keys_scored"] += scored
+            self._stats["index_keys_selected"] += selected
+            if decode_step:
+                self._stats["index_keys_scored_decode"] += scored
+                self._stats["index_keys_selected_decode"] += selected
+        self._m_host_counters["index_keys_scored"].inc(scored)
+        self._m_host_counters["index_keys_selected"].inc(selected)
 
     def _count_chunk_blocks(self, off: int) -> None:
         """A chunk at offset ``off`` ran: count the blocks that hold the
@@ -1472,11 +1544,17 @@ class InferenceServer:
         read in a decode step, summed over rows and layers: every block
         up to a row's position (``attended``, summed over the rows) on a
         layer that attends every key, those that hold ``pos - window + 1
-        .. pos`` on a windowed one."""
+        .. pos`` on a windowed one. An indexed layer reads every block of
+        its index keys (its indexer scores every key) and, of its cached
+        rows, the fewest blocks that can hold the ``min(k, pos + 1)`` keys
+        kept."""
         bs = self.pool.block_size
         return (self._n_layers - len(self._windows)) * attended + sum(
             pos // bs - max(pos - window + 1, 0) // bs + 1
             for window in self._windows for pos in positions
+        ) + sum(
+            -(-min(pos + 1, k) // bs)
+            for k in self._index_topk for pos in positions
         )
 
     def _blocks_walked(self, positions) -> int:
@@ -1487,8 +1565,12 @@ class InferenceServer:
             decode.paged_blocks_walked, positions, self.pool.block_size,
             self.pool.max_slots, self.pool.blocks_per_row,
             kernel=self._kernel_paged)
-        full = self._n_layers - len(self._windows)
-        return (full * walked() + sum(
+        # (An indexed layer walks its index keys, by the gather loop
+        # whatever the backend; the rows it then reads are single rows.)
+        indexed = len(self._index_topk)
+        full = self._n_layers - len(self._windows) - indexed
+        by_loop = functools.partial(walked, kernel=False)
+        return (full * walked() + indexed * by_loop() + sum(
             walked(window) for window in self._windows)) // self._n_layers
 
     def _layer_keys(self, positions) -> int:
@@ -1499,9 +1581,11 @@ class InferenceServer:
         b = self._block.length if self._block else 1
         # (A block's b queries each see the context and the block.)
         seen = sum(b * (pos + b) for pos in positions)
-        return (self._n_layers - len(self._windows)) * seen + sum(
-            min(pos + 1, window)
-            for window in self._windows for pos in positions
+        # An indexed layer's row attends the ``min(k, pos + 1)`` keys its
+        # indexer kept of those it scored: a window's count.
+        narrow = self._windows + self._index_topk
+        return (self._n_layers - len(narrow)) * seen + sum(
+            min(pos + 1, width) for width in narrow for pos in positions
         )
 
     def _count_state_resets(self, n: int) -> None:
@@ -1795,6 +1879,17 @@ class InferenceServer:
         self._m_kv_slab.inc(slab)
         self._m_kv_layer_attended.inc(by_layer)
         self._m_decode_keys.inc(keys)
+        if self._index_topk:
+            self._count_index(
+                len(self._index_topk) * sum(pos + 1 for pos in positions),
+                sum(min(pos + 1, k)
+                    for k in self._index_topk for pos in positions),
+                decode_step=True)
+        if self._windows:
+            dead = sum(max(pos - window + 1, 0) // bs
+                       for window in self._windows for pos in positions)
+            self._stats["kv_dead_blocks"] += dead
+            self._m_host_counters["kv_dead_blocks"].inc(dead)
         if any(req.temperature > 0.0 for req in reqs):
             self._stats["draw_steps"] += 1
             self._m_draw_steps.inc()

@@ -234,7 +234,7 @@ def test_chunk_block_counters_by_hand(model, read):
                for kind in ("read", "row")]
     was = [m.labels(server="default").value() if m else 0 for m in mirrors]
     _, st = _serve(model, prompt, chunk=8)
-    n_layers = decode.serving_model(cfg).kv_spec()[0]
+    n_layers = cfg.n_layers
     blocks_per_row = -(-(64 + 1) // 4)
     assert st["chunk_blocks_read"] == read
     assert st["chunk_blocks_row"] == 3 * n_layers * blocks_per_row

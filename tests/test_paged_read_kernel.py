@@ -217,6 +217,9 @@ MODELS = {
     "hybrid": lambda: _tiny_of("test_falcon_h1"),
     "windowed": lambda: _tiny_of("test_cohere2_moe"),
     "latent": lambda: _tiny_of("test_pangu_ultra_moe"),
+    # (Its windowed layers' read alone is the kernel: a latent pool under
+    # a window; the indexed layers read single rows by a gather.)
+    "selected": lambda: _tiny_of("test_dots3_note"),
 }
 
 
@@ -242,7 +245,7 @@ def _serve(cfg, params, extra):
 @pytest.mark.parametrize("model", list(MODELS))
 def test_the_engine_serves_the_same_tokens_through_the_kernel(
         model, monkeypatch):
-    """The four serving models through ``InferenceServer``, once as every
+    """The five serving models through ``InferenceServer``, once as every
     CPU party runs them (the loop) and once with the decode read as the
     kernel (interpret mode; only ``decode`` and the engine are told they
     are on a TPU): the same greedy tokens, the same number of compiled
@@ -264,6 +267,10 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
     assert got == want
     assert stats["compiled_programs"] == loop_stats["compiled_programs"]
     assert stats["kv_blocks_attended"] == loop_stats["kv_blocks_attended"]
+    if model == "selected":
+        # (An indexer walks its index keys by the loop on every backend.)
+        assert 0 < stats["kv_blocks_walked"] < loop_stats["kv_blocks_walked"]
+        return
     assert 0 < stats["kv_blocks_walked"] <= stats["kv_blocks_attended"]
     assert loop_stats["kv_blocks_walked"] >= loop_stats["kv_blocks_attended"]
 

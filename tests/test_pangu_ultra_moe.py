@@ -135,7 +135,7 @@ def test_the_configuration_from_published_keys_and_its_refusals():
     # At the published sizes a token keeps 1,152 B a layer in bfloat16.
     published = pm.PanguUltraMoeConfig()
     assert published.cache_width * 2 == 1152
-    assert decode.serving_model(published).kv_spec() == (61, ((576,),))
+    assert decode.serving_model(published).kv_spec() == ((61, (576,)),)
 
 
 # -- the model against the reference -----------------------------------------
@@ -515,7 +515,7 @@ def test_the_other_models_pools_are_a_key_and_a_value_array_as_before(
     pool = PagedKVPool(cfg, max_slots=2, max_len=24, dtype=jnp.float32,
                        block_size=BLOCK)
     assert decode.serving_model(cfg).kv_spec() == (
-        2, ((kv_heads, 8), (kv_heads, 8)))
+        (2, (kv_heads, 8)), (2, (kv_heads, 8)))
     k, v = pool.kv
     assert k.shape == v.shape == (2, 1 + 2 * pool.blocks_per_row, BLOCK,
                                   kv_heads, 8)

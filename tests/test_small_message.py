@@ -203,11 +203,13 @@ _PAYLOADS = [
 def _run_roundtrip(party, addresses, transport, threshold):
     comm = dict(FAST_COMM_CONFIG)
     comm["small_message_threshold"] = threshold
-    fed.init(
-        addresses=addresses,
-        party=party,
-        config={"cross_silo_comm": comm, "transport": transport},
-    )
+    config = {"cross_silo_comm": comm, "transport": transport}
+    if transport == "grpc":
+        # gRPC clamps a channel's retries at 5 (under a second at this
+        # policy): a peer whose receiver comes up later than that, on a
+        # loaded host, would refuse the first send.
+        config["barrier_on_initializing"] = True
+    fed.init(addresses=addresses, party=party, config=config)
 
     @fed.remote
     def produce(i):

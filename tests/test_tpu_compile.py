@@ -159,12 +159,11 @@ def _cell_decode_step(workload, one_chip):
             lambda a: np.broadcast_to(np.zeros((), a.dtype), a.shape),
             jax.eval_shape(canonical)))
     params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
-    layers, rows = model.kv_spec()
     slots, block = serving["max_slots"], serving["kv_block_size"]
     blocks_per_row = -(-(serving["max_len"] + 1) // block)
     kv = tuple(sds((layers, 1 + slots * blocks_per_row, block, *row))
-               for row in rows)
-    state = {name: sds((layers, slots, *shape), dtype) for name, (
+               for layers, row in model.kv_spec())
+    state = {name: sds((cfg.n_layers, slots, *shape), dtype) for name, (
         shape, dtype) in model.state_spec(jnp.bfloat16).items()}
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     lowered = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
@@ -318,7 +317,7 @@ def test_the_latent_programs_compile_for_v5e_and_fit_beside_their_pool(
                   for a in jax.tree_util.tree_leaves(params))
     assert round(weights / 1e9, 2) == 9.84
     model = decode.serving_model(cfg)
-    layers, (row,) = model.kv_spec()
+    ((layers, row),) = model.kv_spec()
     assert row == (576,)
     blocks_per_row = -(-(PANGU_MAX_LEN + 1) // PANGU_BLOCK)
     # Rows padded to whole tiles, as ``PagedKVPool`` allocates them: a
@@ -439,4 +438,70 @@ def test_the_block_programs_compile_for_v5e_and_fit_beside_their_pool(
         'custom_call_target="tpu_custom_call"') >= 3 * expert_layers
     print(program, memory.temp_size_in_bytes, memory.output_size_in_bytes)
     assert memory.temp_size_in_bytes < 2.5e9
+    assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_512"])
+def test_the_selected_reads_compile_for_v5e_and_fit_beside_their_pool(
+        program, one_chip, monkeypatch):
+    """``dots3-note-prev`` as ``dots3-longdocs-closed16`` runs it: 8.17 GB
+    of weights and a pool of THREE arrays of two depths (9,984 B a token
+    as allocated: 12 slots of 33,024 positions, 4.13 GB) go in; the pool
+    comes back aliased to its arguments, the sliding layers' read is the
+    paged kernel (one form, lowered once), and what the indexer's scores,
+    the top-k and the selected read need beside them leaves the chip's
+    16.9 GB room."""
+    import importlib
+
+    from chipbench import run
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import decode
+    from rayfed_tpu.serving import kv_pool
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    plan = run.resolve("dots3-longdocs-closed16", False)
+    adapter = importlib.import_module("chipbench.seeded_" + plan["reference"])
+    cfg = adapter.program_cfg(plan["model"], plan["precision"])
+    model = decode.serving_model(cfg)
+    serving = plan["mix"]["serving"]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    shapes = adapter.to_program_tree(jax.tree_util.tree_map(
+        lambda a: np.broadcast_to(np.zeros((), a.dtype), a.shape),
+        jax.eval_shape(lambda: adapter.make_canonical(
+            jax.random.PRNGKey(0), plan["model"]))), plan["model"])
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    nbytes = lambda t: sum(  # noqa: E731
+        a.dtype.itemsize * int(np.prod(a.shape))
+        for a in jax.tree_util.tree_leaves(t))
+    weights = nbytes(params)
+    assert round(weights / 1e9, 2) == 8.17
+    slots, block = serving["max_slots"], serving["kv_block_size"]
+    blocks_per_row = -(-(serving["max_len"] + 1) // block)
+    kv = tuple(sds((layers, 1 + slots * blocks_per_row, block,
+                    *kv_pool._allocated(row)))
+               for layers, row in model.kv_spec())
+    assert [a.shape[0] for a in kv] == [2, 2, 3]
+    assert [a.shape[-1] for a in kv] == [640, 128, 1152]
+    pool_bytes = nbytes(kv)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step":
+        lowered = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+            params, kv, {}, i32(slots), i32(slots),
+            i32(slots, blocks_per_row), sds((slots,), jnp.bool_))
+        # One windowed latent form, called from three layers.
+        assert lowered.as_text().count("func.func private @paged_read") == 1
+    else:
+        lowered = jax.jit(model.chunk, donate_argnums=(1,)).lower(
+            params, kv, {}, i32(blocks_per_row), i32(), i32(512), i32(),
+            i32())
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    print(program, weights, pool_bytes, memory.temp_size_in_bytes)
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert ("%paged_read" in compiled.as_text()) == (program == "decode_step")
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') >= 12
     assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9

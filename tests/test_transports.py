@@ -35,6 +35,11 @@ def aggregate(a, b):
 
 def run_matrix(party, addresses, transport):
     config = {"cross_silo_comm": dict(FAST_COMM_CONFIG), "transport": transport}
+    if transport == "grpc":
+        # gRPC clamps a channel's retries at 5 (under a second at this
+        # policy): a peer whose receiver comes up later than that, on a
+        # loaded host, would refuse the first send.
+        config["barrier_on_initializing"] = True
     fed.init(addresses=addresses, party=party, config=config)
     a = produce.party("alice").remote([1.0, 2.0])
     b = produce.party("bob").remote([3.0, 4.0])
