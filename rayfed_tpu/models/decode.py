@@ -544,7 +544,32 @@ def paged_block_write(pk, pv, k_new, v_new, positions, tables, commit):
 # read 7.0 / 12.4 / 15.9 ms with 256, 12.5 / 24.6 / 31.2 with 512 and
 # 13.2 / 26.1 / 37.5 with 1,024: there a trip's scores are 67 MB at 256
 # and 134 MB at 512, and a key costs twice as much in the wider trip.
+# Since PR 45 the pools whose slots reach ``CHUNK_KERNEL_REACH`` keys and
+# more read a trip as a kernel on a TPU (below); this loop is still the
+# read of the pools that reach less (at most eight trips of 16-32 heads:
+# 4-8 MB of scores a trip), of a chunk under a ``block`` mask, and of
+# every pool on every other backend.
 CHUNK_TRIP_KEYS = 256
+# The same trip where it is one Pallas kernel
+# (:mod:`rayfed_tpu.ops.paged_chunk_attention`): scores, mask and softmax
+# stay in fast memory, the gathered (a latent pool's EXPANDED) keys and
+# values cross memory once each way and the softmax's state (2 x 33 MB at
+# 128 heads) once a trip. On a v5e (PR 45, PERF.md section 6: one layer's
+# read of 512 queries x 128 heads over a latent pool with a selection, a
+# context of 2k / 8k / 32k) trips of 1,024 keys read 2.6 / 6.4 / 20.7 ms
+# where the loop above reads 2.7 / 7.4 / 26.1; an earlier form of the
+# kernel read a key slower in trips of 2,048 and 4,096, and slower still
+# where a trip was walked in turns of 256 or 512 keys. And the reach from
+# which a pool's chunks read that way. No context was found at which the
+# kernel reads slower than the loop (2k to 32k, every form); what the
+# constant weighs is a start: a Pallas call costs every chunk program
+# about 0.05 s of trace and lowering a shape of trip, the short pools
+# (at most 2,048 keys a slot, eight trips of the loop) start in 17-21 s
+# under a bound of a tenth, and with the loop they compile the programs
+# they compiled before. The three pools past it reach 11,264 keys and
+# more.
+CHUNK_KERNEL_TRIP_KEYS = 1024
+CHUNK_KERNEL_REACH = 8192
 
 
 def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
@@ -594,9 +619,19 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
     the keys and values the queries attend ((trip keys, Hk, Dh), (trip
     keys, Hk, Dv)); heads, head sizes and the scores' scale are then
     those of ``q``, ``k`` and ``v`` as handed over, not the pool's.
+
+    On a TPU backend, for a pool whose slots reach far
+    (:func:`paged_chunk_is_kernel`), the same ``attend`` runs each trip's
+    scores, mask, softmax and PV product as one Pallas kernel
+    (:func:`_paged_chunk_kernel_attention`, ``paged_chunk_read`` in a
+    device trace), and the loop below is the definition it is tested
+    against.
     """
-    n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
     (blocks_per_row,) = table.shape
+    if paged_chunk_is_kernel(pk, pv, blocks_per_row, block=block):
+        return _paged_chunk_kernel_attention(
+            pk, pv, table, offset, n_real, window)
+    n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
     trip_blocks = max(1, min(blocks_per_row, CHUNK_TRIP_KEYS // bs))
     trip_keys = trip_blocks * bs
     table_p = jnp.pad(table, (0, -blocks_per_row % trip_blocks))
@@ -632,17 +667,7 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
             ) * scale
             return jnp.where(seen[None, None], s, -jnp.inf)
 
-        upto = idx if block is None else idx | (block - 1)
-        own = (idx[None, :] <= upto[:, None]) & (
-            (idx < n_real)[None, :] | (idx[None, :] == idx[:, None]))
-        if window is not None:
-            own &= idx[None, :] > idx[:, None] - window
-        if seen is not None:
-            # (A padded query keeps itself whatever its junk scores chose:
-            # no softmax is empty.)
-            own &= jax.lax.dynamic_slice(seen, (0, offset), (c, c)) | (
-                (idx >= n_real)[:, None] & (idx[None, :] == idx[:, None]))
-        s = scores(k, own)
+        s = scores(k, _own_keys_seen(c, offset, n_real, window, block, seen))
         m = s.max(-1)
         p = jnp.exp(s - finite(m)[..., None])
         init = (m, p.sum(-1), jnp.einsum(
@@ -679,6 +704,116 @@ def paged_chunk_attention(pk, pv, table, offset, n_real, window=None,
 
         _, den, acc = jax.lax.fori_loop(first, last, trip, init)
         out = (acc / den[..., None]).astype(v.dtype)
+        return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, v.shape[-1])
+
+    return attend
+
+
+def _own_keys_seen(c: int, offset, n_real, window, block, seen):
+    """(C, C) bool: which of a chunk's own keys each of its queries
+    attends. Causal inside the chunk (up to its block's end under
+    ``block``); a padded query attends the real keys and itself; a window
+    and a selection ``seen`` (C, W) cut further, but a padded query keeps
+    itself whatever its junk scores chose: no softmax is empty."""
+    idx = jnp.arange(c)
+    itself = idx[None, :] == idx[:, None]
+    upto = idx if block is None else idx | (block - 1)
+    own = (idx[None, :] <= upto[:, None]) & ((idx < n_real)[None, :] | itself)
+    if window is not None:
+        own &= idx[None, :] > idx[:, None] - window
+    if seen is not None:
+        own &= jax.lax.dynamic_slice(seen, (0, offset), (c, c)) | (
+            (idx >= n_real)[:, None] & itself)
+    return own
+
+
+def paged_chunk_is_kernel(pk, pv, blocks_per_row: int, block=None) -> bool:
+    """Whether :func:`paged_chunk_attention` reads this pool's trips
+    through the Pallas kernel: on a TPU backend, a pool of a dtype the
+    kernel takes whose slots reach ``CHUNK_KERNEL_REACH`` keys and more
+    (``blocks_per_row`` blocks of the pool's size: static in every chunk
+    program), and no ``block`` mask (the one model that asks for it
+    reaches 1,024 keys). Nothing else chooses: no serving key, no
+    environment variable, no model's name."""
+    del pv
+    return (block is None and utils.is_tpu_backend()
+            and pk.dtype in (jnp.bfloat16, jnp.float32)
+            and blocks_per_row * _pool_dims(pk)[2] >= CHUNK_KERNEL_REACH)
+
+
+def _paged_chunk_kernel_attention(pk, pv, table, offset, n_real, window):
+    """:func:`paged_chunk_attention`'s ``attend`` with each trip as one
+    call of :func:`rayfed_tpu.ops.paged_chunk_attention.chunk_trip`. What
+    stays here: the runtime trip count, the gather of a trip's blocks
+    through the table, ``expand``, and a trip's mask, every form's in one
+    int8 tile (queries, keys). The chunk's own keys are the first trip.
+    The cached keys follow from the first block any query sees (block 0,
+    or the block that holds the first query's window's first key: a
+    window's trips start there, not at a multiple of a trip), in trips
+    sized from what a chunk may have to visit
+    (:func:`~rayfed_tpu.ops.paged_chunk_attention.trip_keys`)."""
+    # The import is the engine's, begun on a thread of its own when the
+    # server was made: by now it is done, or this waits for it.
+    from rayfed_tpu.ops import paged_chunk_attention as kernel
+
+    n_layers, n_phys, bs, n_kv, dh = _pool_dims(pk)
+    (blocks_per_row,) = table.shape
+    reach = blocks_per_row * bs
+    span = reach if window is None else min(reach, window + bs - 2)
+    trip_keys = kernel.trip_keys(bs, span, CHUNK_KERNEL_TRIP_KEYS)
+    trip_blocks = trip_keys // bs
+    # (A slice of a trip's blocks never runs off the table's end.)
+    table_p = jnp.pad(table, (0, trip_blocks))
+    lo = 0
+    if window is not None:
+        lo = jnp.maximum(offset - window + 1, 0) // bs * bs
+    trips = (offset - lo + trip_keys - 1) // trip_keys
+    pk_flat = pk.reshape(n_layers * n_phys, *pk.shape[2:])
+    if pv is not None:
+        pv_flat = pv.reshape(n_layers * n_phys, bs, n_kv, dh)
+
+    def attend(q, k, v, base, expand=None, seen=None):
+        c, n_heads, d_qk = q.shape
+        n_k = k.shape[1]
+        q = jnp.moveaxis(q.reshape(c, n_k, n_heads // n_k, d_qk), 0, 2)
+        q_pos = offset + jnp.arange(c)
+        if seen is not None:
+            # As wide as the chunk's own keys lie and a trip may reach.
+            wide = reach + max(c, trip_keys)
+            seen = jnp.pad(
+                seen, ((0, 0), (0, max(0, wide - seen.shape[1]))))
+
+        def read(keys, values, mask, state):
+            # (Operands of one dtype, as the loop's product promotes.)
+            op = jnp.promote_types(q.dtype, keys.dtype)
+            return kernel.chunk_trip(
+                q.astype(op), jnp.swapaxes(keys, 0, 1).astype(op),
+                jnp.swapaxes(values, 0, 1), mask.astype(jnp.int8), state,
+                scale=d_qk**-0.5)
+
+        def trip(t, state):
+            start = lo + t * trip_keys
+            blocks = base + jax.lax.dynamic_slice_in_dim(
+                table_p, start // bs, trip_blocks)
+            kc = pk_flat[blocks].reshape(trip_keys, n_kv, dh)
+            if pv is None:
+                kc, vc = expand(kc)
+            else:
+                vc = pv_flat[blocks].reshape(trip_keys, n_kv, dh)
+            k_pos = start + jnp.arange(trip_keys)
+            cached = jnp.broadcast_to(
+                (k_pos < offset)[None, :], (c, trip_keys))
+            if window is not None:
+                cached &= k_pos[None, :] > q_pos[:, None] - window
+            if seen is not None:
+                cached &= jax.lax.dynamic_slice(
+                    seen, (0, start), (c, trip_keys))
+            return read(kc, vc, cached, state)
+
+        own = _own_keys_seen(c, offset, n_real, window, None, seen)
+        out = kernel.chunk_output(jax.lax.fori_loop(
+            0, trips, trip, read(k, v, own, None)), v.dtype)
+        out = out.reshape(n_k, n_heads // n_k, c, v.shape[-1])
         return jnp.moveaxis(out, 2, 0).reshape(c, n_heads, v.shape[-1])
 
     return attend

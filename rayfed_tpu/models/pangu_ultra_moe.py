@@ -272,12 +272,21 @@ def expand(c, layer, cfg: PanguUltraMoeConfig):
     ``k`` (K, H, dn + dr) = ``[ckv Wk_h | kr]``, ``v`` (K, H, dv)."""
     cdt = cfg.compute_dtype
     ckv, kr = c[:, 0, :cfg.kv_rank], c[:, :, cfg.kv_rank:cfg.cache_width]
-    kn = _mm(ckv, layer["wk_b"], cfg).astype(cdt)
-    kn = kn.reshape(-1, cfg.n_heads, cfg.d_nope)
-    v = _mm(ckv, layer["wv_b"], cfg).astype(cdt)
+
+    def per_head(w, width):
+        # The heads as a dimension of the PRODUCT (the weight's reshape
+        # is free): the compiler may then lay the result out heads first
+        # for a reader that wants it so (the chunk's kernel), where a
+        # (K, H * width) product reshaped afterwards is transposed by
+        # two copies of itself (PERF.md section 6, PR 45).
+        w = w.astype(cdt).reshape(cfg.kv_rank, cfg.n_heads, width)
+        return jnp.einsum("ka,ahd->khd", ckv, w,
+                          preferred_element_type=F32).astype(cdt)
+
     kr = jnp.broadcast_to(kr, (kr.shape[0], cfg.n_heads, cfg.d_rope))
-    return (jnp.concatenate([kn, kr.astype(cdt)], -1),
-            v.reshape(-1, cfg.n_heads, cfg.d_v))
+    return (jnp.concatenate(
+        [per_head(layer["wk_b"], cfg.d_nope), kr.astype(cdt)], -1),
+        per_head(layer["wv_b"], cfg.d_v))
 
 
 def absorb_query(qn, qr, layer, cfg: PanguUltraMoeConfig):

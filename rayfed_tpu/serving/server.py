@@ -214,9 +214,11 @@ class InferenceServer:
         # the trace of the decode program, whose own ``import`` it is,
         # waits for what is left of it.
         if utils.is_tpu_backend() and not hasattr(self.model, "block_spec"):
+            # (The chunk's kernel is a few lines on top of the same
+            # Pallas: ``decode.paged_chunk_attention``.)
             threading.Thread(
                 target=importlib.import_module,
-                args=("rayfed_tpu.ops.paged_attention",),
+                args=("rayfed_tpu.ops.paged_chunk_attention",),
                 name="fed-serve-kernel-import", daemon=True,
             ).start()
         self.scfg = config or ServingConfig()
@@ -255,12 +257,18 @@ class InferenceServer:
             self._check_block_shapes()
         # Which read a decode step makes of the pool: what it walks is
         # counted by that (``kv_blocks_walked``).
+        operands = decode.paged_read_operands(
+            self.pool.model.kv_spec(), self.pool.kv)
         self._kernel_paged = (
             self._block is None
             and decode.paged_read_is_kernel(
-                *decode.paged_read_operands(
-                    self.pool.model.kv_spec(), self.pool.kv),
-                self.pool.max_slots * self.pool.blocks_per_row))
+                *operands, self.pool.max_slots * self.pool.blocks_per_row))
+        # And which read a prompt chunk makes of it: the loop, or on a TPU
+        # a kernel a trip where the slots reach far
+        # (``prefill_chunks_kernel``).
+        self._kernel_chunk = decode.paged_chunk_is_kernel(
+            *operands, self.pool.blocks_per_row,
+            block=self._block and self._block.length)
         self._prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
         self._special_fns: Dict[tuple, Any] = {}
@@ -290,6 +298,10 @@ class InferenceServer:
             "steps_ahead": 0,
             "rows_wasted": 0,
             "prefill_chunks": 0,
+            # Of those, the chunks whose read of the pool ran a trip as
+            # one kernel (``decode.paged_chunk_is_kernel``: all of a
+            # pool's, or none).
+            "prefill_chunks_kernel": 0,
             "streamed_tokens": 0,
             "preempted": 0,
             # Decode: blocks the live rows' lengths cover (what a step has
@@ -450,6 +462,11 @@ class InferenceServer:
         self._m_chunks = _reg.counter(
             "fed_serving_prefill_chunks_total",
             "Prompt chunks merged into decode iterations.",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_chunks_kernel = _reg.counter(
+            "fed_serving_prefill_chunks_kernel_total",
+            "Prompt chunks whose read of the pool ran as a kernel.",
             labels=("server",),
         ).labels(server=name)
         self._m_streamed = _reg.counter(
@@ -1358,7 +1375,10 @@ class InferenceServer:
                 ran = True
                 with self._lock:
                     self._stats["prefill_chunks"] += 1
+                    self._stats["prefill_chunks_kernel"] += self._kernel_chunk
                 self._m_chunks.inc()
+                if self._kernel_chunk:
+                    self._m_chunks_kernel.inc()
                 self._count_prefill(off, real)
                 self._count_chunk_blocks(off)
                 if req.chunk_done >= plen:

@@ -91,12 +91,15 @@ def test_the_routed_experts_compile_for_v5e_with_the_grouped_kernel(
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
 
 
-def test_the_chunk_program_updates_the_donated_pool_in_place(one_chip):
-    """The dense chunk program at ``coder1b-complete-closed16``'s shapes
-    (24 layers, 16 heads of 128, 6 slots of 2,049 positions in blocks of
-    16, a chunk of 256): both halves of the donated pool come back
-    aliased to their arguments, and beside them the program needs room
-    for a chunk's activations, not for a copy of a pool or of a row."""
+@pytest.fixture(scope="module")
+def dense_chunk_program(one_chip):
+    """``compiled(as_a_tpu)``: the dense chunk program at
+    ``coder1b-complete-closed16``'s shapes (24 layers, 16 heads of 128, 6
+    slots of 2,049 positions in blocks of 16, a chunk of 256) for the
+    described chip, traced as a TPU backend traces it or as every other
+    backend does; each compiled once a process, so a test that compares
+    the two compiles both itself wherever it runs."""
+    from rayfed_tpu import utils
     from rayfed_tpu.models import decode
     from rayfed_tpu.models import transformer as tfm
 
@@ -112,21 +115,49 @@ def test_the_chunk_program_updates_the_donated_pool_in_place(one_chip):
     blocks_per_row = -(-2049 // 16)
     pool = sds((24, 1 + 6 * blocks_per_row, 16, 16, 128))
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
-    compiled = jax.jit(
-        decode.serving_model(cfg).chunk, donate_argnums=(1,)
-    ).lower(params, (pool, pool), {}, i32(blocks_per_row), i32(), i32(256),
-            i32(), i32()).compile()
-    pool_bytes = 2 * 24 * (1 + 6 * blocks_per_row) * 16 * 16 * 128 * 2
+    programs = {}
+
+    def compiled(as_a_tpu: bool):
+        if as_a_tpu not in programs:
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(utils, "is_tpu_backend", lambda: as_a_tpu)
+                programs[as_a_tpu] = jax.jit(
+                    decode.serving_model(cfg).chunk, donate_argnums=(1,)
+                ).lower(params, (pool, pool), {}, i32(blocks_per_row), i32(),
+                        i32(256), i32(), i32()).compile()
+        return programs[as_a_tpu]
+
+    compiled.pool_bytes = 2 * 24 * (1 + 6 * blocks_per_row) * 16 * 16 * 128 * 2
+    return compiled
+
+
+@pytest.mark.parametrize("backend", ["as-every-backend", "as-a-tpu"])
+def test_the_chunk_program_updates_the_donated_pool_in_place(
+        backend, dense_chunk_program):
+    """The dense chunk program at ``coder1b-complete-closed16``'s shapes:
+    both halves of the donated pool come back aliased to their arguments,
+    and beside them the program needs room for a chunk's activations, not
+    for a copy of a pool or of a row. Traced as a TPU backend traces it,
+    it is the same program: a slot reaches 2,064 keys, so its chunks keep
+    the loop and the program holds no custom call
+    (``decode.paged_chunk_is_kernel``)."""
+    compiled = dense_chunk_program(backend == "as-a-tpu")
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.alias_size_in_bytes >= dense_chunk_program.pool_bytes
     assert memory.temp_size_in_bytes < 400e6
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # Instruction for instruction the program every other backend traces
+    # (compiled here too, whichever process runs this case).
+    assert text == dense_chunk_program(False).as_text()
 
 
-def _cell_decode_step(workload, one_chip):
+def _cell_decode_step(workload, one_chip, chunk=None):
     """``jit_decode_step`` of a cell's model as the cell runs it (the
     configuration's file cut as the benchmark cuts it, the mix's slots and
-    lengths), lowered for the described chip from shapes: (lowered,
-    bytes of weights, bytes of pool)."""
+    lengths), or its ``jit_chunk_step`` of ``chunk`` tokens, lowered for
+    the described chip from shapes: (lowered, bytes of weights, bytes of
+    pool)."""
     import importlib
     import inspect
 
@@ -161,14 +192,22 @@ def _cell_decode_step(workload, one_chip):
     params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
     slots, block = serving["max_slots"], serving["kv_block_size"]
     blocks_per_row = -(-(serving["max_len"] + 1) // block)
-    kv = tuple(sds((layers, 1 + slots * blocks_per_row, block, *row))
+    from rayfed_tpu.serving import kv_pool
+
+    kv = tuple(sds((layers, 1 + slots * blocks_per_row, block,
+                    *kv_pool._allocated(row)))
                for layers, row in model.kv_spec())
     state = {name: sds((cfg.n_layers, slots, *shape), dtype) for name, (
         shape, dtype) in model.state_spec(jnp.bfloat16).items()}
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
-    lowered = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
-        params, kv, state, i32(slots), i32(slots),
-        i32(slots, blocks_per_row), sds((slots,), jnp.bool_))
+    if chunk is None:
+        lowered = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
+            params, kv, state, i32(slots), i32(slots),
+            i32(slots, blocks_per_row), sds((slots,), jnp.bool_))
+    else:
+        lowered = jax.jit(model.chunk, donate_argnums=(1, 2)).lower(
+            params, kv, state, i32(blocks_per_row), i32(), i32(chunk),
+            i32(), i32())
     nbytes = lambda t: sum(  # noqa: E731
         a.dtype.itemsize * int(jnp.prod(jnp.asarray(a.shape)))
         for a in jax.tree_util.tree_leaves(t))
@@ -196,6 +235,41 @@ def test_the_paged_read_is_one_kernel_a_form_in_the_decode_step(
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert "paged_read" in text
     assert memory.alias_size_in_bytes >= pool_bytes
+    assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
+
+
+@pytest.mark.parametrize("workload, chunk, trips", [
+    # The chunk's own keys, and the cached keys' trips: 1,024 keys of
+    # the full layer and 896 of the sliding ones' 4,096-key window.
+    ("commandaplus-docs-closed24", 512, 3),
+    ("pangu718b-reason-closed72", 512, 2),
+    # Two latent widths: 128 heads over 1,024 selected-or-not keys a
+    # trip, 64 heads over one 640-key trip of the 513-key window.
+    ("dots3-longdocs-closed16", 512, 4),
+    # A prompt's ragged start: fewer queries than a tile holds.
+    ("dots3-longdocs-closed16", 8, 4),
+])
+def test_the_chunk_read_is_one_kernel_a_form_in_the_long_cells(
+        workload, chunk, trips, one_chip, monkeypatch):
+    """The chunk programs of the three cells whose slots reach thousands
+    of keys, compiled for v5e with a chunk's trips as the Pallas kernel
+    (``decode.paged_chunk_is_kernel``): lowered once a shape of trip
+    however many layers and trips call it, present in the program under
+    its name, the pool aliased and never copied, and what the program
+    needs beside weights and pool (a trip's expanded keys and values, the
+    softmax's state) leaves the chip's 16.9 GB room."""
+    from rayfed_tpu import utils
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    lowered, weights, pool_bytes = _cell_decode_step(
+        workload, one_chip, chunk=chunk)
+    assert lowered.as_text().count("func.func private @chunk_trip") == trips
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "%paged_chunk_read" in text and "%paged_read" not in text
+    assert memory.alias_size_in_bytes >= pool_bytes
+    print(workload, chunk, weights, pool_bytes, memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < 1.2e9
     assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
 
 
@@ -342,9 +416,11 @@ def test_the_latent_programs_compile_for_v5e_and_fit_beside_their_pool(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
     # The decode step reads the latent pool through the paged kernel (one
-    # array, every head the same rows); the chunk's read is a loop still.
+    # array, every head the same rows), a chunk through its own (PR 45).
     assert ("%paged_read" in compiled.as_text()) == (
         program == "decode_step")
+    assert ("%paged_chunk_read" in compiled.as_text()) == (
+        program == "chunk_512")
     # The grouped kernel three times an expert layer, never a copy of a
     # weight or of the pool: room for activations and score tiles only.
     assert compiled.as_text().count(
@@ -502,6 +578,8 @@ def test_the_selected_reads_compile_for_v5e_and_fit_beside_their_pool(
     print(program, weights, pool_bytes, memory.temp_size_in_bytes)
     assert memory.alias_size_in_bytes >= pool_bytes
     assert ("%paged_read" in compiled.as_text()) == (program == "decode_step")
+    assert ("%paged_chunk_read" in compiled.as_text()) == (
+        program == "chunk_512")
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') >= 12
     assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
