@@ -424,8 +424,11 @@ class BlockSpec:
     engine (``block_spec()`` of its serving protocol, optional): a decode
     step then carries ``length`` ids a row, ``mask_id`` where a position
     is still masked, and a block is denoised in at most ``steps``
-    forwards under ``rule`` (:func:`rayfed_tpu.serving.sampling.unmask`)
-    before one more forward commits it."""
+    forwards under ``rule`` (:func:`rayfed_tpu.serving.sampling.unmask`).
+    The forward that finds the carried block clean commits it AND is the
+    first denoising forward of the block behind it: the model's
+    ``decode_step`` forwards both and hands back the logits of the one
+    that denoises."""
 
     length: int
     mask_id: int
@@ -441,25 +444,32 @@ class BlockSpec:
 
 
 def paged_block_attention(pk, pv, positions, tables):
-    """:func:`paged_attention` for ``B`` queries a row: the read of a
-    block-paged K/V pool through the block tables for a decode step that
-    carries a block a row. ``positions`` (R,) is each row's first
-    position, which is also the number of keys it has cached. Returns
-    ``attend(q, kb, vb, base)``: ``q`` (R, B, H, Dh) a layer's queries at
-    positions ``positions[r] .. + B - 1``, ``kb``/``vb`` (R, B, Hkv, Dh)
-    the block's own keys and values (in hand, beside the pool: they are
-    written there only when the block commits, :func:`paged_block_write`),
-    ``base`` as in :func:`paged_attention`; the result is the attention
-    output (R, B, H, Dh).
+    """:func:`paged_attention` for a decode step whose rows forward a
+    pair of blocks under the block-causal mask: the read of a block-paged
+    K/V pool through the block tables. ``positions`` (R, 2): each row's
+    first position, which is also the number of keys it has cached, and
+    the position at which its SECOND block starts. Returns ``attend(q,
+    kb, vb, base)``: ``q`` (R, n, H, Dh) a layer's queries, ``kb``/``vb``
+    (R, c, Hkv, Dh) the row's own keys and values at positions
+    ``positions[r, 0] .. + c - 1`` (in hand, beside the pool: they are
+    written there only when a block commits, :func:`paged_block_write`),
+    the queries at the LAST ``n`` of those places; ``base`` as in
+    :func:`paged_attention`; the result is the attention output (R, n,
+    H, Dh).
 
-    The row's own keys (``kb`` may hold any number of them) are the
-    online softmax's first block, every one visible to every query of the
-    row; the cached keys, all of which every query of the row sees,
-    follow ``PAGED_CHUNK_KEYS`` at a time as in :func:`paged_attention`,
-    the trip count a runtime value. A junk row (position 0 under an
-    all-zero table) reads block 0 masked."""
+    The row's own keys are the online softmax's first block: a key of the
+    second block is visible to the queries of the second block only, a
+    key before it to every query, so the first block's queries see what
+    they would see alone and the second's see both blocks. The cached
+    keys, all of which every query of the row sees, follow
+    ``PAGED_CHUNK_KEYS`` at a time as in :func:`paged_attention`, ONE
+    walk for all of the row's queries, the trip count a runtime value. A
+    junk row (position 0 under an all-zero table) reads block 0 masked."""
     n_layers, n_phys, bs, n_kv, dh = pk.shape
     n_rows, blocks_per_row = tables.shape
+    # Own place at which each row's second block starts.
+    second = (positions[:, 1] - positions[:, 0])[:, None, None]
+    positions = positions[:, 0]
     chunk_blocks = max(1, min(blocks_per_row, PAGED_CHUNK_KEYS // bs))
     chunk_keys = chunk_blocks * bs
     tables_p = jnp.pad(
@@ -471,11 +481,14 @@ def paged_block_attention(pk, pv, positions, tables):
     scale = dh**-0.5
 
     def attend(q, kb, vb, base):
-        n_q, n_heads = q.shape[1], q.shape[2]
+        n_q, n_heads, n_own = q.shape[1], q.shape[2], kb.shape[1]
         q = q.reshape(n_rows, n_q, n_kv, n_heads // n_kv, dh)
         s = jnp.einsum(
             "rbhgd,rchd->rhgbc", q, kb, preferred_element_type=jnp.float32
         ) * scale
+        at = (n_own - n_q + jnp.arange(n_q))[None, :, None]
+        own = (jnp.arange(n_own)[None, None, :] < second) | (at >= second)
+        s = jnp.where(own[:, None, None], s, -jnp.inf)
         m = s.max(-1)
         p = jnp.exp(s - m[..., None])
         init = (m, p.sum(-1), jnp.einsum(
@@ -1112,15 +1125,21 @@ class TransformerServing:
     engine's bank casts a version once, when it is installed, and the
     programs are handed that tree (``InferenceServer._make_snapshot_fn``).
 
-    Two further members are optional, declared by the model that needs
-    them (:mod:`rayfed_tpu.models.cohere2_moe`) and absent here:
-    ``layer_windows()`` (per layer the keys a token attends, or None for
-    every key: the engine counts the blocks each layer must read) and
-    ``step_counters`` (names of int32 counts only the device knows:
-    ``decode_step`` then returns them as a fourth value and they ride
-    home behind the ids). ``prefill_rows`` may hand back rows shorter
-    than ``row_len`` (as long as its bucket): the pool lands rows of the
-    length they come in.
+    Four further members are optional, declared by the model that needs
+    them and absent here: ``layer_windows()`` (per layer the keys a token
+    attends, or None for every key: the engine counts the blocks each
+    layer must read) and ``step_counters`` (names of int32 counts only
+    the device knows: ``decode_step`` then returns them as a fourth value
+    and they ride home behind the ids), both first declared by
+    :mod:`rayfed_tpu.models.cohere2_moe`; ``layer_index_topk()`` (per
+    indexed layer the keys its learned indexer keeps: the engine counts
+    the pairs scored and kept, :mod:`rayfed_tpu.models.dots3_note`); and
+    ``block_spec()`` (a :class:`BlockSpec`: the model generates by
+    blocks, a row of a decode step carries a block of ids and
+    ``decode_step`` is also told which rows commit,
+    :mod:`rayfed_tpu.models.sdar_moe`). ``prefill_rows`` may hand back
+    rows shorter than ``row_len`` (as long as its bucket): the pool lands
+    rows of the length they come in.
     """
 
     def __init__(self, cfg: tfm.TransformerConfig):

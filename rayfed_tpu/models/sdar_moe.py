@@ -38,12 +38,15 @@ starts as the prompt's left-over tokens followed by the mask id; a
 forward over the clean earlier blocks chooses a candidate and a
 confidence for every masked position and unmasks some
 (:func:`rayfed_tpu.serving.sampling.unmask`), its K/V NOT kept; once the
-block holds no mask id one more forward commits it (K/V kept, logits not
-used) and the next block opens. The serving engine
-(:mod:`rayfed_tpu.serving.server`) runs both kinds of forward as ONE
-decode step over every row (:func:`paged_decode_step`, told which rows
-commit) and learns of this through the one optional member of the
-protocol this model adds: ``block_spec()`` (a ``decode.BlockSpec``).
+block holds no mask id one more forward commits it (K/V kept) and the
+next block opens. Under the block-causal mask that forward and the next
+block's first denoising forward are ONE forward: nothing of a block
+depends on the block behind it. So a row of the serving engine's decode
+step (:mod:`rayfed_tpu.serving.server`; :func:`paged_decode_step`, told
+which rows commit) forwards a PAIR of blocks, the carried one and a block
+of mask ids behind it, and no forward is spent on a commit alone. The
+engine learns of all this through the one optional member of the protocol
+this model adds: ``block_spec()`` (a ``decode.BlockSpec``).
 
 The layers are a LIST of per-layer trees and the programs walk it in
 Python (a scan hands a layer its weights as a copy of its slice of the
@@ -383,25 +386,38 @@ def chunk(params, pk, pv, table, toks, offset, n_real, cfg: SdarMoeConfig):
 
 def paged_decode_step(params, pk, pv, tokens, positions, tables, live,
                       commit, cfg: SdarMoeConfig):
-    """One forward of every row's carried block: ``tokens`` (R, B) the
-    blocks (the mask id where a position is still masked), ``positions``
-    (R,) each block's first position, the clean earlier blocks read
-    through the block tables and the block's own keys beside the pool,
-    every one visible (:func:`decode.paged_block_attention`). The K/V of
-    the rows that ``commit`` (R,) bool names are written in place
-    (:func:`decode.paged_block_write`: the one place K/V of generated
-    tokens are kept); every other row's land in the sacrificial block.
-    ``live`` (R,) bool names the rows that are requests: the others are
-    routed to no expert. Returns (logits (R, B, V) at the blocks'
-    positions, pk, pv, counters (2,) int32 as
+    """One forward of every row's carried block AND the block behind it:
+    ``tokens`` (R, B) the carried blocks (the mask id where a position is
+    still masked), ``positions`` (R,) each carried block's first
+    position. A row forwards ``2B`` positions: its carried block, then a
+    block of mask ids. The clean earlier blocks are read through the
+    block tables, once for all of a row's queries, and the pair's own
+    keys beside the pool under the block-causal mask: the carried block's
+    queries see the carried block, the second block's see both
+    (:func:`decode.paged_block_attention`).
+
+    The rows that ``commit`` (R,) bool names carry a clean block: its K/V
+    are written in place (:func:`decode.paged_block_write`: the one place
+    K/V of generated tokens are kept; the carried half only, every other
+    row's land in the sacrificial block), and the second half is the
+    first denoising forward of the block that follows. Every other row
+    denoises its carried block, and its second half is junk. ``live``
+    (R,) bool names the rows that are requests: only their carried
+    blocks, and the second blocks of those that commit, are routed to
+    experts. Returns (logits (R, B, V) at the positions of the half that
+    denoises: the second where a row commits, else the carried one; pk,
+    pv, counters (2,) int32 as
     :func:`rayfed_tpu.models.cohere2_moe.paged_decode_step` counts
     them)."""
     n_rows, n_pos = tokens.shape
     n_phys = pk.shape[1]
-    attend = decode.paged_block_attention(pk, pv, positions, tables)
-    pos = positions[:, None] + jnp.arange(n_pos)
-    live_pos = jnp.repeat(live, n_pos)
-    x = _embed(params, tokens, cfg)
+    attend = decode.paged_block_attention(
+        pk, pv, jnp.stack([positions, positions + n_pos], axis=1), tables)
+    pos = positions[:, None] + jnp.arange(2 * n_pos)
+    live_pos = jnp.repeat(
+        jnp.stack([live, commit], axis=1), n_pos, axis=1).reshape(-1)
+    x = _embed(params, jnp.concatenate(
+        [tokens, jnp.full_like(tokens, cfg.mask_id)], axis=1), cfg)
     hit = local = jnp.asarray(0, jnp.int32)
     ks, vs = [], []
     for i, layer in enumerate(params["layers"]):
@@ -409,11 +425,12 @@ def paged_decode_step(params, pk, pv, tokens, positions, tables, live,
             x, layer, pos, live_pos, cfg,
             _paged(attend, pk.dtype, i * n_phys))
         hit, local = hit + n_hit, local + n_local
-        ks.append(k.astype(pk.dtype))
-        vs.append(v.astype(pv.dtype))
+        ks.append(k[:, :n_pos].astype(pk.dtype))
+        vs.append(v[:, :n_pos].astype(pv.dtype))
     with jax.named_scope("serve/commit"):
         pk, pv = decode.paged_block_write(
             pk, pv, jnp.stack(ks), jnp.stack(vs), positions, tables, commit)
+    x = jnp.where(commit[:, None, None], x[:, n_pos:], x[:, :n_pos])
     return _head(x, params, cfg), pk, pv, jnp.stack([hit, local])
 
 
@@ -422,7 +439,9 @@ class SdarMoeServing:
     :class:`rayfed_tpu.models.decode.TransformerServing`), with ``step_
     counters`` as the other expert models declare them and the ONE member
     this model adds: ``block_spec()``. An engine that finds it carries a
-    block a row and hands ``decode_step`` the rows that commit."""
+    block a row and hands ``decode_step`` the rows that commit; the
+    logits it gets back are those of the block each row denoises (the one
+    behind the carried block where that one commits)."""
 
     step_counters = ("moe_experts_hit", "moe_assignments_local")
 

@@ -358,18 +358,23 @@ class PagedKVPool:
         the one-token step. A row carries ``B`` ids (``tokens`` (R, B)
         from the host where ``from_host`` says so, else the block the
         step before returned for it), the mask id where a position is
-        still masked, and ``positions`` is each block's first position.
-        ONE forward of every row's block does per live row what the
-        model's routine does in one iteration: a block that came in clean
-        COMMITS (its K/V are written through the block table, and the
-        next block, all mask ids, is what the step returns for the row);
-        any other is DENOISED (a candidate and its confidence for every
-        masked position, some unmasked:
-        :func:`rayfed_tpu.serving.sampling.unmask`; its K/V are not kept).
-        Which of the two a row does is decided here from the carried
-        block, so the host need not have read the step before. Returns
-        the (R * B) ids of the blocks as the step leaves them, the
-        model's counters and ``BLOCK_STEP_COUNTERS`` behind them."""
+        still masked, and ``positions`` is each carried block's first
+        position. ONE forward of every row does per live row what the
+        model's routine does in one iteration, or in two: a carried block
+        that still holds a mask id is DENOISED (a candidate and its
+        confidence for every masked position, some unmasked:
+        :func:`rayfed_tpu.serving.sampling.unmask`; its K/V are not
+        kept); one that came in clean COMMITS (its K/V are written
+        through the block table) and the same forward is the first
+        denoising step of the block behind it, all mask ids, which the
+        step returns for the row as that step left it (the model's
+        ``decode_step`` forwards both blocks of every row and hands back
+        the logits of the one that denoises; the sampler's index moves on
+        by ``B`` and its denoising step is 0 for such a row). Which of
+        the two a row does is decided here from the carried block, so the
+        host need not have read the step before. Returns the (R * B) ids
+        of the blocks as the step leaves them, the model's counters and
+        ``BLOCK_STEP_COUNTERS`` behind them."""
         R, model, spec = self.max_slots, self.model, self.block
 
         @jax.named_scope("serve/decode_step")
@@ -383,9 +388,14 @@ class PagedKVPool:
                 commit
             )
             with jax.named_scope("serve/unmask"):
+                # The block each row denoises, and where it lies in the
+                # output: the one behind the carried block, at its step
+                # 0, where that one committed.
+                draw = draw.at[2].add(jnp.where(commit, spec.length, 0))
+                draw = draw.at[3].set(jnp.where(commit, 0, draw[3]))
                 block, unmasked = sampling.unmask(
-                    logits, tokens, draw, spec, live & ~commit)
-                block = jnp.where(commit[:, None], spec.mask_id, block)
+                    logits, jnp.where(commit[:, None], spec.mask_id, tokens),
+                    draw, spec, live)
             counts = jnp.stack([
                 jnp.sum(live, dtype=jnp.int32),
                 jnp.sum(commit, dtype=jnp.int32), unmasked])

@@ -51,16 +51,17 @@ A model that generates by blocks (its protocol declares ``block_spec()``:
 :mod:`rayfed_tpu.models.sdar_moe`) runs through the same loop. A live row
 then carries a block of ``B`` ids on the device, the mask id where a
 position is still masked; a decode step forwards every row's block and
-either unmasks some of its positions or, where it came in clean, commits
-it (keeps its K/V) and opens the next (:meth:`PagedKVPool._block_step`
-decides which, on the device), so a step yields 0 to ``B`` tokens a row
-and the host learns how many one fetch late. A prompt's whole blocks are
-prefilled; its left-over tokens start the first block unmasked. Tokens
-leave in the order of their positions, none later than the fetch that
-shows its block clean; a request ends when the block that holds its last
-token is clean, and what that block holds beyond ``max_new_tokens`` is
-dropped. Prefix reuse, beam and speculative requests are refused for such
-a model.
+unmasks some of its positions or, where it came in clean, commits it
+(keeps its K/V) and in the same forward unmasks the first positions of
+the block behind it (:meth:`PagedKVPool._block_step` decides which, on
+the device), so a step yields 1 to ``B`` tokens a row, no forward is
+spent on a commit alone, and the host learns one fetch late what a step
+did. A prompt's whole blocks are prefilled; its left-over tokens start
+the first block unmasked. Tokens leave in the order of their positions,
+none later than the fetch that shows its block clean; a request ends when
+the block that holds its last token is clean, and what that block holds
+beyond ``max_new_tokens`` is dropped. Prefix reuse, beam and speculative
+requests are refused for such a model.
 
 Token streaming: ``submit(..., stream=sink)`` attaches a sink the engine
 pushes each sampled token into (never blocking — see
@@ -378,8 +379,12 @@ class InferenceServer:
         self._stats.update(dict.fromkeys(self.pool.step_counters, 0))
         if self._block is not None:
             # Generation by blocks: positions of requests' last blocks
-            # beyond ``max_new_tokens``, computed and dropped.
+            # beyond ``max_new_tokens``, computed and dropped; rows whose
+            # forward committed one block and denoised the next (of the
+            # device's ``diffusion_commit_forwards``, those the host
+            # fetched for a request still running).
             self._stats["diffusion_positions_dropped"] = 0
+            self._stats["diffusion_fused_forwards"] = 0
         # The windows of the layers that have one (optional in the
         # protocol: a model without ``layer_windows`` attends every key
         # on every layer).
@@ -596,6 +601,12 @@ class InferenceServer:
             "fed_serving_diffusion_positions_dropped_total",
             "Positions of requests' last blocks beyond max_new_tokens, "
             "computed and dropped (generation by blocks).",
+            labels=("server",),
+        ).labels(server=name)
+        self._m_fused = _reg.counter(
+            "fed_serving_diffusion_fused_forwards_total",
+            "Rows whose forward committed their block and was the first "
+            "denoising step of the next (generation by blocks).",
             labels=("server",),
         ).labels(server=name)
         self._update_kv_gauges()
@@ -1464,12 +1475,16 @@ class InferenceServer:
 
     def _next_block(self, req: _Request):
         """(first position, denoising step) of the block that the next
-        step dispatched for ``req`` forwards. The host knows both though
+        step dispatched for ``req`` carries. The host knows both though
         it has not read the step in flight: that step commits exactly
-        when the block the last fetch showed is clean, and then the next
-        block opens; else the block stays, a step further on."""
+        when the block the last fetch showed is clean, and is then step 0
+        of the block behind it, which the next step carries a step
+        further on; else the block stays, a step further on. (A clean
+        block with nothing in flight is carried as it is: the device
+        commits it and moves the sampler on to the next block's step 0
+        itself.)"""
         if req.ahead and self._block.mask_id not in req.block:
-            return req.pos + self._block.length, 0
+            return req.pos + self._block.length, 1
         return req.pos, req.block_step + req.ahead
 
     def _ends_in_flight(self, req: _Request) -> bool:
@@ -1480,9 +1495,14 @@ class InferenceServer:
             return len(req.out) + req.ahead >= req.max_new_tokens
         spec = self._block
         end = int(req.prompt.size) + req.max_new_tokens
+        # The block the step in flight denoises: the held one, or step 0
+        # of the one behind it where the held one is clean.
+        pos, step = req.pos, req.block_step
         masked = req.block.count(spec.mask_id)
-        return (req.ahead > 0 and req.pos + spec.length >= end
-                and 0 < masked <= spec.quota(req.block_step))
+        if not masked:
+            pos, step, masked = pos + spec.length, 0, spec.length
+        return (req.ahead > 0 and pos + spec.length >= end
+                and masked <= spec.quota(step))
 
     def _fetch(self, ids) -> np.ndarray:
         """The ids a program chose, on the host (this waits for the
@@ -1720,16 +1740,17 @@ class InferenceServer:
 
         Where the model generates by blocks the same holds with a block
         for a token: the rows that were live in t take their BLOCK from
-        t's returned array, and whether t + 1 denoises it or commits it is
-        decided on the device from what t left of it. The host still
-        knows t + 1's position and denoising step (:meth:`_next_block`:
-        t commits exactly when the block the last fetch showed is clean),
-        grants the pool's block for it, and learns one fetch late what t
-        unmasked (:meth:`_take_block`). A row whose last block t is bound
-        to finish is not put into t + 1 (:meth:`_ends_in_flight`); one
-        whose last block t finished early (more confidences over the
-        threshold than the step had to unmask) was live in t + 1 and is
-        counted in ``rows_wasted``.
+        t's returned array, and whether t + 1 denoises it, or commits it
+        and denoises the one behind it, is decided on the device from
+        what t left of it. The host still knows t + 1's position and
+        denoising step (:meth:`_next_block`: t commits exactly when the
+        block the last fetch showed is clean, and has then opened the
+        next), grants the pool's block for it, and learns one fetch late
+        what t unmasked (:meth:`_take_block`). A row whose last block t
+        is bound to finish is not put into t + 1
+        (:meth:`_ends_in_flight`); one whose last block t finished early
+        (more confidences over the threshold than the step had to unmask)
+        was live in t + 1 and is counted in ``rows_wasted``.
 
         Returns True when a step was dispatched or a token emitted."""
         with self._lock:
@@ -1823,14 +1844,15 @@ class InferenceServer:
     def _take_block(self, req: _Request, ids) -> bool:
         """What a fetched step did with ``req``'s block (generation by
         blocks). If the block the host held was clean, the step committed
-        it and opened the next. Else it denoised: the positions it
-        unmasked are noted with the step of their block, and every token
-        that now follows the last one emitted without a masked position
-        between leaves, in the order of the positions (so none leaves
-        later than the fetch that shows its block clean). True when the
-        request ends: an ``eos_id`` left, or the block that holds its
-        last token is clean (what it holds beyond ``max_new_tokens`` is
-        dropped and counted)."""
+        it, and what it denoised is the next block at its step 0: that
+        block opens here first. The positions the step unmasked are noted
+        with the step of their block, and every token that now follows
+        the last one emitted without a masked position between leaves, in
+        the order of the positions (so none leaves later than the fetch
+        that shows its block clean). True when the request ends: an
+        ``eos_id`` left, or the block that holds its last token is clean
+        (what it holds beyond ``max_new_tokens`` is dropped and
+        counted)."""
         spec = self._block
         n, mask = spec.length, spec.mask_id
         if mask not in req.block:
@@ -1838,7 +1860,13 @@ class InferenceServer:
             req.block = [mask] * n
             req.block_steps = [0] * n
             req.block_step = req.block_out = 0
-            return False
+            # The committed block's queries were counted at the dispatch;
+            # the opened block's saw that block too.
+            keys = self._n_layers * n * (req.pos + n)
+            self._stats["diffusion_fused_forwards"] += 1
+            self._stats["decode_keys_attended"] += keys
+            self._m_fused.inc()
+            self._m_decode_keys.inc(keys)
         new = ids[req.slot * n:(req.slot + 1) * n]
         for j in range(n):
             if req.block[j] == mask and new[j] != mask:

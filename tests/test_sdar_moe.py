@@ -28,6 +28,7 @@ that a position's best logit leads its second by far more than that).
 
 from __future__ import annotations
 
+import functools
 import importlib
 
 import numpy as np
@@ -225,6 +226,84 @@ def test_the_sigmoid_models_get_the_parents_routed_experts(scale):
                           np.asarray(old, np.float32))
 
 
+# -- the forward of a pair of blocks ------------------------------------------
+
+
+def _prefilled(seq, n_phys=12):
+    """An empty pool of three slots (tables of 4 blocks of 4 each, slot
+    ``r``'s blocks ``1 + 4 r ..``) with ``seq`` (whole blocks) prefilled
+    into slot 0 and slot 1: (pk, pv, tables)."""
+    head = (CFG.n_layers, 1 + n_phys, B, CFG.n_kv_heads, CFG.head_dim)
+    pk = pv = jnp.zeros(head, jnp.float32)
+    tables = np.arange(1, 1 + n_phys, dtype=np.int32).reshape(3, -1)
+    toks = jnp.asarray(seq + [0] * (-len(seq) % CHUNK), jnp.int32)
+    for slot in (0, 1):
+        _, pk, pv = sm.chunk(PARAMS, pk, pv, tables[slot], toks, 0, len(seq),
+                             CFG)
+    return pk, pv, tables
+
+
+def _block_of(pool, table, start):
+    """The K or V rows (L, B, Hkv, Dh) of one slot at positions ``start
+    .. start + B - 1``."""
+    return np.asarray(pool)[:, table[start // B]]
+
+
+@pytest.mark.parametrize("second", ["denoises", "junk"])
+def test_the_pair_forward_is_the_commit_and_the_next_blocks_first_step(
+        second):
+    """ONE forward of a clean carried block and the block behind it ==
+    the two it replaces: the K/V it keeps for the carried block are those
+    a prefill of the same tokens keeps, and the logits it hands back are
+    those of the next block's step 0 over that context (a forward of its
+    own after the commit, and the reference's ``block_logits``). A row
+    beside it whose carried block still holds a mask id denoises that
+    block as it would alone and keeps nothing; a junk row touches no
+    slot."""
+    seq = _tokens(12, seed=21)
+    ctx, clean = seq[:8], seq[8:]
+    pk, pv, tables = _prefilled(ctx)
+    noisy = [clean[0], MASK, clean[2], MASK]
+    live = jnp.asarray([True, second == "denoises", False])
+    blocks = jnp.asarray([clean, noisy, [0] * B], jnp.int32)
+    run = jax.jit(functools.partial(sm.paged_decode_step, cfg=CFG))
+    step = functools.partial(run, PARAMS)
+    at = jnp.asarray([8, 8, 0], jnp.int32)
+    commit = jnp.asarray([True, False, False])
+    run_tables = np.array(tables)
+    run_tables[2] = 0
+    logits, pk1, pv1, counters = step(pk, pv, blocks, at, run_tables, live,
+                                      commit)
+    # The committed K/V: what a prefill of context + block keeps there.
+    want_k, want_v, _ = _prefilled(seq)
+    for got, want in ((pk1, want_k), (pv1, want_v)):
+        assert np.abs(_block_of(got, tables[0], 8)
+                      - _block_of(want, tables[0], 8)).max() < TOL
+        # Nothing of the row that denoised, nothing of the second half.
+        assert not _block_of(got, tables[1], 8).any()
+        assert not _block_of(got, tables[0], 12).any()
+        assert not np.asarray(got)[:, tables[2]].any()
+    # The next block's step 0: the forward after the commit, alone.
+    opened = jnp.asarray([[MASK] * B] * 3, jnp.int32)
+    after, pk2, _, _ = step(
+        pk1, pv1, opened, jnp.asarray([12, 0, 0], jnp.int32),
+        run_tables * np.asarray([[1], [0], [0]], np.int32),
+        jnp.asarray([True, False, False]), jnp.zeros(3, bool))
+    assert np.array_equal(_block_of(pk2, tables[0], 8),
+                          _block_of(pk1, tables[0], 8))
+    assert np.abs(np.asarray(logits[0]) - np.asarray(after[0])).max() < TOL
+    want = np.asarray(ref.block_logits(W, seq, [MASK] * B, HP))
+    assert np.abs(np.asarray(logits[0]) - want).max() < TOL
+    if second == "denoises":
+        # It reads its carried block's logits.
+        want = np.asarray(ref.block_logits(W, ctx, noisy, HP))
+        assert np.abs(np.asarray(logits[1]) - want).max() < TOL
+    # Routed: the live rows' carried blocks and the committing row's
+    # second block, two experts a position, on every layer.
+    n_live = B * (2 + (second == "denoises"))
+    assert int(counters[1]) == CFG.n_layers * CFG.top_k * n_live
+
+
 # -- prefill then block decoding through the engine ----------------------------
 
 
@@ -302,9 +381,16 @@ def test_the_dynamic_rule_with_a_peaked_head_token_for_token(steps):
     # Blocks that ended in fewer steps than the schedule, and blocks that
     # needed all of them.
     assert min(last) < steps - 1 and max(last) == steps - 1
+    # Every forward of a live row unmasks its step's quota at least (no
+    # forward is a commit's alone) and a block at most.
     forwards = st["diffusion_row_forwards"]
-    assert B / (steps + 1) < st["diffusion_tokens_unmasked"] / forwards < 2
-    assert 0 < st["diffusion_commit_forwards"] < forwards
+    assert B / steps <= st["diffusion_tokens_unmasked"] / forwards < B
+    commits, fused = (st["diffusion_commit_forwards"],
+                      st["diffusion_fused_forwards"])
+    assert 0 < fused <= commits < forwards
+    # Every commit the host fetched had opened the next block; the others
+    # are those of rows that had ended under their step.
+    assert commits - fused <= st["rows_wasted"]
 
 
 def _without_run_ahead(srv):
@@ -322,6 +408,88 @@ def _without_run_ahead(srv):
         return progressed
 
     srv._step_groups = no_lag
+
+
+@pytest.mark.parametrize("ahead", [True, False], ids=["ahead", "in-step"])
+@pytest.mark.parametrize("steps", [4, 2, 1], ids=["T=4", "T=2", "T=1"])
+def test_whole_blocks_at_the_schedules_floor_spend_no_forward_on_a_commit(
+        steps, ahead):
+    """``N`` blocks under the static rule take ``T`` forwards each and not
+    one more: every commit rides in the forward that opens the next
+    block, the last block is never committed, and the host never puts a
+    row into a step that it knows is past its end."""
+    n_blocks = 5
+    cfg, w, params, hp = _weights(dict(
+        TINY, denoising_steps=steps, remasking="low_confidence_static"))
+    srv = _server(cfg, params, max_new_tokens=32)
+    if not ahead:
+        _without_run_ahead(srv)
+    try:
+        prompt = _tokens(8, seed=33)
+        out = srv.submit(prompt, max_new_tokens=B * n_blocks).result(
+            timeout=WAIT_S)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    tokens, want_steps = ref.generate(w, prompt, B * n_blocks, hp)
+    assert out["tokens"] == tokens and out["unmask_steps"] == want_steps
+    assert st["diffusion_row_forwards"] == steps * n_blocks
+    assert st["diffusion_tokens_unmasked"] == B * n_blocks
+    assert st["diffusion_fused_forwards"] == n_blocks - 1
+    assert st["diffusion_commit_forwards"] == n_blocks - 1
+    assert st["rows_wasted"] == 0 and st["steps"] == steps * n_blocks
+    assert (st["steps_ahead"] > 0) == ahead
+    # A forward's B queries see their context and their block. Where a
+    # block opens in the forward that commits the one before, those are
+    # the committed block's queries, and the opened block's B see the
+    # context and both blocks.
+    assert st["decode_keys_attended"] == cfg.n_layers * B * sum(
+        steps * (8 + B * i + B) + (8 + B * i if i else 0)
+        for i in range(n_blocks))
+
+
+@pytest.mark.parametrize("fault", ["break_commit", "break_blockmask",
+                                   "break_unmask"])
+def test_the_benchmarks_controls_still_engage_through_the_fused_step(
+        fault, monkeypatch):
+    """The benchmark's controls patch three functions by name and call
+    them by position (``chipbench/kinds/closed_loop_diffusion.py``): the
+    step that forwards a pair of blocks still goes through all three, so
+    an engine built under one serves without raising, and serves other
+    tokens, other steps or keeps other K/V than a sound one."""
+    kind = importlib.import_module("chipbench.kinds.closed_loop_diffusion")
+
+    plens = (6, 9)
+
+    def served():
+        srv = _server()
+        try:
+            outs = [srv.submit(_tokens(n, seed=70 + n),
+                               max_new_tokens=13).result(timeout=WAIT_S)
+                    for n in plens]
+            kept = [np.asarray(a) for a in srv.pool.kv]
+        finally:
+            srv.stop()
+        return [(o["tokens"], o["unmask_steps"]) for o in outs], kept
+
+    sound, sound_kv = served()
+    # (monkeypatch puts the sound functions back.)
+    for module, name in ((decode, "paged_block_write"),
+                         (decode, "paged_block_attention"),
+                         (sampling, "choose_with_confidence")):
+        monkeypatch.setattr(module, name, getattr(module, name))
+    getattr(kind, fault)()
+    broken, broken_kv = served()
+    if fault == "break_unmask":
+        # Left to right in every block, whatever the confidences.
+        for n, (_, steps) in zip(plens, broken):
+            steps = [-1] * (n % B) + steps
+            assert all(steps[i:i + B] == sorted(steps[i:i + B])
+                       for i in range(0, len(steps), B))
+        assert broken != sound
+    else:
+        assert any(not np.array_equal(a, b)
+                   for a, b in zip(sound_kv, broken_kv))
 
 
 @pytest.mark.parametrize("ahead", [True, False], ids=["ahead", "in-step"])

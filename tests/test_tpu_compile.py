@@ -448,7 +448,8 @@ def test_the_block_programs_compile_for_v5e_and_fit_beside_their_pool(
     """``sdar-30b-a3b-chat`` as ``sdar30b-gen-closed128`` runs it: 8.72 GB
     of weights (all 128 experts of 6 layers, the whole vocabulary twice)
     and a pool of 12,288 B a token go in; the decode step is the POOL's
-    program (a block a row, the unmasking rule traced behind the head: 96
+    program (a block a row in and out, a PAIR of blocks a row forwarded
+    inside it since PR 46, the unmasking rule traced behind the head: 96
     x 4 rows of 151,936 float32 logits), and what each program needs
     beside weights and pool leaves the chip's 16.9 GB room."""
     from rayfed_tpu import utils
@@ -493,6 +494,10 @@ def test_the_block_programs_compile_for_v5e_and_fit_beside_their_pool(
             params, (pool, pool), i32(r, 4), i32(r), i32(r, blocks_per_row),
             i32(4, r), i32(r * 4 + 5), sds((r,), jnp.bool_), {},
             sds((r,), jnp.bool_))
+        # The pair of blocks a row forwards is made inside: the step takes
+        # a block a row and returns a block a row, as it always did.
+        assert jax.tree.map(lambda a: a.shape, lowered.out_info) == (
+            (r * 4 + 5,), (pool.shape, pool.shape), {})
     elif program == "chunk_256":
         lowered = jax.jit(model.chunk, donate_argnums=(1,)).lower(
             params, (pool, pool), {}, i32(blocks_per_row), i32(), i32(256),
