@@ -314,16 +314,14 @@ def prefill_rows(params, prompts, last_idx, cache_dtype,
     cache's rows (the pool lands rows of the length they come in).
 
     Only the rows ``landed`` (R,) bool names are computed (all, when it
-    is None), one at a time under a runtime trip count, as
-    :func:`rayfed_tpu.models.falcon_h1.prefill_rows` does; the others
+    is None), one at a time (:func:`decode.landed_rows`); the others
     come back zero and land in the sacrificial block."""
     r, s = prompts.shape
     cache_dtype = cache_dtype or cfg.compute_dtype
-    if landed is None:
-        landed = jnp.ones((r,), bool)
     positions = jnp.arange(s)
 
-    def one_row(prompt, n_real):
+    def one_row(i):
+        prompt, n_real = prompts[i], last_idx[i] + 1
         x, k, v = _seq_layers(
             _embed(params, prompt, cfg), params, positions,
             positions < n_real, cfg,
@@ -333,23 +331,10 @@ def prefill_rows(params, prompts, last_idx, cache_dtype,
         return (_head(last, params, cfg), k.astype(cache_dtype),
                 v.astype(cache_dtype))
 
-    order = jnp.argsort(jnp.logical_not(landed), stable=True)
-
-    def step(j, out):
-        i = order[j]
-        new = one_row(prompts[i], last_idx[i] + 1)
-        logits, k, v = out
-        return (
-            jax.lax.dynamic_update_index_in_dim(logits, new[0], i, 0),
-            jax.lax.dynamic_update_index_in_dim(k, new[1], i, 1),
-            jax.lax.dynamic_update_index_in_dim(v, new[2], i, 1),
-        )
-
     kv = jnp.zeros((cfg.n_layers, r, s, cfg.n_kv_heads, cfg.head_dim),
                    cache_dtype)
-    out = (jnp.zeros((r, cfg.vocab), F32), kv, kv)
-    logits, k, v = jax.lax.fori_loop(
-        0, jnp.sum(landed, dtype=jnp.int32), step, out)
+    logits, k, v = decode.landed_rows(
+        one_row, landed, (jnp.zeros((r, cfg.vocab), F32), kv, kv))
     return logits, k, v, {}
 
 

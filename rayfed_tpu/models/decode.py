@@ -1081,6 +1081,39 @@ def paged_decode_step(
     return logits, pk, pv
 
 
+def landed_rows(one_row, landed, zeros):
+    """The loop of a ``prefill_rows`` that computes only the rows that
+    are requests. ``one_row(i)`` is row ``i`` of the round: its logits
+    and its row of every other output; ``landed`` (R,) bool names the
+    rows to compute (None: all); ``zeros`` are the outputs at zero, the
+    logits ``(R, ...)`` and every other ``(layers, R, ...)``.
+
+    The landed rows run first, in slot order, one at a time under a trip
+    count that is a runtime value: an admission round costs what its
+    requests cost, not what ``R`` rows of the bucket would (at 32 slots a
+    round is mostly one request), and a row is the same one-row program
+    whoever its neighbours are. A row's logits land at ``[i]``, its other
+    outputs at ``[:, i]`` (from the start of the later axes where it is
+    shorter than they: a row as long as its bucket in an output as long
+    as the cache's rows). The other rows stay zero: the pool scatters
+    them into the sacrificial block and a state of theirs lands
+    nowhere."""
+    zeros = tuple(zeros)
+    if landed is None:
+        landed = jnp.ones((zeros[0].shape[0],), bool)
+    order = jnp.argsort(jnp.logical_not(landed), stable=True)
+
+    def step(j, out):
+        i = order[j]
+        logits, *rows = one_row(i)
+        return (jax.lax.dynamic_update_index_in_dim(out[0], logits, i, 0),
+                *(jax.lax.dynamic_update_index_in_dim(o, new, i, 1)
+                  for o, new in zip(out[1:], rows)))
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(landed, dtype=jnp.int32), step, zeros)
+
+
 class TransformerServing:
     """What the serving engine asks of a model: the protocol, with the
     dense transformer as its first implementer.

@@ -451,35 +451,24 @@ def prefill_rows(params, prompts, last_idx, cache_dtype,
     from an empty cache. Returns the logits (R, V) at ``last_idx`` and
     the three arrays' rows ((Lf, R, S, width), (Lf, R, S, D), (Ls, R, S,
     width)), as long as the bucket. Only the rows ``landed`` (R,) bool
-    names are computed (all, when it is None), one at a time under a
-    runtime trip count, as :func:`pangu_ultra_moe.prefill_rows` does."""
+    names are computed (all, when it is None), one at a time
+    (:func:`decode.landed_rows`)."""
     r, s = prompts.shape
     cache_dtype = cache_dtype or cfg.compute_dtype
-    if landed is None:
-        landed = jnp.ones((r,), bool)
     positions = jnp.arange(s)
 
-    def one_row(prompt, n_real):
+    def one_row(i):
+        prompt, n_real = prompts[i], last_idx[i] + 1
         x, rows = _seq_layers(_embed(params, prompt, cfg), params,
                               positions, positions < n_real, cfg)
         last = jax.lax.dynamic_index_in_dim(x, n_real - 1, 0, keepdims=False)
         return (mla._head(last, params, cfg),
                 *(a.astype(cache_dtype) for a in rows))
 
-    order = jnp.argsort(jnp.logical_not(landed), stable=True)
-
-    def step(j, out):
-        i = order[j]
-        logits, *rows = one_row(prompts[i], last_idx[i] + 1)
-        return (jax.lax.dynamic_update_index_in_dim(out[0], logits, i, 0),
-                *(jax.lax.dynamic_update_index_in_dim(a, new, i, 1)
-                  for a, new in zip(out[1:], rows)))
-
-    out = (jnp.zeros((r, cfg.vocab), F32),
-           *(jnp.zeros((layers, r, s, *shape), cache_dtype)
-             for layers, shape in cache_arrays(cfg)))
-    logits, *rows = jax.lax.fori_loop(
-        0, jnp.sum(landed, dtype=jnp.int32), step, out)
+    logits, *rows = decode.landed_rows(one_row, landed, (
+        jnp.zeros((r, cfg.vocab), F32),
+        *(jnp.zeros((layers, r, s, *shape), cache_dtype)
+          for layers, shape in cache_arrays(cfg))))
     return logits, tuple(rows)
 
 

@@ -461,21 +461,17 @@ def prefill_rows(params, prompts, last_idx, row_len: int, cache_dtype,
     after its last real token.
 
     Only the rows ``landed`` (R,) bool names are computed (all, when it
-    is None), one at a time under a trip count that is a runtime value:
-    an admission round costs what its requests cost, not what ``R`` rows
-    of the bucket would (at 32 slots a round is mostly one request), and
-    a row is the same one-row program whoever its neighbours are. The
-    other rows come back zero: their K/V goes to the sacrificial block
-    and their state lands nowhere."""
+    is None), one at a time (:func:`decode.landed_rows`); the other rows
+    come back zero."""
     r, s = prompts.shape
     cache_dtype = cache_dtype or cfg.compute_dtype
-    if landed is None:
-        landed = jnp.ones((r,), bool)
     positions = jnp.arange(s)[None]
     tail0, state0 = zero_state(cfg, 1, cache_dtype)
 
-    def one_row(prompt, n_real):
-        # prompt (1, S), n_real (1,)
+    def one_row(i):
+        # A batch of one: prompt (1, S), n_real (1,).
+        prompt = jax.lax.dynamic_slice_in_dim(prompts, i, 1, 0)
+        n_real = jax.lax.dynamic_slice_in_dim(last_idx, i, 1, 0) + 1
         real = positions < n_real[:, None]
 
         def body(x, layer):
@@ -493,34 +489,17 @@ def prefill_rows(params, prompts, last_idx, row_len: int, cache_dtype,
             body, _embed(params, prompt, cfg), params["layers"])
         last = jax.lax.dynamic_index_in_dim(
             x[0], n_real[0] - 1, 0, keepdims=False)
-        return _head(last, params, cfg), k, v, tails, states
-
-    # Landed rows first, in slot order.
-    order = jnp.argsort(jnp.logical_not(landed), stable=True)
-
-    def step(j, out):
-        i = order[j]
-        new = one_row(
-            jax.lax.dynamic_slice_in_dim(prompts, i, 1, 0),
-            jax.lax.dynamic_slice_in_dim(last_idx, i, 1, 0) + 1)
-        logits, k, v, tails, states = out
-        return (
-            jax.lax.dynamic_update_index_in_dim(logits, new[0], i, 0),
-            jax.lax.dynamic_update_slice(k, new[1], (0, i, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(v, new[2], (0, i, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(tails, new[3], (0, i, 0, 0)),
-            jax.lax.dynamic_update_slice(states, new[4], (0, i, 0, 0, 0)),
-        )
+        # (L, 1, ...) each: the row without the batch's axis.
+        return (_head(last, params, cfg), k[:, 0], v[:, 0], tails[:, 0],
+                states[:, 0])
 
     n = cfg.n_layers
     kv = jnp.zeros((n, r, row_len, cfg.n_kv_heads, cfg.head_dim), cache_dtype)
-    out = (
+    logits, k, v, tails, states = decode.landed_rows(one_row, landed, (
         jnp.zeros((r, cfg.vocab), F32), kv, kv,
         jnp.zeros((n, r) + tail0.shape[1:], tail0.dtype),
         jnp.zeros((n, r) + state0.shape[1:], state0.dtype),
-    )
-    logits, k, v, tails, states = jax.lax.fori_loop(
-        0, jnp.sum(landed, dtype=jnp.int32), step, out)
+    ))
     return logits, k, v, {"conv": tails, "ssm": states}
 
 

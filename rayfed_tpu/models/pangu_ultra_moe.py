@@ -409,32 +409,22 @@ def prefill_rows(params, prompts, last_idx, cache_dtype,
     the cache's rows (the pool lands rows of the length they come in).
 
     Only the rows ``landed`` (R,) bool names are computed (all, when it
-    is None), one at a time under a runtime trip count, as
-    :func:`rayfed_tpu.models.cohere2_moe.prefill_rows` does; the others
+    is None), one at a time (:func:`decode.landed_rows`); the others
     come back zero and land in the sacrificial block."""
     r, s = prompts.shape
     cache_dtype = cache_dtype or cfg.compute_dtype
-    if landed is None:
-        landed = jnp.ones((r,), bool)
     positions = jnp.arange(s)
 
-    def one_row(prompt, n_real):
+    def one_row(i):
+        prompt, n_real = prompts[i], last_idx[i] + 1
         x, c = _seq_layers(_embed(params, prompt, cfg), params, positions,
                            positions < n_real, cfg)
         last = jax.lax.dynamic_index_in_dim(x, n_real - 1, 0, keepdims=False)
         return _head(last, params, cfg), c[:, :, 0].astype(cache_dtype)
 
-    order = jnp.argsort(jnp.logical_not(landed), stable=True)
-
-    def step(j, out):
-        i = order[j]
-        new = one_row(prompts[i], last_idx[i] + 1)
-        return (jax.lax.dynamic_update_index_in_dim(out[0], new[0], i, 0),
-                jax.lax.dynamic_update_index_in_dim(out[1], new[1], i, 1))
-
-    out = (jnp.zeros((r, cfg.vocab), F32),
-           jnp.zeros((cfg.n_layers, r, s, cfg.cache_width), cache_dtype))
-    return jax.lax.fori_loop(0, jnp.sum(landed, dtype=jnp.int32), step, out)
+    return decode.landed_rows(one_row, landed, (
+        jnp.zeros((r, cfg.vocab), F32),
+        jnp.zeros((cfg.n_layers, r, s, cfg.cache_width), cache_dtype)))
 
 
 def chunk(params, pc, table, toks, offset, n_real,

@@ -318,31 +318,21 @@ def prefill_rows(params, prompts, last_idx, cache_dtype, cfg: SdarMoeConfig,
     protocol hands some back.
 
     Only the rows ``landed`` (R,) bool names are computed, one at a time
-    under a runtime trip count
-    (:func:`rayfed_tpu.models.cohere2_moe.prefill_rows`)."""
+    (:func:`decode.landed_rows`)."""
     r, s = prompts.shape
     cache_dtype = cache_dtype or cfg.compute_dtype
-    if landed is None:
-        landed = jnp.ones((r,), bool)
-    order = jnp.argsort(jnp.logical_not(landed), stable=True)
 
-    def step(j, out):
-        i = order[j]
+    def one_row(i):
         _, k, v = _seq_layers(
             _embed(params, prompts[i], cfg), params,
             jnp.arange(s) <= last_idx[i], cfg)
-        return (
-            jax.lax.dynamic_update_index_in_dim(
-                out[0], k.astype(cache_dtype), i, 1),
-            jax.lax.dynamic_update_index_in_dim(
-                out[1], v.astype(cache_dtype), i, 1),
-        )
+        return (jnp.zeros((1,), F32), k.astype(cache_dtype),
+                v.astype(cache_dtype))
 
     kv = jnp.zeros((cfg.n_layers, r, s, cfg.n_kv_heads, cfg.head_dim),
                    cache_dtype)
-    k, v = jax.lax.fori_loop(
-        0, jnp.sum(landed, dtype=jnp.int32), step, (kv, kv))
-    return jnp.zeros((r, 1), F32), k, v
+    return decode.landed_rows(
+        one_row, landed, (jnp.zeros((r, 1), F32), kv, kv))
 
 
 def _paged(attend, cache_dtype, base):

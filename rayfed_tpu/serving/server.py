@@ -92,7 +92,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -173,6 +173,152 @@ class _Ahead:
     ``rows``: its id is dropped."""
     ids: Any
     rows: List[_Request]
+
+
+class _Counter(NamedTuple):
+    """One counter of the engine: its key in ``stats()`` and, where it has
+    one, its series in the telemetry registry (docs/observability.md).
+    ``InferenceServer._count`` bumps both."""
+    key: str
+    help: Optional[str]           # the series' help; None: it has no series
+    series: Optional[str] = None  # where not ``fed_serving_{key}_total``
+    event: bool = False           # the child ``event=key`` of that family
+    # The optional member of the model protocol whose models have the key
+    # and the series (None: all have them), or the key alone: the series
+    # is then every server's, 0 for the others (as before this table).
+    gate: Optional[str] = None
+    series_for_all: bool = False
+
+
+_request = functools.partial(
+    _Counter, help="Serving requests by lifecycle event.",
+    series="fed_serving_requests_total", event=True)
+
+# What the engine counts. Every per-step counter counts at dispatch, a
+# wasted row included: what the device was handed.
+_COUNTERS = (
+    _request("submitted"), _request("completed"), _request("rejected"),
+    _Counter("prefix_hits", "Prefill prefix-cache hits."),
+    _Counter("tokens_out", "Tokens generated.",
+             series="fed_serving_tokens_total"),
+    _Counter("steps", "Batched decode iterations."),
+    # Run-ahead, beside "steps". steps_ahead / steps: how often the device
+    # had its next step queued. A wasted row: an ``eos`` the host read one
+    # step late (an end by ``max_new_tokens`` is known ahead).
+    _Counter("steps_ahead",
+             "Decode iterations dispatched before the previous one of "
+             "their version was fetched."),
+    _Counter("rows_wasted",
+             "Rows live in a decode iteration dispatched after their last "
+             "token."),
+    _Counter("prefill_chunks",
+             "Prompt chunks merged into decode iterations."),
+    # (``decode.paged_chunk_is_kernel``: all of a pool's chunks, or none.)
+    _Counter("prefill_chunks_kernel",
+             "Prompt chunks whose read of the pool ran as a kernel."),
+    _Counter("streamed_tokens", "Tokens pushed to streaming sinks."),
+    _Counter("preempted",
+             "Requests preempted to break a KV block-pool deadlock.",
+             series="fed_serving_preemptions_total"),
+    # Decode: what a step has to read (``pos // block_size + 1`` a live
+    # row) beside max_slots x blocks_per_row: a contiguous slab of the
+    # rows.
+    _Counter("kv_blocks_attended",
+             "KV blocks covered by live rows' lengths, summed over paged "
+             "decode steps."),
+    _Counter("kv_blocks_slab",
+             "KV blocks of every row at full length, summed over paged "
+             "decode steps."),
+    # (``decode.paged_blocks_walked``: the kernel's what each row's length
+    # covers, the loop's every row of the program walked as far as the
+    # longest.)
+    _Counter("kv_blocks_walked",
+             "KV blocks the decode read copies for the live rows (the mean "
+             "over the layers), summed over paged decode steps."),
+    # Recurrent state (0 for a model without one).
+    _Counter("ssm_state_bytes",
+             "Recurrent-state bytes read and written by live rows, summed "
+             "over decode steps."),
+    _Counter("state_resets",
+             "Requests started from a zero recurrent state."),
+    # Rows that sat a decode step out with their state kept.
+    _Counter("state_rows_held", None),
+    # (0: the trees came in that dtype, or the model takes them as
+    # published.)
+    _Counter("publish_cast_bytes",
+             "Bytes of published leaves cast to the model's serving dtype "
+             "as versions were installed."),
+    # 4 x max_slots a step or a prefill round, 4 a last chunk; a block a
+    # row and no id of a prefill where the model generates by blocks.
+    _Counter("fetch_bytes",
+             "Bytes the engine thread fetched from its programs' outputs "
+             "(the chosen token ids)."),
+    # (The noise's branch ran.) Beside "steps".
+    _Counter("draw_steps",
+             "Decode steps in which at least one live row was sampled."),
+    # n_layers x kv_blocks_attended for a model without windows.
+    _Counter("kv_layer_blocks_attended",
+             "KV blocks each layer of each live row must read (a windowed "
+             "layer its window's), summed over layers and decode steps."),
+    # Both prefill paths; a windowed layer's queries see at most its
+    # window.
+    _Counter("prefill_tokens",
+             "Real prompt tokens put through the prefill programs."),
+    _Counter("prefill_keys_attended",
+             "(query, key) pairs the prefill programs' real tokens "
+             "attended, summed over layers."),
+    # Chunked prefill: what a chunk's attention gathers through the table
+    # (a windowed layer's from its first query's window on) beside
+    # n_layers x blocks_per_row: what a contiguous row of the slot would
+    # carry each way.
+    _Counter("chunk_blocks_read",
+             "KV blocks holding the cached keys a prompt chunk's attention "
+             "gathers, summed over layers and chunks."),
+    _Counter("chunk_blocks_row",
+             "KV blocks of a slot's whole row in every layer, summed over "
+             "prompt chunks."),
+    # Each row's own key among them, a windowed layer's at most its
+    # window: times the bytes a token keeps in one layer, what a step had
+    # to read of the cache.
+    _Counter("decode_keys_attended",
+             "Keys scored by live rows in paged decode steps, summed over "
+             "layers."),
+    # The indexed layers' selections, counted on the host from positions:
+    # every causal key scored, ``min(k, pos + 1)`` a query kept.
+    # ``*_decode``: the decode steps' part of each.
+    _Counter("index_keys_scored",
+             "(query, key) pairs the indexed layers' indexers scored, "
+             "decode steps and prefill.", gate="layer_index_topk"),
+    _Counter("index_keys_selected",
+             "(query, key) pairs the indexed layers' top-k kept, "
+             "decode steps and prefill.", gate="layer_index_topk"),
+    _Counter("index_keys_scored_decode", None, gate="layer_index_topk"),
+    _Counter("index_keys_selected_decode", None, gate="layer_index_topk"),
+    # Generation by blocks. Fused: of the device's
+    # ``diffusion_commit_forwards``, those the host fetched for a request
+    # still running.
+    _Counter("diffusion_positions_dropped",
+             "Positions of requests' last blocks beyond max_new_tokens, "
+             "computed and dropped (generation by blocks).",
+             gate="block_spec", series_for_all=True),
+    _Counter("diffusion_fused_forwards",
+             "Rows whose forward committed their block and was the first "
+             "denoising step of the next (generation by blocks).",
+             gate="block_spec", series_for_all=True),
+    # Held (one block table a slot), never read again.
+    _Counter("kv_dead_blocks",
+             "KV blocks live rows hold wholly behind a windowed "
+             "layer's window, summed over those layers and decode "
+             "steps.", gate="layer_windows"),
+)
+
+
+def _bump(stats: Dict[str, int], series: Dict[str, Any], key: str,
+          n: int) -> None:
+    """``key`` goes up by ``n`` in ``stats`` and in its series, if any."""
+    stats[key] += n
+    if key in series:
+        series[key].inc(n)
 
 
 class InferenceServer:
@@ -283,120 +429,20 @@ class InferenceServer:
         self._rid_counter = itertools.count()
         self._stopping = False
         self._fatal: Optional[BaseException] = None
-        self._stats = {
-            "submitted": 0,
-            "completed": 0,
-            "rejected": 0,
-            "prefix_hits": 0,
-            "tokens_out": 0,
-            "steps": 0,
-            # Run-ahead, beside "steps": decode steps dispatched while
-            # their version group's previous step had not been fetched
-            # (steps_ahead / steps: how often the device had its next
-            # step queued), and rows that were live in a step dispatched
-            # after their last token (an ``eos`` the host read one step
-            # late; an end by ``max_new_tokens`` is known ahead).
-            "steps_ahead": 0,
-            "rows_wasted": 0,
-            "prefill_chunks": 0,
-            # Of those, the chunks whose read of the pool ran a trip as
-            # one kernel (``decode.paged_chunk_is_kernel``: all of a
-            # pool's, or none).
-            "prefill_chunks_kernel": 0,
-            "streamed_tokens": 0,
-            "preempted": 0,
-            # Decode: blocks the live rows' lengths cover (what a step has
-            # to read) beside max_slots x blocks_per_row (every row at
-            # full length: a contiguous slab of the rows).
-            "kv_blocks_attended": 0,
-            "kv_blocks_slab": 0,
-            # Decode: blocks the step's read copies for them, the mean
-            # over the layers (``decode.paged_blocks_walked``): the
-            # kernel's what each row's length covers, the loop's every
-            # row of the program walked as far as the longest.
-            "kv_blocks_walked": 0,
-            # Recurrent state (a model that has one): bytes of state the
-            # live rows read and wrote, summed over decode steps; requests
-            # started from a zero state; rows that sat a decode step out
-            # with their state kept.
-            "ssm_state_bytes": 0,
-            "state_resets": 0,
-            "state_rows_held": 0,
-            # Bytes of published leaves read by the casts to the model's
-            # serving dtype as versions were installed (0: the trees came
-            # in it, or the model takes them as published).
-            "publish_cast_bytes": 0,
-            # Bytes the engine thread pulled host-ward from its programs'
-            # outputs (the chosen ids: 4 x max_slots a step or a prefill
-            # round, 4 a last chunk; a block a row and no id of a prefill
-            # where the model generates by blocks), and decode steps in
-            # which at least one live row was sampled (the noise's branch
-            # ran), beside "steps".
-            "fetch_bytes": 0,
-            "draw_steps": 0,
-            # Decode, per layer: the blocks each layer of each live row
-            # must read (a windowed layer only its window's), summed over
-            # layers and steps: n_layers x kv_blocks_attended for a model
-            # without windows. And the real prompt tokens put through
-            # both prefill paths, with the (query, key) pairs they
-            # attended, summed over layers (a windowed layer's queries
-            # see at most its window).
-            "kv_layer_blocks_attended": 0,
-            "prefill_tokens": 0,
-            "prefill_keys_attended": 0,
-            # Chunked prefill, per chunk and summed over layers: the
-            # blocks that hold the cached keys its attention gathers
-            # through the table (a windowed layer's from its first
-            # query's window on), beside n_layers x blocks_per_row: what
-            # a contiguous row of the slot would carry each way.
-            "chunk_blocks_read": 0,
-            "chunk_blocks_row": 0,
-            # Decode: the keys the live rows' steps scored, each row's
-            # own among them, summed over layers and steps (a windowed
-            # layer's at most its window): times the bytes a token keeps
-            # in one layer, what a step had to read of the cache.
-            "decode_keys_attended": 0,
-        }
-        # The indexed layers' selections (a model that declares
-        # ``layer_index_topk``), decode steps and prefill alike, counted
-        # on the host from positions: the (query, key) pairs the indexer
-        # scored (every causal key) and those its top-k kept (``min(k,
-        # pos + 1)`` a query); ``*_decode``: the decode steps' part of
-        # each. And the blocks the live rows hold wholly
-        # behind a windowed layer's window, summed over those layers and
-        # the decode steps: held (one block table a slot), never read
-        # again.
-        self._index_topk = tuple(
-            k for k in getattr(self.model, "layer_index_topk", tuple)()
-            if k is not None
-        )
-        if self._index_topk:
-            self._stats.update(
-                index_keys_scored=0, index_keys_selected=0,
-                index_keys_scored_decode=0, index_keys_selected_decode=0)
-        # What the model's decode step counts on the device (it declares
-        # the names; none for most models): fetched behind the ids.
-        self._stats.update(dict.fromkeys(self.pool.step_counters, 0))
-        if self._block is not None:
-            # Generation by blocks: positions of requests' last blocks
-            # beyond ``max_new_tokens``, computed and dropped; rows whose
-            # forward committed one block and denoised the next (of the
-            # device's ``diffusion_commit_forwards``, those the host
-            # fetched for a request still running).
-            self._stats["diffusion_positions_dropped"] = 0
-            self._stats["diffusion_fused_forwards"] = 0
-        # The windows of the layers that have one (optional in the
-        # protocol: a model without ``layer_windows`` attends every key
-        # on every layer).
         # The MODEL's layers (each attends once a token), whatever arrays
-        # of the pool each of them keeps a row in.
+        # of the pool each of them keeps a row in, and of them the windows
+        # of those that have one and the top-k of those that select their
+        # keys (both optional in the protocol: without ``layer_windows``
+        # and ``layer_index_topk`` every layer attends every key).
         self._n_layers = self.cfg.n_layers
         self._windows = tuple(
             w for w in getattr(self.model, "layer_windows", tuple)()
             if w is not None
         )
-        if self._windows:
-            self._stats["kv_dead_blocks"] = 0
+        self._index_topk = tuple(
+            k for k in getattr(self.model, "layer_index_topk", tuple)()
+            if k is not None
+        )
         if self._index_topk and self.scfg.prefix_reuse:
             raise ValueError(
                 "serving.prefix_reuse is not served for "
@@ -404,43 +450,37 @@ class InferenceServer:
                 "no test shows an adopted block chain sound under it yet); "
                 "set prefix_reuse=False"
             )
-        self._latencies_ms: "deque[float]" = deque(maxlen=4096)
-        # Telemetry mirrors of the stats dict (docs/observability.md);
-        # stats() stays the per-instance source of truth.
+        # The counters: ``_COUNTERS``' rows that this model has, then what
+        # its decode step counts on the device (it declares the names;
+        # none for most models), fetched behind the ids. ``_stats`` is
+        # what ``stats()`` returns of them, ``_series`` each key's child in
+        # the telemetry registry; :meth:`_count` bumps both.
         _reg = telemetry_metrics.get_registry()
-        _events = _reg.counter(
-            "fed_serving_requests_total",
-            "Serving requests by lifecycle event.",
-            labels=("server", "event"),
-        )
-        self._m_events = {
-            k: _events.labels(server=name, event=k)
-            for k in ("submitted", "completed", "rejected")
-        }
-        self._m_prefix_hits = _reg.counter(
-            "fed_serving_prefix_hits_total", "Prefill prefix-cache hits.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_tokens = _reg.counter(
-            "fed_serving_tokens_total", "Tokens generated.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_steps = _reg.counter(
-            "fed_serving_steps_total", "Batched decode iterations.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_steps_ahead = _reg.counter(
-            "fed_serving_steps_ahead_total",
-            "Decode iterations dispatched before the previous one of "
-            "their version was fetched.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_rows_wasted = _reg.counter(
-            "fed_serving_rows_wasted_total",
-            "Rows live in a decode iteration dispatched after their last "
-            "token.",
-            labels=("server",),
-        ).labels(server=name)
+        has = {"layer_windows": self._windows,
+               "layer_index_topk": self._index_topk,
+               "block_spec": self._block}
+        self._stats: Dict[str, int] = {}
+        self._series: Dict[str, Any] = {}
+        for row in _COUNTERS + tuple(
+            _Counter(key,
+                     f"The served model's decode-step counter {key!r}, "
+                     "counted on the device and fetched with the chosen ids.")
+            for key in self.pool.step_counters
+        ):
+            held = row.gate is None or bool(has[row.gate])
+            if held:
+                self._stats[row.key] = 0
+            if row.help is not None and (held or row.series_for_all):
+                labels = {"server": name}
+                if row.event:
+                    labels["event"] = row.key
+                self._series[row.key] = _reg.counter(
+                    row.series or f"fed_serving_{row.key}_total", row.help,
+                    labels=tuple(labels),
+                ).labels(**labels)
+        self._latencies_ms: "deque[float]" = deque(maxlen=4096)
+        # Gauges and the latency histogram mirror live values, not
+        # ``_stats``.
         self._m_pending = _reg.gauge(
             "fed_serving_pending", "Requests awaiting admission.",
             labels=("server",),
@@ -464,151 +504,12 @@ class InferenceServer:
             "KV blocks on the free list.",
             labels=("server",),
         ).labels(server=name)
-        self._m_chunks = _reg.counter(
-            "fed_serving_prefill_chunks_total",
-            "Prompt chunks merged into decode iterations.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_chunks_kernel = _reg.counter(
-            "fed_serving_prefill_chunks_kernel_total",
-            "Prompt chunks whose read of the pool ran as a kernel.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_streamed = _reg.counter(
-            "fed_serving_streamed_tokens_total",
-            "Tokens pushed to streaming sinks.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_preempted = _reg.counter(
-            "fed_serving_preemptions_total",
-            "Requests preempted to break a KV block-pool deadlock.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_kv_attended = _reg.counter(
-            "fed_serving_kv_blocks_attended_total",
-            "KV blocks covered by live rows' lengths, summed over paged "
-            "decode steps.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_kv_walked = _reg.counter(
-            "fed_serving_kv_blocks_walked_total",
-            "KV blocks the decode read copies for the live rows (the mean "
-            "over the layers), summed over paged decode steps.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_kv_slab = _reg.counter(
-            "fed_serving_kv_blocks_slab_total",
-            "KV blocks of every row at full length, summed over paged "
-            "decode steps.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_decode_keys = _reg.counter(
-            "fed_serving_decode_keys_attended_total",
-            "Keys scored by live rows in paged decode steps, summed over "
-            "layers.",
-            labels=("server",),
-        ).labels(server=name)
         _reg.gauge(
             "fed_serving_kv_token_bytes",
             "Bytes one token keeps in the paged pool, all layers and "
             "arrays the model declares.",
             labels=("server",),
         ).labels(server=name).set(self.pool.token_bytes)
-        self._m_state_bytes = _reg.counter(
-            "fed_serving_ssm_state_bytes_total",
-            "Recurrent-state bytes read and written by live rows, summed "
-            "over decode steps.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_state_resets = _reg.counter(
-            "fed_serving_state_resets_total",
-            "Requests started from a zero recurrent state.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_publish_cast = _reg.counter(
-            "fed_serving_publish_cast_bytes_total",
-            "Bytes of published leaves cast to the model's serving dtype "
-            "as versions were installed.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_fetch_bytes = _reg.counter(
-            "fed_serving_fetch_bytes_total",
-            "Bytes the engine thread fetched from its programs' outputs "
-            "(the chosen token ids).",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_draw_steps = _reg.counter(
-            "fed_serving_draw_steps_total",
-            "Decode steps in which at least one live row was sampled.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_kv_layer_attended = _reg.counter(
-            "fed_serving_kv_layer_blocks_attended_total",
-            "KV blocks each layer of each live row must read (a windowed "
-            "layer its window's), summed over layers and decode steps.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_prefill_tokens = _reg.counter(
-            "fed_serving_prefill_tokens_total",
-            "Real prompt tokens put through the prefill programs.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_prefill_keys = _reg.counter(
-            "fed_serving_prefill_keys_attended_total",
-            "(query, key) pairs the prefill programs' real tokens "
-            "attended, summed over layers.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_host_counters = {
-            key: _reg.counter(
-                f"fed_serving_{key}_total", text, labels=("server",),
-            ).labels(server=name)
-            for key, text in (
-                ("index_keys_scored",
-                 "(query, key) pairs the indexed layers' indexers scored, "
-                 "decode steps and prefill."),
-                ("index_keys_selected",
-                 "(query, key) pairs the indexed layers' top-k kept, "
-                 "decode steps and prefill."),
-                ("kv_dead_blocks",
-                 "KV blocks live rows hold wholly behind a windowed "
-                 "layer's window, summed over those layers and decode "
-                 "steps."),
-            ) if key in self._stats
-        }
-        self._m_chunk_read = _reg.counter(
-            "fed_serving_chunk_blocks_read_total",
-            "KV blocks holding the cached keys a prompt chunk's attention "
-            "gathers, summed over layers and chunks.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_chunk_row = _reg.counter(
-            "fed_serving_chunk_blocks_row_total",
-            "KV blocks of a slot's whole row in every layer, summed over "
-            "prompt chunks.",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_step_counters = {
-            key: _reg.counter(
-                f"fed_serving_{key}_total",
-                f"The served model's decode-step counter {key!r}, counted "
-                "on the device and fetched with the chosen ids.",
-                labels=("server",),
-            ).labels(server=name)
-            for key in self.pool.step_counters
-        }
-        self._m_dropped = _reg.counter(
-            "fed_serving_diffusion_positions_dropped_total",
-            "Positions of requests' last blocks beyond max_new_tokens, "
-            "computed and dropped (generation by blocks).",
-            labels=("server",),
-        ).labels(server=name)
-        self._m_fused = _reg.counter(
-            "fed_serving_diffusion_fused_forwards_total",
-            "Rows whose forward committed their block and was the first "
-            "denoising step of the next (generation by blocks).",
-            labels=("server",),
-        ).labels(server=name)
         self._update_kv_gauges()
         # Whatever way a version comes in (publish, a promoted standby's
         # state), the bank's snapshot of it is the tree the programs read.
@@ -729,7 +630,8 @@ class InferenceServer:
         models = {"params": self.model}
         if self.draft_cfg is not None:
             models["draft_params"] = decode.serving_model(self.draft_cfg)
-        stats, lock, mirror = self._stats, self._lock, self._m_publish_cast
+        count = functools.partial(_bump, self._stats, self._series)
+        lock = self._lock
 
         def snapshot(key: str, tree: Any) -> Any:
             dtype = models[key].serving_dtype() if key in models else None
@@ -739,8 +641,7 @@ class InferenceServer:
             with tracing.phase("fed:serve:publish_cast"):
                 snap = snapshot_tree(tree, dtype)
             with lock:
-                stats["publish_cast_bytes"] += nbytes
-            mirror.inc(nbytes)
+                count("publish_cast_bytes", nbytes)
             return snap
 
         return snapshot
@@ -846,8 +747,7 @@ class InferenceServer:
             if self._stopping:
                 raise ServerStoppedError("server is stopped")
             if len(self._pending) >= self.scfg.max_pending:
-                self._stats["rejected"] += 1
-                self._m_events["rejected"].inc()
+                self._count("rejected")
                 raise ServerOverloadedError(
                     f"pending queue full ({self.scfg.max_pending}); "
                     "back off and resubmit"
@@ -866,8 +766,7 @@ class InferenceServer:
                 stream=stream,
             )
             req.timing["enqueue"] = now
-            self._stats["submitted"] += 1
-            self._m_events["submitted"].inc()
+            self._count("submitted")
             self._pending.append(req)
             self._m_pending.set(len(self._pending))
             self._cond.notify_all()
@@ -1184,8 +1083,7 @@ class InferenceServer:
                 status = self.pool.adopt_prefix(donor, slot, plen)
                 if status == "ok":
                     req.prefix_reuse = True
-                    self._stats["prefix_hits"] += 1
-                    self._m_prefix_hits.inc()
+                    self._count("prefix_hits")
                     self._post_prefill(req, self._step_one_row(params, req))
                     return "ok"
                 # fall through: no blocks for the boundary clone — the
@@ -1385,11 +1283,9 @@ class InferenceServer:
                 budget -= clen
                 ran = True
                 with self._lock:
-                    self._stats["prefill_chunks"] += 1
-                    self._stats["prefill_chunks_kernel"] += self._kernel_chunk
-                self._m_chunks.inc()
-                if self._kernel_chunk:
-                    self._m_chunks_kernel.inc()
+                    self._count("prefill_chunks")
+                    if self._kernel_chunk:
+                        self._count("prefill_chunks_kernel")
                 self._count_prefill(off, real)
                 self._count_chunk_blocks(off)
                 if req.chunk_done >= plen:
@@ -1508,9 +1404,15 @@ class InferenceServer:
         """The ids a program chose, on the host (this waits for the
         program), counted in ``fetch_bytes``."""
         ids = np.asarray(ids)
-        self._stats["fetch_bytes"] += ids.nbytes
-        self._m_fetch_bytes.inc(ids.nbytes)
+        self._count("fetch_bytes", ids.nbytes)
         return ids
+
+    def _count(self, key: str, n: int = 1) -> None:
+        """The counter ``key`` goes up by ``n``: its entry of ``stats()``
+        and its series in the telemetry registry, if it has one
+        (``_COUNTERS``). Takes no lock: where another thread than the
+        engine's reads or writes the key, the caller holds ``_lock``."""
+        _bump(self._stats, self._series, key, n)
 
     def _count_prefill(self, off: int, n: int) -> None:
         """``n`` real prompt tokens at positions ``off .. off + n - 1``
@@ -1542,10 +1444,8 @@ class InferenceServer:
                 len(self._index_topk) * causal,
                 sum(seen(end, k) - seen(off, k) for k in self._index_topk))
         with self._lock:
-            self._stats["prefill_tokens"] += n
-            self._stats["prefill_keys_attended"] += keys
-        self._m_prefill_tokens.inc(n)
-        self._m_prefill_keys.inc(keys)
+            self._count("prefill_tokens", n)
+            self._count("prefill_keys_attended", keys)
 
     def _count_index(self, scored: int, selected: int,
                      decode_step: bool = False) -> None:
@@ -1553,13 +1453,11 @@ class InferenceServer:
         if not self._index_topk:
             return
         with self._lock:
-            self._stats["index_keys_scored"] += scored
-            self._stats["index_keys_selected"] += selected
+            self._count("index_keys_scored", scored)
+            self._count("index_keys_selected", selected)
             if decode_step:
-                self._stats["index_keys_scored_decode"] += scored
-                self._stats["index_keys_selected_decode"] += selected
-        self._m_host_counters["index_keys_scored"].inc(scored)
-        self._m_host_counters["index_keys_selected"].inc(selected)
+                self._count("index_keys_scored_decode", scored)
+                self._count("index_keys_selected_decode", selected)
 
     def _count_chunk_blocks(self, off: int) -> None:
         """A chunk at offset ``off`` ran: count the blocks that hold the
@@ -1574,10 +1472,8 @@ class InferenceServer:
         )
         row = self._n_layers * self.pool.blocks_per_row
         with self._lock:
-            self._stats["chunk_blocks_read"] += read
-            self._stats["chunk_blocks_row"] += row
-        self._m_chunk_read.inc(read)
-        self._m_chunk_row.inc(row)
+            self._count("chunk_blocks_read", read)
+            self._count("chunk_blocks_row", row)
 
     def _layer_blocks(self, positions, attended: int) -> int:
         """Blocks the layers of the live rows (at ``positions``) must
@@ -1631,8 +1527,7 @@ class InferenceServer:
     def _count_state_resets(self, n: int) -> None:
         if self._recurrent:
             with self._lock:
-                self._stats["state_resets"] += n
-            self._m_state_resets.inc(n)
+                self._count("state_resets", n)
 
     def _step_one_row(self, params, req: _Request):
         """The decode program with only ``req``'s row live, at the last
@@ -1650,8 +1545,7 @@ class InferenceServer:
             return
         req.stream.push(len(req.out) - 1, [tok], False)
         with self._lock:
-            self._stats["streamed_tokens"] += 1
-        self._m_streamed.inc()
+            self._count("streamed_tokens")
 
     def _maybe_preempt(self) -> bool:
         """Deadlock breaker: when an iteration made no progress and
@@ -1704,11 +1598,10 @@ class InferenceServer:
         if req.stream is not None:
             req.stream.reset()
         with self._cond:
-            self._stats["preempted"] += 1
+            self._count("preempted")
             self._pending.appendleft(req)
             self._m_pending.set(len(self._pending))
             self._cond.notify_all()
-        self._m_preempted.inc()
         tracing.record_request(req.rid, "preempt")
         logger.info("serving[%s]: preempted %s to free KV blocks",
                     self.name, req.rid)
@@ -1806,8 +1699,7 @@ class InferenceServer:
                     # step.
                     for key, n in zip(self.pool.step_counters,
                                       ids[self.pool.ids_len:]):
-                        self._stats[key] += int(n)
-                        self._m_step_counters[key].inc(int(n))
+                        self._count(key, int(n))
                 with tracing.phase("fed:serve:emit"):
                     take = (self._take_token if self._block is None
                             else self._take_block)
@@ -1818,8 +1710,7 @@ class InferenceServer:
                             if req.ahead:
                                 # Live in the step dispatched above.
                                 self._forget(req)
-                                self._stats["rows_wasted"] += 1
-                                self._m_rows_wasted.inc()
+                                self._count("rows_wasted")
                             with self._lock:
                                 self._active.pop(req.slot, None)
                                 self._m_active.set(len(self._active))
@@ -1863,10 +1754,8 @@ class InferenceServer:
             # The committed block's queries were counted at the dispatch;
             # the opened block's saw that block too.
             keys = self._n_layers * n * (req.pos + n)
-            self._stats["diffusion_fused_forwards"] += 1
-            self._stats["decode_keys_attended"] += keys
-            self._m_fused.inc()
-            self._m_decode_keys.inc(keys)
+            self._count("diffusion_fused_forwards")
+            self._count("decode_keys_attended", keys)
         new = ids[req.slot * n:(req.slot + 1) * n]
         for j in range(n):
             if req.block[j] == mask and new[j] != mask:
@@ -1895,8 +1784,7 @@ class InferenceServer:
             if tok == self.scfg.eos_id:
                 return True
         if dropped:
-            self._stats["diffusion_positions_dropped"] += dropped
-            self._m_dropped.inc(dropped)
+            self._count("diffusion_positions_dropped", dropped)
         return req.block_out == n and req.pos + n >= end
 
     def _dispatch(self, params, live, inputs, prev) -> _Ahead:
@@ -1917,16 +1805,11 @@ class InferenceServer:
         by_layer = self._layer_blocks(positions, attended)
         keys = self._layer_keys(positions)
         walked = self._blocks_walked(positions)
-        self._stats["kv_blocks_attended"] += attended
-        self._stats["kv_blocks_slab"] += slab
-        self._stats["kv_blocks_walked"] += walked
-        self._m_kv_walked.inc(walked)
-        self._stats["kv_layer_blocks_attended"] += by_layer
-        self._stats["decode_keys_attended"] += keys
-        self._m_kv_attended.inc(attended)
-        self._m_kv_slab.inc(slab)
-        self._m_kv_layer_attended.inc(by_layer)
-        self._m_decode_keys.inc(keys)
+        self._count("kv_blocks_attended", attended)
+        self._count("kv_blocks_slab", slab)
+        self._count("kv_blocks_walked", walked)
+        self._count("kv_layer_blocks_attended", by_layer)
+        self._count("decode_keys_attended", keys)
         if self._index_topk:
             self._count_index(
                 len(self._index_topk) * sum(pos + 1 for pos in positions),
@@ -1936,11 +1819,9 @@ class InferenceServer:
         if self._windows:
             dead = sum(max(pos - window + 1, 0) // bs
                        for window in self._windows for pos in positions)
-            self._stats["kv_dead_blocks"] += dead
-            self._m_host_counters["kv_dead_blocks"].inc(dead)
+            self._count("kv_dead_blocks", dead)
         if any(req.temperature > 0.0 for req in reqs):
-            self._stats["draw_steps"] += 1
-            self._m_draw_steps.inc()
+            self._count("draw_steps")
         if self._recurrent:
             # Read and written once each by every live row; held:
             # admitted rows whose state this step kept (stalled, on
@@ -1950,14 +1831,11 @@ class InferenceServer:
             held = len(self._active) - len(reqs) + sum(
                 1 for r in self._prefilling if r.chunk_done
             )
-            self._stats["ssm_state_bytes"] += moved
-            self._stats["state_rows_held"] += held
-            self._m_state_bytes.inc(moved)
-        self._stats["steps"] += 1
-        self._m_steps.inc()
+            self._count("ssm_state_bytes", moved)
+            self._count("state_rows_held", held)
+        self._count("steps")
         if prev is not None:
-            self._stats["steps_ahead"] += 1
-            self._m_steps_ahead.inc()
+            self._count("steps_ahead")
         return _Ahead(ids, reqs)
 
     @staticmethod
@@ -1988,10 +1866,8 @@ class InferenceServer:
         req.timing["finish"] = now
         latency_ms = (now - req.enqueue_s) * 1e3
         with self._lock:
-            self._stats["completed"] += 1
-            self._m_events["completed"].inc()
-            self._stats["tokens_out"] += len(req.out)
-            self._m_tokens.inc(len(req.out))
+            self._count("completed")
+            self._count("tokens_out", len(req.out))
             self._m_latency.observe(latency_ms)
             self._latencies_ms.append(latency_ms)
         tracing.record_request(req.rid, "finish", t_s=now,
@@ -2067,8 +1943,7 @@ class InferenceServer:
             # Whole-request paths produce everything at once; one frame.
             req.stream.push(0, list(req.out), False)
             with self._lock:
-                self._stats["streamed_tokens"] += len(req.out)
-            self._m_streamed.inc(len(req.out))
+                self._count("streamed_tokens", len(req.out))
         self._finish(req)
 
 

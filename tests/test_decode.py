@@ -429,3 +429,55 @@ def test_sharded_beam_search_matches_single_device():
     np.testing.assert_allclose(
         np.asarray(scores), np.asarray(ref_scores), rtol=1e-5, atol=1e-6
     )
+
+
+@pytest.mark.parametrize("landed", [
+    None, [True, False, True, False], [False, False, False, True],
+    [False] * 4, [True] * 4,
+])
+def test_landed_rows_computes_and_lands_only_the_named_rows(landed):
+    """The loop every landed-rows ``prefill_rows`` shares: ``one_row`` runs
+    ``sum(landed)`` times, landed rows first in slot order; a row's logits
+    land at ``[i]`` and its other outputs at ``[:, i]``, one shorter than
+    its output from the start of the later axis; the rest stays zero;
+    ``landed=None`` lands all."""
+    R, V, L, S = 4, 5, 2, 3
+    calls = []
+
+    def one_row(i):
+        jax.debug.callback(lambda i: calls.append(int(i)), i, ordered=True)
+        f = i.astype(jnp.float32) + 1
+        return (jnp.full((V,), f), jnp.full((L, S), 10 * f),
+                jnp.full((L, S - 1, 2), 100 * f))
+
+    zeros = (jnp.zeros((R, V)), jnp.zeros((L, R, S)),
+             jnp.zeros((L, R, S + 2, 2)))
+    mask = None if landed is None else jnp.asarray(landed)
+    logits, rows, longer = jax.jit(
+        lambda m: decode.landed_rows(one_row, m, zeros))(mask)
+    jax.effects_barrier()
+    want = [i for i in range(R) if landed is None or landed[i]]
+    assert calls == want
+    f = np.zeros(R, np.float32)
+    f[want] = np.asarray(want, np.float32) + 1
+    np.testing.assert_array_equal(logits, np.tile(f[:, None], (1, V)))
+    np.testing.assert_array_equal(
+        rows, np.tile(10 * f[None, :, None], (L, 1, S)))
+    np.testing.assert_array_equal(
+        longer[:, :, :S - 1], np.tile(100 * f[None, :, None, None],
+                                      (L, 1, S - 1, 2)))
+    assert not np.asarray(longer[:, :, S - 1:]).any()
+
+
+def test_landed_rows_trip_count_is_a_runtime_value():
+    """One program whatever ``landed`` holds: the loop's bound is
+    ``sum(landed)`` computed in the program, not a constant of the trace."""
+    zeros = (jnp.zeros((4, 2)), jnp.zeros((1, 4, 3)))
+    fn = jax.jit(lambda m: decode.landed_rows(
+        lambda i: (jnp.ones((2,)), jnp.ones((1, 3))), m, zeros))
+    text = fn.lower(jnp.zeros((4,), bool)).as_text()
+    assert "stablehlo.while" in text and text.count("stablehlo.sort") == 1
+    for mask in ([True] * 4, [False, True, False, False]):
+        logits, _ = fn(jnp.asarray(mask))
+        np.testing.assert_array_equal(logits[:, 0], np.asarray(mask, float))
+    assert fn._cache_size() == 1

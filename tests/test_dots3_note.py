@@ -747,6 +747,32 @@ def test_the_engines_counters_against_the_positions():
         assert mirror.value() == st[name]
 
 
+def test_the_gated_rows_of_the_counter_table_in_stats_and_registry():
+    """With windows, an index and ``step_counters``: the rows they gate
+    are in ``stats()``, their series read the same, the ``*_decode`` keys
+    have none, and no row of a model that generates by blocks is there
+    (``tests/test_serving.py`` has the dense engine's half)."""
+    from tests.utils import assert_counters_agree
+
+    srv = _server(name="dots3-table")
+    try:
+        srv.submit(_tokens(5).tolist(), max_new_tokens=4).result(
+            timeout=WAIT_S)
+        srv.submit(_tokens(21, seed=1).tolist(), max_new_tokens=6,
+                   temperature=0.7, seed=3).result(timeout=WAIT_S)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    counters = assert_counters_agree(srv, st)
+    assert {"index_keys_scored", "index_keys_selected", "kv_dead_blocks",
+            "index_keys_scored_decode", "index_keys_selected_decode",
+            *srv.pool.step_counters} <= counters
+    assert srv.pool.step_counters and not any(
+        key.startswith("diffusion_") for key in counters)
+    assert 0 < st["index_keys_scored_decode"] < st["index_keys_scored"]
+    assert st["kv_dead_blocks"] > 0 and st["moe_experts_hit"] > 0
+
+
 def test_a_model_without_an_indexer_or_a_window_has_no_such_counters():
     srv = InferenceServer(
         _dense_cfg(), ServingConfig(max_slots=2, max_len=32,

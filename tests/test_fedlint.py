@@ -191,17 +191,27 @@ def test_cli_singleton_inventory(tmp_path):
 def test_repo_singleton_inventory_is_fresh(tmp_path):
     """tools/singleton_inventory.json (the multi-tenant worklist) must
     match what the detector reports today — regenerate it when module
-    globals are added or removed."""
+    globals are added or removed. What is compared is what that says:
+    the singletons (module, path, name, kind, value) and how many
+    mutators each has, not the lines they stand on (a PR that moves a
+    line above one need not regenerate the file)."""
     out = tmp_path / "inventory.json"
     # Relative path on purpose: the committed inventory stores
     # repo-relative paths (the CLI runs with cwd=REPO here).
     proc = _run_cli("rayfed_tpu", "--singleton-inventory", str(out))
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    fresh = json.loads(out.read_text())
-    committed = json.loads(
-        open(os.path.join(REPO, "tools", "singleton_inventory.json")).read()
-    )
-    assert fresh == committed, (
+
+    def worklist(payload):
+        assert payload["version"] == 1
+        return sorted(
+            (s["module"], s["path"], s["name"], s["kind"], s["value"],
+             len(s["mutators"]))
+            for s in payload["singletons"]
+        )
+
+    with open(os.path.join(REPO, "tools", "singleton_inventory.json")) as f:
+        committed = json.load(f)
+    assert worklist(json.loads(out.read_text())) == worklist(committed), (
         "tools/singleton_inventory.json is stale; regenerate with "
         "`python -m rayfed_tpu.lint rayfed_tpu --singleton-inventory "
         "tools/singleton_inventory.json`"

@@ -573,6 +573,35 @@ def test_a_stalled_row_and_a_preempted_row_replay_to_the_same_tokens():
     assert st["preempted"] >= 1 and st["kv_blocks_in_use"] == 0
 
 
+def test_the_block_rows_of_the_counter_table_in_stats_and_registry():
+    """Generation by blocks: the host's ``diffusion_*`` rows and the
+    device's (``kv_pool.BLOCK_STEP_COUNTERS``, behind the model's own)
+    are in ``stats()`` and read the same in the registry; no row of an
+    index or a window is there."""
+    from tests.utils import assert_counters_agree
+
+    srv = InferenceServer(
+        CFG, ServingConfig(max_slots=3, max_len=MAX_LEN, kv_block_size=4,
+                           prefill_chunk=CHUNK,
+                           prefill_token_budget=2 * CHUNK,
+                           prefix_reuse=False),
+        params=PARAMS, cache_dtype=CFG.compute_dtype, name="sdar-table")
+    try:
+        srv.submit(_tokens(6), max_new_tokens=7).result(timeout=WAIT_S)
+        srv.submit(_tokens(2 * CHUNK + 2, seed=1),
+                   max_new_tokens=9).result(timeout=WAIT_S)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    counters = assert_counters_agree(srv, st)
+    assert {"diffusion_positions_dropped", "diffusion_fused_forwards",
+            *srv.pool.step_counters} <= counters
+    assert not counters & {"index_keys_scored", "kv_dead_blocks"}
+    assert st["diffusion_fused_forwards"] > 0
+    assert st["diffusion_positions_dropped"] > 0
+    assert st["diffusion_row_forwards"] >= st["diffusion_commit_forwards"] > 0
+
+
 def test_an_eos_ends_a_request_at_the_token_that_leaves():
     cfg, w, params, hp = _peaked()
     prompt = _tokens(6, seed=91)

@@ -839,6 +839,61 @@ def test_draw_steps_count_the_steps_in_which_a_live_row_was_sampled(
     assert st["draw_steps"] == (st["steps"] if drew else 0)
 
 
+def test_every_counter_is_one_row_bumped_once_in_stats_and_registry():
+    """A fixed run on the dense engine: greedy and sampled requests, one
+    streamed, one prompt long enough for chunks, a prefix hit, one
+    rejected. Every counter key of ``stats()`` is a row of
+    ``server._COUNTERS`` and reads the same in the registry's series for
+    this server; the rows gated by an optional protocol member are
+    absent, the keys without a series have none."""
+    from rayfed_tpu.serving import server
+    from tests.utils import assert_counters_agree
+
+    srv = InferenceServer(
+        CFG, ServingConfig(max_slots=4, max_len=48, max_new_tokens=8,
+                           prefill_chunk=8, prefill_token_budget=16),
+        params=PARAMS_A, name="counters-dense")
+    rng = np.random.default_rng(5)
+    short, long = ([int(t) for t in rng.integers(1, 255, size=n)]
+                   for n in (6, 21))
+    try:
+        srv.submit_and_wait(short, max_new_tokens=5)
+        fut, stream = srv.submit_stream(
+            short[:5], max_new_tokens=6, temperature=0.8, seed=7)
+        assert len(list(stream)) == 6 == len(fut.result()["tokens"])
+        srv.submit_and_wait(long, max_new_tokens=4)
+        # (The donor is still running when its twin is admitted.)
+        futs = [srv.submit(short, max_new_tokens=30) for _ in range(2)]
+        for fut in futs:
+            fut.result()
+        srv.scfg.max_pending = 0
+        with pytest.raises(ServerOverloadedError):
+            srv.submit(short)
+        st = srv.stats()
+    finally:
+        srv.stop()
+    counters = assert_counters_agree(srv, st)
+    gated = {row.key for row in server._COUNTERS if row.gate}
+    assert counters == {row.key for row in server._COUNTERS} - gated
+    assert (st["submitted"], st["completed"], st["rejected"]) == (5, 5, 1)
+    assert st["tokens_out"] == 5 + 6 + 4 + 30 + 30
+    assert st["streamed_tokens"] == 6 and st["draw_steps"] >= 5
+    assert st["prefill_chunks"] == 3 and st["prefix_hits"] >= 1
+    assert st["prefill_tokens"] >= 6 + 5 + 21
+    assert st["state_rows_held"] == st["ssm_state_bytes"] == 0
+    # A block row's series is every server's; an index row's is not.
+    snap = telemetry_metrics.get_registry().snapshot()
+
+    def servers(name):
+        return {s["labels"]["server"]
+                for s in snap.get(name, {}).get("series", [])}
+
+    assert "counters-dense" in servers(
+        "fed_serving_diffusion_positions_dropped_total")
+    assert "counters-dense" not in servers(
+        "fed_serving_index_keys_scored_total")
+
+
 def test_wrapping_the_sample_seam_alters_tokens_and_keeps_no_engine_alive():
     """What the benchmark's ``--inject broken-token`` does: every token
     goes through ``_sample``, and the original kept in a local is a

@@ -96,6 +96,17 @@ def test_multi_buffer_send():
     assert payload.nbytes == total
 
 
+def test_conftest_built_the_native_module_where_a_compiler_is():
+    """``tests/conftest.py`` builds ``rayfed_tpu/_fastwire`` into a
+    checkout that lacks it before anything imports the package, so the
+    suite counts the same with and without a build left behind."""
+    import shutil
+
+    if not (shutil.which("cc") or shutil.which("gcc")):
+        pytest.skip("no C compiler on this machine")
+    assert sockio._fastwire is not None
+
+
 @pytest.mark.skipif(sockio._fastwire is None, reason="fastwire not built")
 def test_fastwire_timeout():
     a, b = socket.socketpair()

@@ -158,3 +158,40 @@ def record_logits(monkeypatch):
 
     monkeypatch.setattr(sampling, "choose_tokens", spy)
     return seen
+
+
+# ``stats()`` keys that are live values or facts, not counters.
+STATS_NOT_COUNTERS = {
+    "pending", "active", "kv_blocks_in_use", "kv_blocks_free",
+    "kv_block_size", "kv_token_bytes", "compiled_programs",
+    "current_version", "swaps", "live_versions", "p50_ms", "p99_ms",
+}
+
+
+def assert_counters_agree(srv, st):
+    """``st`` (the stopped engine's ``stats()``) against the telemetry
+    registry and ``server._COUNTERS``: every counter key of ``stats()`` is
+    a row of the table (or one of the model's ``step_counters``), and every
+    row with a series reads there, under this server's label, what
+    ``stats()`` says. Returns the rows' keys that ``stats()`` holds."""
+    from rayfed_tpu.serving import server
+    from rayfed_tpu.telemetry import metrics as telemetry_metrics
+
+    reg = telemetry_metrics.get_registry()
+    rows = {row.key: row for row in server._COUNTERS}
+    for key in srv.pool.step_counters:
+        rows[key] = server._Counter(key, "the model's own")
+    counters = set(st) - STATS_NOT_COUNTERS
+    assert counters <= set(rows), counters - set(rows)
+    for key in counters:
+        row = rows[key]
+        if row.help is None:
+            assert key not in srv._series
+            continue
+        labels = {"server": srv.name}
+        if row.event:
+            labels["event"] = key
+        series = reg.get(row.series or f"fed_serving_{key}_total")
+        assert series.labels(**labels).value() == st[key], key
+        assert isinstance(st[key], int), key
+    return counters
