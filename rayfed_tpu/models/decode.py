@@ -1149,8 +1149,14 @@ class TransformerServing:
     the pool through the slot's block table, its own rows written there
     in place) and ``decode_step`` (one token for every row, the cache
     read through the block tables) take and return ``kv``.
-    ``state_spec`` is what a slot holds beside its paged rows; a model
-    that has none (this one) returns ``{}``, takes and
+    ``state_spec`` is what a slot holds beside its paged rows, ``name ->
+    (layers that keep it, shape, dtype)``: the pool allocates one
+    ``(layers, slots, *shape)`` array per entry, each as deep as the
+    layers that keep it, as the arrays of ``kv_spec`` are (every layer in
+    :mod:`rayfed_tpu.models.falcon_h1`; the linear-attention layers alone
+    in :mod:`rayfed_tpu.models.olmo_hybrid`, whose full layers alone keep
+    K/V), and a program indexes it by a layer's ordinal among those; a
+    model that has none (this one) returns ``{}``, takes and
     returns ``{}`` wherever a program hands state on, and ignores
     ``live``, ``n_real`` and what else exists for the sake of a state.
     ``serving_dtype`` is the dtype in which the programs read the
@@ -1160,8 +1166,9 @@ class TransformerServing:
 
     Four further members are optional, declared by the model that needs
     them and absent here: ``layer_windows()`` (per layer the keys a token
-    attends, or None for every key: the engine counts the blocks each
-    layer must read) and ``step_counters`` (names of int32 counts only
+    attends, None for every key, 0 for a layer that attends none, as a
+    linear-attention layer: the engine counts the blocks each layer must
+    read, over the layers that attend) and ``step_counters`` (names of int32 counts only
     the device knows: ``decode_step`` then returns them as a fourth value
     and they ride home behind the ids), both first declared by
     :mod:`rayfed_tpu.models.cohere2_moe`; ``layer_index_topk()`` (per

@@ -253,16 +253,19 @@ def _dt_of(dt_raw, layer):
     return jax.nn.softplus(dt_raw.astype(F32) + layer["dt_bias"].astype(F32))
 
 
-def conv_seq(xbc, tail, layer, n_real, cfg: FalconH1Config):
+def conv_seq(xbc, tail, layer, n_real):
     """Depthwise causal convolution of (B, S, C) inputs whose first
     ``n_real`` (B,) positions are real, continuing from ``tail`` (B, K-1,
-    C), the inputs before position 0. Returns silu(conv + bias) and the
-    new tail: the last K-1 *real* inputs."""
+    C), the inputs before position 0; ``layer`` holds ``conv_w`` (K, C)
+    and, where the model has one, the bias ``conv_b`` (C,). Returns
+    silu(conv + bias) and the new tail: the last K-1 *real* inputs.
+    (Shared with :mod:`rayfed_tpu.models.olmo_hybrid`, whose
+    linear-attention layers convolve the same way, without a bias.)"""
     with jax.named_scope("serve/conv"):
-        k, s = cfg.ssm_conv, xbc.shape[1]
-        u = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
         w = layer["conv_w"].astype(F32)
-        out = layer["conv_b"].astype(F32)
+        k, s = w.shape[0], xbc.shape[1]
+        u = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+        out = layer["conv_b"].astype(F32) if "conv_b" in layer else 0.0
         for j in range(k):
             out = out + w[j] * u[:, j:j + s].astype(F32)
         idx = n_real[:, None] + jnp.arange(k - 1)
@@ -270,16 +273,16 @@ def conv_seq(xbc, tail, layer, n_real, cfg: FalconH1Config):
         return jax.nn.silu(out).astype(xbc.dtype), new_tail
 
 
-def conv_step(xbc, tail, layer, cfg: FalconH1Config):
+def conv_step(xbc, tail, layer):
     """One position: ``xbc`` (R, C), ``tail`` (R, K-1, C)."""
     with jax.named_scope("serve/conv"):
         u = jnp.concatenate(
             [tail.astype(xbc.dtype), xbc[:, None]], axis=1
         )
         w = layer["conv_w"].astype(F32)
-        out = layer["conv_b"].astype(F32) + jnp.sum(
-            w * u.astype(F32), axis=1
-        )
+        out = jnp.sum(w * u.astype(F32), axis=1)
+        if "conv_b" in layer:
+            out = layer["conv_b"].astype(F32) + out
         return jax.nn.silu(out).astype(xbc.dtype), u[:, 1:]
 
 
@@ -383,7 +386,7 @@ def mixer_seq(h, layer, tail, state, real, n_real, cfg: FalconH1Config):
     ``real`` (B, S) count (a prefix of ``n_real`` (B,) of them). Returns
     the branch's output, the new tail and the new state."""
     z, xbc, dt_raw = _in_proj(h, layer, cfg)
-    xbc, tail = conv_seq(xbc, tail, layer, n_real, cfg)
+    xbc, tail = conv_seq(xbc, tail, layer, n_real)
     x, b, c = _split_xbc(xbc, cfg)
     dt = jnp.where(real[..., None], _dt_of(dt_raw, layer), 0.0)
     y, state = ssd_scan(x, dt, b, c, layer, state, cfg)
@@ -395,7 +398,7 @@ def mixer_seq(h, layer, tail, state, real, n_real, cfg: FalconH1Config):
 def mixer_step(h, layer, tail, state, cfg: FalconH1Config):
     """The Mamba-2 branch for one position of every row: ``h`` (R, d)."""
     z, xbc, dt_raw = _in_proj(h, layer, cfg)
-    xbc, tail = conv_step(xbc, tail, layer, cfg)
+    xbc, tail = conv_step(xbc, tail, layer)
     x, b, c = _split_xbc(xbc, cfg)
     y, state = ssm_step(x, _dt_of(dt_raw, layer), b, c, layer, state, cfg)
     out = _mm(gated_norm(y, z, layer, cfg), layer["out_proj"], cfg,
@@ -604,13 +607,15 @@ class FalconH1Serving:
         return (self.cfg.n_layers, head), (self.cfg.n_layers, head)
 
     def state_spec(self, cache_dtype=None):
-        """Per layer and slot, beside the paged K/V: name -> (shape,
-        dtype). The pool owns one (L, slots, *shape) array of each."""
+        """Per slot, beside the paged K/V: name -> (layers that keep it,
+        shape, dtype); every layer keeps both here. The pool owns one
+        (layers, slots, *shape) array of each."""
         cfg = self.cfg
         return {
-            "conv": ((cfg.ssm_conv - 1, cfg.conv_dim),
+            "conv": (cfg.n_layers, (cfg.ssm_conv - 1, cfg.conv_dim),
                      cache_dtype or cfg.compute_dtype),
-            "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), F32),
+            "ssm": (cfg.n_layers,
+                    (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), F32),
         }
 
     def serving_dtype(self):

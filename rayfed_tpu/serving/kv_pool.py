@@ -76,8 +76,14 @@ was overwritten by its own prefill/decode first.
 
 Recurrent state (a model whose ``state_spec`` is not empty, e.g.
 :mod:`rayfed_tpu.models.falcon_h1`): the pool also owns one
-``(model layers, max_slots, *shape)`` array per entry of the spec, donated through
-the decode step like the K/V pair. Unlike K/V it is *carried*, so none
+``(L_s, max_slots, *shape)`` array per entry of the spec, donated through
+the decode step like the K/V pair, where ``L_s`` is the number of the
+model's layers that keep THAT entry (each entry declares its own, as each
+array of ``kv_spec()`` does: a model whose layers mostly hold a state with
+a full-attention layer among every few allocates the state for the former
+and K/V for the latter, :mod:`rayfed_tpu.models.olmo_hybrid`, and a
+program indexes a state array by a layer's ordinal among those that keep
+it). Unlike K/V it is *carried*, so none
 of the "stale is invisible" arguments above hold for it: a slot's state
 is made zero by the prefill that starts a request in it (the bucketed
 prefill computes from a zero state and :meth:`PagedKVPool.scatter_rows`
@@ -227,11 +233,12 @@ class PagedKVPool:
             for layers_of_array, shape in arrays
         )
         # What a slot holds beside its paged rows (a recurrent state):
-        # one (the model's layers, max_slots, ...) array per entry of the
-        # model's spec.
+        # one (layers of the entry, max_slots, ...) array per entry of the
+        # model's spec, each as deep as the layers that keep it.
         self._state = {
-            name: jnp.zeros((cfg.n_layers, max_slots, *shape), sdtype)
-            for name, (shape, sdtype) in self.model.state_spec(dtype).items()
+            name: jnp.zeros((layers_of_entry, max_slots, *shape), sdtype)
+            for name, (layers_of_entry, shape, sdtype)
+            in self.model.state_spec(dtype).items()
         }
         self.state_row_bytes = sum(
             int(a.nbytes) // max_slots for a in self._state.values()
@@ -484,7 +491,8 @@ class PagedKVPool:
 
     @property
     def state(self):
-        """The recurrent-state arrays, name -> (L, max_slots, ...)."""
+        """The recurrent-state arrays, name -> (layers that keep it,
+        max_slots, ...)."""
         return self._state
 
     @property

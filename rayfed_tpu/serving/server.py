@@ -216,6 +216,10 @@ _COUNTERS = (
     # (``decode.paged_chunk_is_kernel``: all of a pool's chunks, or none.)
     _Counter("prefill_chunks_kernel",
              "Prompt chunks whose read of the pool ran as a kernel."),
+    # Of ``prefill_tokens``, those that went through chunks (the rest:
+    # the bucketed prefill): what a chunk's least work is reckoned from.
+    _Counter("chunk_tokens",
+             "Real prompt tokens put through prompt chunks."),
     _Counter("streamed_tokens", "Tokens pushed to streaming sinks."),
     _Counter("preempted",
              "Requests preempted to break a KV block-pool deadlock.",
@@ -243,6 +247,10 @@ _COUNTERS = (
              "Requests started from a zero recurrent state."),
     # Rows that sat a decode step out with their state kept.
     _Counter("state_rows_held", None),
+    # A chunk reads its slot's state (what the chunk before handed on)
+    # and writes it back: 2 x the slot's state bytes a chunk.
+    _Counter("chunk_state_bytes",
+             "Recurrent-state bytes read and written by prompt chunks."),
     # (0: the trees came in that dtype, or the model takes them as
     # published.)
     _Counter("publish_cast_bytes",
@@ -429,16 +437,17 @@ class InferenceServer:
         self._rid_counter = itertools.count()
         self._stopping = False
         self._fatal: Optional[BaseException] = None
-        # The MODEL's layers (each attends once a token), whatever arrays
-        # of the pool each of them keeps a row in, and of them the windows
-        # of those that have one and the top-k of those that select their
-        # keys (both optional in the protocol: without ``layer_windows``
-        # and ``layer_index_topk`` every layer attends every key).
-        self._n_layers = self.cfg.n_layers
-        self._windows = tuple(
-            w for w in getattr(self.model, "layer_windows", tuple)()
-            if w is not None
-        )
+        # The MODEL's layers that attend (each once a token), whatever
+        # arrays of the pool each of them keeps a row in, and of them the
+        # windows of those that have one and the top-k of those that select
+        # their keys (both optional in the protocol: without
+        # ``layer_windows`` and ``layer_index_topk`` every layer attends
+        # every key). A window of 0 is a layer that attends NO key (a
+        # linear-attention layer: it reads no key and walks no block): the
+        # counts by layer below are over the others.
+        windows = getattr(self.model, "layer_windows", tuple)()
+        self._n_attending = self.cfg.n_layers - sum(w == 0 for w in windows)
+        self._windows = tuple(w for w in windows if w)
         self._index_topk = tuple(
             k for k in getattr(self.model, "layer_index_topk", tuple)()
             if k is not None
@@ -1284,8 +1293,11 @@ class InferenceServer:
                 ran = True
                 with self._lock:
                     self._count("prefill_chunks")
+                    self._count("chunk_tokens", real)
                     if self._kernel_chunk:
                         self._count("prefill_chunks_kernel")
+                    self._count("chunk_state_bytes",
+                                2 * self.pool.state_row_bytes)
                 self._count_prefill(off, real)
                 self._count_chunk_blocks(off)
                 if req.chunk_done >= plen:
@@ -1430,13 +1442,13 @@ class InferenceServer:
             # (``off`` and ``n`` are whole blocks).
             b = self._block.length
             lo, hi = off // b, end // b
-            keys = self._n_layers * b * b * (
+            keys = self._n_attending * b * b * (
                 hi * (hi + 1) // 2 - lo * (lo + 1) // 2)
         else:
             # (An indexed layer's query attends the ``min(k, q + 1)`` keys
             # its indexer kept: a window's count.)
             causal = seen(end, end) - seen(off, end)
-            full = self._n_layers - len(self._windows) - len(self._index_topk)
+            full = self._n_attending - len(self._windows) - len(self._index_topk)
             keys = full * causal + sum(
                 seen(end, w) - seen(off, w)
                 for w in self._windows + self._index_topk)
@@ -1467,10 +1479,10 @@ class InferenceServer:
         layer."""
         bs = self.pool.block_size
         upto = -(-off // bs)
-        read = (self._n_layers - len(self._windows)) * upto + sum(
+        read = (self._n_attending - len(self._windows)) * upto + sum(
             upto - max(off - w + 1, 0) // bs for w in self._windows
         )
-        row = self._n_layers * self.pool.blocks_per_row
+        row = self._n_attending * self.pool.blocks_per_row
         with self._lock:
             self._count("chunk_blocks_read", read)
             self._count("chunk_blocks_row", row)
@@ -1485,7 +1497,7 @@ class InferenceServer:
         rows, the fewest blocks that can hold the ``min(k, pos + 1)`` keys
         kept."""
         bs = self.pool.block_size
-        return (self._n_layers - len(self._windows)) * attended + sum(
+        return (self._n_attending - len(self._windows)) * attended + sum(
             pos // bs - max(pos - window + 1, 0) // bs + 1
             for window in self._windows for pos in positions
         ) + sum(
@@ -1504,10 +1516,10 @@ class InferenceServer:
         # (An indexed layer walks its index keys, by the gather loop
         # whatever the backend; the rows it then reads are single rows.)
         indexed = len(self._index_topk)
-        full = self._n_layers - len(self._windows) - indexed
+        full = self._n_attending - len(self._windows) - indexed
         by_loop = functools.partial(walked, kernel=False)
         return (full * walked() + indexed * by_loop() + sum(
-            walked(window) for window in self._windows)) // self._n_layers
+            walked(window) for window in self._windows)) // self._n_attending
 
     def _layer_keys(self, positions) -> int:
         """Keys the layers of the live rows (at ``positions``) score in a
@@ -1520,7 +1532,7 @@ class InferenceServer:
         # An indexed layer's row attends the ``min(k, pos + 1)`` keys its
         # indexer kept of those it scored: a window's count.
         narrow = self._windows + self._index_topk
-        return (self._n_layers - len(narrow)) * seen + sum(
+        return (self._n_attending - len(narrow)) * seen + sum(
             min(pos + 1, width) for width in narrow for pos in positions
         )
 
@@ -1753,7 +1765,7 @@ class InferenceServer:
             req.block_step = req.block_out = 0
             # The committed block's queries were counted at the dispatch;
             # the opened block's saw that block too.
-            keys = self._n_layers * n * (req.pos + n)
+            keys = self._n_attending * n * (req.pos + n)
             self._count("diffusion_fused_forwards")
             self._count("decode_keys_attended", keys)
         new = ids[req.slot * n:(req.slot + 1) * n]

@@ -197,8 +197,8 @@ def _cell_decode_step(workload, one_chip, chunk=None):
     kv = tuple(sds((layers, 1 + slots * blocks_per_row, block,
                     *kv_pool._allocated(row)))
                for layers, row in model.kv_spec())
-    state = {name: sds((cfg.n_layers, slots, *shape), dtype) for name, (
-        shape, dtype) in model.state_spec(jnp.bfloat16).items()}
+    state = {name: sds((layers, slots, *shape), dtype) for name, (
+        layers, shape, dtype) in model.state_spec(jnp.bfloat16).items()}
     i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
     if chunk is None:
         lowered = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
@@ -588,3 +588,75 @@ def test_the_selected_reads_compile_for_v5e_and_fit_beside_their_pool(
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') >= 12
     assert weights + pool_bytes + memory.temp_size_in_bytes < 15.5e9
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_256", "chunk_128",
+                                     "prefill_256"])
+def test_the_hybrid_programs_compile_for_v5e_and_fit_beside_their_pool(
+        program, one_chip, monkeypatch):
+    """``olmohybrid-assist-closed48``'s programs (depth 16 of the
+    published widths: 12 linear-attention layers whose state is a
+    float32 ``S`` and a convolution tail a slot, 4 full-attention layers
+    whose K/V is paged, a row's 30 heads padded to 32; 32 slots of 1,793
+    positions), compiled for v5e: the decode step, a whole and a ragged
+    prompt chunk, and the bucketed prefill of a round. The pool's arrays
+    and the state come back aliased to their arguments, the decode step's
+    read is the paged kernel (one form, lowered once, called from the
+    four full layers), a chunk's read keeps the loop (a slot reaches
+    1,808 keys, under ``decode.CHUNK_KERNEL_REACH``), and what a program
+    needs beside its arguments and results is activations and a
+    sub-chunk's products, never a copy of a layer's weights, of the tails
+    or of ``S`` (a period's leaves sliced out of the stack, or the tails
+    under their own shape, made the compiler copy 1.2 to 1.7 GB a call:
+    PERF.md section 6, PR 48)."""
+    from chipbench import run
+    from rayfed_tpu import utils
+    from rayfed_tpu.models import decode
+
+    monkeypatch.setattr(utils, "is_tpu_backend", lambda: True)
+    cell, kind = "olmohybrid-assist-closed48", program.split("_")
+    plan = run.resolve(cell, False)
+    serving = plan["mix"]["serving"]
+    slots = serving["max_slots"]
+    assert (slots, serving["max_len"]) == (32, 1792)
+    # The state as counted: 27.37 MB a slot (its 96 columns are tiled as
+    # 128 on the device: a third more).
+    state_bytes = slots * 27371520
+    if kind[0] == "prefill":
+        import importlib
+
+        adapter = importlib.import_module(
+            "chipbench.seeded_" + plan["reference"])
+        model = decode.serving_model(
+            adapter.program_cfg(plan["model"], plan["precision"]))
+        sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+            tuple(shape), dtype, sharding=one_chip)
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda: adapter.make_canonical(
+                jax.random.PRNGKey(0), plan["model"])))
+        lowered = jax.jit(lambda p, t, i, w: model.prefill_rows(
+            p, t, i, serving["max_len"] + 1, jnp.bfloat16, w)).lower(
+                params, sds((slots, int(kind[1])), jnp.int32),
+                sds((slots,), jnp.int32), sds((slots,), jnp.bool_))
+    else:
+        lowered, weights, kv_bytes = _cell_decode_step(
+            cell, one_chip, chunk=int(kind[1]) if kind[0] == "chunk" else None)
+        assert round(weights / 1e9, 2) == 8.2
+        assert round(kv_bytes / 1e9, 2) == 3.79       # 4 layers deep
+    if program == "decode_step":
+        assert lowered.as_text().count("func.func private @paged_read") == 1
+    compiled = lowered.compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    print(program, memory.temp_size_in_bytes, memory.output_size_in_bytes)
+    assert ("%paged_read" in text) == (program == "decode_step")
+    assert "%paged_chunk_read" not in text
+    assert memory.temp_size_in_bytes < 0.25e9
+    if kind[0] == "prefill":
+        # Its rows and their state are new arrays (the pool lands them):
+        # the state rows and 0.54 GB of K/V rows a round of 32.
+        assert memory.output_size_in_bytes < 1.8e9
+        assert 8.2e9 + 3.79e9 + state_bytes + 1.8e9 + 0.25e9 < 15.5e9
+    else:
+        assert memory.alias_size_in_bytes >= kv_bytes + state_bytes
+        assert weights + kv_bytes + state_bytes + 0.25e9 < 15.5e9
