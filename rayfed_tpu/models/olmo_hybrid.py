@@ -49,13 +49,18 @@ of ``layer_types`` at a time (a ``lax.scan`` over the periods, the layers
 of one period unrolled inside it).
 
 The delta rule has two forms. A decode row takes the step above
-(:func:`delta_step`). A prompt (``prefill_rows``, ``chunk``) takes the
-chunked form (:func:`delta_chunked`): sub-chunks of ``DELTA_CHUNK``
-positions, within one the unit lower-triangular system of the rule is
-solved at once and the state is handed on from sub-chunk to sub-chunk; a
-prompt's chunks hand ``S`` and the tail on through the pool's state row.
+(:func:`delta_step`; on a TPU backend, where :func:`delta_step_is_kernel`
+says so by what the state's array shows, the same lines as one Pallas
+kernel over the stacked state, in place:
+:func:`rayfed_tpu.ops.delta_rule.delta_state_step`). A prompt
+(``prefill_rows``, ``chunk``) takes the chunked form
+(:func:`delta_chunked`): sub-chunks of ``DELTA_CHUNK`` positions, within
+one the unit lower-triangular system of the rule is solved at once and
+the state is handed on from sub-chunk to sub-chunk; a prompt's chunks
+hand ``S`` and the tail on through the pool's state row.
 The recurrence is the definition; the chunked form is held to it by
-``tests/test_olmo_hybrid.py``. Both are plain ``jnp``.
+``tests/test_olmo_hybrid.py``, the kernel by
+``tests/test_delta_step_kernel.py``. Both forms here are plain ``jnp``.
 
 A carried state is not masked, so (as in :mod:`rayfed_tpu.models.
 falcon_h1`) a padded position must leave everything as it was (``g = 0``
@@ -89,11 +94,13 @@ Parameter tree (``Ll`` linear layers, ``Lf`` full ones, leaves in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from rayfed_tpu import utils
 from rayfed_tpu.models import decode, falcon_h1
 from rayfed_tpu.models import transformer as tfm
 
@@ -319,12 +326,27 @@ def delta_step(q, k, v, g, beta, state):
     """One position of the gated delta rule for every row: ``q``/``k``
     (R, H, dk), ``v`` (R, H, dv), ``g``/``beta`` (R, H), ``state`` (R, H,
     dv, dk), all float32. Elementwise products and sums (no matmul
-    rounds anything): the state is read and written once."""
+    rounds anything). As written the state is read and written once; a
+    TPU's compiler makes three reads and a write of it (each sum over
+    ``dk`` is a fusion of its own, the update with its write-back into
+    the stack a third: ledger, PR 48, the breakdown of
+    ``olmohybrid-assist-closed48``). The one-pass form is the kernel
+    :func:`rayfed_tpu.ops.delta_rule.delta_state_step`, whose definition
+    and whose test's reference this function is."""
     with jax.named_scope("serve/delta_rule"):
         decayed = jnp.exp(g)[..., None, None] * state
         u = v - jnp.sum(decayed * k[..., None, :], axis=-1)
         state = decayed + (beta[..., None] * u)[..., None] * k[..., None, :]
         return jnp.sum(state * q[..., None, :], axis=-1), state
+
+
+def delta_step_is_kernel(delta) -> bool:
+    """Whether a decode step advances the stacked state ``delta`` (layers,
+    R, H, dv, dk) through the Pallas kernel: on a TPU backend, a float32
+    state whose ``dv`` is whole sublanes (a head's tile is copied and
+    computed on whole)."""
+    return (utils.is_tpu_backend() and delta.dtype == F32
+            and delta.shape[-2] % 8 == 0)
 
 
 def _unit_lower_inverse(a):
@@ -436,15 +458,17 @@ def linear_seq(x, layer, tail, state, real, n_real, cfg: OlmoHybridConfig):
         return _mm(o, layer["w_o"], cfg), tail, state
 
 
-def linear_step(x, layer, tail, state, cfg: OlmoHybridConfig):
+def linear_step(x, layer, tail, step, cfg: OlmoHybridConfig):
     """A linear layer's mixer for one position of every row: ``x`` (R,
-    d)."""
+    d). ``step(q, k, v, g, beta)`` advances the rows' state by the
+    position and returns ``o`` and the state as its caller keeps it.
+    Returns the mixer's output, the new tail and that state."""
     with jax.named_scope("serve/linear_attn"):
         qkv_c, tail = falcon_h1.conv_step(
             _mm(x, layer["w_qkv"], cfg), tail, layer)
         q, k, v = _split_qkv(qkv_c, cfg)
         g, beta = _decay_beta(x, layer, cfg)
-        o, state = delta_step(q, k, v, g, beta, state)
+        o, state = step(q, k, v, g, beta)
         o = gated_norm(o, _mm(x, layer["w_g"], cfg), layer, cfg)
         return _mm(o, layer["w_o"], cfg), tail, state
 
@@ -685,6 +709,21 @@ def paged_decode_step(params, pk, pv, state, tokens, positions, tables,
     n_phys = pk.shape[1]
     attend = decode.paged_attention(pk, pv, positions, tables)
     keep = live[:, None, None]
+    if delta_step_is_kernel(state["delta"]):
+        # (Pallas is the engine's import, as ``decode.paged_attention``'s.)
+        from rayfed_tpu.ops import delta_rule
+
+        def step(delta, ordinal, q, k, v, g, beta):
+            with jax.named_scope("serve/delta_rule"):
+                return delta_rule.delta_state_step(
+                    delta, ordinal, live, q, k, v, g, beta)
+    else:
+        def step(delta, ordinal, q, k, v, g, beta):
+            st = jax.lax.dynamic_index_in_dim(
+                delta, ordinal, 0, keepdims=False)
+            o, st_new = delta_step(q, k, v, g, beta, st)
+            return o, jax.lax.dynamic_update_index_in_dim(
+                delta, jnp.where(keep[..., None], st_new, st), ordinal, 0)
 
     def linear(carry, layer, ordinal):
         x, conv, delta = carry
@@ -692,15 +731,12 @@ def paged_decode_step(params, pk, pv, state, tokens, positions, tables,
         with jax.named_scope("serve/linear_attn"):
             tail = jax.lax.dynamic_index_in_dim(
                 conv, ordinal, 0, keepdims=False)
-            st = jax.lax.dynamic_index_in_dim(
-                delta, ordinal, 0, keepdims=False)
-            mixed, tail_new, st_new = linear_step(
-                x[:, 0], layer, tail, st, cfg)
+            mixed, tail_new, delta = linear_step(
+                x[:, 0], layer, tail,
+                functools.partial(step, delta, ordinal), cfg)
             conv = jax.lax.dynamic_update_index_in_dim(
                 conv, jnp.where(keep, tail_new.astype(conv.dtype), tail),
                 ordinal, 0)
-            delta = jax.lax.dynamic_update_index_in_dim(
-                delta, jnp.where(keep[..., None], st_new, st), ordinal, 0)
         return (block(x, mixed[:, None], layer, cfg), conv, delta), ()
 
     def full(carry, layer, ordinal):

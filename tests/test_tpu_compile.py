@@ -30,6 +30,8 @@ describes it keeps the TPU's library until it exits.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -602,9 +604,14 @@ def test_the_hybrid_programs_compile_for_v5e_and_fit_beside_their_pool(
     prompt chunk, and the bucketed prefill of a round. The pool's arrays
     and the state come back aliased to their arguments, the decode step's
     read is the paged kernel (one form, lowered once, called from the
-    four full layers), a chunk's read keeps the loop (a slot reaches
-    1,808 keys, under ``decode.CHUNK_KERNEL_REACH``), and what a program
-    needs beside its arguments and results is activations and a
+    four full layers) and its delta rule the kernel over the stacked
+    ``S`` (``ops/delta_rule.py``: lowered once, called from a period's
+    three unrolled linear layers, the state aliased through every call:
+    no copy of its 0.88 GB among the temporaries), a chunk's read keeps
+    the loop (a slot reaches 1,808 keys, under
+    ``decode.CHUNK_KERNEL_REACH``) and a prompt's delta rule the chunked
+    form (no such kernel in the chunk programs or the prefill), and what
+    a program needs beside its arguments and results is activations and a
     sub-chunk's products, never a copy of a layer's weights, of the tails
     or of ``S`` (a period's leaves sliced out of the stack, or the tails
     under their own shape, made the compiler copy 1.2 to 1.7 GB a call:
@@ -645,11 +652,16 @@ def test_the_hybrid_programs_compile_for_v5e_and_fit_beside_their_pool(
         assert round(weights / 1e9, 2) == 8.2
         assert round(kv_bytes / 1e9, 2) == 3.79       # 4 layers deep
     if program == "decode_step":
-        assert lowered.as_text().count("func.func private @paged_read") == 1
+        for kernel in ("paged_read", "delta_state_step"):
+            assert lowered.as_text().count(
+                f"func.func private @{kernel}") == 1
     compiled = lowered.compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     print(program, memory.temp_size_in_bytes, memory.output_size_in_bytes)
     assert ("%paged_read" in text) == (program == "decode_step")
+    # One call a linear layer of the unrolled period.
+    assert len(re.findall(r"%delta_state_step\.\d+ = ", text)) == (
+        3 if program == "decode_step" else 0)
     assert "%paged_chunk_read" not in text
     assert memory.temp_size_in_bytes < 0.25e9
     if kind[0] == "prefill":
